@@ -50,6 +50,7 @@ from presto_tpu.ops.join import (
 )
 from presto_tpu.ops import pallas_join
 from presto_tpu.ops.hashing import bloom_build
+from presto_tpu.ops.pallas_mode import count_program
 from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.spi import batch_capacity
 
@@ -374,6 +375,7 @@ class LookupJoinOperator(Operator):
         if self._strategy is None:
             self._strategy = name
             REGISTRY.counter(f"join.strategy.{name}").add()
+            count_program("join", name == "pallas")
             if name == "pallas":
                 REGISTRY.counter("exec.pallas_join_route").add()
 
@@ -409,10 +411,7 @@ class LookupJoinOperator(Operator):
             return False
         if not pallas_join.key_dtype_ok(batch[k.name].data.dtype):
             return False
-        if pallas_join.probe_block(batch.capacity) is None:
-            return False
-        return pallas_join.probe_ok(spec.mode, build.pallas_side[0].shape[0],
-                                    len(self.build_outputs), spec.nbits)
+        return pallas_join.probe_block(batch.capacity) is not None
 
     def _ensure_pallas_step(self):
         from presto_tpu.cache.exec_cache import EXEC_CACHE, trace_probe

@@ -880,11 +880,8 @@ def execute_leaf_route_distributed(route: LeafRoute, executor, node,
     shard_cap = b.capacity // max(executor.nworkers, 1)
     if route.kind == "q1":
         from presto_tpu.ops import pallas_q1
-        from presto_tpu.ops.strings import use_pallas
 
-        pallas_ok = (use_pallas() and jax.default_backend() == "tpu"
-                     and pallas_q1.supported(b)
-                     and pallas_q1.probe_supported(shard_cap))
+        pallas_ok = pallas_q1.pallas_eligible(b, cap=shard_cap)
     else:
         from presto_tpu.ops.pallas_agg import pallas_eligible
 

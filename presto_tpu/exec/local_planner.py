@@ -186,7 +186,7 @@ class LocalExecutor(OomLadderMixin):
     def __init__(self, catalog: Catalog, join_build_budget: int | None = None,
                  direct_group_limit: int = DIRECT_LIMIT,
                  runtime_join_filters: bool = True,
-                 pallas_join_enabled: bool = True,
+                 pallas_join_enabled: bool = False,
                  approx_join: bool = False,
                  scan_sample_fraction: float = 1.0,
                  spill_host_budget: int | None = None):
@@ -863,10 +863,10 @@ class LocalExecutor(OomLadderMixin):
         None when no kernel mode fits. Exact modes first; the sketch
         (approximate) mode only under ``approx_join``, only for semi
         joins, and only when no exact table fits."""
-        if not self.pallas_join_enabled:
-            return None
         from presto_tpu.ops import pallas_join
 
+        if not (self.pallas_join_enabled and pallas_join.available()):
+            return None
         if iv is not None and pallas_join.interval_ok(int(iv[0]), int(iv[1])):
             lo, hi = int(iv[0]), int(iv[1])
             domain = hi - lo + 1

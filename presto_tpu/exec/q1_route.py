@@ -321,11 +321,8 @@ def execute_q1_route(route: Q1Route, catalog, aggs) -> Optional[list[Batch]]:
             # on tracers, so deciding inside the jitted step would
             # silently pin the route to the XLA twin on TPU
             from presto_tpu.ops import pallas_q1
-            from presto_tpu.ops.strings import use_pallas
 
-            pallas_ok = (use_pallas() and jax.default_backend() == "tpu"
-                         and pallas_q1.supported(b)
-                         and pallas_q1.probe_supported(cap))
+            pallas_ok = pallas_q1.pallas_eligible(b)
             step = EXEC_CACHE.get_or_build(
                 EXEC_CACHE.key_of("q1_route_step", pallas_ok,
                                   jax.default_backend()),

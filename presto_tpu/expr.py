@@ -897,16 +897,17 @@ def _like(args, out):
 
     target, pat = args
     if target.dtype.kind is TypeKind.BYTES:
+        from presto_tpu.ops.pallas_mode import count_program
+        from presto_tpu.ops.pallas_strings import (
+            like_mask_pallas,
+            like_supported,
+        )
         from presto_tpu.ops.strings import like_mask, use_pallas
 
-        if use_pallas():
-            from presto_tpu.ops.pallas_strings import (
-                like_mask_pallas,
-                like_supported,
-            )
-
-            if like_supported(pat.data, target.data.shape[1]):
-                return like_mask_pallas(target.data, pat.data), None
+        pallas_ok = use_pallas() and like_supported(pat.data)
+        count_program("strings", pallas_ok)
+        if pallas_ok:
+            return like_mask_pallas(target.data, pat.data), None
         return like_mask(target.data, pat.data), None
     if target.dictionary is None:
         raise NotImplementedError("LIKE on dictionary-less VARCHAR")
@@ -919,16 +920,14 @@ def _like(args, out):
 def _starts_with(args, out):
     target, pref = args
     if target.dtype.kind is TypeKind.BYTES:
+        from presto_tpu.ops.pallas_mode import count_program
+        from presto_tpu.ops.pallas_strings import starts_with_pallas
         from presto_tpu.ops.strings import starts_with_mask, use_pallas
 
-        if use_pallas():
-            from presto_tpu.ops.pallas_strings import (
-                starts_with_pallas,
-                starts_with_supported,
-            )
-
-            if starts_with_supported(pref.data, target.data.shape[1]):
-                return starts_with_pallas(target.data, pref.data), None
+        pallas_ok = use_pallas()
+        count_program("strings", pallas_ok)
+        if pallas_ok:
+            return starts_with_pallas(target.data, pref.data), None
         return starts_with_mask(target.data, pref.data), None
     if target.dictionary is None:
         raise NotImplementedError("starts_with on dictionary-less VARCHAR")

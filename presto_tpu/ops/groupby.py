@@ -266,7 +266,7 @@ def fused_small_sums(values, bits_list, contribs, gids, max_groups: int,
     lane-chunk aligned, the whole computation runs as ONE Pallas pass
     (ops.pallas_groupby) — the XLA einsum below materializes the lane
     matrix + one-hot in HBM (~6 round trips; measured 73 ms vs ~20 ms
-    for 60M rows). Falls back here when the compile probe fails.
+    for 60M rows). Statically ineligible shapes take the einsum.
     """
     # identical mask objects (e.g. one ``live`` reused for every
     # aggregate) get ONE count column — slots map back through uniq
@@ -280,43 +280,44 @@ def fused_small_sums(values, bits_list, contribs, gids, max_groups: int,
             mask_cols.append(m)
         slot.append(uniq[id(m)])
 
+    from presto_tpu.ops import pallas_groupby as PG
+    from presto_tpu.ops.pallas_mode import count_program
+    from presto_tpu.ops.strings import use_pallas
+
     pallas_ok = (
         all(not jnp.issubdtype(v.dtype, jnp.floating) for v in values)
         and all(b <= 31 for b in bits_list)
+        and use_pallas()
     )
     if pallas_ok:
-        from presto_tpu.ops.strings import use_pallas
-
-        pallas_ok = use_pallas()
-    if pallas_ok:
-        from presto_tpu.ops import pallas_groupby as PG
-
         eff_bits = [
             min(b, jnp.iinfo(v.dtype).bits - 1)
             for v, b in zip(values, bits_list)
         ]
-        if PG.probe_supported(eff_bits, len(mask_cols), max_groups,
-                              gids.shape[0]):
-            # bound check on the ORIGINAL dtype, before the int32 cast
-            # (a wide value would wrap and dodge the in-kernel check);
-            # XLA fuses this into the zeroing pass below
-            oflow = jnp.zeros((), jnp.bool_)
-            for v, c, eb in zip(values, contribs, eff_bits):
-                if eb < jnp.iinfo(v.dtype).bits - 1:
-                    oflow = oflow | jnp.any(
-                        jnp.where(c, jnp.abs(v) >> eb, 0) != 0)
-            zeroed = [
-                jnp.where(c, v, 0).astype(jnp.int32)
-                for v, c in zip(values, contribs)
-            ]
-            sums, counts_all, k_oflow = PG.fused_lane_sums(
-                zeroed, eff_bits, mask_cols, gids.astype(jnp.int32),
-                max_groups,
-            )
-            counts = [counts_all[slot[i]] for i in range(len(contribs))]
-            extra = [counts_all[slot[len(contribs) + i]]
-                     for i in range(len(extra_count_masks))]
-            return sums, counts, extra, oflow | k_oflow
+        pallas_ok = PG.lane_sums_supported(eff_bits, len(mask_cols),
+                                           max_groups, gids.shape[0])
+    count_program("groupby", pallas_ok)
+    if pallas_ok:
+        # bound check on the ORIGINAL dtype, before the int32 cast
+        # (a wide value would wrap and dodge the in-kernel check);
+        # XLA fuses this into the zeroing pass below
+        oflow = jnp.zeros((), jnp.bool_)
+        for v, c, eb in zip(values, contribs, eff_bits):
+            if eb < jnp.iinfo(v.dtype).bits - 1:
+                oflow = oflow | jnp.any(
+                    jnp.where(c, jnp.abs(v) >> eb, 0) != 0)
+        zeroed = [
+            jnp.where(c, v, 0).astype(jnp.int32)
+            for v, c in zip(values, contribs)
+        ]
+        sums, counts_all, k_oflow = PG.fused_lane_sums(
+            zeroed, eff_bits, mask_cols, gids.astype(jnp.int32),
+            max_groups,
+        )
+        counts = [counts_all[slot[i]] for i in range(len(contribs))]
+        extra = [counts_all[slot[len(contribs) + i]]
+                 for i in range(len(extra_count_masks))]
+        return sums, counts, extra, oflow | k_oflow
 
     lane_cols = []
     spans = []

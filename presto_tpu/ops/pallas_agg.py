@@ -49,7 +49,6 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
@@ -62,6 +61,7 @@ from presto_tpu.ops.pallas_groupby import (
     rsum32,
     slots_pallas_call,
 )
+from presto_tpu.ops.pallas_mode import count_program, kernel_mode
 
 #: slot budget: groups * (total value lanes + 1 count) + 1 overflow
 #: must fit the shared (1, 1, 1024) output tile
@@ -192,12 +192,23 @@ def _kernel(spec: LeafAggSpec, spm, *refs):
         if hi is not None:
             live = live & (c <= np.int32(hi))
 
+    # Mosaic refuses a select whose two value operands are BOTH splat
+    # constants under a varying predicate ("Invalid relayout:
+    # Non-singleton logical dimension is replicated in destination but
+    # not in source" on the i1 mask) — the keyless gid, the dead-row
+    # zeroing of each value (a splat for ``sum(1)``) and the guard-free
+    # badrow sum below are therefore arithmetic on the mask as int32,
+    # never ``where(live, const, const)``
+    livei = live.astype(jnp.int32)
     G = np.int32(spec.groups)
-    gid = jnp.zeros_like(cols[0]) if not spec.keys else None
-    for ci, lo, stride in spec.keys:
-        t = (cols[ci] - np.int32(lo)) * np.int32(stride)
-        gid = t if gid is None else gid + t
-    gid = jnp.where(live, gid, G)
+    if spec.keys:
+        gid = None
+        for ci, lo, stride in spec.keys:
+            t = (cols[ci] - np.int32(lo)) * np.int32(stride)
+            gid = t if gid is None else gid + t
+        gid = jnp.where(live, gid, G)
+    else:
+        gid = G - G * livei
 
     # declared-bounds guard (advisory stats' runtime check): a live row
     # outside its declared interval could wrap the int32 products the
@@ -223,7 +234,7 @@ def _kernel(spec: LeafAggSpec, spm, *refs):
         val = term(v.a)
         if v.b is not None:
             val = val * term(v.b)
-        val = jnp.where(live, val, zero)
+        val = val * livei
         neg = val < 0
         mag = jnp.abs(val)
         bits = min(v.bits, 31)
@@ -239,7 +250,7 @@ def _kernel(spec: LeafAggSpec, spm, *refs):
         for lane in lanes:
             scalars.append(rsum32(jnp.where(m, lane, zero)))
         scalars.append(rsum32(m.astype(jnp.int32)))
-    scalars.append(rsum32(jnp.where(live, badrow, zero)))
+    scalars.append(rsum32(badrow * livei))
     emit_slots(o_ref, i, spm, scalars)
 
 
@@ -250,10 +261,8 @@ def _pallas_step(spec: LeafAggSpec, batch, interpret: bool | None = None):
     B = _block_rows(spec, cap)
     args = [batch[c].data for c in spec.cols]
     args.append(batch.live.astype(jnp.int8))
-    o = slots_pallas_call(
-        partial(_kernel, spec), args, cap, B,
-        interpret=(jax.default_backend() != "tpu"
-                   if interpret is None else interpret))
+    o = slots_pallas_call(partial(_kernel, spec), args, cap, B,
+                          interpret=interpret)
     G = spec.groups
     nl = spec.nlanes
     per_g = o[: G * (sum(nl) + 1)].reshape(G, sum(nl) + 1)
@@ -346,10 +355,10 @@ def _xla_step(spec: LeafAggSpec, batch):
 def agg_step(spec: LeafAggSpec, batch, pallas_ok: bool | None = None):
     """One fused partial-aggregation step over ``batch``: the Pallas
     kernel on TPU when eligible (sum-only, narrow NULL-free columns,
-    aligned capacity, compile probe green), the fused XLA twin
-    otherwise. Returns a dict of [groups] states: one ``{op}_{i}`` per
-    value aggregate, ``count`` (live rows per group), ``present``, and
-    the ``value_overflow`` flag callers MUST honor by falling back.
+    aligned capacity), the fused XLA twin otherwise. Returns a dict of
+    [groups] states: one ``{op}_{i}`` per value aggregate, ``count``
+    (live rows per group), ``present``, and the ``value_overflow`` flag
+    callers MUST honor by falling back.
 
     ``pallas_ok``: the hoisted eligibility decision (see
     :func:`pallas_eligible`). Callers tracing this inside jit/shard_map
@@ -357,6 +366,7 @@ def agg_step(spec: LeafAggSpec, batch, pallas_ok: bool | None = None):
     batches (tracer identity breaks the shared-mask test)."""
     if pallas_ok is None:
         pallas_ok = pallas_eligible(spec, batch)
+    count_program("leaf_agg", pallas_ok)
     if pallas_ok:
         return _pallas_step(spec, batch)
     return _xla_step(spec, batch)
@@ -379,14 +389,13 @@ def null_violation(batch):
 
 def pallas_eligible(spec: LeafAggSpec, batch, cap: int | None = None) -> bool:
     """The full hoisted Pallas decision for a CONCRETE batch: toggle,
-    backend, static spec/batch eligibility, and the compile probe.
+    backend and static spec/batch eligibility. A kernel admitted here
+    compiles, or the query fails with the compiler's error.
     ``cap``: per-device capacity for sharded execution."""
     from presto_tpu.ops.strings import use_pallas
 
-    return (use_pallas() and jax.default_backend() == "tpu"
-            and kernel_supported(spec, batch, cap)
-            and probe_supported(spec,
-                                cap if cap is not None else batch.capacity))
+    return (use_pallas() and kernel_mode() == "mosaic"
+            and kernel_supported(spec, batch, cap))
 
 
 def combine_states(spec: LeafAggSpec, a: dict, b: dict) -> dict:
@@ -404,38 +413,3 @@ def combine_states(spec: LeafAggSpec, a: dict, b: dict) -> dict:
     out["present"] = a["present"] | b["present"]
     out["value_overflow"] = a["value_overflow"] | b["value_overflow"]
     return out
-
-
-# -- compile probe (contract shared with ops.pallas_groupby's): the
-# remote Mosaic helper can reject valid programs; callers fall back to
-# the XLA twin visibly, never silently -------------------------------------
-
-_PROBE: dict = {}
-
-
-def probe_supported(spec: LeafAggSpec, cap: int) -> bool:
-    if jax.default_backend() != "tpu":
-        return True
-    B = _block_rows(spec, cap)
-    if B is None:
-        return False
-    key = (spec, B)
-    if key not in _PROBE:
-        try:
-            from presto_tpu.batch import Batch, Column
-            from presto_tpu.types import BIGINT
-
-            c = 2 * B  # two blocks: the accumulate branch compiles too
-            cols = {name: Column(jnp.ones(c, jnp.int32), None, BIGINT)
-                    for name in spec.cols}
-            bt = Batch(cols, jnp.ones(c, jnp.bool_))
-            jax.block_until_ready(_pallas_step(spec, bt))
-            _PROBE[key] = True
-        except Exception as e:  # noqa: BLE001 — fallback must be visible
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "pallas leaf-agg kernel probe failed (falling back to the "
-                "fused XLA step): %s: %s", type(e).__name__, e)
-            _PROBE[key] = False
-    return _PROBE[key]

@@ -44,9 +44,12 @@ the table; gather indices are clipped and the clipped lookup is masked
 by that exact in-range bit.
 
 The Mosaic/x64 scaffolding (int32-pinned literals and index maps,
-keepdims reductions, per-major accumulation, compile probes with
-visible fallback) follows ops/pallas_groupby.py, which documents each
-workaround.
+keepdims reductions, per-major accumulation) follows
+ops/pallas_groupby.py, which documents each workaround.
+
+STATUS: the chip's compiler refuses the gather these kernels are built
+on (see :func:`available`), so on a TPU backend the route is statically
+off and the XLA probes run; the kernels execute in interpret mode only.
 """
 
 from __future__ import annotations
@@ -60,8 +63,8 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
+from presto_tpu.ops import pallas_mode
 from presto_tpu.ops.hashing import mix32_slots
-from presto_tpu.ops.pallas_groupby import emit_slots
 
 _I0 = np.int32(0)
 _LANES = 128
@@ -76,8 +79,23 @@ _INT32_MIN = -(1 << 31)
 _INT32_MAX = (1 << 31) - 1
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def available() -> bool:
+    """Static backend admission for every probe mode in this module.
+
+    The installed Mosaic (jaxlib 0.9.0 / libtpu 0.0.34) lowers
+    ``tpu.dynamic_gather`` only when table and index block have the
+    SAME shape (``_gather_lowering_rule`` asserts ``indices.shape ==
+    operand.shape + (1,)``) and, cut to that form, only within ONE
+    source vreg along the gather dimension ("Not implemented: Multiple
+    source vregs along gather dimension" for any chunk taller than 8
+    sublanes) — a [w, 128] replicated table would need w/8 gathers per
+    index vreg. So on a TPU backend these kernels are statically OUT
+    of the route (the planner and EXPLAIN ask here, nothing is
+    compiled to find out; with no spec planned the build publishes no
+    table) and joins run the XLA dense/sorted/expansion probes.
+    Off-TPU the same bodies run in Pallas interpret mode, which is
+    what the tests exercise."""
+    return pallas_mode.kernel_mode() == "interpret"
 
 
 def _pad8(n: int) -> int:
@@ -193,8 +211,7 @@ def _replicate(flat):
     return jnp.broadcast_to(flat[:, None], (flat.shape[0], _LANES))
 
 
-def build_exists_table(keys, live, key_min: int, key_max: int,
-                       pad_words: int | None = None):
+def build_exists_table(keys, live, key_min: int, key_max: int):
     """Replicated [W, 128] int32 bitmask over the key domain.
 
     Returns (table, oob): ``oob`` is True when some LIVE key fell
@@ -203,8 +220,6 @@ def build_exists_table(keys, live, key_min: int, key_max: int,
     Duplicate keys are fine (existence semantics)."""
     domain = key_max - key_min + 1
     w = exists_words(domain)
-    if pad_words is not None:
-        w = pad_words
     k = keys.astype(jnp.int64)
     slot = k - np.int64(key_min)
     inr = (slot >= 0) & (slot < domain)
@@ -341,7 +356,7 @@ def exists_probe(table, key_min: int, key_max: int, keys, live,
                   pl.BlockSpec((sp, _LANES), lambda i: (i, _I0))],
         out_specs=pl.BlockSpec((sp, _LANES), lambda i: (i, _I0)),
         out_shape=jax.ShapeDtypeStruct((nblk * sp, _LANES), jnp.int8),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=pallas_mode.interpret(interpret),
     )(table, _blocked(keys, nblk, sp), _blocked(live.astype(jnp.int8),
                                                 nblk, sp))
     return out.reshape(cap) != 0
@@ -363,7 +378,7 @@ def sketch_probe(table, nbits: int, keys, live,
                   pl.BlockSpec((sp, _LANES), lambda i: (i, _I0))],
         out_specs=pl.BlockSpec((sp, _LANES), lambda i: (i, _I0)),
         out_shape=jax.ShapeDtypeStruct((nblk * sp, _LANES), jnp.int8),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=pallas_mode.interpret(interpret),
     )(table, _blocked(keys, nblk, sp), _blocked(live.astype(jnp.int8),
                                                 nblk, sp))
     return out.reshape(cap) != 0
@@ -392,162 +407,8 @@ def payload_probe(tables, key_min: int, key_max: int, keys, live,
         out_shape=[jax.ShapeDtypeStruct((nblk * sp, _LANES), jnp.int8)]
         + [jax.ShapeDtypeStruct((nblk * sp, _LANES), jnp.int32)
            for _ in range(nval)],
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=pallas_mode.interpret(interpret),
     )(*tables, _blocked(keys, nblk, sp), _blocked(live.astype(jnp.int8),
                                                   nblk, sp))
     matched = outs[0].reshape(cap) != 0
     return matched, [o.reshape(cap) for o in outs[1:]]
-
-
-# ---------------------------------------------------------------------------
-# Q3 bench kernel: partitioned bitmask probe + fused filter + agg.
-# The engine modes above cap the domain at the VMEM budget; the bench's
-# SF1 o_orderkey domain (6M) exceeds it, so this kernel PARTITIONS the
-# bitmask across the outer grid dimension: partition p's 8 MB table
-# slice loads once while every probe block streams past it (probe rows
-# re-read nparts times — still HBM-sequential, no per-element gather).
-# Each key lands in exactly one partition, so count/sum partials are
-# exact; revenue = ep*(100-disc) < 2^31 (ep < 2^24, disc in [0,100],
-# the Q1 kernel's proven bounds) splits into four unsigned 8-bit lanes
-# accumulated int32-exactly per <= 2^23-row output major (255 * 2^23 <
-# 2^31), recombined in int64 outside — ops/pallas_q1's arithmetic.
-# ---------------------------------------------------------------------------
-
-_MAJOR_ROWS = 1 << 23
-_SLOTS = 1024
-#: bench probe sublanes (2^16 rows/block: 12B/row double-buffered
-#: inputs ~1.6 MB beside the 8 MB table slice)
-_Q3_SP = 512
-
-
-def q3_partitions(domain: int, wmax: int | None = None) -> tuple[int, int]:
-    """(words per partition, partition count) covering ``domain``.
-    ``wmax`` overrides the budget-derived partition width — the bench's
-    compile-retry ladder shrinks it when Mosaic rejects the big table
-    shape."""
-    if wmax is None:
-        wmax = _TABLE_BUDGET // (_LANES * 4)
-    words = -(-domain // 32)
-    nparts = -(-words // wmax)
-    return wmax, nparts
-
-
-def _rsum2d(x):
-    """(sp, 128) int32 block -> (1, 1, 1) via per-axis keepdims sums
-    (never a rank-0 reduce primitive — the Mosaic rule rsum32 follows
-    for 3-D blocks)."""
-    s = jnp.sum(x, axis=1, dtype=jnp.int32, keepdims=True)
-    return jnp.sum(s, axis=0, dtype=jnp.int32, keepdims=True).reshape(1, 1, 1)
-
-
-def _q3_kernel(kmin, w, nblk, spm, cutoff, *refs):
-    tab_ref, key_ref, ship_ref, ep_ref, disc_ref, live_ref, o_ref = refs
-    p = pl.program_id(0)
-    b = pl.program_id(1)
-    keys = key_ref[...].astype(jnp.int32)
-    live = (live_ref[...] != 0) & (ship_ref[...].astype(jnp.int32) > cutoff)
-    slot = keys - kmin
-    # the bench key domain is stats-proven (the build asserts oob), so
-    # slot is exact; partition membership selects each key once
-    word = (slot >> np.int32(5)) - p * np.int32(w)
-    inp = live & (word >= 0) & (word < np.int32(w))
-    hit = _bit_test(tab_ref[...], jnp.clip(word, _I0, np.int32(w - 1)),
-                    slot & np.int32(31)) & inp
-    ep = jnp.where(hit, ep_ref[...].astype(jnp.int32), _I0)
-    rev = ep * (np.int32(100) - disc_ref[...].astype(jnp.int32))
-    scalars = [_rsum2d(hit.astype(jnp.int32))]
-    for k in range(4):
-        scalars.append(_rsum2d((rev >> np.int32(8 * k)) & np.int32(255)))
-    emit_slots(o_ref, p * np.int32(nblk) + b, spm, scalars)
-
-
-def q3_probe_step(table, key_min: int, domain: int, cutoff: int, lb,
-                  interpret: bool | None = None, wmax: int | None = None):
-    """Fused Q3 probe: shipdate filter + membership + revenue agg in
-    one pass. ``table`` is the (padded, partition-concatenated)
-    replicated bitmask from ``build_exists_table(pad_words=w*nparts)``.
-    Returns (matched_count, revenue) int64 — revenue at scale 4."""
-    cap = lb.capacity
-    sp = min(_Q3_SP, probe_block(cap) or 0)
-    assert sp, f"bench capacity {cap} cannot block"
-    # revenue int32-exactness proof (the pallas_q1 lane discipline):
-    # rev = ep * (100 - disc) with ep < 2^24 and disc in [0, 100]
-    # (the Q1 kernel's proven TPC-H bounds) gives 0 <= rev <= 100*2^24
-    # < 2^31 — the int32 product cannot wrap; each 8-bit lane partial
-    # is <= 255 per row and a major accumulates <= _MAJOR_ROWS = 2^23
-    # rows, so 255 * 2^23 < 2^31 keeps every per-major int32 sum exact
-    # (recombined in int64 below). Violated bounds cannot happen from
-    # the bench's stats-narrowed put_table arrays; engine routes never
-    # reach this kernel (it is bench-only), so the guard is the pair
-    # of static asserts + the oracle validation in bench_q3_join.
-    assert _MAJOR_ROWS * 255 < (1 << 31) and 100 * (1 << 24) < (1 << 31)
-    nblk = cap // (sp * _LANES)
-    w, nparts = q3_partitions(domain, wmax)
-    if nparts == 1:
-        w = table.shape[0]
-    B = sp * _LANES
-    spm = max(1, _MAJOR_ROWS // B)
-    nmajor = -(-(nparts * nblk) // spm)
-    args = [lb[c].data for c in ("l_orderkey", "l_shipdate",
-                                 "l_extendedprice", "l_discount")]
-    args.append(lb.live.astype(jnp.int8))
-    out = pl.pallas_call(
-        partial(_q3_kernel, np.int32(key_min), w, nblk, np.int32(spm),
-                np.int32(cutoff)),
-        grid=(nparts, nblk),
-        in_specs=[pl.BlockSpec((w, _LANES), lambda p, b: (p, _I0))]
-        + [pl.BlockSpec((sp, _LANES), lambda p, b: (b, _I0)) for _ in args],
-        out_specs=pl.BlockSpec(
-            (1, 1, _SLOTS),
-            lambda p, b: ((p * np.int32(nblk) + b) // np.int32(spm),
-                          _I0, _I0)),
-        out_shape=jax.ShapeDtypeStruct((nmajor, 1, _SLOTS), jnp.int32),
-        interpret=_interpret() if interpret is None else interpret,
-    )(table, *[_blocked(a, nblk, sp) for a in args])
-    tot = out.astype(jnp.int64).sum(axis=(0, 1))
-    rev = sum(tot[1 + k] << (8 * k) for k in range(4))
-    return tot[0], rev
-
-
-# ---------------------------------------------------------------------------
-# Compile probes: the remote Mosaic helper can reject valid programs;
-# callers fall back visibly (the pallas_groupby pattern). Keyed by the
-# kernel configuration — the compiled artifact is shape-generic beyond
-# the block/table shapes.
-# ---------------------------------------------------------------------------
-
-_PROBE_CACHE: dict = {}
-
-
-def probe_ok(mode: str, table_rows: int, nval: int = 0,
-             nbits: int = SKETCH_BITS) -> bool:
-    """One tiny compile of the mode's kernel on the live backend."""
-    if _interpret():
-        return True
-    key = (mode, table_rows, nval, nbits if mode == "sketch" else 0)
-    if key not in _PROBE_CACHE:
-        try:
-            cap = 8 * _LANES
-            keys = jnp.zeros(cap, jnp.int32)
-            live = jnp.ones(cap, jnp.bool_)
-            if mode == "exists":
-                tab = jnp.zeros((table_rows, _LANES), jnp.int32)
-                jax.block_until_ready(
-                    exists_probe(tab, 0, table_rows * 32 - 1, keys, live))
-            elif mode == "sketch":
-                tab = jnp.zeros((nbits // 32, _LANES), jnp.int32)
-                jax.block_until_ready(sketch_probe(tab, nbits, keys, live))
-            else:
-                tabs = tuple(jnp.zeros((table_rows, _LANES), jnp.int32)
-                             for _ in range(1 + nval))
-                jax.block_until_ready(
-                    payload_probe(tabs, 0, table_rows - 1, keys, live))
-            _PROBE_CACHE[key] = True
-        except Exception as e:  # noqa: BLE001 — fallback must be visible
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "pallas join kernel probe failed (%s; falling back to the "
-                "XLA join paths): %s: %s", mode, type(e).__name__, e)
-            _PROBE_CACHE[key] = False
-    return _PROBE_CACHE[key]

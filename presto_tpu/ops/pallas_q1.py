@@ -34,7 +34,6 @@ workaround.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
@@ -64,8 +63,9 @@ def _block_rows(cap: int) -> int | None:
     return None
 
 
-def supported(batch) -> bool:
-    """Static eligibility: narrow integer columns, aligned capacity.
+def supported(batch, cap: int | None = None) -> bool:
+    """Static eligibility: narrow integer columns, aligned capacity
+    (``cap``: per-device capacity for sharded execution).
 
     Since stats-driven narrow storage became the engine's native scan
     representation (ISSUE-5), the SQL tier's canonical lineitem batch
@@ -90,7 +90,20 @@ def supported(batch) -> bool:
         # values the generic route excludes
         if col.valid is not None and col.valid is not batch.live:
             return False
-    return _block_rows(batch.capacity) is not None
+    return _block_rows(cap if cap is not None else batch.capacity) \
+        is not None
+
+
+def pallas_eligible(batch, cap: int | None = None) -> bool:
+    """The full hoisted decision for a CONCRETE batch (the identity
+    check in :func:`supported` breaks on tracers): toggle, backend and
+    static eligibility. An admitted kernel compiles, or the query
+    fails with the compiler's error."""
+    from presto_tpu.ops.pallas_mode import kernel_mode
+    from presto_tpu.ops.strings import use_pallas
+
+    return (use_pallas() and kernel_mode() == "mosaic"
+            and supported(batch, cap))
 
 
 def _divmod100(dp):
@@ -180,10 +193,7 @@ def q1_step(batch, interpret: bool | None = None):
         "l_shipdate", "l_returnflag", "l_linestatus", "l_quantity",
         "l_extendedprice", "l_discount", "l_tax")]
     args.append(batch.live.astype(jnp.int8))
-    o = slots_pallas_call(
-        _kernel, args, cap, B,
-        interpret=(jax.default_backend() != "tpu"
-                   if interpret is None else interpret))
+    o = slots_pallas_call(_kernel, args, cap, B, interpret=interpret)
     per_g = o[: G * (_NL + 1)].reshape(G, _NL + 1)
     names = ("sum_qty", "sum_base_price", "sum_disc_price", "sum_charge",
              "sum_disc")
@@ -199,42 +209,3 @@ def q1_step(batch, interpret: bool | None = None):
     res["present"] = res["count_order"] > 0
     res["value_overflow"] = o[G * (_NL + 1)] != 0
     return res
-
-
-# -- compile probe (same contract as ops.pallas_groupby's): the remote
-# Mosaic helper can reject valid programs; callers fall back visibly --
-
-_PROBE: dict = {}
-
-
-def probe_supported(cap: int) -> bool:
-    if jax.default_backend() != "tpu":
-        return True
-    B = _block_rows(cap)
-    if B is None:
-        return False
-    if B not in _PROBE:
-        try:
-            from presto_tpu.batch import Batch, Column
-            from presto_tpu.types import BIGINT
-
-            c = 2 * B
-            mk = {
-                "l_shipdate": jnp.int16, "l_returnflag": jnp.int8,
-                "l_linestatus": jnp.int8, "l_quantity": jnp.int16,
-                "l_extendedprice": jnp.int32, "l_discount": jnp.int8,
-                "l_tax": jnp.int8,
-            }
-            cols = {k: Column(jnp.ones(c, dt), None, BIGINT)
-                    for k, dt in mk.items()}
-            b = Batch(cols, jnp.ones(c, jnp.bool_))
-            jax.block_until_ready(q1_step(b))
-            _PROBE[B] = True
-        except Exception as e:  # noqa: BLE001 — fallback must be visible
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "pallas Q1 kernel probe failed (falling back to the "
-                "generic route): %s: %s", type(e).__name__, e)
-            _PROBE[B] = False
-    return _PROBE[B]

@@ -24,23 +24,10 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401 — re-exported to the executors
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from presto_tpu.runtime.errors import UserError
-
-try:  # jax >= 0.6: top-level export, ``check_vma`` kwarg
-    from jax import shard_map
-except ImportError:  # jax 0.4/0.5: experimental module, ``check_rep`` kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-        """Compat wrapper: the engine's shard_map call shape (the
-        modern ``check_vma`` signature) on older jax releases."""
-        return _shard_map_exp(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma, **kw,
-        )
-
 
 WORKERS = "workers"
 DCN = "dcn"

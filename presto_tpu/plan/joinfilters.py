@@ -133,7 +133,8 @@ def filter_edges(plan: N.PlanNode) -> list[tuple[object, N.TableScan, str]]:
 def planned_join_strategy(node, catalog,
                           join_build_budget: int | None = None,
                           approx_join: bool = False,
-                          memo: "dict | None" = None) -> str:
+                          memo: "dict | None" = None,
+                          pallas_join_enabled: bool = False) -> str:
     """The probe strategy the executors will pick for this join, from
     stats alone: grouped (build over budget) > pallas (fused VMEM
     probe) > dense (direct-address table) > unique (sorted probe) >
@@ -146,6 +147,10 @@ def planned_join_strategy(node, catalog,
     ``sketch(approx)``, rendering the APPROXIMATE mode distinctly in
     EXPLAIN (the other half of the never-silently-approximate
     contract; QueryInfo.approximate is the runtime half).
+
+    ``pallas_join_enabled``: mirrors the ``pallas_join`` session
+    property (off by default), as the executor's ``_pallas_spec`` reads
+    it.
 
     ``memo``: optional per-walk estimate/interval cache
     (plan/bounds) — the estimate snapshot passes one dict over the
@@ -173,7 +178,12 @@ def planned_join_strategy(node, catalog,
         iv = expr_interval(node.right_keys[0],
                            node_intervals(node.right, catalog, memo))
     unique = True if semi else node.unique
-    if iv is not None and pallas_join.interval_ok(iv[0], iv[1]):
+    # the fused probes run only where the session asks for them AND
+    # Mosaic does not refuse their gather (every TPU backend does today
+    # — pallas_join.available): otherwise the strategy reported is the
+    # XLA probe that really runs
+    fused = pallas_join_enabled and pallas_join.available()
+    if fused and iv is not None and pallas_join.interval_ok(iv[0], iv[1]):
         domain = iv[1] - iv[0] + 1
         outs = () if semi else node.output_right
         if not outs and (semi or (unique and node.kind == "inner")) \
@@ -182,7 +192,7 @@ def planned_join_strategy(node, catalog,
         if outs and unique and node.kind in ("inner", "left") \
                 and pallas_join.payload_rows(domain, len(outs)):
             return "pallas"
-    if approx_join and semi and not node.negated:
+    if fused and approx_join and semi and not node.negated:
         # no exact fused table fit above: the executor's _pallas_spec
         # will hand the build a Bloom sketch — approximate, and said so
         return "sketch(approx)"

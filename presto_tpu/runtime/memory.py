@@ -24,11 +24,12 @@ import time
 from collections import deque
 
 from presto_tpu.plan import nodes as N
+from presto_tpu.runtime.errors import InternalError
 from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.types import DataType, TypeKind
 
-#: conservative default when the backend exposes no memory stats
-#: (v5e chip = 16 GB HBM; leave headroom for XLA scratch + outputs)
+#: assumed budget for the CPU backend, which exposes no memory stats
+#: (tests); a TPU that reports none is an error, never this value
 DEFAULT_BUDGET_BYTES = 8 << 30
 
 #: floor on the computed budget: a warm process whose allocator already
@@ -69,15 +70,19 @@ def device_budget_bytes(device=None) -> int:
     import jax
 
     dev = device or jax.devices()[0]
-    budget = DEFAULT_BUDGET_BYTES
-    try:
-        stats = dev.memory_stats()
-        if stats and "bytes_limit" in stats:
-            budget = int(stats["bytes_limit"] * 0.5)
-            budget -= int(stats.get("bytes_in_use", 0))
-            budget = max(budget, MIN_BUDGET_BYTES)
-    except Exception:  # noqa: BLE001 — CPU/interpret backends
-        pass
+    stats = dev.memory_stats()
+    if stats and "bytes_limit" in stats:
+        budget = int(stats["bytes_limit"] * 0.5)
+        budget -= int(stats.get("bytes_in_use", 0))
+        budget = max(budget, MIN_BUDGET_BYTES)
+    elif dev.platform == "tpu":
+        # a chip that does not say how much memory it has is a broken
+        # attachment, not a device to size queries against a guess
+        raise InternalError(
+            f"TPU device {dev} reports no bytes_limit "
+            f"(memory_stats() = {stats!r})")
+    else:
+        budget = DEFAULT_BUDGET_BYTES  # the CPU backend reports none
     if device is None:
         _DEFAULT_BUDGET = budget
     return budget

@@ -431,6 +431,16 @@ METRIC_HELP: dict[str, str] = {
         "health-watchdog sampling passes that raised (isolated)"),
     "slo.good": "SLO observations within objective (all tenants)",
     "slo.breach": "SLO observations over objective (all tenants)",
+    # ---- which program each kernel family ran (ops/pallas_mode.py)
+    **{
+        f"kernel.{fam}.{kind}": f"{fam} steps built from {what}"
+        for fam in ("q1", "leaf_agg", "groupby", "join", "strings")
+        for kind, what in (
+            ("mosaic", "the Mosaic-compiled Pallas kernel"),
+            ("interpret", "the Pallas kernel in interpret mode (no chip)"),
+            ("xla", "the XLA twin"),
+        )
+    },
     # ---- join strategy
     "join.filter_rows_in": (
         "probe rows entering join-pushdown filters"),

@@ -376,6 +376,7 @@ class Session:
         hints = self._plan_hints(plan)
         out = plan_tree_str(plan, catalog=self.catalog,
                             approx_join=bool(self.prop("approx_join")),
+                            pallas_join=bool(self.prop("pallas_join")),
                             plan_hints=hints,
                             agg_bypass=bool(self.prop("partial_agg_bypass")),
                             join_build_budget=self.prop(
@@ -793,6 +794,7 @@ class Session:
                     plan, self.catalog,
                     join_build_budget=self.prop("join_build_budget_bytes"),
                     approx_join=bool(self.prop("approx_join")),
+                    pallas_join=bool(self.prop("pallas_join")),
                     plan_hints=hints,
                     agg_bypass=bool(self.prop("partial_agg_bypass")),
                 )

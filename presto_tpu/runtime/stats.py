@@ -207,7 +207,8 @@ class StatsRecorder:
                          join_build_budget: Optional[int] = None,
                          approx_join: bool = False,
                          plan_hints: Optional[dict] = None,
-                         agg_bypass: bool = True) -> None:
+                         agg_bypass: bool = True,
+                         pallas_join: bool = False) -> None:
         """Snapshot the planner's per-node predictions BEFORE execution,
         keyed by the same stable node ids the actuals use: estimated
         rows (bounds.estimate_rows), the sound upper bound + exactness
@@ -243,7 +244,8 @@ class StatsRecorder:
                 try:
                     strategy = planned_join_strategy(
                         node, catalog, join_build_budget=join_build_budget,
-                        approx_join=approx_join, memo=memo)
+                        approx_join=approx_join, memo=memo,
+                        pallas_join_enabled=pallas_join)
                 except Exception:  # noqa: BLE001
                     strategy = ""
             elif isinstance(node, N.Aggregate):

@@ -116,12 +116,11 @@ def q1_fused_step(batch: Batch, pallas_ok: bool | None = None):
     (pytree flattening gives distinct tracers in-trace).
     """
     from presto_tpu.ops import pallas_q1
-    from presto_tpu.ops.strings import use_pallas
+    from presto_tpu.ops.pallas_mode import count_program
 
     if pallas_ok is None:
-        pallas_ok = (use_pallas() and jax.default_backend() == "tpu"
-                     and pallas_q1.supported(batch)
-                     and pallas_q1.probe_supported(batch.capacity))
+        pallas_ok = pallas_q1.pallas_eligible(batch)
+    count_program("q1", pallas_ok)
     if pallas_ok:
         # HandTpchQuery1 fast path: the whole fragment as one Pallas
         # pass (predicate, gid, decimals, lane split, segment sums in

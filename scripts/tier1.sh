@@ -260,7 +260,8 @@ from presto_tpu.runtime.session import Session
 
 conn = TpchConnector(sf=0.005)
 q = QUERIES["q3"]
-s_on = Session({"tpch": conn}, properties={"result_cache_enabled": False})
+s_on = Session({"tpch": conn}, properties={
+    "result_cache_enabled": False, "pallas_join": True})
 a = s_on.sql(q)
 snap = REGISTRY.snapshot()
 assert snap.get("exec.pallas_join_route", 0) >= 1, \

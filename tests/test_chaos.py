@@ -452,7 +452,9 @@ def test_chaos_mixed_ingest_subscriptions(conn, oracle):
             "retry_backoff_s": 0.0,
         },
     )
-    server = QueryServer(session=s)
+    # the approx tier's sketch is a fused Pallas probe: opt in
+    server = QueryServer(session=s,
+                         approx_properties={"pallas_join": True})
     w = StreamWriter(s)
     rng0 = np.random.default_rng(1717)
 

@@ -38,8 +38,12 @@ def conn():
 
 
 def _session(conn, **props):
+    """The fused probes are off by default (the chip's compiler refuses
+    them); off a TPU ``pallas_join`` runs them in interpret mode, which
+    is what this file tests."""
     return Session({"tpch": conn},
-                   properties={"result_cache_enabled": False, **props})
+                   properties={"result_cache_enabled": False,
+                               "pallas_join": True, **props})
 
 
 def _frames_equal(a: pd.DataFrame, b: pd.DataFrame):
@@ -286,6 +290,24 @@ def test_forced_grouped_oom_rung(conn):
     assert spilled > 0, "OOM rung did not route the spill tier"
     assert after.get("exec.pallas_join_route", 0) == before.get(
         "exec.pallas_join_route", 0), "forced spill rung must not route pallas"
+
+
+def test_default_session_plans_the_xla_probes(conn):
+    """``pallas_join`` is off by default, so a default plan reads the
+    same on the CPU and on the chip: EXPLAIN names the XLA probe that
+    runs and the fused route is not taken; the property opts in."""
+    s = Session({"tpch": conn}, properties={"result_cache_enabled": False})
+    assert "strategy=pallas" not in s.explain(QUERIES["q3"])
+    before = REGISTRY.snapshot()
+    want = s.sql(QUERIES["q3"])
+    after = REGISTRY.snapshot()
+    assert after.get("exec.pallas_join_route", 0) == before.get(
+        "exec.pallas_join_route", 0)
+    assert after.get("join.strategy.pallas", 0) == before.get(
+        "join.strategy.pallas", 0)
+    on = _session(conn)
+    assert "strategy=pallas" in on.explain(QUERIES["q3"])
+    _frames_equal(want, on.sql(QUERIES["q3"]))
 
 
 def test_explain_renders_strategy_and_filters(conn):

@@ -159,8 +159,9 @@ def test_double_stays_double_across_inserts():
 
 
 class _FakeDevice:
-    def __init__(self, stats):
+    def __init__(self, stats, platform="cpu"):
         self._stats = stats
+        self.platform = platform
 
     def memory_stats(self):
         return self._stats
@@ -182,15 +183,18 @@ def test_device_budget_subtracts_bytes_in_use():
     assert full == 256 << 20
 
 
-def test_device_budget_fallbacks():
+def test_device_budget_without_stats_cpu_assumes_tpu_fails():
+    """The assumed budget is for the CPU backend (which reports no
+    memory stats) only: a TPU that reports no ``bytes_limit`` is a
+    broken attachment and an error, never an 8 GiB guess."""
+    from presto_tpu.runtime.errors import InternalError
     from presto_tpu.runtime.memory import DEFAULT_BUDGET_BYTES
 
-    class NoStats:
-        def memory_stats(self):
-            raise RuntimeError("unavailable")
-
-    assert device_budget_bytes(NoStats()) == DEFAULT_BUDGET_BYTES
     assert device_budget_bytes(_FakeDevice(None)) == DEFAULT_BUDGET_BYTES
+    assert device_budget_bytes(_FakeDevice({})) == DEFAULT_BUDGET_BYTES
+    for stats in (None, {}, {"bytes_in_use": 1}):
+        with pytest.raises(InternalError, match="bytes_limit"):
+            device_budget_bytes(_FakeDevice(stats, platform="tpu"))
 
 
 # ---------------------------------------------------------------------------

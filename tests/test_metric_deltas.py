@@ -144,7 +144,8 @@ def test_queryinfo_no_filter_observations_reports_minus_one():
 
 def test_query_info_carries_join_strategy_deltas(conn):
     s = Session({"tpch": conn},
-                properties={"result_cache_enabled": False})
+                properties={"result_cache_enabled": False,
+                            "pallas_join": True})
     _df, info = s.execute(_q3())
     assert info.metrics.get("join.strategy.pallas", 0) >= 1
     assert "pallas" in info.join_strategy
@@ -172,7 +173,7 @@ def test_concurrent_queries_report_disjoint_strategies(conn):
     ``join.strategy.*`` moves."""
     grouped_q = ("select count(*) c from lineitem "
                  "join orders on l_orderkey = o_orderkey")
-    props_a = {"result_cache_enabled": False}
+    props_a = {"result_cache_enabled": False, "pallas_join": True}
     props_b = {"result_cache_enabled": False,
                "join_build_budget_bytes": 1}
     # warm both signatures so the concurrent phase measures execution,
@@ -218,7 +219,8 @@ def test_concurrent_queries_report_disjoint_strategies(conn):
 
 def test_query_history_carries_attribution_columns(conn):
     s = Session({"tpch": conn},
-                properties={"result_cache_enabled": False})
+                properties={"result_cache_enabled": False,
+                            "pallas_join": True})
     s.execute(_q3())
     df = s.sql("select query_id, oom_rung, join_strategy, "
                "filter_selectivity from query_history")

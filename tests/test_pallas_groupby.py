@@ -8,7 +8,10 @@ import pytest
 import jax.numpy as jnp
 
 from presto_tpu.ops.groupby import fused_small_sums
-from presto_tpu.ops.pallas_groupby import fused_lane_sums, probe_supported
+from presto_tpu.ops.pallas_groupby import (
+    fused_lane_sums,
+    lane_sums_supported,
+)
 
 CAP = 1 << 16  # one lane chunk: eligible capacity
 
@@ -87,10 +90,11 @@ def test_multi_major_accumulation(rng, monkeypatch):
         np.testing.assert_array_equal(np.asarray(sums[i]), want)
 
 
-def test_probe_rejects_ineligible():
-    assert not probe_supported([40], 1, 6, CAP)  # bits > 31
-    assert not probe_supported([13], 1, 6, CAP + 3)  # misaligned capacity
-    assert not probe_supported([13] * 20, 2, 32, CAP)  # slot blowup
+def test_static_admission_rejects_ineligible():
+    assert lane_sums_supported([13], 1, 6, CAP)
+    assert not lane_sums_supported([40], 1, 6, CAP)  # bits > 31
+    assert not lane_sums_supported([13], 1, 6, CAP + 3)  # misaligned capacity
+    assert not lane_sums_supported([13] * 20, 2, 32, CAP)  # slot blowup
 
 
 def test_wide_value_overflow_trips_before_cast(rng, monkeypatch):

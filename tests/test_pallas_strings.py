@@ -134,18 +134,18 @@ def test_empty_prefix_matches_everything():
     assert out.all()
 
 
-def test_probe_failure_is_logged(monkeypatch, caplog):
-    import logging
-
+def test_kernel_compile_error_reaches_the_query(env_pallas, monkeypatch):
+    """No probe stands between a chosen kernel and its caller: when the
+    LIKE kernel's compile raises, the query FAILS with that error
+    instead of quietly answering from the jnp twin."""
     import presto_tpu.ops.pallas_strings as ps
 
-    monkeypatch.setattr(ps, "_PROBE_CACHE", {})
-    monkeypatch.setattr(ps, "_interpret", lambda: False)
+    def boom(data, pattern, interpret=None):
+        raise RuntimeError("mosaic refused the program")
 
-    def boom(data, pattern):
-        raise RuntimeError("mosaic compile crashed")
-
-    with caplog.at_level(logging.WARNING, logger="presto_tpu.ops.pallas_strings"):
-        ok = ps._probe("like", "x%", 12, boom)
-    assert not ok
-    assert any("falling back" in r.message for r in caplog.records)
+    monkeypatch.setattr(ps, "like_mask_pallas", boom)
+    session, _tables = env_pallas
+    # a literal no other test used: the step must be traced afresh
+    q = "select count(*) as n from part where p_name like '%unswallowed%'"
+    with pytest.raises(Exception, match="mosaic refused the program"):
+        session.sql(q)

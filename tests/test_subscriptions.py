@@ -272,7 +272,8 @@ def test_approx_subscription_sketch_join_superset_flagged():
     # the exact exists-bitmap (a tiny join_build_budget_bytes would
     # instead re-route the join through the grouped-spill tier, away
     # from the kernel entirely)
-    _conn, s, server = make_server()
+    # the sketch is a fused Pallas probe: the approx tier opts in
+    _conn, s, server = make_server(approx_properties={"pallas_join": True})
     sql = _wide_domain_tables(StreamWriter(s))
     exact = int(server.execute(sql, "t0")["n"][0])
     sub = server.subscribe(sql, "t0", mode="approx")

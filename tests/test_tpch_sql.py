@@ -2,14 +2,13 @@
 (reference parity: AbstractTestQueries + H2QueryRunner diffing
 MaterializedResults [SURVEY §4])."""
 
-import numpy as np
-import pandas as pd
 import pytest
 
 pytestmark = pytest.mark.slow
 
 from presto_tpu.connectors.tpch import TpchConnector
 from presto_tpu.connectors.tpch.queries import QUERIES
+from presto_tpu.oracle.compare import compare, normalize  # noqa: F401
 from presto_tpu.oracle.tpch_oracle import ORACLES
 from presto_tpu.runtime.session import Session
 
@@ -22,56 +21,6 @@ def env():
     session = Session({"tpch": conn})
     tables = {name: conn.table_pandas(name) for name in conn.tables()}
     return session, tables
-
-
-def normalize(df: pd.DataFrame) -> pd.DataFrame:
-    df = df.copy()
-    df.columns = [f"c{i}" for i in range(len(df.columns))]
-    for c in df.columns:
-        if pd.api.types.is_float_dtype(df[c]):
-            df[c] = df[c].astype(np.float64).round(2)
-        elif pd.api.types.is_datetime64_any_dtype(df[c]):
-            df[c] = df[c].astype("datetime64[s]")
-        elif df[c].dtype == object or pd.api.types.is_string_dtype(df[c]):
-            # engine NULL doubles ride object columns as Python None
-            # beside real floats (stddev of a 1-row sample, NULL lag
-            # windows); astype(str) would freeze those None values into
-            # the literal string 'None' and poison the float compare
-            # below. A numeric-or-null object column aligns with the
-            # oracle's NaN floats instead.
-            vals = df[c].dropna()
-            if len(vals) == 0 or vals.map(
-                lambda v: isinstance(v, (int, float, np.number))
-                and not isinstance(v, bool)
-            ).all():
-                df[c] = df[c].astype(np.float64).round(2)
-            else:
-                df[c] = df[c].astype(str).str.rstrip()
-        else:
-            df[c] = pd.to_numeric(df[c]).astype(np.int64)
-    return df.sort_values(list(df.columns), kind="stable").reset_index(drop=True)
-
-
-def compare(got: pd.DataFrame, want: pd.DataFrame, query: str):
-    assert got.shape == want.shape, (
-        f"{query}: shape {got.shape} != oracle {want.shape}"
-    )
-    if len(got) == 0:
-        return
-    g = normalize(got)
-    w = normalize(want)
-    for c in g.columns:
-        if pd.api.types.is_float_dtype(w[c]):
-            if not pd.api.types.is_float_dtype(g[c]):
-                # engine NULL doubles surface as None (object column);
-                # the oracle has NaN floats — align for allclose
-                g[c] = g[c].astype(np.float64)
-            np.testing.assert_allclose(
-                g[c].to_numpy(), w[c].to_numpy(), rtol=1e-3, atol=0.02,
-                err_msg=f"{query}: column {c}",
-            )
-        else:
-            assert g[c].tolist() == w[c].tolist(), f"{query}: column {c}"
 
 
 @pytest.mark.parametrize("name", sorted(QUERIES, key=lambda x: int(x[1:])))

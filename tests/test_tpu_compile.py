@@ -1,0 +1,208 @@
+"""The main path's Pallas kernels compiled for a DESCRIBED v5e (no chip
+attached) at the shapes the SF1 scan feeds them — the chip compiler's
+refusals (tiling, relayout, VMEM) surface here on the CPU, which
+interpret mode cannot show. Nothing runs: a pass says "compiles", not
+"correct on the chip" (``chip_smoke.py`` is that).
+
+The topology is described inside a module-scoped fixture (only the
+worker handed this file loads the TPU library) and every compile
+happens in the test's own process.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from presto_tpu.batch import Batch, Column
+from presto_tpu.ops import (
+    pallas_agg,
+    pallas_groupby,
+    pallas_join,
+    pallas_q1,
+    pallas_strings,
+)
+from presto_tpu.ops.pallas_agg import LeafAggSpec, Term, ValueAgg
+from presto_tpu.types import BIGINT
+
+SCAN_CAP = 1 << 20  # one SF1 lineitem split (2^17 orders x <=7 lines)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    # a described-device compile is written to the persistent cache but
+    # can never be read back without a chip: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe skips
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield t
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower ``fn`` over ShapeDtypeStructs placed on the described chip
+    and compile; returns the compiled program's text."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+def _batch_fn(step, names):
+    """``step(Batch)`` as a function of flat (cols..., live) arrays."""
+    def fn(*arrs):
+        live = arrs[-1]
+        cols = {n: Column(a, None, BIGINT) for n, a in zip(names, arrs)}
+        return step(Batch(cols, live))
+    return fn
+
+
+_Q1_COLS = {
+    "l_shipdate": jnp.int16, "l_returnflag": jnp.int8,
+    "l_linestatus": jnp.int8, "l_quantity": jnp.int16,
+    "l_extendedprice": jnp.int32, "l_discount": jnp.int8,
+    "l_tax": jnp.int8,
+}
+
+
+def test_q1_kernel_compiles(one_chip):
+    fn = _batch_fn(lambda b: pallas_q1.q1_step(b, interpret=False),
+                   list(_Q1_COLS))
+    _compile(fn, one_chip,
+             *[((SCAN_CAP,), dt) for dt in _Q1_COLS.values()],
+             ((SCAN_CAP,), jnp.bool_))
+
+
+# specs recorded from Session.sql on the CPU (pallas_eligible patched):
+# TPC-H Q6, SSB Q1.1 (date join folded to a membership bitmap — the
+# kernel sees only the fact-table conjuncts) and a two-key small-domain
+# GROUP BY over lineitem, each with the scan's narrow physical dtypes
+_SPECS = {
+    "tpch_q6": (
+        LeafAggSpec(
+            cols=("l_extendedprice", "l_discount", "l_quantity",
+                  "l_shipdate"),
+            filters=((1, 5, 7), (2, None, 2399), (3, 8766, 9130)),
+            keys=(), groups=1,
+            values=(ValueAgg("sum", Term(0), Term(1), bits=27),),
+            guards=((0, 90000, 10495000), (1, 0, 10))),
+        (jnp.int32, jnp.int8, jnp.int16, jnp.int16)),
+    "ssb_q1_1": (
+        LeafAggSpec(
+            cols=("lo_extendedprice", "lo_discount", "lo_quantity",
+                  "lo_orderdate"),
+            filters=((1, 100, 300), (2, None, 2499)),
+            keys=(), groups=1,
+            values=(ValueAgg("sum", Term(0), Term(1), bits=27),),
+            guards=((0, 90, 99995), (1, 0, 1000))),
+        (jnp.int32, jnp.int16, jnp.int16, jnp.int32)),
+    "keyed_rf_ls": (
+        LeafAggSpec(
+            cols=("l_returnflag", "l_linestatus", "l_quantity",
+                  "l_extendedprice", "l_discount", "l_shipdate"),
+            filters=((5, None, 10471),),
+            keys=((0, 0, 2), (1, 0, 1)), groups=6,
+            values=(ValueAgg("sum", Term(2), bits=13),
+                    ValueAgg("sum", Term(3), Term(4), bits=27)),
+            guards=((0, 0, 2), (1, 0, 1), (2, 100, 5000),
+                    (3, 90000, 10495000), (4, 0, 10))),
+        (jnp.int8, jnp.int8, jnp.int16, jnp.int32, jnp.int8, jnp.int16)),
+    # constant-valued sums (``sum(1)``, ``sum(2)``: Term(col=-1) is a
+    # splat) — keyless, keyed, and next to a column sum
+    "const_sum": (
+        LeafAggSpec(cols=("l_quantity",), filters=((0, None, 2399),),
+                    keys=(), groups=1,
+                    values=(ValueAgg("sum", Term(-1, 1, 0), bits=1),),
+                    guards=()),
+        (jnp.int16,)),
+    "const_sum_keyed": (
+        LeafAggSpec(cols=("l_returnflag",), filters=(),
+                    keys=((0, 0, 1),), groups=3,
+                    values=(ValueAgg("sum", Term(-1, 2, 0), bits=2),),
+                    guards=((0, 0, 2),)),
+        (jnp.int8,)),
+    "const_sum_mixed": (
+        LeafAggSpec(cols=("l_quantity",), filters=((0, None, 2399),),
+                    keys=(), groups=1,
+                    values=(ValueAgg("sum", Term(0), bits=13),
+                            ValueAgg("sum", Term(-1, 1, 0), bits=1)),
+                    guards=((0, 100, 5000),)),
+        (jnp.int16,)),
+    # no guards and no sub-31-bit values: badrow stays a splat constant
+    "unguarded_count": (
+        LeafAggSpec(cols=("a",), filters=((0, 0, None),), keys=(), groups=1,
+                    values=(ValueAgg("sum", Term(0), bits=31),), guards=()),
+        (jnp.int32,)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_SPECS))
+def test_leaf_agg_kernel_compiles(one_chip, name):
+    spec, dtypes = _SPECS[name]
+    assert pallas_agg._block_rows(spec, SCAN_CAP) == 1 << 17
+    fn = _batch_fn(
+        lambda b: pallas_agg._pallas_step(spec, b, interpret=False),
+        list(spec.cols))
+    _compile(fn, one_chip, *[((SCAN_CAP,), dt) for dt in dtypes],
+             ((SCAN_CAP,), jnp.bool_))
+
+
+def test_groupby_kernel_compiles(one_chip):
+    bits = [24, 16, 31]
+
+    def fn(v0, v1, v2, m, g):
+        return pallas_groupby.fused_lane_sums(
+            [v0, v1, v2], bits, [m], g, 64, interpret=False)
+
+    _compile(fn, one_chip, *[((SCAN_CAP,), jnp.int32)] * 3,
+             ((SCAN_CAP,), jnp.bool_), ((SCAN_CAP,), jnp.int32))
+
+
+@pytest.mark.parametrize("kind,pattern,width", [
+    ("like", "%special%requests%", 79),  # TPC-H Q13 over o_comment
+    ("like", "%special%requests%", 44),  # the same over l_comment
+    ("like", "PROMO%", 25),
+    ("prefix", "PROMO", 25),
+])
+def test_strings_kernel_compiles(one_chip, kind, pattern, width):
+    run = (pallas_strings.like_mask_pallas if kind == "like"
+           else pallas_strings.starts_with_pallas)
+    _compile(lambda d: run(d, pattern, interpret=False), one_chip,
+             ((1 << 17, width), jnp.uint8))
+
+
+def test_join_probe_is_refused_and_the_refusal_reaches_the_caller(one_chip):
+    """Why ``pallas_join.available()`` is False on a TPU backend: the
+    installed Mosaic gather rule asserts table and index block share
+    one shape, which the lane-replicated [w, 128] table does not. And
+    what every kernel now does with a refusal: nothing catches it, the
+    compiler's own exception comes out of the jit. When a newer jaxlib
+    makes this compile, re-admit the kernels in ``available()``."""
+    w = 64  # table words; the probe block is [512, 128]
+
+    def fn(tab, keys, live):
+        return pallas_join.exists_probe(tab, 0, w * 32 - 1, keys, live,
+                                        interpret=False)
+
+    with pytest.raises(AssertionError):
+        _compile(fn, one_chip, ((w, 128), jnp.int32),
+                 ((1 << 16,), jnp.int32), ((1 << 16,), jnp.bool_))
