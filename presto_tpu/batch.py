@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from presto_tpu.runtime import trace
+from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.types import DataType, TypeKind, check_narrow_range
 
 
@@ -212,6 +214,15 @@ class Batch:
         Narrowed physical types (``DataType.phys``) range-check their
         input here: connector stats are *declared* bounds, and a value
         outside the narrowed dtype must fail loudly, never wrap.
+
+        Two spans part the work: ``batch:pad`` (range check, ``astype``,
+        the zero-filled capacity-sized copies and the masks, all
+        columns) and then ``batch:upload`` (the ``jnp.asarray`` calls,
+        all columns). ``batch:upload`` is the host's time inside those
+        calls; the transfer itself may complete later, and then shows
+        as a wait in the first ``sync:*`` span that needs the data.
+        ``exec.h2d.bytes`` / ``exec.h2d.arrays`` count what was handed
+        over, padding and masks included.
         """
         n = len(next(iter(arrays.values())))
         count = n if count is None else count
@@ -221,32 +232,44 @@ class Batch:
                 f"capacity {cap} < {n} input rows: batches never silently "
                 "truncate; pick a larger capacity bucket"
             )
-        live = np.zeros(cap, dtype=np.bool_)
-        live[:count] = True
-        live = jnp.asarray(live)
-        cols = {}
-        for name, arr in arrays.items():
-            t = types[name]
-            arr = np.asarray(arr)
-            if t.kind is TypeKind.BYTES:
-                padded = np.zeros((cap, t.width), dtype=np.uint8)
-                padded[: arr.shape[0], : arr.shape[1]] = arr[:cap]
-            else:
-                check_narrow_range(name, t, arr)
-                padded = np.zeros(cap, dtype=t.np_dtype)
-                padded[:n] = arr.astype(t.np_dtype, copy=False)[:cap]
-            if valids is not None and name in valids and valids[name] is not None:
-                v = np.zeros(cap, dtype=np.bool_)
-                v[:n] = valids[name][:cap]
-                v = jnp.asarray(v)
-            elif count == n:
-                v = live  # NULL-free column: share the live mask object
-            else:
-                v = np.zeros(cap, dtype=np.bool_)
-                v[:n] = True
-                v = jnp.asarray(v)
-            d = dictionaries.get(name) if dictionaries else None
-            cols[name] = Column(jnp.asarray(padded), v, t, d)
+        # pad first, upload second: every host copy is made before the
+        # first array is handed to the device, so each span is one
+        # interval of one kind of work
+        with trace.span("batch:pad", "scan"):
+            live = np.zeros(cap, dtype=np.bool_)
+            live[:count] = True
+            padded, masks = {}, {}
+            for name, arr in arrays.items():
+                t = types[name]
+                arr = np.asarray(arr)
+                if t.kind is TypeKind.BYTES:
+                    p = np.zeros((cap, t.width), dtype=np.uint8)
+                    p[: arr.shape[0], : arr.shape[1]] = arr[:cap]
+                else:
+                    check_narrow_range(name, t, arr)
+                    p = np.zeros(cap, dtype=t.np_dtype)
+                    p[:n] = arr.astype(t.np_dtype, copy=False)[:cap]
+                padded[name] = p
+                if valids is not None and valids.get(name) is not None:
+                    v = np.zeros(cap, dtype=np.bool_)
+                    v[:n] = valids[name][:cap]
+                    masks[name] = v
+                elif count != n:
+                    v = np.zeros(cap, dtype=np.bool_)
+                    v[:n] = True
+                    masks[name] = v
+                # else a NULL-free column: it shares the live mask object
+        with trace.span("batch:upload", "scan"):
+            live = jnp.asarray(live)
+            cols = {}
+            for name, p in padded.items():
+                v = jnp.asarray(masks[name]) if name in masks else live
+                d = dictionaries.get(name) if dictionaries else None
+                cols[name] = Column(jnp.asarray(p), v, types[name], d)
+        REGISTRY.counter("exec.h2d.arrays").add(1 + len(padded) + len(masks))
+        REGISTRY.counter("exec.h2d.bytes").add(
+            cap + sum(p.nbytes for p in padded.values())
+            + sum(v.nbytes for v in masks.values()))
         return cls(cols, live)
 
     def to_pandas(self, decode_strings: bool = True, logical: bool = True):
@@ -275,8 +298,10 @@ jax.tree_util.register_pytree_node(
 
 
 def live_count(batch: Batch) -> int:
-    """Host-side concrete live-row count."""
-    return int(batch.count())
+    """Host-side concrete live-row count (a device readback:
+    ``sync:live_count``)."""
+    with trace.sync("live_count"):
+        return int(batch.count())
 
 
 def decode_values(

@@ -38,6 +38,7 @@ from presto_tpu.spi import (
     ColumnStats,
     Split,
     batch_capacity,
+    generate_split,
     narrowed_schema,
     split_valids,
 )
@@ -469,7 +470,7 @@ class MemoryConnector:
         capacity: int | None = None,
     ) -> Batch:
         t = self._tables[split.table]
-        arrays, valids = split_valids(self.scan_numpy(split, columns))
+        arrays, valids = split_valids(generate_split(self, split, columns))
         n = split.hi - split.lo
         cap = capacity or batch_capacity(max(n, 1))
         types = self.physical_schema(split.table, list(arrays))

@@ -13,7 +13,12 @@ import numpy as np
 from presto_tpu.batch import Batch, Dictionary
 from presto_tpu.connectors.ssb import schema as S
 from presto_tpu.connectors.ssb.generator import SsbGenerator
-from presto_tpu.spi import Split, batch_capacity, narrowed_schema
+from presto_tpu.spi import (
+    Split,
+    batch_capacity,
+    generate_split,
+    narrowed_schema,
+)
 
 
 class SsbConnector:
@@ -81,7 +86,7 @@ class SsbConnector:
         columns: Sequence[str] | None = None,
         capacity: int | None = None,
     ) -> Batch:
-        arrays = dict(self.scan_numpy(split, columns))
+        arrays = generate_split(self, split, columns)
         n = len(next(iter(arrays.values())))
         cap = capacity or batch_capacity(n)
         types = self.physical_schema(split.table, list(arrays))

@@ -17,7 +17,13 @@ import numpy as np
 from presto_tpu.batch import Batch, Dictionary
 from presto_tpu.connectors.tpcds import schema as S
 from presto_tpu.connectors.tpcds.generator import TpcdsGenerator
-from presto_tpu.spi import Split, batch_capacity, narrowed_schema, split_valids
+from presto_tpu.spi import (
+    Split,
+    batch_capacity,
+    generate_split,
+    narrowed_schema,
+    split_valids,
+)
 
 
 class TpcdsConnector:
@@ -87,7 +93,7 @@ class TpcdsConnector:
         columns: Sequence[str] | None = None,
         capacity: int | None = None,
     ) -> Batch:
-        arrays, valids = split_valids(self.scan_numpy(split, columns))
+        arrays, valids = split_valids(generate_split(self, split, columns))
         n = len(next(iter(arrays.values())))
         cap = capacity or batch_capacity(n)
         types = self.physical_schema(split.table, list(arrays))

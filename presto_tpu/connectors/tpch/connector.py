@@ -18,7 +18,12 @@ import numpy as np
 from presto_tpu.batch import Batch, Dictionary
 from presto_tpu.connectors.tpch import schema as S
 from presto_tpu.connectors.tpch.generator import TpchGenerator
-from presto_tpu.spi import Split, batch_capacity, narrowed_schema
+from presto_tpu.spi import (
+    Split,
+    batch_capacity,
+    generate_split,
+    narrowed_schema,
+)
 from presto_tpu.types import DataType
 
 
@@ -90,7 +95,7 @@ class TpchConnector:
         columns: Sequence[str] | None = None,
         capacity: int | None = None,
     ) -> Batch:
-        arrays = dict(self.scan_numpy(split, columns))
+        arrays = generate_split(self, split, columns)
         n = len(next(iter(arrays.values())))
         cap = capacity or batch_capacity(n)
         types = self.physical_schema(split.table, list(arrays))

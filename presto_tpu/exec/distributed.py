@@ -84,11 +84,13 @@ from presto_tpu.plan import nodes as N
 from presto_tpu.plan.catalog import Catalog
 from presto_tpu.runtime.faults import fault_point
 from presto_tpu.runtime.lifecycle import check_deadline
+from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.runtime.trace import (
     batch_device_bytes,
     batch_row_bytes,
 )
 from presto_tpu.runtime.trace import span as trace_span
+from presto_tpu.runtime.trace import sync as trace_sync
 from presto_tpu.spi import batch_capacity
 from presto_tpu.types import TypeKind, check_narrow_range
 
@@ -121,14 +123,12 @@ def _compact_step(mesh, out_cap: int):
     """Compiled per-device compaction, cached per (mesh, capacity) so
     repeated guarded replications reuse the XLA program."""
     ax = worker_axes(mesh)
-    step = partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(P(ax),),
-        out_specs=P(ax),
-        check_vma=False,
-    )(lambda local: _compact_local(local, out_cap))
-    return jax.jit(step)
+    @partial(shard_map, mesh=mesh, in_specs=(P(ax),), out_specs=P(ax),
+             check_vma=False)
+    def dist_compact_step(local):
+        return _compact_local(local, out_cap)
+
+    return jax.jit(dist_compact_step)
 
 
 def _pad_rows(b: Batch, cap: int) -> Batch:
@@ -325,7 +325,8 @@ class DistributedExecutor(OomLadderMixin):
                     dict(zip(plan.sources, plan.names)))
                 if live_count(b) == 0:
                     return pd.DataFrame(columns=list(plan.names))
-                return b.to_pandas()[list(plan.names)]
+                with trace_sync("result"):
+                    return b.to_pandas()[list(plan.names)]
         finally:
             # in the finally so FAILED runs flush too: a post-mortem's
             # most useful line is which partition was hot when it died
@@ -509,7 +510,7 @@ class DistributedExecutor(OomLadderMixin):
         # process to contribute just its local pieces). Single-process
         # meshes address every device, so this is the old loop there.
         proc = jax.process_index()
-        from presto_tpu.spi import split_valids
+        from presto_tpu.spi import generate_split, split_valids
 
         data_shards: dict[str, list] = {c: [] for c in src_cols}
         valid_shards: dict[str, list] = {c: [] for c in src_cols}
@@ -522,41 +523,55 @@ class DistributedExecutor(OomLadderMixin):
             # transfer buffer and dropped before the next split is
             # touched — peak host allocation beyond the buffer itself
             # is ONE split, not the whole shard plus a concat copy
+            # the local tier's three scan spans (Batch.from_numpy), per
+            # device shard: batch:pad is the zero-filled buffers here
+            # and each split's copy into them below
             padded = {}
             vmasks = {}
-            for c in src_cols:
-                t = types[c]
-                tail = (t.width,) if t.kind is TypeKind.BYTES else ()
-                padded[c] = np.zeros((cap_dev,) + tail, dtype=t.np_dtype)
-                vmasks[c] = np.zeros(cap_dev, np.bool_)
+            with trace_span("batch:pad", "scan"):
+                for c in src_cols:
+                    t = types[c]
+                    tail = (t.width,) if t.kind is TypeKind.BYTES else ()
+                    padded[c] = np.zeros((cap_dev,) + tail, dtype=t.np_dtype)
+                    vmasks[c] = np.zeros(cap_dev, np.bool_)
             rows = 0
             for s in sp:
                 # per-split deadline boundary, matching the local tier's
                 # scan loop — a long multi-split scan must notice an
                 # expired query_max_run_time between splits
                 check_deadline("scan")
-                arrays, valids = split_valids(conn.scan_numpy(s, src_cols))
+                arrays, valids = split_valids(generate_split(conn, s, src_cols))
                 srows = len(next(iter(arrays.values()))) if arrays else 0
                 if rows + srows > cap_dev:
                     raise CapacityOverflow("TableScan shard", cap_dev,
                                            rows + srows)
-                for c in src_cols:
-                    a = arrays.get(c)
-                    if a is not None:
-                        if a.ndim > 1:  # BYTES rows may be narrower
-                            padded[c][rows : rows + srows, : a.shape[1]] = a
-                        else:
-                            check_narrow_range(c, types[c], a)
-                            padded[c][rows : rows + srows] = a
-                    vm = valids.get(c)
-                    vmasks[c][rows : rows + srows] = True if vm is None else vm
+                with trace_span("batch:pad", "scan"):
+                    for c in src_cols:
+                        a = arrays.get(c)
+                        if a is not None:
+                            if a.ndim > 1:  # BYTES rows may be narrower
+                                padded[c][rows : rows + srows,
+                                          : a.shape[1]] = a
+                            else:
+                                check_narrow_range(c, types[c], a)
+                                padded[c][rows : rows + srows] = a
+                        vm = valids.get(c)
+                        vmasks[c][rows : rows + srows] = (
+                            True if vm is None else vm)
                 rows += srows
-            for c in src_cols:
-                data_shards[c].append(jax.device_put(padded[c], devices[d]))
-                valid_shards[c].append(jax.device_put(vmasks[c], devices[d]))
             lv = np.zeros(cap_dev, np.bool_)
             lv[:rows] = True
-            live_shards.append(jax.device_put(lv, devices[d]))
+            with trace_span("batch:upload", "scan"):
+                for c in src_cols:
+                    data_shards[c].append(
+                        jax.device_put(padded[c], devices[d]))
+                    valid_shards[c].append(
+                        jax.device_put(vmasks[c], devices[d]))
+                live_shards.append(jax.device_put(lv, devices[d]))
+            REGISTRY.counter("exec.h2d.arrays").add(2 * len(src_cols) + 1)
+            REGISTRY.counter("exec.h2d.bytes").add(
+                lv.nbytes + sum(padded[c].nbytes + vmasks[c].nbytes
+                                for c in src_cols))
 
         sh = row_sharding(self.mesh)
 
@@ -908,7 +923,7 @@ class DistributedExecutor(OomLadderMixin):
             out_specs=(P(axes), P(), P(), P(), P()),
             check_vma=False,
         )
-        def step(b: Batch, params=()):
+        def dist_hash_agg_step(b: Batch, params=()):
             trace_probe()
             with param_scope(params):
                 part, ovf1 = (bypass_phase(b) if bypass else partial_phase(b))
@@ -925,7 +940,7 @@ class DistributedExecutor(OomLadderMixin):
                 return (out, any_flag(ovf1 | ovf2 | ovf3, axes), rounds,
                         dest, any_flag(ovf2, axes))
 
-        return jax.jit(step)
+        return jax.jit(dist_hash_agg_step)
 
     # ---- joins -----------------------------------------------------------
     def _join_key_exprs(self, node, left: DistBatch, right: DistBatch, scalars):
@@ -1063,10 +1078,10 @@ class DistributedExecutor(OomLadderMixin):
                 in_specs=(P(axes), P(axes)), out_specs=P(axes),
                 check_vma=False,
             )
-            def step(a: Batch, b: Batch):
+            def dist_concat_step(a: Batch, b: Batch):
                 return concat_batches([a.select(names), b])
 
-            return jax.jit(step)
+            return jax.jit(dist_concat_step)
 
         step = EXEC_CACHE.get_or_build(
             EXEC_CACHE.key_of("dist_concat2", tuple(names), self._mesh_fp),
@@ -1309,7 +1324,7 @@ class DistributedExecutor(OomLadderMixin):
             out_specs=(P(axes), P(), P(), P(), P()),
             check_vma=False,
         )
-        def step(lb: Batch, rb: Batch, params=()):
+        def dist_repartition_join_step(lb: Batch, rb: Batch, params=()):
             trace_probe()
             with param_scope(params):
                 return step_body(lb, rb)
@@ -1467,7 +1482,7 @@ class DistributedExecutor(OomLadderMixin):
                 dest,
             )
 
-        return jax.jit(step)
+        return jax.jit(dist_repartition_join_step)
 
     # ---- grouped (bucketed) execution: the distributed L9 tier -----------
     def _pull_host(self, d: DistBatch, key, nbuckets: int):
@@ -1497,14 +1512,14 @@ class DistributedExecutor(OomLadderMixin):
 
         def make_bids_step():
             @jax.jit
-            def bids_step(bb: Batch, params=()):
+            def dist_bids_step(bb: Batch, params=()):
                 with param_scope(params):
                     v = evaluate(key, bb)
                     data = jnp.where(bb.live & v.valid,
                                      v.data.astype(jnp.int64), 0)
                     return bucket_ids([data], nbuckets)
 
-            return bids_step
+            return dist_bids_step
 
         bids_step = EXEC_CACHE.get_or_build(
             EXEC_CACHE.key_of("dist_spill_bids", key, nbuckets),
@@ -1580,10 +1595,10 @@ class DistributedExecutor(OomLadderMixin):
                 in_specs=tuple(P(axes) for _ in range(nparts)),
                 out_specs=P(axes), check_vma=False,
             )
-            def step(*bs):
+            def dist_union_step(*bs):
                 return concat_batches(list(bs))
 
-            return jax.jit(step)
+            return jax.jit(dist_union_step)
 
         step = EXEC_CACHE.get_or_build(
             EXEC_CACHE.key_of("dist_concat_many", tuple(names), nparts,
@@ -1743,7 +1758,7 @@ class DistributedExecutor(OomLadderMixin):
                 in_specs=(P(axes), P()), out_specs=(P(axes), P(axes)),
                 check_vma=False,
             )
-            def bids_step(local: Batch, params=()):
+            def dist_bids_step(local: Batch, params=()):
                 with param_scope(params):
                     bids = bucket_ids(key_sortables(local), nbuckets)
                     onehot = ((bids[:, None] == jnp.arange(nbuckets))
@@ -1751,7 +1766,7 @@ class DistributedExecutor(OomLadderMixin):
                     counts = jnp.sum(onehot, axis=0, dtype=jnp.int32)[None, :]
                     return bids, counts
 
-            return jax.jit(bids_step)
+            return jax.jit(dist_bids_step)
 
         bids, counts = EXEC_CACHE.get_or_build(
             EXEC_CACHE.key_of("dist_bucket_ids", keys, nbuckets,
@@ -1767,11 +1782,11 @@ class DistributedExecutor(OomLadderMixin):
                 in_specs=(P(axes), P(axes), P()),
                 out_specs=P(axes), check_vma=False,
             )
-            def filter_step(local: Batch, lbids, bkv):
+            def dist_filter_step(local: Batch, lbids, bkv):
                 keep = local.live & (lbids == bkv)
                 return _compact_local(local.with_live(keep), cap_pass)
 
-            return jax.jit(filter_step)
+            return jax.jit(dist_filter_step)
 
         fstep = EXEC_CACHE.get_or_build(
             EXEC_CACHE.key_of("dist_bucket_filter", cap_pass, self._mesh_fp),
@@ -1940,7 +1955,7 @@ class DistributedExecutor(OomLadderMixin):
             in_specs=(P(axes), P()), out_specs=(P(axes), P(), P()),
             check_vma=False,
         )
-        def step(local: Batch, params=()):
+        def dist_window_step(local: Batch, params=()):
             trace_probe()
             with param_scope(params):
                 pids = partition_ids(hash_cols(local), Pn)
@@ -1950,7 +1965,7 @@ class DistributedExecutor(OomLadderMixin):
                 out = window_body(exch, params)
                 return out, any_flag(ovf, axes), rounds
 
-        return jax.jit(step)
+        return jax.jit(dist_window_step)
 
     # ---- ordering / limiting ---------------------------------------------
     def _exec_sort(self, node: N.Sort, scalars) -> DistBatch:
@@ -2025,7 +2040,7 @@ class DistributedExecutor(OomLadderMixin):
                 in_specs=(P(axes), P()), out_specs=P(axes),
                 check_vma=False,
             )
-            def step(local: Batch, params=()):
+            def dist_topn_step(local: Batch, params=()):
                 with param_scope(params):
                     return step_body(local)
 
@@ -2051,7 +2066,7 @@ class DistributedExecutor(OomLadderMixin):
                 live = live & (jnp.arange(cap_out) < n)
                 return Batch(cols, live)
 
-            return jax.jit(step)
+            return jax.jit(dist_topn_step)
 
         step = EXEC_CACHE.get_or_build(
             EXEC_CACHE.key_of("dist_local_topn", tuple(keys), n, cap_out,
@@ -2076,7 +2091,7 @@ class DistributedExecutor(OomLadderMixin):
                 in_specs=(P(axes),), out_specs=P(axes),
                 check_vma=False,
             )
-            def step(local: Batch):
+            def dist_limit_step(local: Batch):
                 live_rank = jnp.cumsum(local.live.astype(jnp.int64))
                 keep = local.live & (live_rank <= n)
                 idx, _, _ = compact_indices(keep, cap_out)
@@ -2090,7 +2105,7 @@ class DistributedExecutor(OomLadderMixin):
                 }
                 return Batch(cols, gather_padded(local.live, idx, False))
 
-            return jax.jit(step)
+            return jax.jit(dist_limit_step)
 
         step = EXEC_CACHE.get_or_build(
             EXEC_CACHE.key_of("dist_local_limit", n, cap_out, self._mesh_fp),
@@ -2142,7 +2157,7 @@ class DistributedExecutor(OomLadderMixin):
                 in_specs=(P(axes), P()), out_specs=(P(), P()),
                 check_vma=False,
             )
-            def sample_step(local: Batch, params=()):
+            def dist_sample_step(local: Batch, params=()):
                 with param_scope(params):
                     return sample_body(local)
 
@@ -2157,7 +2172,7 @@ class DistributedExecutor(OomLadderMixin):
                 # addressable (replicated) array in multi-process runs
                 return _ag(samp, axes), _ag(ok, axes)
 
-            return jax.jit(sample_step)
+            return jax.jit(dist_sample_step)
 
         sample = EXEC_CACHE.get_or_build(
             EXEC_CACHE.key_of("dist_sort_sample", k0, nsamples,
@@ -2217,7 +2232,7 @@ class DistributedExecutor(OomLadderMixin):
             in_specs=(P(axes), P(), P()), out_specs=(P(axes), P(), P()),
             check_vma=False,
         )
-        def step(local: Batch, splitters, params=()):
+        def dist_range_sort_step(local: Batch, splitters, params=()):
             trace_probe()
             with param_scope(params):
                 return step_body(local, splitters)
@@ -2247,7 +2262,7 @@ class DistributedExecutor(OomLadderMixin):
             out = Batch(cols, gather_padded(exch.live, order, False))
             return out, any_flag(ovf, axes), rounds
 
-        return jax.jit(step)
+        return jax.jit(dist_range_sort_step)
 
     # ---- scalar subqueries ----------------------------------------------
     def _exec_bindscalars(self, node: N.BindScalars, scalars) -> DistBatch:

@@ -35,6 +35,7 @@ from presto_tpu.exec.operators import (
 )
 from presto_tpu.expr import Expr, InputRef, evaluate, param_scope
 from presto_tpu.runtime.trace import span as trace_span
+from presto_tpu.runtime.trace import sync as trace_sync
 from presto_tpu.ops.groupby import gather_padded
 from presto_tpu.ops.join import (
     BuildSide,
@@ -222,7 +223,7 @@ class JoinBuildOperator(CollectingOperator):
 
         def make_build():
             @jax.jit
-            def build(b: Batch, params=()):
+            def join_build_step(b: Batch, params=()):
                 trace_probe()
                 with param_scope(params):
                     return body(b)
@@ -265,7 +266,7 @@ class JoinBuildOperator(CollectingOperator):
                 return (side, dense, long_dup_runs_flag(side.sorted_keys),
                         ptables, poob, pnull, filt)
 
-            return build
+            return join_build_step
 
         # shared across queries: the closure bakes in only (key expr,
         # capacity, dense domain, pack bits, pallas spec, filter bits)
@@ -278,9 +279,18 @@ class JoinBuildOperator(CollectingOperator):
         with trace_span("step:join_build", "step", {"capacity": cap}):
             side, dense, long_runs, ptables, poob, pnull, filt = build(
                 batch, self._params)
+        # the build's flags are the first host reads after its dispatch:
+        # the host waits here for the whole build (sort included)
+        with trace_sync("join_build"):
+            pallas_bad = spec is not None and (
+                (poob is not None and bool(poob))
+                or (pnull is not None and bool(pnull)))
+            overflow = bool(side.overflow)
+            sentinel_hit = bool(side.sentinel_hit)
+            long_dup_runs = bool(long_runs)
+            dense_ok = dense is not None and not bool(dense.overflow)
         if spec is not None:
-            if (poob is not None and bool(poob)) or (
-                    pnull is not None and bool(pnull)):
+            if pallas_bad:
                 # advisory stats violated (or a NULL payload): the
                 # generic probes take over — loud, never wrong
                 REGISTRY.counter("join.pallas_fallback").add()
@@ -290,9 +300,9 @@ class JoinBuildOperator(CollectingOperator):
         if filt is not None:
             self.filter_minmax = (filt[0], filt[1])
             self.filter_bloom = filt[2]
-        if bool(side.overflow):
+        if overflow:
             raise CapacityOverflow("JoinBuild", cap, int(side.n_rows))
-        if bool(side.sentinel_hit):
+        if sentinel_hit:
             if self.pack_bits is not None:
                 raise NotImplementedError(
                     "a join build key violated its advisory stats bound "
@@ -306,7 +316,7 @@ class JoinBuildOperator(CollectingOperator):
                 "lose their matches"
             )
         self.build_side = side
-        self.long_dup_runs = bool(long_runs)
+        self.long_dup_runs = long_dup_runs
         # dictionary provenance for the probe-side runtime guard:
         # dictionary codes are only comparable within ONE dictionary
         self.key_dict = (
@@ -314,7 +324,7 @@ class JoinBuildOperator(CollectingOperator):
             if isinstance(self.key, InputRef) and self.key.name in batch
             else None
         )
-        if dense is not None and not bool(dense.overflow):
+        if dense_ok:
             self.dense_side = dense
         self.payload = batch
         return []
@@ -424,7 +434,6 @@ class LookupJoinOperator(Operator):
         jt = self.join_type
 
         def make():
-            @jax.jit
             def step(tables, payload: Batch, batch: Batch, params=()) -> Batch:
                 trace_probe()
                 with param_scope(params):
@@ -456,7 +465,8 @@ class LookupJoinOperator(Operator):
                 keep = ~matched if jt == "anti" else matched
                 return batch.with_live(batch.live & keep)
 
-            return step
+            step.__name__ = f"probe_{jt}_step"
+            return jax.jit(step)
 
         self._pallas_step = EXEC_CACHE.get_or_build(
             EXEC_CACHE.key_of("lookup_pallas", key, outs, jt, spec.key()),
@@ -518,7 +528,6 @@ class LookupJoinOperator(Operator):
             )
 
             def make_semi():
-                @jax.jit
                 def step(side, payload: Batch, batch: Batch, params=()) -> Batch:
                     trace_probe()
                     with param_scope(params):
@@ -530,7 +539,8 @@ class LookupJoinOperator(Operator):
                                 else batch.live & ~exists)
                         return batch.with_live(batch.live & keep)
 
-                return step
+                step.__name__ = f"probe_{jt}_step"
+                return jax.jit(step)
 
             self._step = EXEC_CACHE.get_or_build(
                 EXEC_CACHE.key_of("lookup_semi", key, jt, use_dense),
@@ -547,7 +557,6 @@ class LookupJoinOperator(Operator):
             unique_probe = self._make_unique_probe(use_dense)
 
             def make_unique():
-                @jax.jit
                 def step(side, payload: Batch, batch: Batch, params=()) -> Batch:
                     trace_probe()
                     with param_scope(params):
@@ -565,7 +574,8 @@ class LookupJoinOperator(Operator):
                                 else batch.live)
                         return Batch(cols, live)
 
-                return step
+                step.__name__ = f"probe_{jt}_step"
+                return jax.jit(step)
 
             self._step = EXEC_CACHE.get_or_build(
                 EXEC_CACHE.key_of("lookup_unique", key, outs, jt, verify,
@@ -617,6 +627,7 @@ class LookupJoinOperator(Operator):
                     )
                 return Batch(cols, live), res.overflow
 
+            step.__name__ = f"probe_{jt}_step"
             return jax.jit(step)
 
         self._step = EXEC_CACHE.get_or_build(
@@ -673,7 +684,9 @@ class LookupJoinOperator(Operator):
             out, overflow = self._step(self.build.build_side,
                                        self.build.payload, batch,
                                        self._params)
-        if bool(overflow):
+        with trace_sync("probe_overflow"):
+            overflow = bool(overflow)
+        if overflow:
             raise CapacityOverflow("LookupJoin", self.out_capacity)
         return [out]
 
@@ -708,8 +721,8 @@ class LookupJoinOperator(Operator):
 
             def make_full_unique():
                 @jax.jit
-                def step(side, payload: Batch, flags, batch: Batch,
-                         params=()):
+                def probe_full_step(side, payload: Batch, flags, batch: Batch,
+                                    params=()):
                     trace_probe()
                     with param_scope(params):
                         return body(side, payload, flags, batch)
@@ -732,7 +745,7 @@ class LookupJoinOperator(Operator):
                     flags = flags.at[rows].set(True, mode="drop")
                     return Batch(cols, batch.live), flags
 
-                return step
+                return probe_full_step
 
             self._full_step = EXEC_CACHE.get_or_build(
                 EXEC_CACHE.key_of("lookup_full_unique", key, outs, verify,
@@ -751,8 +764,8 @@ class LookupJoinOperator(Operator):
 
         def make_full_expand():
             @jax.jit
-            def step(side: BuildSide, payload: Batch, flags, batch: Batch,
-                     params=()):
+            def probe_full_step(side: BuildSide, payload: Batch, flags,
+                                batch: Batch, params=()):
                 trace_probe()
                 with param_scope(params):
                     return body(side, payload, flags, batch)
@@ -781,7 +794,7 @@ class LookupJoinOperator(Operator):
                 flags = flags.at[res.build_row].set(True, mode="drop")
                 return Batch(cols, res.live), flags, res.overflow
 
-            return step
+            return probe_full_step
 
         self._full_step = EXEC_CACHE.get_or_build(
             EXEC_CACHE.key_of("lookup_full_expand", key, outs, out_cap),
@@ -809,7 +822,9 @@ class LookupJoinOperator(Operator):
                 self.build.build_side, self.build.payload, flags, batch,
                 self._params,
             )
-        if bool(overflow):
+        with trace_sync("probe_overflow"):
+            overflow = bool(overflow)
+        if overflow:
             raise CapacityOverflow("LookupJoin", self.out_capacity)
         return out, new_flags
 

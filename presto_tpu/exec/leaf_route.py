@@ -660,7 +660,7 @@ def _build_local_step(spec: LeafAggSpec, member: Optional[Membership],
     lo = None if member is None else member.lo
     hi = None if member is None else member.hi
 
-    def step(batch: Batch, *bitmap):
+    def leaf_agg_step(batch: Batch, *bitmap):
         trace_probe()
         # declared NULL-freedom's runtime check, on the PRE-membership
         # batch (membership rebuilds validity as the live mask)
@@ -675,7 +675,7 @@ def _build_local_step(spec: LeafAggSpec, member: Optional[Membership],
             state["value_overflow"] = state["value_overflow"] | oob
         return state
 
-    return jax.jit(step)
+    return jax.jit(leaf_agg_step)
 
 
 def decode_leaf_state(route: LeafRoute, conn, aggs, state) -> Batch:
@@ -719,6 +719,8 @@ def execute_leaf_route(route: LeafRoute, executor, node, scalars):
     from presto_tpu.runtime.faults import fault_point
     from presto_tpu.runtime.lifecycle import check_deadline
     from presto_tpu.runtime.metrics import REGISTRY
+    from presto_tpu.runtime.trace import span as trace_span
+    from presto_tpu.runtime.trace import sync as trace_sync
 
     catalog = executor.catalog
     if route.kind == "q1":
@@ -749,9 +751,15 @@ def execute_leaf_route(route: LeafRoute, executor, node, scalars):
     cap = batch_capacity(max(s.row_hint for s in splits))
     mb = (None if route.member is None
           else (route.member.probe_col, route.member.lo, route.member.hi))
+    def build_fold():
+        def leaf_fold_step(a, b):
+            return combine_states(spec, a, b)
+
+        return jax.jit(leaf_fold_step)
+
     fold = EXEC_CACHE.get_or_build(
         EXEC_CACHE.key_of("leaf_route_fold", tuple(state_keys(spec))),
-        lambda: jax.jit(lambda a, b: combine_states(spec, a, b)),
+        build_fold,
     )
     state = None
     step = None
@@ -774,13 +782,21 @@ def execute_leaf_route(route: LeafRoute, executor, node, scalars):
                                   jax.default_backend()),
                 lambda: _build_local_step(spec, route.member, pallas_ok),
             )
-        s = step(b, *(() if bitmap is None else (bitmap,)))
-        state = s if state is None else fold(state, s)
-    if bool(state["value_overflow"]):
+        with trace_span("step:leaf_agg", "step"):
+            s = step(b, *(() if bitmap is None else (bitmap,)))
+        if state is None:
+            state = s
+        else:
+            with trace_span("step:leaf_fold", "step"):
+                state = fold(state, s)
+    with trace_sync("leaf_state"):
+        overflow = bool(state["value_overflow"])
+    if overflow:
         count_fallback("value_overflow")
         return None
     REGISTRY.counter("exec.leaf_fused_route").add()
-    return [decode_leaf_state(route, conn, node.aggs, state)]
+    with trace_span("decode:leaf_state", "step"):
+        return [decode_leaf_state(route, conn, node.aggs, state)]
 
 
 # ---------------------------------------------------------------------------
@@ -810,7 +826,7 @@ def _build_dist_step(spec, member_bounds, mesh, axes, q1: bool,
 
     @partial(shard_map, mesh=mesh, in_specs=in_specs, out_specs=P(),
              check_vma=False)
-    def step(batch: Batch, *bitmap):
+    def dist_leaf_agg_step(batch: Batch, *bitmap):
         trace_probe()
         nulls = null_violation(batch)
         oob = None
@@ -838,7 +854,10 @@ def _build_dist_step(spec, member_bounds, mesh, axes, q1: bool,
 
         return {k: allreduce(k, v) for k, v in state.items()}
 
-    return jax.jit(step)
+    # the device module carries the family's name (q1 or generic)
+    dist_leaf_agg_step.__name__ = ("dist_q1_agg_step" if q1
+                                   else "dist_leaf_agg_step")
+    return jax.jit(dist_leaf_agg_step)
 
 
 def execute_leaf_route_distributed(route: LeafRoute, executor, node,
@@ -853,6 +872,8 @@ def execute_leaf_route_distributed(route: LeafRoute, executor, node,
     from presto_tpu.parallel.mesh import worker_axes
     from presto_tpu.runtime.faults import fault_point
     from presto_tpu.runtime.metrics import REGISTRY
+    from presto_tpu.runtime.trace import span as trace_span
+    from presto_tpu.runtime.trace import sync as trace_sync
 
     fault_point("aggregation")
     fault_point("step.agg")
@@ -894,17 +915,22 @@ def execute_leaf_route_distributed(route: LeafRoute, executor, node,
         lambda: _build_dist_step(route.spec, member_bounds, mesh, axes,
                                  route.kind == "q1", pallas_ok),
     )
-    state = step(b, *(() if bitmap is None else (bitmap,)))
-    if bool(state["value_overflow"]):
+    q1 = route.kind == "q1"
+    with trace_span("step:q1_agg" if q1 else "step:leaf_agg", "step"):
+        state = step(b, *(() if bitmap is None else (bitmap,)))
+    with trace_sync("leaf_state"):
+        overflow = bool(state["value_overflow"])
+    if overflow:
         count_fallback("value_overflow")
         return None
     REGISTRY.counter("exec.leaf_fused_route").add()
-    if route.kind == "q1":
-        from presto_tpu.exec.q1_route import decode_q1_state
+    with trace_span("decode:leaf_state", "step"):
+        if q1:
+            from presto_tpu.exec.q1_route import decode_q1_state
 
-        REGISTRY.counter("exec.q1_fused_route").add()
-        return decode_q1_state(route.q1, conn, node.aggs, state)
-    return decode_leaf_state(route, conn, node.aggs, state)
+            REGISTRY.counter("exec.q1_fused_route").add()
+            return decode_q1_state(route.q1, conn, node.aggs, state)
+        return decode_leaf_state(route, conn, node.aggs, state)
 
 
 # ---------------------------------------------------------------------------

@@ -47,6 +47,7 @@ from presto_tpu.exec.pipeline import BatchSource, BatchStream, Pipeline, ScanSou
 from presto_tpu.expr import BIGINT, Call, Expr, InputRef, Literal, bind_scalars
 from presto_tpu.plan import nodes as N
 from presto_tpu.plan.catalog import Catalog
+from presto_tpu.runtime.trace import sync as trace_sync
 from presto_tpu.spi import batch_capacity
 from presto_tpu.types import TypeKind
 
@@ -288,7 +289,12 @@ class LocalExecutor(OomLadderMixin):
         batches, names = self.run_batches(plan)
         if not batches:
             return pd.DataFrame(columns=names)
-        dfs = [b.to_pandas() for b in batches if live_count(b) > 0]
+        # the result's readback: the host waits in the first count for
+        # whatever the last steps left running (sync:live_count), then
+        # copies the live rows out (sync:result)
+        batches = [b for b in batches if live_count(b) > 0]
+        with trace_sync("result"):
+            dfs = [b.to_pandas() for b in batches]
         if not dfs:
             return pd.DataFrame(columns=names)
         return pd.concat(dfs, ignore_index=True)[list(names)]
@@ -797,8 +803,9 @@ class LocalExecutor(OomLadderMixin):
                 v = evaluate(key, b)
                 data = v.data.astype(jnp.int64)
                 live = b.live & v.valid
-                mx = max(mx, int(jnp.max(jnp.where(live, data, 0))))
-                mn = min(mn, int(jnp.min(jnp.where(live, data, 0))))
+                with trace_sync("join_key_range"):
+                    mx = max(mx, int(jnp.max(jnp.where(live, data, 0))))
+                    mn = min(mn, int(jnp.min(jnp.where(live, data, 0))))
             return (mn, mx)
 
         def runtime_dict(side: int, key: Expr):
@@ -941,7 +948,8 @@ class LocalExecutor(OomLadderMixin):
 
         ck = stats_cache.minmax_key(self.catalog, node_right, rkey)
         if ck is not None and stats_cache.peek(ck) is None:
-            mn, mx = int(slot.minmax[0]), int(slot.minmax[1])
+            with trace_sync("join_key_range"):
+                mn, mx = int(slot.minmax[0]), int(slot.minmax[1])
             if mn <= mx:  # non-empty build only: an empty build's
                 # sentinel interval would poison key packing
                 stats_cache.cached_minmax(ck, lambda: (mn, mx))
@@ -968,7 +976,7 @@ class LocalExecutor(OomLadderMixin):
             from presto_tpu.ops.hashing import bloom_test
 
             @jax.jit
-            def step(b: Batch, mn, mx, *wrds):
+            def join_filter_step(b: Batch, mn, mx, *wrds):
                 trace_probe()
                 col = b[name]
                 k = col.data.astype(jnp.int64)
@@ -981,7 +989,7 @@ class LocalExecutor(OomLadderMixin):
                 pruned = jnp.sum((b.live & ~live).astype(jnp.int32))
                 return b.with_live(live), n_in, pruned
 
-            return step
+            return join_filter_step
 
         step = EXEC_CACHE.get_or_build(
             EXEC_CACHE.key_of("join_filter", name, words is not None),
@@ -1009,7 +1017,8 @@ class LocalExecutor(OomLadderMixin):
             for slot in slots:
                 if slot.stat_in is None:
                     continue
-                n_in, pruned = int(slot.stat_in), int(slot.stat_pruned)
+                with trace_sync("join_filter_stats"):
+                    n_in, pruned = int(slot.stat_in), int(slot.stat_pruned)
                 slot.stat_in = slot.stat_pruned = None
                 REGISTRY.counter("join.filter_rows_in").add(n_in)
                 REGISTRY.counter("join.filter_rows_pruned").add(pruned)

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from presto_tpu.batch import Batch, Column, Dictionary
+from presto_tpu.batch import Batch, Column, Dictionary, live_count
 from presto_tpu.expr import Expr, Val, evaluate, evaluate_predicate, param_scope
 from presto_tpu.ops.groupby import (
     ValueBitsOverflow,
@@ -46,6 +46,7 @@ from presto_tpu.ops.groupby import (
 from presto_tpu.ops.sort import sort_indices, top_n_indices
 from presto_tpu.runtime.errors import InternalError, ResourceExhausted
 from presto_tpu.runtime.trace import span as trace_span
+from presto_tpu.runtime.trace import sync as trace_sync
 from presto_tpu.types import BIGINT, DOUBLE, DataType, TypeKind
 
 
@@ -129,7 +130,7 @@ class FilterProjectOperator(Operator):
 
         pred, projs = self.predicate, self.projections
 
-        def step(batch: Batch, params=()) -> Batch:
+        def filter_project_step(batch: Batch, params=()) -> Batch:
             trace_probe()
             with param_scope(params):
                 return body(batch)
@@ -165,7 +166,7 @@ class FilterProjectOperator(Operator):
                 cols[name] = Column(v.data, v.valid, v.dtype, v.dictionary)
             return Batch(cols, live)
 
-        return step
+        return filter_project_step
 
     def process(self, batch: Batch) -> list[Batch]:
         # FilterProject usually runs via stream.map closures (never
@@ -276,8 +277,13 @@ class HashAggregationOperator(Operator):
         tmpl.state = None
         tmpl._dicts = {}
         tmpl._key_types = dict(self._key_types)
+        # jitted under the family's name, which is what the device
+        # trace calls the module (the sort strategy's is _sort_update)
         if isinstance(self.strategy, DirectStrategy):
-            return jax.jit(tmpl._direct_update)
+            def hash_agg_direct_step(state, batch: Batch, params=()):
+                return tmpl._direct_update(state, batch, params)
+
+            return jax.jit(hash_agg_direct_step)
         return jax.jit(tmpl._sort_update)
 
     def _dict_carrier(self, kvals, pvals=()):
@@ -604,14 +610,20 @@ class HashAggregationOperator(Operator):
             else:
                 self.state = self._sort_init()
         st = self.state
-        if isinstance(self.strategy, SortStrategy) and bool(st["overflow"]):
+        # the state's flags are the first host read after the updates:
+        # the host waits here for every update dispatched so far
+        flags = (("overflow",) if isinstance(self.strategy, SortStrategy)
+                 else ("null_key", "value_overflow"))
+        with trace_sync("hash_agg_state"):
+            raised = {k for k in flags if bool(st[k])}
+        if "overflow" in raised:
             raise CapacityOverflow("HashAggregation", self.strategy.max_groups)
-        if isinstance(self.strategy, DirectStrategy) and bool(st["null_key"]):
+        if "null_key" in raised:
             raise NullGroupKeys(
                 "direct-addressed grouping met NULL key values "
                 f"({[n for n, _ in self.group_keys]}) — replan with the "
                 "sort strategy")
-        if isinstance(self.strategy, DirectStrategy) and bool(st["value_overflow"]):
+        if "value_overflow" in raised:
             raise ValueBitsOverflow(
                 "a declared AggSpec.value_bits bound was exceeded at "
                 f"runtime in {[a.name for a in self.aggs]} — the planner "
@@ -701,7 +713,11 @@ class GlobalAggregationOperator(Operator):
         tmpl.aggs = list(self.aggs)
         tmpl.phase = self.phase
         tmpl.state = None
-        return jax.jit(tmpl._step)
+
+        def global_agg_step(state, batch: Batch, params=()):
+            return tmpl._step(state, batch, params)
+
+        return jax.jit(global_agg_step)
 
     def _step(self, state, batch: Batch, params=()):
         with param_scope(params):
@@ -1050,7 +1066,7 @@ class WindowOperator(CollectingOperator):
                 return bytes_sort_chunks(v.data)
             return [sortable(v)]
 
-        def step(batch: Batch, params=()) -> Batch:
+        def window_step(batch: Batch, params=()) -> Batch:
             trace_probe()
             with param_scope(params):
                 return body(batch)
@@ -1186,7 +1202,7 @@ class WindowOperator(CollectingOperator):
                     )
             return Batch(cols, live)
 
-        return step
+        return window_step
 
     def finish(self) -> list[Batch]:
         if not self.batches:
@@ -1222,7 +1238,7 @@ class LimitOperator(Operator):
     def process(self, batch: Batch) -> list[Batch]:
         if self.remaining <= 0:
             return []
-        c = int(batch.count())
+        c = live_count(batch)
         if c <= self.remaining:
             self.remaining -= c
             return [batch]

@@ -281,6 +281,8 @@ def execute_q1_route(route: Q1Route, catalog, aggs) -> Optional[list[Batch]]:
     from presto_tpu.runtime.faults import fault_point
     from presto_tpu.runtime.lifecycle import check_deadline
     from presto_tpu.runtime.metrics import REGISTRY
+    from presto_tpu.runtime.trace import span as trace_span
+    from presto_tpu.runtime.trace import sync as trace_sync
     from presto_tpu.workloads import combine_q1_states, q1_fused_step
 
     fault_point("aggregation")
@@ -296,18 +298,21 @@ def execute_q1_route(route: Q1Route, catalog, aggs) -> Optional[list[Batch]]:
     def _build(pallas_ok: bool):
         from presto_tpu.ops.pallas_agg import null_violation
 
-        def step(batch: Batch):
+        def q1_agg_step(batch: Batch):
             trace_probe()
             nulls = null_violation(batch)
             state = q1_fused_step(batch, pallas_ok=pallas_ok)
             state["value_overflow"] = state["value_overflow"] | nulls
             return state
 
-        return jax.jit(step)
+        return jax.jit(q1_agg_step)
+
+    def leaf_fold_step(a, b):
+        return combine_q1_states(a, b)
 
     fold = EXEC_CACHE.get_or_build(
         EXEC_CACHE.key_of("q1_route_fold"),
-        lambda: jax.jit(combine_q1_states),
+        lambda: jax.jit(leaf_fold_step),
     )
     state = None
     step = None
@@ -328,13 +333,21 @@ def execute_q1_route(route: Q1Route, catalog, aggs) -> Optional[list[Batch]]:
                                   jax.default_backend()),
                 lambda: _build(pallas_ok),
             )
-        s = step(b)
-        state = s if state is None else fold(state, s)
-    if state is None or bool(state["value_overflow"]):
+        with trace_span("step:q1_agg", "step"):
+            s = step(b)
+        if state is None:
+            state = s
+        else:
+            with trace_span("step:leaf_fold", "step"):
+                state = fold(state, s)
+    with trace_sync("leaf_state"):
+        overflow = bool(state["value_overflow"])
+    if overflow:
         REGISTRY.counter("exec.q1_route_fallback").add()
         return None
     REGISTRY.counter("exec.q1_fused_route").add()
-    return [decode_q1_state(route, conn, aggs, state)]
+    with trace_span("decode:leaf_state", "step"):
+        return [decode_q1_state(route, conn, aggs, state)]
 
 
 def decode_q1_state(route: Q1Route, conn, aggs, state) -> Batch:

@@ -261,7 +261,7 @@ def _pallas_step(spec: LeafAggSpec, batch, interpret: bool | None = None):
     B = _block_rows(spec, cap)
     args = [batch[c].data for c in spec.cols]
     args.append(batch.live.astype(jnp.int8))
-    o = slots_pallas_call(partial(_kernel, spec), args, cap, B,
+    o = slots_pallas_call(partial(_kernel, spec), args, cap, B, "leaf_agg",
                           interpret=interpret)
     G = spec.groups
     nl = spec.nlanes

@@ -120,10 +120,12 @@ def emit_slots(o_ref, i, spm, scalars):
         o_ref[...] = o_ref[...] + vec
 
 
-def slots_pallas_call(kernel, args, cap, B, interpret=None):
+def slots_pallas_call(kernel, args, cap, B, name, interpret=None):
     """Run ``kernel`` on a (nblk,) grid over 1-D [cap] arrays reshaped
     to (1, 8, B//8) blocks, accumulating (1, 1, _SLOTS) int32 tiles per
-    <= 2^23-row major; returns the int64 [_SLOTS] recombined totals."""
+    <= 2^23-row major; returns the int64 [_SLOTS] recombined totals.
+    ``name`` is the caller's kernel family: what the device trace calls
+    the custom call."""
     nblk = cap // B
     spm = max(1, _MAJOR_ROWS // B)
     nmajor = -(-nblk // spm)
@@ -137,6 +139,7 @@ def slots_pallas_call(kernel, args, cap, B, interpret=None):
             (1, 1, _SLOTS), lambda i: (i // np.int32(spm), _I0, _I0)),
         out_shape=jax.ShapeDtypeStruct((nmajor, 1, _SLOTS), jnp.int32),
         interpret=pallas_mode.interpret(interpret),
+        name=name,
     )(*args3d)
     return out.astype(jnp.int64).sum(axis=(0, 1)).reshape(_SLOTS)
 
@@ -205,7 +208,7 @@ def fused_lane_sums(values, bits_list, count_masks, gids, max_groups: int,
             + [jnp.minimum(gids, max_groups).astype(jnp.int32)])
     o = slots_pallas_call(
         partial(_kernel, nlanes_list, max_groups, nval, nmask),
-        args, cap, B, interpret=interpret)
+        args, cap, B, "groupby_slots", interpret=interpret)
 
     per_g = o[: max_groups * (nl_total + len(count_masks))].reshape(
         max_groups, nl_total + len(count_masks))

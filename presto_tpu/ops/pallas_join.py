@@ -357,6 +357,7 @@ def exists_probe(table, key_min: int, key_max: int, keys, live,
         out_specs=pl.BlockSpec((sp, _LANES), lambda i: (i, _I0)),
         out_shape=jax.ShapeDtypeStruct((nblk * sp, _LANES), jnp.int8),
         interpret=pallas_mode.interpret(interpret),
+        name="join_probe_exists",
     )(table, _blocked(keys, nblk, sp), _blocked(live.astype(jnp.int8),
                                                 nblk, sp))
     return out.reshape(cap) != 0
@@ -379,6 +380,7 @@ def sketch_probe(table, nbits: int, keys, live,
         out_specs=pl.BlockSpec((sp, _LANES), lambda i: (i, _I0)),
         out_shape=jax.ShapeDtypeStruct((nblk * sp, _LANES), jnp.int8),
         interpret=pallas_mode.interpret(interpret),
+        name="join_probe_sketch",
     )(table, _blocked(keys, nblk, sp), _blocked(live.astype(jnp.int8),
                                                 nblk, sp))
     return out.reshape(cap) != 0
@@ -408,6 +410,7 @@ def payload_probe(tables, key_min: int, key_max: int, keys, live,
         + [jax.ShapeDtypeStruct((nblk * sp, _LANES), jnp.int32)
            for _ in range(nval)],
         interpret=pallas_mode.interpret(interpret),
+        name="join_probe_payload",
     )(*tables, _blocked(keys, nblk, sp), _blocked(live.astype(jnp.int8),
                                                   nblk, sp))
     matched = outs[0].reshape(cap) != 0

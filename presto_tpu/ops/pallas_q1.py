@@ -193,7 +193,8 @@ def q1_step(batch, interpret: bool | None = None):
         "l_shipdate", "l_returnflag", "l_linestatus", "l_quantity",
         "l_extendedprice", "l_discount", "l_tax")]
     args.append(batch.live.astype(jnp.int8))
-    o = slots_pallas_call(_kernel, args, cap, B, interpret=interpret)
+    o = slots_pallas_call(_kernel, args, cap, B, "q1_agg",
+                          interpret=interpret)
     per_g = o[: G * (_NL + 1)].reshape(G, _NL + 1)
     names = ("sum_qty", "sum_base_price", "sum_disc_price", "sum_charge",
              "sum_disc")

@@ -158,9 +158,10 @@ def _like_kernel(pattern: str, width: int, data_ref, out_ref):
     out_ref[:] = _bool_i32(ok)
 
 
-def _run_rowwise(kernel, data, interpret=None) -> jnp.ndarray:
+def _run_rowwise(kernel, data, name, interpret=None) -> jnp.ndarray:
     """Launch a [tile, W] -> [tile, 1] int32 kernel over row tiles and
-    return the bool [n] mask."""
+    return the bool [n] mask. ``name`` is the kernel family, as the
+    device trace shows it."""
     n0, width = data.shape
     padded, _ = _pad_rows(jnp.asarray(data), _ROW_TILE)
     padded = padded.astype(_I32)  # widen outside the kernel (see _match_at)
@@ -179,6 +180,7 @@ def _run_rowwise(kernel, data, interpret=None) -> jnp.ndarray:
         out_specs=pl.BlockSpec((_ROW_TILE, 1), lambda i: (i, np.int32(0)),
                                memory_space=pltpu.VMEM),
         interpret=pallas_mode.interpret(interpret),
+        name=name,
     )(padded)
     return out[:n0, 0] > 0
 
@@ -197,7 +199,7 @@ def like_mask_pallas(data, pattern: str, interpret=None) -> jnp.ndarray:
         raise NotImplementedError("LIKE '_' wildcard on byte columns")
     width = data.shape[1]
     return _run_rowwise(partial(_like_kernel, pattern, width), data,
-                        interpret)
+                        "strings_like", interpret)
 
 
 def _prefix_kernel(prefix: bytes, data_ref, out_ref):
@@ -213,4 +215,5 @@ def starts_with_pallas(data, prefix: str, interpret=None) -> jnp.ndarray:
         return jnp.ones(data.shape[0], jnp.bool_)
     if len(pb) > data.shape[1]:
         return jnp.zeros(data.shape[0], jnp.bool_)
-    return _run_rowwise(partial(_prefix_kernel, pb), data, interpret)
+    return _run_rowwise(partial(_prefix_kernel, pb), data,
+                        "strings_starts_with", interpret)

@@ -333,11 +333,11 @@ def make_shuffle_step(mesh, num_partitions: int, quota: int):
         out_specs=(P(axes), P()),
         check_vma=False,
     )
-    def step(batch: Batch, pids):
+    def exchange_shuffle_step(batch: Batch, pids):
         out, ovf = exchange_local(batch, pids, num_partitions, quota, axes)
         return out, any_flag(ovf, axes)
 
-    return jax.jit(step)
+    return jax.jit(exchange_shuffle_step)
 
 
 def make_multiround_shuffle_step(
@@ -359,13 +359,13 @@ def make_multiround_shuffle_step(
         out_specs=(P(axes), P()),
         check_vma=False,
     )
-    def step(batch: Batch, pids):
+    def exchange_multiround_step(batch: Batch, pids):
         out, ovf = exchange_multiround(
             batch, pids, num_partitions, quota, recv_cap, axes=axes
         )
         return out, any_flag(ovf, axes)
 
-    return jax.jit(step)
+    return jax.jit(exchange_multiround_step)
 
 
 def make_broadcast_step(mesh):
@@ -382,7 +382,7 @@ def make_broadcast_step(mesh):
         out_specs=P(),
         check_vma=False,
     )
-    def step(batch: Batch):
+    def exchange_broadcast_step(batch: Batch):
         return broadcast_local(batch, axes)
 
-    return jax.jit(step)
+    return jax.jit(exchange_broadcast_step)

@@ -395,6 +395,15 @@ METRIC_HELP: dict[str, str] = {
         "aggregation queries routed through the fused Q1-shape kernel"),
     "exec.q1_route_fallback": (
         "Q1-shape route bailouts to the general aggregation path"),
+    "exec.scan.splits": "splits generated by a connector scan",
+    "exec.scan.rows": "live rows generated by connector scans",
+    "exec.h2d.bytes": (
+        "bytes handed to the device by Batch.from_numpy (capacity "
+        "padding and masks included)"),
+    "exec.h2d.arrays": "host arrays handed to the device by Batch.from_numpy",
+    "exec.sync.reads": (
+        "places the host read a device value and waited for it "
+        "(one per sync:* span)"),
     "exec.traces": "actual jit traces executed (the no-retrace probe)",
     "exec.trace_errors": (
         "best-effort trace/observability plumbing failures (the "

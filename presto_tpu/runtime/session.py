@@ -426,9 +426,8 @@ class Session:
         recorder = StatsRecorder()
         t0 = time.perf_counter()
         plan = self.plan(sql)
-        planning_s = time.perf_counter() - t0
-        _df, info = self._run_tracked(sql, plan, recorder,
-                                      planning_s=planning_s)
+        _df, info = self._run_tracked(
+            sql, plan, recorder, planning=(t0, time.perf_counter() - t0))
         rendered = render_analyzed_plan(
             plan, recorder, tracer=self.traces.for_query(info.query_id)
         )
@@ -445,8 +444,24 @@ class Session:
 
         from presto_tpu.sql import ast as A
 
+        # the ``plan`` interval (parse, analyse, template binding) ends
+        # before the query's recorder exists: it is annotated here, where
+        # it happens, and _run_tracked puts ``planning`` = (start,
+        # seconds) on the recorder as the ``plan`` span
+        rctx = REQUEST_TRACE.get()
+        want = bool(self.prop("collect_node_stats"))
         t0 = time.perf_counter()
-        stmt = parse(sql)
+        with trace.annotation(
+                "plan", (rctx.get("token") if rctx else None)
+                or self.trace_token,
+                on=bool(self.prop("profile_annotations"))):
+            stmt = parse(sql)
+            if not isinstance(stmt, (A.Prepare, A.ExecuteStmt, A.Deallocate,
+                                     A.CreateTableAs, A.InsertInto,
+                                     A.DropTable)):
+                plan, bound = self._plan_binding(stmt,
+                                                 parameterize=not want)
+        planning = (t0, time.perf_counter() - t0)
         if isinstance(stmt, A.Prepare):
             self._prepared[stmt.name] = self._prepare_ast(
                 stmt.name, sql, stmt.statement)
@@ -454,7 +469,7 @@ class Session:
         if isinstance(stmt, A.ExecuteStmt):
             df, _info = self.execute_prepared(
                 stmt.name, [_ast_literal_value(a) for a in stmt.args],
-                planning_s=time.perf_counter() - t0,
+                planning=planning,
             )
             return df
         if isinstance(stmt, A.Deallocate):
@@ -463,12 +478,9 @@ class Session:
             return pd.DataFrame({"deallocated": [stmt.name]})
         if isinstance(stmt, (A.CreateTableAs, A.InsertInto, A.DropTable)):
             return self._run_ddl(sql, stmt)
-        want = bool(self.prop("collect_node_stats"))
-        plan, bound = self._plan_binding(stmt, parameterize=not want)
-        planning_s = time.perf_counter() - t0
         df, _info = self._run_with_retries(
             sql, plan, (lambda: StatsRecorder()) if want else (lambda: None),
-            planning_s=planning_s, bound=bound,
+            planning=planning, bound=bound,
         )
         return df
 
@@ -533,7 +545,7 @@ class Session:
         self._prepared[handle.name] = handle
         return handle
 
-    def execute_prepared(self, handle, params=(), planning_s: float = 0.0):
+    def execute_prepared(self, handle, params=(), planning=(0.0, 0.0)):
         """Execute a prepared handle (or its registered name) with
         positional ``?`` bindings; returns (DataFrame, QueryInfo)."""
         from presto_tpu.plan.templates import PreparedStatement
@@ -546,7 +558,7 @@ class Session:
         bound = handle.bind(list(params))
         return self._run_with_retries(
             handle.sql, handle.plan, lambda: None,
-            planning_s=planning_s, bound=bound,
+            planning=planning, bound=bound,
         )
 
     def _owning_catalog(self, table: str):
@@ -599,9 +611,9 @@ class Session:
                 )
         t0 = time.perf_counter()
         plan, bound = self._plan_binding(stmt.query)
-        planning_s = time.perf_counter() - t0
-        df, _info = self._run_with_retries(sql, plan, lambda: None,
-                                           planning_s=planning_s, bound=bound)
+        df, _info = self._run_with_retries(
+            sql, plan, lambda: None,
+            planning=(t0, time.perf_counter() - t0), bound=bound)
         if isinstance(stmt, A.CreateTableAs):
             rows = mem.create_table(stmt.name, df)
         else:
@@ -620,12 +632,12 @@ class Session:
             return self.execute_prepared(sql, params or ())
         t0 = time.perf_counter()
         plan = self.plan(sql)
-        planning_s = time.perf_counter() - t0
-        return self._run_with_retries(sql, plan, StatsRecorder,
-                                      planning_s=planning_s)
+        return self._run_with_retries(
+            sql, plan, StatsRecorder,
+            planning=(t0, time.perf_counter() - t0))
 
     def _run_with_retries(self, sql: str, plan, make_recorder,
-                          planning_s: float = 0.0, bound=()):
+                          planning=(0.0, 0.0), bound=()):
         """The engine's whole failure-recovery posture, like the
         reference's: no mid-query recovery — a failed attempt fails the
         query, and recovery is re-running it from the top
@@ -636,7 +648,7 @@ class Session:
         for attempt in range(retries + 1):
             try:
                 return self._run_tracked(sql, plan, make_recorder(),
-                                         planning_s=planning_s, bound=bound)
+                                         planning=planning, bound=bound)
             except Exception:
                 if attempt == retries:
                     raise
@@ -644,11 +656,16 @@ class Session:
 
     # ------------------------------------------------------------------
     def _run_tracked(self, sql: str, plan: PlanNode, recorder,
-                     planning_s: float = 0.0, bound=()):
+                     planning=(0.0, 0.0), bound=()):
         """Track one execution attempt: QueryInfo lifecycle, span trace
         (when ``trace_enabled``), result-cache lookup, events.
         ``bound`` is the plan template's slot-ordered (dtype, value)
-        literal binding (empty for unparameterized plans)."""
+        literal binding (empty for unparameterized plans).
+        ``planning`` is the (perf_counter start, seconds) of the
+        caller's parse + analyse + bind, where it timed one: the seconds
+        are ``QueryInfo.planning_s``, and the interval goes on the
+        recorder as the ``plan`` span, beside the ``query`` span whose
+        extent it does not change."""
         # request-scoped trace context (serving front-end / subscription
         # manager): the client's trace token overrides the session's,
         # the subscription id rides into history attribution, and the
@@ -661,7 +678,7 @@ class Session:
             state="QUEUED",
             created_at=time.time(),
             created_mono=time.monotonic(),
-            planning_s=planning_s,
+            planning_s=planning[1],
             trace_token=(rctx.get("token") if rctx else None)
             or self.trace_token,
             # serving-layer attribution: request-scoped tenant first
@@ -696,6 +713,8 @@ class Session:
             self.query_manager.close_scope(info.query_id)
             if tracer is not None:
                 trace.uninstall(token)
+                if planning[1] > 0.0:
+                    tracer.add_complete("plan", "planner", *planning)
                 self.traces.add(tracer)
 
     def _run_tracked_inner(self, sql: str, plan: PlanNode, recorder, info,
@@ -996,8 +1015,13 @@ class Session:
                     raise
             remaining = (None if deadline is None
                          else max(0.0, deadline - time.monotonic()))
-            role, payload = gate.lead_or_wait(base_fp, member, remaining,
-                                              max_batch=max_batch)
+            # annotated where it happens: the span below is recorded
+            # post-hoc, once the verdict is known
+            with trace.annotation(
+                    "batch:gate_wait", info.trace_token,
+                    on=bool(self.prop("profile_annotations"))):
+                role, payload = gate.lead_or_wait(base_fp, member, remaining,
+                                                  max_batch=max_batch)
             if role != "retry":
                 # the batch-gate wait, visible in the trace between
                 # submit and dispatch (the serving-tier span chain)

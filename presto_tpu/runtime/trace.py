@@ -28,6 +28,20 @@ Design constraints:
 - **Bounded.** Spans per query cap at ``max_spans`` (overflow counts
   into the ``trace.spans_dropped`` metric, never errors); the
   per-session :class:`TraceStore` is a fixed-size ring.
+- **A span the benchmark reads is a live interval, never a post-hoc
+  sum.** Every span is one real interval on the query's thread, so
+  with ``profile_annotations`` on it is also a ``TraceAnnotation`` on
+  the profiler's clock and the benchmark's gap attribution can put an
+  idle gap of the device under it. Two categories exist for that
+  reading: ``scan`` parts the host's work inside a connector scan
+  (``scan:generate`` making the split's host arrays, ``batch:pad`` the
+  capacity-sized copies, ``batch:upload`` the hand-over to the device),
+  and ``sync`` marks every place the served local path blocks on a
+  device value (:func:`sync`). Intervals that begin before the query's
+  recorder exists or end after it closed (``plan``,
+  ``frontend:submit``, ``frontend:encode``) are timed where they
+  happen under :func:`annotation` and put on the recorder with
+  ``add_complete`` from the same two clock reads.
 """
 
 from __future__ import annotations
@@ -55,6 +69,9 @@ CATEGORIES = (
     "stats",      # estimate snapshot / plan-stats history recording
     "frontend",   # HTTP serving-tier spans (submit / poll round-trips)
     "subscription",  # a continuous-query refresh fire (child of its sub)
+    "scan",       # host work inside a connector scan: generate / pad / upload
+    "sync",       # the host blocked on a device value (see sync())
+    "planner",    # parse + analyse + template binding, before the query span
 )
 
 _TRACE: ContextVar[Optional["TraceRecorder"]] = ContextVar(
@@ -126,7 +143,7 @@ class TraceRecorder:
 
     __slots__ = (
         "query_id", "trace_token", "max_spans", "annotate",
-        "spans", "dropped", "created_wall", "_stack", "_seq",
+        "spans", "dropped", "created_clock", "_stack", "_seq",
     )
 
     def __init__(self, query_id: str, trace_token: Optional[str] = None,
@@ -137,7 +154,9 @@ class TraceRecorder:
         self.annotate = annotate
         self.spans: list[Span] = []
         self.dropped = 0
-        self.created_wall = time.time()
+        #: (perf_counter, time_ns) read together: span times are on
+        #: the first clock, a profiler trace on the second
+        self.created_clock = (time.perf_counter(), time.time_ns())
         self._stack: list[int] = []  # open span ids (parents)
         self._seq = 0
 
@@ -200,9 +219,6 @@ class TraceRecorder:
 
     def spans_by_cat(self, cat: str) -> list[Span]:
         return [s for s in self.spans if s.cat == cat]
-
-    def children_of(self, span_id: int) -> list[Span]:
-        return [s for s in self.spans if s.parent_id == span_id]
 
     # -- export ------------------------------------------------------------
     def to_events(self, pid: int) -> list[dict]:
@@ -272,6 +288,26 @@ def add_complete(name: str, cat: str, t0: float, dur_s: float,
     rec = _TRACE.get()
     if rec is not None:
         rec.add_complete(name, cat, t0, dur_s, args)
+
+
+def annotation(name: str, token: Optional[str], on: bool):
+    """The profiler annotation ``<name>#<token>`` alone, for an interval
+    that reaches the recorder through ``add_complete`` (it starts before
+    the recorder exists, ends after it closed, or is recorded only once
+    its outcome is known): entered where the interval really happens, so
+    that the interval is on the profiler's clock like a live span.
+    ``on`` is the ``profile_annotations`` property; a no-op when off."""
+    ann = _annotation(name, token) if on else None
+    return _NOOP if ann is None else ann
+
+
+def sync(what: str):
+    """The span for one place where the host reads a device value and so
+    waits for every step dispatched before it: ``with sync("leaf_state"):
+    overflow = bool(state["value_overflow"])`` records ``sync:leaf_state``
+    (category ``sync``) and counts ``exec.sync.reads``."""
+    REGISTRY.counter("exec.sync.reads").add()
+    return span(f"sync:{what}", "sync")
 
 
 # ---------------------------------------------------------------------------
@@ -349,6 +385,12 @@ def to_chrome_trace(recorders: list[TraceRecorder]) -> dict:
             "engine": "presto_tpu",
             "trace_tokens": sorted(set(tokens)),
             "queries": [rec.query_id for rec in recorders],
+            # per query, the two clocks read together at recorder
+            # creation: ts is on the first, a profiler trace on the second
+            "clocks": [{"query": rec.query_id,
+                        "perf_counter_s": rec.created_clock[0],
+                        "time_ns": rec.created_clock[1]}
+                       for rec in recorders],
         },
     }
 

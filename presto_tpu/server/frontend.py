@@ -38,6 +38,7 @@ from presto_tpu.runtime.errors import (
     error_code,
 )
 from presto_tpu.runtime.metrics import REGISTRY
+from presto_tpu.runtime.trace import annotation as trace_annotation
 from presto_tpu.runtime.overload import OverloadController, shed_retry_after
 from presto_tpu.server.scheduler import FairScheduler, TenantSpec
 
@@ -301,9 +302,16 @@ class QueryServer:
         starved at the scheduler must poll as QUEUED, not RUNNING).
         Callers own ``_enter``/``_leave`` (submit() enters at accept
         time so a drain never drops an already-accepted query)."""
-        from presto_tpu.runtime.session import CURRENT_TENANT
+        from presto_tpu.runtime.session import CURRENT_TENANT, REQUEST_TRACE
 
-        with self.scheduler.slot(tenant, timeout_s):
+        # the slot wait, annotated where it happens (the frontend:submit
+        # span itself is stitched on post-hoc, once the query's recorder
+        # exists)
+        rctx = REQUEST_TRACE.get()
+        waiting = trace_annotation(
+            "frontend:submit", rctx.get("token") if rctx else None,
+            on=bool(self.session.prop("profile_annotations")))
+        with self.scheduler.slot(tenant, timeout_s, waiting):
             if on_start is not None:
                 on_start()
             token = CURRENT_TENANT.set(tenant)
@@ -543,7 +551,14 @@ class QueryServer:
                 # serialized once, on first poll of the terminal page —
                 # repeat polls (or several clients sharing the id) must
                 # not re-pay O(rows) JSON encoding per request
-                payload = rec["payload"] = _df_payload(rec["df"])
+                trace_ctx = rec["trace"]
+                t0 = time.perf_counter()
+                with trace_annotation(
+                        "frontend:encode", trace_ctx["token"],
+                        on=bool(self.session.prop("profile_annotations"))):
+                    payload = rec["payload"] = _df_payload(rec["df"])
+                # (start, seconds): stitched on as frontend:encode below
+                trace_ctx["encode"] = (t0, time.perf_counter() - t0)
             page.update(payload)
         elif rec["state"] == "FAILED":
             page["error"] = rec["error"]
@@ -581,6 +596,10 @@ class QueryServer:
                 max(0.0, started - t0),
                 {"queryId": rec["id"], "tenant": rec["tenant"],
                  "traceToken": trace_ctx["token"]})
+            if "encode" in trace_ctx:
+                tracer.add_complete(
+                    "frontend:encode", "frontend", *trace_ctx["encode"],
+                    {"queryId": rec["id"]})
             tracer.add_complete(
                 "frontend:poll", "frontend", poll_t0,
                 time.perf_counter() - poll_t0,

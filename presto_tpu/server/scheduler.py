@@ -47,7 +47,7 @@ import itertools
 import re
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 from typing import Iterable, Mapping, Optional
 
@@ -395,8 +395,13 @@ class FairScheduler:
             self._cv.notify_all()
 
     @contextmanager
-    def slot(self, tenant: str, timeout_s: Optional[float] = None):
-        token = self.acquire(tenant, timeout_s)
+    def slot(self, tenant: str, timeout_s: Optional[float] = None,
+             waiting=nullcontext()):
+        """Hold a slot for the body; ``waiting`` is entered around the
+        wait for it alone (the front end's ``frontend:submit``
+        annotation)."""
+        with waiting:
+            token = self.acquire(tenant, timeout_s)
         t0 = time.monotonic()
         try:
             yield

@@ -21,6 +21,8 @@ from typing import Callable, Mapping, Protocol, Sequence
 import numpy as np
 
 from presto_tpu.batch import Batch, Dictionary
+from presto_tpu.runtime import trace
+from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.types import DataType, TypeKind, narrow_physical
 
 
@@ -55,6 +57,20 @@ class Connector(Protocol):
     ) -> Batch: ...
 
     def row_count(self, table: str) -> int: ...
+
+
+def generate_split(conn, split: Split,
+                   columns: Sequence[str] | None = None) -> dict:
+    """``conn.scan_numpy`` as every connector's ``scan`` runs it: under
+    the ``scan:generate`` span (making the split's host arrays, before
+    ``Batch.from_numpy`` pads and uploads them), counting the split
+    (``exec.scan.splits``) and its live rows (``exec.scan.rows``)."""
+    with trace.span("scan:generate", "scan", {"table": split.table}):
+        arrays = dict(conn.scan_numpy(split, columns))
+    REGISTRY.counter("exec.scan.splits").add()
+    REGISTRY.counter("exec.scan.rows").add(
+        len(next(iter(arrays.values()))) if arrays else 0)
+    return arrays
 
 
 def split_valids(arrays: Mapping[str, np.ndarray]):

@@ -192,7 +192,7 @@ def q1_distributed_step(mesh):
         out_specs=P(),
         check_vma=False,
     )
-    def step(batch: Batch):
+    def dist_q1_agg_step(batch: Batch):
         state = q1_fused_step(batch)
 
         def allreduce(x):
@@ -202,7 +202,7 @@ def q1_distributed_step(mesh):
 
         return jax.tree.map(allreduce, state)
 
-    return jax.jit(step)
+    return jax.jit(dist_q1_agg_step)
 
 
 def q1_batch(conn: TpchConnector, split=None, capacity=None) -> Batch:

@@ -10,6 +10,7 @@ happens in the test's own process.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -58,12 +59,18 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, one_chip, *shapes, kernel):
     """Lower ``fn`` over ShapeDtypeStructs placed on the described chip
-    and compile; returns the compiled program's text."""
+    and compile; returns the compiled program's text. ``kernel`` is the
+    Pallas family's ``name=``: the compiled custom call is called after
+    it (``%leaf_agg.1 = ... custom_call_target="tpu_custom_call"``),
+    which is the name the device trace shows."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    calls = re.findall(r"%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert calls and set(calls) == {kernel}, calls
     return text
 
 
@@ -89,7 +96,7 @@ def test_q1_kernel_compiles(one_chip):
                    list(_Q1_COLS))
     _compile(fn, one_chip,
              *[((SCAN_CAP,), dt) for dt in _Q1_COLS.values()],
-             ((SCAN_CAP,), jnp.bool_))
+             ((SCAN_CAP,), jnp.bool_), kernel="q1_agg")
 
 
 # specs recorded from Session.sql on the CPU (pallas_eligible patched):
@@ -163,7 +170,7 @@ def test_leaf_agg_kernel_compiles(one_chip, name):
         lambda b: pallas_agg._pallas_step(spec, b, interpret=False),
         list(spec.cols))
     _compile(fn, one_chip, *[((SCAN_CAP,), dt) for dt in dtypes],
-             ((SCAN_CAP,), jnp.bool_))
+             ((SCAN_CAP,), jnp.bool_), kernel="leaf_agg")
 
 
 def test_groupby_kernel_compiles(one_chip):
@@ -174,7 +181,8 @@ def test_groupby_kernel_compiles(one_chip):
             [v0, v1, v2], bits, [m], g, 64, interpret=False)
 
     _compile(fn, one_chip, *[((SCAN_CAP,), jnp.int32)] * 3,
-             ((SCAN_CAP,), jnp.bool_), ((SCAN_CAP,), jnp.int32))
+             ((SCAN_CAP,), jnp.bool_), ((SCAN_CAP,), jnp.int32),
+             kernel="groupby_slots")
 
 
 @pytest.mark.parametrize("kind,pattern,width", [
@@ -187,7 +195,9 @@ def test_strings_kernel_compiles(one_chip, kind, pattern, width):
     run = (pallas_strings.like_mask_pallas if kind == "like"
            else pallas_strings.starts_with_pallas)
     _compile(lambda d: run(d, pattern, interpret=False), one_chip,
-             ((1 << 17, width), jnp.uint8))
+             ((1 << 17, width), jnp.uint8),
+             kernel="strings_like" if kind == "like"
+             else "strings_starts_with")
 
 
 def test_join_probe_is_refused_and_the_refusal_reaches_the_caller(one_chip):
@@ -205,4 +215,5 @@ def test_join_probe_is_refused_and_the_refusal_reaches_the_caller(one_chip):
 
     with pytest.raises(AssertionError):
         _compile(fn, one_chip, ((w, 128), jnp.int32),
-                 ((1 << 16,), jnp.int32), ((1 << 16,), jnp.bool_))
+                 ((1 << 16,), jnp.int32), ((1 << 16,), jnp.bool_),
+                 kernel="join_probe_exists")

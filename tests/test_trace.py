@@ -51,7 +51,10 @@ def test_local_query_records_nested_spans(conn):
     rec = s.traces.latest()
     assert rec is not None and rec.trace_token == "tok-local"
     roots = [sp for sp in rec.spans if sp.parent_id == -1]
-    assert [sp.cat for sp in roots] == ["query"]
+    # the query's root span, and beside it the planning interval that
+    # ended before it began
+    assert [sp.cat for sp in roots] == ["query", "planner"]
+    assert roots[1].name == "plan" and roots[1].t1 <= roots[0].t0
     steps = rec.spans_by_cat("step")
     assert steps, "no jitted-step spans recorded"
     # at least one step nests under node and query (the full chain)
@@ -484,6 +487,9 @@ def test_distributed_q3_trace_acceptance(tmp_path):
 
     node_ids = {sp.args["plan_node_id"] for sp in rec.spans_by_cat("node")}
     assert len(node_ids) == count_nodes(plan)
+    # the distributed scan parts its host work like the local one
+    scan_names = {sp.name for sp in rec.spans_by_cat("scan")}
+    assert scan_names == {"scan:generate", "batch:pad", "batch:upload"}
     # exchange spans carry nonzero byte counts
     ex = rec.spans_by_cat("exchange")
     assert ex and sum(sp.args["bytes"] for sp in ex) > 0
@@ -498,3 +504,231 @@ def test_distributed_q3_trace_acceptance(tmp_path):
         "select query_id, execution_s, planning_s from query_history"
     )
     assert len(hist) == 1 and hist["execution_s"].iloc[0] > 0
+
+
+# ---------------------------------------------------------------------------
+# inside the scan and on the device's own lines (ISSUE 25): the spans that
+# part generation, padding and upload, the sync spans, plan / encode beside
+# the query span, and a family name on every jitted step — served through
+# QueryServer on the CPU at SF 0.01, several splits a table. Counts repeat
+# exactly on a CPU; no timing is asserted.
+# ---------------------------------------------------------------------------
+
+import collections  # noqa: E402
+import re  # noqa: E402
+
+from presto_tpu.connectors.ssb import SsbConnector  # noqa: E402
+from presto_tpu.connectors.ssb.queries import QUERIES as SSB  # noqa: E402
+from presto_tpu.connectors.tpch.queries import QUERIES as TPCH  # noqa: E402
+from presto_tpu.plan import nodes as N  # noqa: E402
+from presto_tpu.spi import batch_capacity  # noqa: E402
+
+#: case -> (catalog, statement, span names beyond those every served
+#: query records)
+SERVED = {
+    "q6_leaf_route": ("tpch", TPCH["q6"], (
+        "step:leaf_agg", "step:leaf_fold", "sync:leaf_state",
+        "decode:leaf_state")),
+    "q3": ("tpch", TPCH["q3"], (
+        "sync:join_build", "sync:hash_agg_state", "sync:live_count",
+        "step:probe_inner")),
+    "ssb_q2_1": ("ssb", SSB["q2_1"], (
+        "sync:join_build", "sync:hash_agg_state", "sync:live_count",
+        "step:probe_inner")),
+}
+EVERY_SERVED = ("scan:generate", "batch:pad", "batch:upload", "plan",
+                "frontend:submit", "frontend:encode", "sync:result", "query")
+NEW_COUNTERS = ("exec.scan.splits", "exec.scan.rows", "exec.h2d.bytes",
+                "exec.h2d.arrays", "exec.sync.reads", "trace.spans_dropped")
+
+
+@pytest.fixture(scope="module")
+def served_conns():
+    import pyarrow as pa
+
+    # the server runs each query on a thread of its own, and Arrow's
+    # default pool has crashed there (PERF.md finding 2)
+    prev = pa.default_memory_pool()
+    pa.set_memory_pool(pa.system_memory_pool())
+    yield {"tpch": TpchConnector(sf=0.01, units_per_split=4096),
+           "ssb": SsbConnector(sf=0.01, units_per_split=16384)}
+    pa.set_memory_pool(prev)
+
+
+def _serve(conns, sql, **props):
+    """One statement through submit/poll on a server of its own:
+    (terminal page, the query's recorder, the new counters' deltas)."""
+    from presto_tpu.server.frontend import QueryServer
+
+    srv = QueryServer(conns, properties=dict(
+        props, result_cache_enabled=False))
+    try:
+        before = REGISTRY.snapshot()
+        qid = srv.submit(sql)
+        deadline = time.monotonic() + 600
+        page = srv.poll(qid)
+        while page["state"] not in ("FINISHED", "FAILED"):
+            assert time.monotonic() < deadline, "query did not finish"
+            time.sleep(0.01)
+            page = srv.poll(qid)
+        after = REGISTRY.snapshot()
+        delta = {k: after.get(k, 0) - before.get(k, 0) for k in NEW_COUNTERS}
+        return page, srv.session.traces.latest(), delta, srv.session.plan(sql)
+    finally:
+        srv.shutdown()
+
+
+def _scans(plan):
+    """[(table, source columns)] of the plan's TableScan nodes."""
+    out, todo = [], [plan]
+    while todo:
+        n = todo.pop()
+        if isinstance(n, N.TableScan):
+            out.append((n.table, [s for _, s in n.columns]))
+        todo.extend(n.children)
+    return out
+
+
+@pytest.mark.parametrize("case", sorted(SERVED))
+def test_served_query_parts_the_scan_and_names_its_syncs(served_conns, case):
+    catalog, sql, extra = SERVED[case]
+    conn = served_conns[catalog]
+    page, rec, delta, plan = _serve({catalog: conn}, sql)
+    assert page["state"] == "FINISHED", page
+    names = collections.Counter(sp.name for sp in rec.spans)
+    for name in EVERY_SERVED + extra:
+        assert names[name] >= 1, (name, dict(names))
+    cats = {sp.name: sp.cat for sp in rec.spans}
+    assert {cats["scan:generate"], cats["batch:pad"],
+            cats["batch:upload"]} == {"scan"}
+    assert cats["plan"] == "planner" and cats["frontend:encode"] == "frontend"
+    assert {c for n, c in cats.items() if n.startswith("sync:")} == {"sync"}
+    assert all(sp.t1 >= sp.t0 > 0 for sp in rec.spans)
+    # the hand count: every scanned table's splits, each padded to the
+    # table's capacity bucket — one live mask and the columns read at
+    # their narrowed storage width (no NULL masks in these tables)
+    scans = _scans(plan)
+    assert len(scans) >= (1 if case == "q6_leaf_route" else 3)
+    nsplits = rows = nbytes = narrays = 0
+    for table, cols in scans:
+        splits = conn.splits(table)
+        cap = batch_capacity(max(s.row_hint for s in splits))
+        width = sum(t.np_dtype.itemsize
+                    for t in conn.physical_schema(table, cols).values())
+        nsplits += len(splits)
+        rows += len(conn.table_numpy(table, cols[:1])[cols[0]])
+        nbytes += len(splits) * cap * (1 + width)
+        narrays += len(splits) * (1 + len(cols))
+    assert nsplits > len(scans)          # several splits a table
+    # once per scanned split, and counted where the work is done
+    for name in ("scan:generate", "batch:pad", "batch:upload"):
+        assert names[name] == nsplits, (name, names[name], nsplits)
+    assert delta["exec.scan.splits"] == nsplits
+    assert delta["exec.scan.rows"] == rows
+    assert delta["exec.h2d.bytes"] == nbytes
+    assert delta["exec.h2d.arrays"] == narrays
+    assert delta["exec.sync.reads"] == sum(
+        v for n, v in names.items() if n.startswith("sync:"))
+    assert delta["trace.spans_dropped"] == 0
+
+
+def test_plan_and_encode_are_recorded_and_annotated(served_conns,
+                                                    monkeypatch):
+    """``plan`` ends before the recorder exists and ``frontend:encode``
+    starts after it closed: both are on the recorder (add_complete) AND,
+    with ``profile_annotations`` on, live annotations ``<span>#<token>``
+    like every other span — as are the slot wait and the scan's parts."""
+    from presto_tpu.runtime import trace as T
+
+    real, seen = T._annotation, []
+
+    def recording(name, token):
+        seen.append(f"{name}#{token}" if token else name)
+        return real(name, token)
+
+    monkeypatch.setattr(T, "_annotation", recording)
+    conns = {"tpch": served_conns["tpch"]}
+    page, rec, _, _ = _serve(conns, TPCH["q6"], profile_annotations=True)
+    assert page["state"] == "FINISHED"
+    token = rec.trace_token
+    assert token
+    by_name = {sp.name: sp for sp in rec.spans}
+    query = by_name["query"]
+    assert by_name["plan"].t1 <= query.t0          # beside it, before
+    assert by_name["frontend:encode"].t0 >= query.t1   # and after
+    for name in ("plan", "frontend:encode", "frontend:submit", "query",
+                 "scan:generate", "batch:pad", "batch:upload",
+                 "sync:leaf_state", "sync:result"):
+        assert f"{name}#{token}" in seen, (name, seen)
+    # the export pairs the two clocks, read together per recorder
+    other = T.to_chrome_trace([rec])["otherData"]
+    (clock,) = other["clocks"]
+    assert clock["query"] == rec.query_id
+    assert 0 < clock["perf_counter_s"] <= query.t0 and clock["time_ns"] > 0
+    # ...and without the property nothing is annotated
+    del seen[:]
+    _serve(conns, TPCH["q6"])
+    assert seen == []
+
+
+#: the jitted families the three cells' templates run (local tier)
+FAMILIES = ("leaf_agg_step", "leaf_fold_step", "filter_project_step",
+            "join_build_step", "join_filter_step", "probe_inner_step",
+            "probe_left_step", "_sort_update")
+
+
+@pytest.fixture(scope="module")
+def lowered_modules(served_conns):
+    """Module names of every step the cells' templates jit, as lowering
+    gives them: ``jax.jit`` is wrapped for the length of the fixture so
+    that each jitted callable lowers its first call's arguments."""
+    import jax
+
+    from presto_tpu.cache.exec_cache import EXEC_CACHE
+
+    real_jit, modules = jax.jit, set()
+
+    class Lowering:
+        def __init__(self, fn):
+            self._fn, self._done = fn, False
+
+        def __call__(self, *args, **kwargs):
+            if not self._done:
+                self._done = True
+                text = self._fn.lower(*args, **kwargs).as_text()
+                modules.add(re.search(r"module @(\S+)", text).group(1))
+            return self._fn(*args, **kwargs)
+
+        def __getattr__(self, name):
+            return getattr(self._fn, name)
+
+    def jit(fn=None, **kwargs):
+        if fn is None:
+            return lambda f: jit(f, **kwargs)
+        return Lowering(real_jit(fn, **kwargs))
+
+    # every builder runs under the wrap, and no wrapped step is kept:
+    # the executable cache is stepped round, not wiped
+    patch = pytest.MonkeyPatch()
+    patch.setattr(EXEC_CACHE, "get_or_build", lambda key, builder: builder())
+    patch.setattr(jax, "jit", jit)
+    try:
+        for catalog, sql in (("tpch", TPCH["q6"]), ("tpch", TPCH["q3"]),
+                             ("tpch", TPCH["q13"]), ("ssb", SSB["q2_1"])):
+            Session({catalog: served_conns[catalog]},
+                    properties={"result_cache_enabled": False}).sql(sql)
+    finally:
+        patch.undo()
+    return modules
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_each_jitted_step_lowers_under_its_familys_name(lowered_modules,
+                                                        family):
+    assert f"jit_{family}" in lowered_modules, sorted(lowered_modules)
+
+
+def test_no_jitted_step_is_called_step(lowered_modules):
+    assert lowered_modules and not [
+        m for m in lowered_modules
+        if m in ("jit_step", "jit__step", "jit__lambda_", "jit__lambda")]
