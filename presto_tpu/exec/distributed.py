@@ -64,6 +64,7 @@ from presto_tpu.exec.operators import (
     SortStrategy,
     TopNOperator,
     _phys_dtype,
+    compact_batch,
 )
 from presto_tpu.exec.ladder import OomLadderMixin
 from presto_tpu.exec.pipeline import BatchSource, Pipeline
@@ -126,7 +127,7 @@ def _compact_step(mesh, out_cap: int):
     @partial(shard_map, mesh=mesh, in_specs=(P(ax),), out_specs=P(ax),
              check_vma=False)
     def dist_compact_step(local):
-        return _compact_local(local, out_cap)
+        return compact_batch(local, out_cap)
 
     return jax.jit(dist_compact_step)
 
@@ -147,24 +148,6 @@ def _pad_rows(b: Batch, cap: int) -> Batch:
         for n, c in b.columns.items()
     }
     return Batch(cols, pad(b.live, False))
-
-
-def _compact_local(b: Batch, out_cap: int) -> Batch:
-    """Gather live rows into a smaller-capacity batch (one nonzero +
-    per-column gather). Caller guarantees live_count <= out_cap."""
-    from presto_tpu.ops.compact import compact_indices
-
-    idx, _, _ = compact_indices(b.live, out_cap)
-    cols = {
-        n: Column(
-            gather_rows(c.data, idx, 0),
-            gather_padded(c.valid, idx, False),
-            c.dtype,
-            c.dictionary,
-        )
-        for n, c in b.columns.items()
-    }
-    return Batch(cols, gather_padded(b.live, idx, False))
 
 
 class DistributedExecutor(OomLadderMixin):
@@ -670,8 +653,11 @@ class DistributedExecutor(OomLadderMixin):
                 return len(first[name].dictionary)
             return None
 
+        from presto_tpu.plan.bounds import group_bound
+
+        bound = group_bound(node, self.catalog)
         strategy = pick_group_strategy(
-            keys, pax, dict_len, live_count(first),
+            keys, pax, dict_len, live_count(first), bound,
             direct_limit=self.direct_group_limit,
         )
         if isinstance(strategy, DirectStrategy):
@@ -692,7 +678,8 @@ class DistributedExecutor(OomLadderMixin):
                 # the packed direct domain has no NULL slot (same replan
                 # the local planner does): fall through to the sort path
                 strategy = pick_group_strategy(
-                    keys, pax, dict_len, live_count(first), direct_limit=0)
+                    keys, pax, dict_len, live_count(first), bound,
+                    direct_limit=0)
         if not d.sharded:
             for _ in range(MAX_RETRIES):
                 op = HashAggregationOperator(keys, aggs, strategy, passengers=pax,
@@ -1784,7 +1771,7 @@ class DistributedExecutor(OomLadderMixin):
             )
             def dist_filter_step(local: Batch, lbids, bkv):
                 keep = local.live & (lbids == bkv)
-                return _compact_local(local.with_live(keep), cap_pass)
+                return compact_batch(local.with_live(keep), cap_pass)
 
             return jax.jit(dist_filter_step)
 

@@ -9,8 +9,8 @@ TPU-first physical decisions made here (the reference makes them in
 the optimizer + operator factories):
 - grouping strategy: direct-addressed gids when every key is a small
   dictionary domain (product <= DIRECT_LIMIT), else bounded
-  merge-by-sort with max_groups sized from the actual input row count
-  (groups <= rows, so no overflow is possible when it fits the cap);
+  merge-by-sort with max_groups sized by the smaller of the input row
+  estimate and the key-domain bound (``bounds.group_bound``);
 - multi-key joins bit-pack key columns into one int64 using runtime
   maxima (non-negative keys; the planner guarantees TPC-H keys are);
 - static capacities come from capacity buckets with a retry-and-double
@@ -140,6 +140,7 @@ def _null_column(dtype, cap: int, tail: tuple = ()):
 
 
 def pick_group_strategy(keys, pax, dict_len, est_rows: int,
+                        group_bound: int | None = None,
                         direct_limit: int = DIRECT_LIMIT):
     """Grouping-strategy choice shared by the local and distributed
     executors: direct addressing for small dictionary-key domains,
@@ -147,10 +148,15 @@ def pick_group_strategy(keys, pax, dict_len, est_rows: int,
 
     ``dict_len``: name -> ordered-dictionary domain size (None when
     unknown) — metadata-only, so streaming inputs are never scanned or
-    drained to make this decision; ``est_rows``: stats-estimated input
-    row count sizing the sort strategy's group capacity, backed by
-    overflow-retry doubling.
+    drained to make this decision. The sort strategy's group capacity
+    is what can be live: the smaller of ``est_rows`` (input rows:
+    groups <= rows) and ``group_bound`` (``bounds.group_bound``: the
+    key domains' product, None when a key is unbounded), backed by
+    overflow-retry doubling. ``agg.strategy.bound_keys`` counts the
+    sizings the key bound lowered, ``agg.strategy.bound_rows`` the rest.
     """
+    from presto_tpu.runtime.metrics import REGISTRY
+
     if not pax and keys:
         domains = []
         ok = True
@@ -174,7 +180,11 @@ def pick_group_strategy(keys, pax, dict_len, est_rows: int,
             return DirectStrategy(
                 tuple(0 for _ in domains), tuple(strides), int(np.prod(domains))
             )
-    return SortStrategy(min(batch_capacity(max(est_rows, 16)), MAX_GROUP_CAP))
+    g = min(batch_capacity(max(est_rows, 16)), MAX_GROUP_CAP)
+    by_keys = group_bound is not None and batch_capacity(group_bound) < g
+    REGISTRY.counter("agg.strategy.bound_keys" if by_keys
+                     else "agg.strategy.bound_rows").add()
+    return SortStrategy(batch_capacity(group_bound) if by_keys else g)
 
 
 class LocalExecutor(OomLadderMixin):
@@ -594,18 +604,23 @@ class LocalExecutor(OomLadderMixin):
             # adaptive bypass (leaf_route.bypass_partial_agg): group
             # cardinality ~ input cardinality, so per-morsel partial
             # folds reduce nothing — materialize the (replayable)
-            # child once and aggregate in ONE pass over the concatenated
-            # rows, with the group capacity sized by the TRUE row count
-            # (groups <= rows: overflow is impossible by construction)
+            # child once and aggregate in ONE pass over its live rows,
+            # compacted to the TRUE row count just read (the sort's
+            # operand follows what is live, not the scans' capacities),
+            # with the group capacity sized by the same count (groups
+            # <= rows: overflow is impossible by construction)
             REGISTRY.counter("agg.strategy.bypass").add()
             batches = child.materialize()
             rows = sum(live_count(b) for b in batches)
+            cap = batch_capacity(max(rows, 16))
             if batches:
-                from presto_tpu.exec.operators import concat_batches
+                from presto_tpu.exec.operators import compact_batches
 
-                child = BatchStream.of([concat_batches(batches)])
-            strategy = SortStrategy(
-                min(batch_capacity(max(rows, 16)), MAX_GROUP_CAP))
+                child = BatchStream.of([compact_batches(batches, cap)])
+                del batches
+                REGISTRY.counter("agg.strategy.bypass_compacted").add()
+                REGISTRY.counter("agg.strategy.sort_live_rows").add(rows)
+            strategy = SortStrategy(min(cap, MAX_GROUP_CAP))
         else:
             REGISTRY.counter("agg.strategy.partial").add()
         fault_point("step.agg")
@@ -649,7 +664,11 @@ class LocalExecutor(OomLadderMixin):
 
     def _pick_group_strategy(self, keys, pax, node: N.Aggregate,
                              child: BatchStream, force_sort: bool = False):
-        from presto_tpu.plan.bounds import estimate_rows, key_dictionary
+        from presto_tpu.plan.bounds import (
+            estimate_rows,
+            group_bound,
+            key_dictionary,
+        )
 
         def dict_len(name: str):
             d = key_dictionary(node.child, name, self.catalog)
@@ -657,6 +676,7 @@ class LocalExecutor(OomLadderMixin):
 
         return pick_group_strategy(
             keys, pax, dict_len, estimate_rows(node.child, self.catalog),
+            group_bound(node, self.catalog),
             direct_limit=0 if force_sort else self.direct_group_limit,
         )
 

@@ -45,6 +45,7 @@ from presto_tpu.ops.groupby import (
 )
 from presto_tpu.ops.sort import sort_indices, top_n_indices
 from presto_tpu.runtime.errors import InternalError, ResourceExhausted
+from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.runtime.trace import span as trace_span
 from presto_tpu.runtime.trace import sync as trace_sync
 from presto_tpu.types import BIGINT, DOUBLE, DataType, TypeKind
@@ -596,6 +597,10 @@ class HashAggregationOperator(Operator):
                 self.state = self._direct_init()
             else:
                 self.state = self._sort_init()
+        if isinstance(self.strategy, SortStrategy):
+            # the sort's operand, from static shapes (no device read)
+            REGISTRY.counter("agg.strategy.sort_rows").add(
+                self.strategy.max_groups + batch.capacity)
         # the carrier hands back the dictionaries THIS trace signature
         # saw (correct even when jit's signature cache skipped the
         # body — the output treedef is stored per signature)
@@ -848,6 +853,47 @@ def concat_batches(batches: list[Batch]) -> Batch:
             d,
         )
     return Batch(cols, jnp.concatenate([b.live for b in batches]))
+
+
+def compact_batch(b: Batch, out_cap: int) -> Batch:
+    """Gather live rows into a batch of capacity ``out_cap`` (one
+    ``compact_indices`` + per-column gather). Caller guarantees
+    live_count <= out_cap."""
+    from presto_tpu.exec.joins import gather_rows
+    from presto_tpu.ops.compact import compact_indices
+
+    idx, _, _ = compact_indices(b.live, out_cap)
+    cols = {
+        n: Column(
+            gather_rows(c.data, idx, 0),
+            gather_padded(c.valid, idx, False),
+            c.dtype,
+            c.dictionary,
+        )
+        for n, c in b.columns.items()
+    }
+    return Batch(cols, gather_padded(b.live, idx, False))
+
+
+def compact_batches(batches: Sequence[Batch], out_cap: int) -> Batch:
+    """The live rows of ``batches`` as ONE batch of capacity
+    ``out_cap`` (caller guarantees total live_count <= out_cap). A
+    batch wider than ``out_cap`` compacts on its own first — one small
+    program per batch shape, and the capacity-sized concatenation is
+    never built — then the pieces concatenate and compact again."""
+    from presto_tpu.cache.exec_cache import EXEC_CACHE, trace_probe
+
+    def bypass_compact_step(batches):
+        trace_probe()
+        return compact_batch(concat_batches(list(batches)), out_cap)
+
+    step = EXEC_CACHE.get_or_build(
+        EXEC_CACHE.key_of("bypass_compact", out_cap),
+        lambda: jax.jit(bypass_compact_step))
+    if len(batches) > 1:
+        batches = [step((b,)) if b.capacity > out_cap else b
+                   for b in batches]
+    return step(tuple(batches))
 
 
 def union_target_dicts(names, sample_batches):

@@ -5,12 +5,13 @@ Reference parity: the positions-list/selected-positions machinery inside
 [SURVEY §2.1; reference tree unavailable]. TPU-first: compaction is the
 *only* data-movement primitive — filters just AND masks; rows physically
 move only at shuffle/build/output boundaries, and then via a single
-``nonzero``+gather with a static output capacity.
+sort of row positions + gather with a static output capacity.
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 
 
 def compact_indices(mask, out_capacity: int):
@@ -25,8 +26,14 @@ def compact_indices(mask, out_capacity: int):
     """
     cap = mask.shape[0]
     n = jnp.sum(mask.astype(jnp.int32))
-    idx = jnp.nonzero(mask, size=out_capacity, fill_value=cap)[0]
-    return idx, n, n > out_capacity
+    # one sort of the positions, dead rows keyed to the sentinel: on
+    # the TPU 1 ms for 2^20 rows where ``jnp.nonzero`` (a cumsum and a
+    # scatter-add per row) takes 72 (PERF.md §6, PR 26)
+    idx = lax.sort(jnp.where(mask, jnp.arange(cap, dtype=jnp.int32), cap))
+    if out_capacity > cap:
+        idx = jnp.concatenate(
+            [idx, jnp.full(out_capacity - cap, cap, jnp.int32)])
+    return idx[:out_capacity], n, n > out_capacity
 
 
 def compact_mask_overflow(mask, out_capacity: int):

@@ -213,6 +213,13 @@ def _node_intervals(node: N.PlanNode, catalog,
         for a in node.aggs:
             out[a.name] = None  # running sums: unbounded without row counts
         return out
+    if isinstance(node, N.Union):
+        # every input contributes rows to each (same-named) column
+        envs = [node_intervals(c, catalog, memo) for c in node.inputs]
+        out = dict(envs[0])
+        for env in envs[1:]:
+            out = {n: _hull(iv, env.get(n)) for n, iv in out.items()}
+        return {f.name: out.get(f.name) for f in node.fields}
     if isinstance(node, (N.Join,)):
         out = dict(node_intervals(node.left, catalog, memo))
         right = node_intervals(node.right, catalog, memo)
@@ -334,6 +341,29 @@ def _estimate_rows(node: N.PlanNode, catalog, memo: Optional[dict]) -> int:
     return 1 << 10
 
 
+def _key_domain_product(node: "N.Aggregate", catalog, numeric_count,
+                        null_slot: int) -> Optional[int]:
+    """The ONE per-key walk behind both group-cardinality numbers:
+    product over the keys of a keyed Aggregate of (distinct values of
+    the key + ``null_slot``), None when any key's count is unknown. A
+    key that resolves to a dictionary column counts its dictionary;
+    any other key counts ``numeric_count(name, expr)`` (None/0:
+    unknown)."""
+    if not isinstance(node, N.Aggregate) or not node.keys:
+        return None
+    prod = 1
+    for name, e in node.keys:
+        d = (key_dictionary(node.child, e.name, catalog)
+             if isinstance(e, InputRef) else None)
+        n = max(len(d), 1) if d is not None else numeric_count(name, e)
+        if not n:
+            return None
+        prod *= int(n) + null_slot
+        if prod > (1 << 40):  # clamp before the product explodes
+            break
+    return prod
+
+
 def estimate_groups(node: "N.Aggregate", catalog,
                     memo: Optional[dict] = None) -> Optional[int]:
     """NDV-based group-cardinality estimate for a keyed Aggregate, or
@@ -344,27 +374,44 @@ def estimate_groups(node: "N.Aggregate", catalog,
     of the partial-aggregation bypass rule (*Partial Partial
     Aggregates* / *Global Hash Tables Strike Back!*): when groups
     approach rows, pre-aggregating per morsel reduces nothing."""
-    if not isinstance(node, N.Aggregate) or not node.keys:
+
+    def ndv(_name, e):
+        src = (resolve_source_column(node.child, e.name)
+               if isinstance(e, InputRef) else None)
+        stats = catalog.stats(*src) if src is not None else None
+        return getattr(stats, "ndv", None) if stats is not None else None
+
+    prod = _key_domain_product(node, catalog, ndv, null_slot=0)
+    if prod is None:
         return None
-    prod = 1
-    for name, e in node.keys:
-        if not isinstance(e, InputRef):
-            return None
-        d = key_dictionary(node.child, name, catalog)
-        if d is not None:
-            prod *= max(len(d), 1)
-            continue
-        src = resolve_source_column(node.child, name)
-        if src is None:
-            return None
-        stats = catalog.stats(*src)
-        ndv = getattr(stats, "ndv", None) if stats is not None else None
-        if not ndv:
-            return None
-        prod *= max(int(ndv), 1)
-        if prod > (1 << 40):  # clamp before the product explodes
-            break
     return max(1, min(prod, estimate_rows(node.child, catalog, memo)))
+
+
+def group_bound(node: "N.Aggregate", catalog,
+                memo: Optional[dict] = None) -> Optional[int]:
+    """Distinct-group upper bound for a keyed Aggregate from the key
+    DOMAINS, or None when any key is unbounded: product over the keys
+    of (dictionary length for a dictionary key | interval width
+    ``hi - lo + 1`` from :func:`node_intervals` for an integer-valued
+    key) + 1 for the NULL group (the sort strategy groups NULL apart),
+    clamped by the SOUND ``fragmenter.upper_bound_rows`` of the child
+    where that is known (one group needs one row). Sizes the sort
+    strategy's group capacity; as sound as the connector's statistics,
+    and backed by the capacity-overflow retry like every capacity, so
+    an understated statistic costs a replay, never an answer."""
+    from presto_tpu.plan.fragmenter import upper_bound_rows
+
+    env = node_intervals(node, catalog, memo)
+
+    def width(name, e):
+        iv = env.get(name) if e.dtype.kind in _INTEGERISH else None
+        return None if iv is None else iv[1] - iv[0] + 1
+
+    prod = _key_domain_product(node, catalog, width, null_slot=1)
+    if prod is None:
+        return None
+    rows = upper_bound_rows(node.child, catalog)
+    return max(1, prod if rows is None else min(prod, rows))
 
 
 def estimate_record(node: N.PlanNode, catalog,

@@ -322,9 +322,24 @@ def _fmt(v: float) -> str:
 #: allows it) — their prefix documents them here via the base family.
 METRIC_HELP: dict[str, str] = {
     # ---- aggregation strategy picks
+    "agg.strategy.bound_keys": (
+        "sort-strategy group capacities the key-domain bound "
+        "(bounds.group_bound) sized below the input-row estimate"),
+    "agg.strategy.bound_rows": (
+        "sort-strategy group capacities sized by the input-row "
+        "estimate (no key-domain bound, or not below it)"),
     "agg.strategy.bypass": (
-        "aggregations answered straight from incremental table stats "
-        "(no scan dispatched)"),
+        "keyed aggregations that skipped the per-morsel partial folds: "
+        "one pass over the materialized child"),
+    "agg.strategy.bypass_compacted": (
+        "bypass aggregations whose input was compacted to its live-row "
+        "count before the sort"),
+    "agg.strategy.sort_rows": (
+        "rows handed to the sort-strategy update (group capacity + "
+        "batch capacity, summed over calls; static shapes)"),
+    "agg.strategy.sort_live_rows": (
+        "live rows among agg.strategy.sort_rows where the executor holds the "
+        "count (the bypass)"),
     "agg.strategy.fused": "aggregations fused into the scan kernel",
     "agg.strategy.partial": (
         "aggregations executed partial-per-fragment then merged"),

@@ -207,3 +207,174 @@ def test_estimate_groups_from_ndv(session):
     from presto_tpu.plan.bounds import estimate_rows
 
     assert g <= estimate_rows(agg.child, session.catalog)
+
+
+# ---------------------------------------------------------------------------
+# the key-domain group bound (ISSUE 26): what sizes the sort strategy's
+# group capacity. Counts only, SF 0.01.
+# ---------------------------------------------------------------------------
+
+
+def _aggregates(node, out=None):
+    """Every Aggregate of a plan, outermost first."""
+    from presto_tpu.plan import nodes as N
+
+    out = [] if out is None else out
+    if isinstance(node, N.Aggregate):
+        out.append(node)
+    for c in node.children:
+        _aggregates(c, out)
+    return out
+
+
+@pytest.fixture(scope="module")
+def sessions(session):
+    from presto_tpu.connectors.ssb import SsbConnector
+
+    return {"tpch": session, "ssb": Session({"ssb": SsbConnector(sf=0.01)})}
+
+
+def _query(suite, name):
+    if suite == "ssb":
+        from presto_tpu.connectors.ssb.queries import QUERIES
+    else:
+        from presto_tpu.connectors.tpch.queries import QUERIES
+    return QUERIES[name]
+
+
+#: case -> (suite, query, which Aggregate, the bound). "rows" = the
+#: product is clamped by the child's sound row bound
+GROUP_BOUNDS = {
+    # (7 years + NULL) x (1000 brands + NULL)
+    "ssb_q2_1": ("ssb", "q2_1", 0, 8008),
+    # (7 + 1) x (25 + 1): too wide a key list for direct addressing
+    # only because d_year is no dictionary
+    "ssb_q4_1": ("ssb", "q4_1", 0, 208),
+    # c_custkey in [1, 1500] + NULL
+    "tpch_q13_inner": ("tpch", "q13", 1, 1501),
+    # c_count is a count: no interval
+    "tpch_q13_outer": ("tpch", "q13", 0, None),
+    # orderkeys x dates x priorities is far more than lineitem's rows
+    "tpch_q3": ("tpch", "q3", 0, "rows"),
+    "tpch_q18_inner": ("tpch", "q18", 1, "rows"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUP_BOUNDS))
+def test_group_bound(sessions, case):
+    from presto_tpu.plan.bounds import group_bound
+    from presto_tpu.plan.fragmenter import upper_bound_rows
+
+    suite, name, which, want = GROUP_BOUNDS[case]
+    s = sessions[suite]
+    agg = _aggregates(s.plan(_query(suite, name)))[which]
+    if want == "rows":
+        want = upper_bound_rows(agg.child, s.catalog)
+        assert want is not None
+    assert group_bound(agg, s.catalog) == want
+    # one memo serves the whole walk, with the same answer
+    assert group_bound(agg, s.catalog, {}) == want
+
+
+@pytest.mark.parametrize("case", sorted(GROUP_BOUNDS))
+def test_group_capacity_never_above_the_row_estimates(sessions, case):
+    """The sort strategy's ``g`` is the smaller of what the row
+    estimate gave before and the key bound's bucket — 8192 for Q2.1."""
+    from presto_tpu.exec.local_planner import MAX_GROUP_CAP, LocalExecutor
+    from presto_tpu.exec.operators import SortStrategy
+    from presto_tpu.plan.bounds import estimate_rows, group_bound
+    from presto_tpu.spi import batch_capacity
+
+    suite, name, which, _ = GROUP_BOUNDS[case]
+    s = sessions[suite]
+    agg = _aggregates(s.plan(_query(suite, name)))[which]
+    st = LocalExecutor(s.catalog)._pick_group_strategy(
+        agg.keys, agg.passengers, agg, None)
+    assert isinstance(st, SortStrategy)
+    before = min(batch_capacity(max(estimate_rows(agg.child, s.catalog), 16)),
+                 MAX_GROUP_CAP)
+    bound = group_bound(agg, s.catalog)
+    assert st.max_groups <= before
+    assert st.max_groups == (
+        before if bound is None else min(before, batch_capacity(bound)))
+    if case == "ssb_q2_1":
+        assert st.max_groups == 8192
+
+
+def test_union_interval_is_the_hull_of_its_inputs(session):
+    """A key under UNION ALL takes values from every input: the bound
+    must not read the first input's interval alone."""
+    plan = session.plan(
+        "select k, count(*) c from (select n_nationkey k from nation "
+        "union all select c_custkey k from customer) group by k")
+    (agg,) = _aggregates(plan)
+    from presto_tpu.plan.bounds import group_bound
+
+    ((key, _),) = agg.keys
+    assert node_intervals(agg, session.catalog)[key] == (0, 1500)
+    assert group_bound(agg, session.catalog) == 1502
+
+
+def _sort_capacities(monkeypatch):
+    """Record the group capacity of every sort-strategy operator built."""
+    from presto_tpu.exec.operators import HashAggregationOperator, SortStrategy
+
+    seen = []
+    real = HashAggregationOperator.__init__
+
+    def spy(self, keys, aggs, strategy, *a, **k):
+        if isinstance(strategy, SortStrategy):
+            seen.append(strategy.max_groups)
+        real(self, keys, aggs, strategy, *a, **k)
+
+    monkeypatch.setattr(HashAggregationOperator, "__init__", spy)
+    return seen
+
+
+def test_null_group_fills_the_bounds_last_slot(monkeypatch):
+    """1023 key values and NULL: the bound is 1024, the capacity is
+    1024, and the NULL group takes the last slot with no overflow."""
+    s = Session({"tpch": TpchConnector(sf=0.01)},
+                properties={"result_cache_enabled": False})
+    s.sql("create table nk as select nullif(c_custkey % 1024, 0) k, "
+          "c_acctbal v from customer")
+    seen = _sort_capacities(monkeypatch)
+    got = s.sql("select k, count(*) c, sum(v) v from nk group by k")
+    assert seen == [1024]
+    assert len(got) == 1024 and int(got["k"].isna().sum()) == 1
+    assert int(got["c"].sum()) == 1500
+    assert int(got[got["k"].isna()]["c"].iloc[0]) == 1  # custkey 1024
+
+
+def test_understated_statistic_costs_a_replay_not_an_answer(monkeypatch):
+    """A connector that declares too small a key range: the group
+    capacity from the bound overflows, the retry doubles it, and the
+    rows are those of honest statistics."""
+    import dataclasses
+
+    import pandas as pd
+
+    q = ("select l_partkey, count(*) c, sum(l_quantity) q from lineitem "
+         "join orders on l_orderkey = o_orderkey group by l_partkey "
+         "order by l_partkey")
+    s = Session({"tpch": TpchConnector(sf=0.01)},
+                properties={"result_cache_enabled": False})
+    want = s.sql(q)
+    assert len(want) == 2000
+    catalog = s.catalog
+    real_stats = catalog.stats
+
+    def understated(connector, table, column):
+        st = real_stats(connector, table, column)
+        if (table, column) == ("lineitem", "l_partkey"):
+            return dataclasses.replace(st, max_value=900)
+        return st
+
+    seen = _sort_capacities(monkeypatch)
+    catalog.stats = understated
+    try:
+        got = s.sql(q)
+    finally:
+        catalog.stats = real_stats
+    assert seen == [1024, 2048]     # bound 901 -> 1024, then one doubling
+    pd.testing.assert_frame_equal(got, want)

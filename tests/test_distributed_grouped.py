@@ -181,3 +181,54 @@ def test_null_varchar_key_direct_replan():
     )
     assert a["k"].isna().any(), "NULL group must exist"
     assert int(a[a["k"].isna()]["c"].iloc[0]) == 5  # FRANCE x 5 regions
+
+
+#: keyed aggregations above a join (no leaf route), whose key domains
+#: bound the groups below both executors' row numbers
+BOUNDED_KEY_QUERIES = {
+    # Q13's inner level: c_custkey in [1, 300] + NULL at SF 0.002
+    "int_key": (
+        "select c_custkey, count(o_orderkey) c from customer "
+        "left join orders on c_custkey = o_custkey group by c_custkey"
+    ),
+    # a dictionary key beside an integer one: (5 + 1) x (1 + 1)
+    "dict_and_int_keys": (
+        "select o_orderpriority, o_shippriority, count(*) c from orders "
+        "join lineitem on o_orderkey = l_orderkey "
+        "group by o_orderpriority, o_shippriority"
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BOUNDED_KEY_QUERIES))
+def test_local_and_distributed_pick_the_same_group_strategy(
+        conn, local, monkeypatch, name):
+    """Both executors size the sort strategy through
+    ``pick_group_strategy`` with the same key-domain bound, so where
+    the bound is the smaller number they build the same strategy."""
+    import presto_tpu.exec.local_planner as LP
+    from presto_tpu.exec.operators import SortStrategy
+
+    picked = []
+    real = LP.pick_group_strategy
+
+    def spy(keys, pax, dict_len, est_rows, group_bound=None, **kw):
+        st = real(keys, pax, dict_len, est_rows, group_bound, **kw)
+        picked.append((st, est_rows, group_bound))
+        return st
+
+    monkeypatch.setattr(LP, "pick_group_strategy", spy)
+    q = BOUNDED_KEY_QUERIES[name]
+    want = local.sql(q)
+    (loc,) = picked
+    del picked[:]
+    got = Session({"tpch": conn}, mesh=make_mesh(4)).sql(q)
+    (dist,) = picked
+    assert isinstance(loc[0], SortStrategy) and loc[0] == dist[0]
+    assert loc[2] == dist[2] and loc[2] is not None
+    key = list(want.columns[:-1])
+    pd.testing.assert_frame_equal(
+        want.sort_values(key).reset_index(drop=True),
+        got.sort_values(key).reset_index(drop=True),
+        check_dtype=False,
+    )

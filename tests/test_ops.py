@@ -37,8 +37,14 @@ def test_compact_indices():
     assert int(n) == 4 and not bool(ovf)
     np.testing.assert_array_equal(np.asarray(idx)[:4], [0, 2, 3, 6])
     assert (np.asarray(idx)[4:] == 8).all()
-    _, _, ovf2 = compact_indices(mask, 3)
+    idx3, _, ovf2 = compact_indices(mask, 3)
     assert bool(ovf2)
+    np.testing.assert_array_equal(np.asarray(idx3), [0, 2, 3])
+    # wider than the mask: the tail is the sentinel too
+    idx12, n12, ovf12 = compact_indices(mask, 12)
+    assert int(n12) == 4 and not bool(ovf12)
+    np.testing.assert_array_equal(
+        np.asarray(idx12), [0, 2, 3, 6] + [8] * 8)
 
 
 def test_hash_determinism_and_order_sensitivity():

@@ -165,3 +165,61 @@ def test_q3_end_to_end(conn):
     np.testing.assert_array_equal(
         got["o_orderdate"].to_numpy().astype(np.int64), want_days
     )
+
+
+def test_q3_bypass_sorts_its_live_rows(monkeypatch):
+    """Served as SQL, Q3 takes the aggregation bypass: the operator is
+    handed ONE batch compacted to the live-row count the executor read
+    (not the scans' concatenated capacities), sizes its groups from the
+    same count, reads the device no more often, and equals the oracle."""
+    from presto_tpu.connectors.tpch.queries import QUERIES
+    from presto_tpu.runtime.metrics import REGISTRY
+    from presto_tpu.runtime.session import Session
+    from presto_tpu.spi import batch_capacity
+
+    conn = TpchConnector(sf=SF, units_per_split=1 << 12)
+    assert len(conn.splits("lineitem")) > 1     # several probe outputs
+    seen = []
+    real = HashAggregationOperator.process
+
+    def spy(self, batch):
+        seen.append((self.strategy.max_groups, batch.capacity,
+                     int(batch.count())))
+        return real(self, batch)
+
+    monkeypatch.setattr(HashAggregationOperator, "process", spy)
+    s = Session({"tpch": conn}, properties={"result_cache_enabled": False})
+    before = REGISTRY.snapshot()
+    got = s.sql(QUERIES["q3"])
+    after = REGISTRY.snapshot()
+
+    def delta(name):
+        return after.get(name, 0) - before.get(name, 0)
+
+    ((g, cap, live),) = seen
+    assert live > 0 and cap == g == batch_capacity(live)
+    assert delta("agg.strategy.bypass") == 1
+    assert delta("agg.strategy.bypass_compacted") == 1
+    assert delta("agg.strategy.sort_rows") == g + cap
+    assert delta("agg.strategy.sort_live_rows") == live
+    # the compaction reads nothing from the device: as many reads as
+    # with the plain concatenation in its place, and the same rows
+    monkeypatch.setattr(HashAggregationOperator, "process", real)
+    import presto_tpu.exec.operators as O
+
+    monkeypatch.setattr(
+        O, "compact_batches",
+        lambda batches, out_cap: O.concat_batches(list(batches)))
+    again = s.sql(QUERIES["q3"])
+    assert (REGISTRY.snapshot()["exec.sync.reads"]
+            - after["exec.sync.reads"]) == delta("exec.sync.reads")
+    pd.testing.assert_frame_equal(got, again)
+
+    want = q3_oracle(conn)
+    assert len(got) == len(want) == 10
+    np.testing.assert_array_equal(
+        np.round(got["revenue"].to_numpy().astype(float) * 10_000)
+        .astype(np.int64), want["rev"].to_numpy())
+    np.testing.assert_array_equal(
+        got["l_orderkey"].to_numpy().astype(np.int64),
+        want["l_orderkey"].to_numpy())

@@ -674,7 +674,7 @@ def test_plan_and_encode_are_recorded_and_annotated(served_conns,
 #: the jitted families the three cells' templates run (local tier)
 FAMILIES = ("leaf_agg_step", "leaf_fold_step", "filter_project_step",
             "join_build_step", "join_filter_step", "probe_inner_step",
-            "probe_left_step", "_sort_update")
+            "probe_left_step", "_sort_update", "bypass_compact_step")
 
 
 @pytest.fixture(scope="module")
