@@ -3,7 +3,9 @@
 
     python chip_smoke.py             # one chip: load, embedded, served
     python chip_smoke.py --chips 4   # ONLY the distributed executor over
-                                     # a 4-device mesh vs the local one
+                                     # a 4-device mesh (the properties of
+                                     # benchmark/configs/tpch_sf1_mesh4.json)
+                                     # vs the pandas oracle, to the cent
 
 One process, no platform pinned, nothing read from outside the
 checkout (TPC-H data comes from the seeded generator). Exits non-zero
@@ -352,25 +354,35 @@ def phase_served(conn, tables) -> None:
 
 
 def phase_four_chips() -> None:
-    """The distributed executor over a 4-device mesh, every join through
-    the all_to_all repartition, against the local executor's frames
-    from a second Session in this process."""
+    """The distributed executor over a 4-device mesh, built from the
+    properties the benchmark's four-chip configuration states (the
+    worker count and every join through the all_to_all repartition):
+    Q6 and Q3 against the pandas oracle to the cent, Q1 (known wrong on
+    the chip, ROADMAP A0) against the local executor's frame from a
+    second Session in this process at the loose tolerance."""
     import jax
 
     from presto_tpu.connectors.tpch import TpchConnector
     from presto_tpu.connectors.tpch.queries import QUERIES
-    from presto_tpu.oracle.compare import compare
-    from presto_tpu.parallel.mesh import make_mesh
+    from presto_tpu.oracle.compare import TO_THE_CENT, compare
+    from presto_tpu.oracle.tpch_oracle import ORACLES
     from presto_tpu.plan import nodes as N
     from presto_tpu.runtime.session import Session
 
     t_phase = time.perf_counter()
     conn = TpchConnector(sf=SF, seed=SEED)
-    mesh = make_mesh(4)
-    props = {"result_cache_enabled": False}
-    dist = Session({"tpch": conn}, mesh=mesh,
-                   properties={**props, "broadcast_join_row_limit": 0})
-    local = Session({"tpch": conn}, properties=props)
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs",
+                           "tpch_sf1_mesh4.json")) as f:
+        stated = json.load(f)["properties"]
+    assert stated["mesh_devices"] == 4 and \
+        stated["broadcast_join_row_limit"] == 0, stated
+    dist = Session({"tpch": conn}, properties=stated)
+    mesh = dist.mesh
+    local = Session({"tpch": conn},
+                    properties={"result_cache_enabled": False})
+    tables = {t: conn.table_pandas(t, REF_COLUMNS[t])
+              for t in ("lineitem", "orders", "customer")}
 
     # a scanned column must really be spread over the mesh: code that
     # has only seen virtual devices may leave everything on the first
@@ -391,14 +403,18 @@ def phase_four_chips() -> None:
         t_dist = time.perf_counter() - t0
         s1 = snapshot()
         t0 = time.perf_counter()
-        want = local.sql(QUERIES[name])
-        t_local = time.perf_counter() - t0
-        compare(got, want, f"distributed {name}")
+        if name == "q1":
+            compare(got, local.sql(QUERIES[name]), f"distributed {name}")
+        else:
+            compare(got, ORACLES[name](tables), f"distributed {name}",
+                    **TO_THE_CENT)
+        t_ref = time.perf_counter() - t0
         d = delta(s1, s0)
         kern = {k: v for k, v in d.items() if k.startswith("kernel.")}
         check_programs(name, kern, {})
         emit(phase="four_chips", query=name, distributed_cold_s=t_dist,
-             local_cold_s=t_local, rows=len(got), matches_local=True,
+             reference_s=t_ref, rows=len(got),
+             matches="local, rtol 1e-3" if name == "q1" else "oracle, to the cent",
              exchange_bytes=d.get("exchange.bytes", 0),
              programs=programs(kern))
     d = delta(snapshot(), start)

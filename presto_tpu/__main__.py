@@ -348,13 +348,10 @@ def main(argv=None):
     for kv in args.session:
         name, _, value = kv.partition("=")
         props[name.strip()] = value.strip()
-    mesh = None
     if args.mesh is not None:
-        from presto_tpu.parallel.mesh import make_mesh
-
-        mesh = make_mesh(args.mesh)
+        props["mesh_devices"] = args.mesh
     conn = make_connector(args.catalog, args.sf)
-    session = Session({args.catalog: conn}, properties=props, mesh=mesh)
+    session = Session({args.catalog: conn}, properties=props)
 
     if args.command not in (None, "metrics", "flightrec", "serve",
                             "health"):

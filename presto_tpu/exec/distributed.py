@@ -71,14 +71,15 @@ from presto_tpu.exec.pipeline import BatchSource, Pipeline
 from presto_tpu.expr import BIGINT, evaluate, bind_scalars, param_scope
 from presto_tpu.ops.groupby import gather_padded, group_ids_sort, segment_agg
 from presto_tpu.ops.hashing import partition_ids
+from presto_tpu.ops.pallas_mode import count_program
 from presto_tpu.ops.sort import sort_indices
 from presto_tpu.ops.join import build_lookup, probe_exists, probe_expand, probe_unique
 from presto_tpu.parallel.exchange import (
     a2a_wire_bytes,
     any_flag,
+    exchange_dispatch,
     exchange_multiround,
     gather_wire_bytes,
-    record_exchange,
 )
 from presto_tpu.parallel.mesh import replicated, row_sharding, worker_axes
 from presto_tpu.plan import nodes as N
@@ -395,15 +396,12 @@ class DistributedExecutor(OomLadderMixin):
             cap2 = batch_capacity(max(rows, 16), minimum=16)
             if self.nworkers * cap2 < b.capacity:
                 b = _compact_step(self.mesh, cap2)(b)
-        import time as _time
-
-        t0 = _time.perf_counter()
-        b = jax.device_put(b, replicated(self.mesh))
-        record_exchange(
-            "gather" if guard is None else f"gather:{guard}",
-            gather_wire_bytes(batch_row_bytes(b), b.capacity, self.nworkers),
-            self.nworkers, _time.perf_counter() - t0,
-        )
+        with exchange_dispatch(
+                "gather" if guard is None else f"gather:{guard}",
+                self.nworkers, "gather") as ex:
+            b = jax.device_put(b, replicated(self.mesh))
+            ex["bytes"] = gather_wire_bytes(
+                batch_row_bytes(b), b.capacity, self.nworkers)
         return DistBatch(b, sharded=False)
 
     def _shard(self, b: Batch) -> Batch:
@@ -741,8 +739,6 @@ class DistributedExecutor(OomLadderMixin):
         from presto_tpu.cache.exec_cache import EXEC_CACHE
 
         mg_final = batch_capacity(Pn * quota, minimum=64)
-        import time as _time
-
         for _ in range(MAX_RETRIES):
             # content-keyed in the executable cache: grouped-execution
             # bucket passes share one XLA program per capacity tuple
@@ -755,25 +751,23 @@ class DistributedExecutor(OomLadderMixin):
                 lambda: self._make_agg_step(keys, aggs, pax, mg_partial,
                                             quota, mgf, bypass=bypass),
             )
-            t0 = _time.perf_counter()
             with trace_span("step:dist_agg", "step",
-                            {"quota": quota, "recv_cap": mgf}):
+                            {"quota": quota, "recv_cap": mgf}), \
+                    exchange_dispatch("aggregate", Pn) as ex:
                 out, overflow, rounds, dest, exch_ovf = step(b, self.params)
                 done = not bool(overflow)
-            # exchanged rows are partial-agg group rows: the final
-            # output's columns plus one int64 merge-count per agg
-            row_b = batch_row_bytes(out) + 9 * len(aggs)
-            r = int(np.asarray(rounds))
-            # hot-partition capture keys on the EXCHANGE receive
-            # overflow specifically — a partial/final group-capacity
-            # overflow retries through the same loop but is NOT skew,
-            # and must not plant a phantom hot partition in post-mortems
-            record_exchange(
-                "aggregate", a2a_wire_bytes(row_b, Pn, quota, r),
-                Pn, _time.perf_counter() - t0, rounds=r,
-                hot_partition=(self._hot_partition(dest)
-                               if not done and bool(exch_ovf) else None),
-            )
+                # exchanged rows are partial-agg group rows: the final
+                # output's columns plus one int64 merge-count per agg
+                row_b = batch_row_bytes(out) + 9 * len(aggs)
+                r = ex["rounds"] = int(np.asarray(rounds))
+                ex["bytes"] = a2a_wire_bytes(row_b, Pn, quota, r)
+                # hot-partition capture keys on the EXCHANGE receive
+                # overflow specifically — a partial/final group-capacity
+                # overflow retries through the same loop but is NOT
+                # skew, and must not plant a phantom hot partition in
+                # post-mortems
+                if not done and bool(exch_ovf):
+                    ex["hot_partition"] = self._hot_partition(dest)
             if done:
                 self._note_exchange_skew("aggregate", node, dest, row_b)
                 return DistBatch(out, sharded=True)
@@ -912,6 +906,7 @@ class DistributedExecutor(OomLadderMixin):
         )
         def dist_hash_agg_step(b: Batch, params=()):
             trace_probe()
+            count_program("dist_agg", False)
             with param_scope(params):
                 part, ovf1 = (bypass_phase(b) if bypass else partial_phase(b))
                 key_sort = [c for n, _ in keys for c in _sortables(part[n])]
@@ -1227,30 +1222,28 @@ class DistributedExecutor(OomLadderMixin):
                     node, lkey, rkey, *caps, verify, salt=salt_t,
                 ),
             )
-            import time as _time
-
-            t0 = _time.perf_counter()
             with trace_span("step:repartition_join", "step",
                             {"kind": node.kind, "lrecv": lrecv,
-                             "rrecv": rrecv}):
+                             "rrecv": rrecv}), \
+                    exchange_dispatch("join", Pn) as ex:
                 out, overflow, flags, rounds, dest = step(
                     left.batch, right.batch, self.params)
                 long_runs, sentinel, exch_ovf = (
                     bool(x) for x in np.asarray(flags))
                 ok = not bool(overflow)
-            lr, rr = (int(x) for x in np.asarray(rounds))
-            # hot-partition capture keys on the exchange RECEIVE
-            # overflow only — probe-expand output overflow retries
-            # through the same loop but is not partition skew
-            record_exchange(
-                "join",
-                a2a_wire_bytes(batch_row_bytes(left.batch), Pn, lquota, lr)
-                + a2a_wire_bytes(batch_row_bytes(right.batch), Pn, rquota,
-                                 rr),
-                Pn, _time.perf_counter() - t0, rounds=lr + rr,
-                hot_partition=(self._hot_partition(dest[0] + dest[1])
-                               if not ok and exch_ovf else None),
-            )
+                lr, rr = (int(x) for x in np.asarray(rounds))
+                ex["rounds"] = lr + rr
+                ex["bytes"] = (
+                    a2a_wire_bytes(batch_row_bytes(left.batch), Pn, lquota,
+                                   lr)
+                    + a2a_wire_bytes(batch_row_bytes(right.batch), Pn,
+                                     rquota, rr))
+                # hot-partition capture keys on the exchange RECEIVE
+                # overflow only — probe-expand output overflow retries
+                # through the same loop but is not partition skew
+                if not ok and exch_ovf:
+                    ex["hot_partition"] = self._hot_partition(
+                        dest[0] + dest[1])
             if ok:
                 # dest[0] = probe-side rows by destination, dest[1] =
                 # build-side: both exchanges shuffle on the SAME key
@@ -1313,6 +1306,7 @@ class DistributedExecutor(OomLadderMixin):
         )
         def dist_repartition_join_step(lb: Batch, rb: Batch, params=()):
             trace_probe()
+            count_program("dist_join", False)
             with param_scope(params):
                 return step_body(lb, rb)
 
@@ -1883,8 +1877,6 @@ class DistributedExecutor(OomLadderMixin):
         recv_cap = batch_capacity(2 * cap_dev, minimum=64)
         from presto_tpu.cache.exec_cache import EXEC_CACHE
 
-        import time as _time
-
         for _ in range(MAX_RETRIES):
             rc = recv_cap
             step = EXEC_CACHE.get_or_build(
@@ -1895,17 +1887,13 @@ class DistributedExecutor(OomLadderMixin):
                 ),
                 lambda: self._make_window_step(part_exprs, op, quota, rc),
             )
-            t0 = _time.perf_counter()
             with trace_span("step:dist_window", "step",
-                            {"quota": quota, "recv_cap": rc}):
+                            {"quota": quota, "recv_cap": rc}), \
+                    exchange_dispatch("window", Pn) as ex:
                 out, overflow, rounds = step(b, self.params)
                 ok = not bool(overflow)
-            r = int(np.asarray(rounds))
-            record_exchange(
-                "window",
-                a2a_wire_bytes(batch_row_bytes(b), Pn, quota, r),
-                Pn, _time.perf_counter() - t0, rounds=r,
-            )
+                r = ex["rounds"] = int(np.asarray(rounds))
+                ex["bytes"] = a2a_wire_bytes(batch_row_bytes(b), Pn, quota, r)
             if ok:
                 return DistBatch(out, sharded=True)
             recv_cap *= 2
@@ -2178,8 +2166,6 @@ class DistributedExecutor(OomLadderMixin):
 
         quota = batch_capacity(-(-cap_dev // Pn), minimum=64)
         recv_cap = batch_capacity(2 * cap_dev, minimum=64)
-        import time as _time
-
         for _ in range(MAX_RETRIES):
             rc = recv_cap
             # splitters are DATA (sampled per input), so they ride in
@@ -2190,17 +2176,13 @@ class DistributedExecutor(OomLadderMixin):
                                   self._mesh_fp),
                 lambda: self._make_range_sort_step(keys, quota, rc),
             )
-            t0 = _time.perf_counter()
             with trace_span("step:dist_sort", "step",
-                            {"quota": quota, "recv_cap": rc}):
+                            {"quota": quota, "recv_cap": rc}), \
+                    exchange_dispatch("sort", Pn) as ex:
                 out, overflow, rounds = step(b, splitters, self.params)
                 ok = not bool(overflow)
-            r = int(np.asarray(rounds))
-            record_exchange(
-                "sort",
-                a2a_wire_bytes(batch_row_bytes(b), Pn, quota, r),
-                Pn, _time.perf_counter() - t0, rounds=r,
-            )
+                r = ex["rounds"] = int(np.asarray(rounds))
+                ex["bytes"] = a2a_wire_bytes(batch_row_bytes(b), Pn, quota, r)
             if ok:
                 return DistBatch(out, sharded=True)
             recv_cap *= 2

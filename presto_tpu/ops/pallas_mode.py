@@ -13,7 +13,10 @@ from __future__ import annotations
 
 import jax
 
-FAMILIES = ("q1", "leaf_agg", "groupby", "join", "strings")
+#: ``dist_join`` / ``dist_agg``: the mesh's repartition join and its
+#: partial -> shuffle -> final aggregation, which have no Pallas kernel
+FAMILIES = ("q1", "leaf_agg", "groupby", "join", "strings", "dist_join",
+            "dist_agg")
 
 
 def kernel_mode() -> str:

@@ -28,6 +28,7 @@ compiles to ONE XLA program with the collective in the middle).
 
 from __future__ import annotations
 
+import contextlib
 import time
 from functools import partial
 
@@ -273,31 +274,38 @@ def gather_wire_bytes(row_bytes: int, capacity: int, mesh_size: int) -> int:
     return capacity * max(mesh_size - 1, 0) * row_bytes
 
 
-def record_exchange(site: str, nbytes: int, partitions: int,
-                    dispatch_s: float, rounds: int = 1,
-                    hot_partition: int | None = None) -> None:
-    """Publish one exchange dispatch: process metrics (counters +
-    ``exchange.dispatch_s`` histogram) and a completed trace span
-    under the active recorder, carrying the byte/partition/round
-    accounting in its args. ``hot_partition`` names the partition that
+@contextlib.contextmanager
+def exchange_dispatch(site: str, partitions: int, collective: str = "a2a"):
+    """One exchange dispatch as a LIVE ``exchange:<site>`` span on the
+    dispatching thread (annotated on the profiler's clock under
+    ``profile_annotations``, so an idle gap can be laid at its door).
+    Yields the span's accounting dict: the caller fills ``bytes`` (and
+    ``rounds``, ``hot_partition``) once the step's outputs say what
+    moved; on a clean exit they are published as process metrics
+    (counters + the ``exchange.dispatch_s`` histogram) and as the
+    span's args. ``collective`` ("a2a" or "gather") picks the
+    ``exchange.bytes.<collective>`` counter beside the total — the two
+    wire formulas differ. ``hot_partition`` names the partition that
     tripped a capacity overflow (skew telemetry: the retry's doubled
-    buffers are THIS destination's fault — the span records who)."""
+    buffers are THIS destination's fault — the span records who). A
+    dispatch that raises leaves its span and publishes nothing."""
     from presto_tpu.runtime import trace
     from presto_tpu.runtime.metrics import REGISTRY
 
+    acct = {"bytes": 0, "partitions": int(partitions), "rounds": 1}
+    t0 = time.perf_counter()
+    with trace.span(f"exchange:{site}", "exchange") as sp:
+        yield acct
+    nbytes = float(acct["bytes"])
     REGISTRY.counter("exchange.dispatches").add()
-    REGISTRY.counter("exchange.bytes").add(float(nbytes))
-    REGISTRY.counter("exchange.rounds").add(float(rounds))
-    REGISTRY.histogram("exchange.dispatch_s").add(dispatch_s)
-    args = {"bytes": int(nbytes), "partitions": int(partitions),
-            "rounds": int(rounds)}
-    if hot_partition is not None:
+    REGISTRY.counter("exchange.bytes").add(nbytes)
+    REGISTRY.counter(f"exchange.bytes.{collective}").add(nbytes)
+    REGISTRY.counter("exchange.rounds").add(float(acct["rounds"]))
+    REGISTRY.histogram("exchange.dispatch_s").add(time.perf_counter() - t0)
+    if acct.get("hot_partition") is not None:
         REGISTRY.counter("exchange.quota_overflow").add()
-        args["hot_partition"] = int(hot_partition)
-    trace.add_complete(
-        f"exchange:{site}", "exchange",
-        time.perf_counter() - dispatch_s, dispatch_s, args,
-    )
+    if sp is not None:
+        sp.args.update(acct)
 
 
 def skew_ratio(counts) -> float:

@@ -389,7 +389,16 @@ METRIC_HELP: dict[str, str] = {
         "query-event listener callbacks that raised (isolated; the "
         "query is unaffected)"),
     # ---- exchange
-    "exchange.bytes": "bytes moved through partitioned exchanges",
+    "exchange.bytes": (
+        "bytes moved through exchanges, by capacity (the sum of "
+        "exchange.bytes.a2a and exchange.bytes.gather)"),
+    "exchange.bytes.a2a": (
+        "all_to_all bytes: rounds x P x P x quota rows x row bytes, "
+        "padding and the diagonal (what a device sends to itself, "
+        "which no link carries) included"),
+    "exchange.bytes.gather": (
+        "replication bytes: each shard's capacity to the P-1 other "
+        "devices"),
     "exchange.dispatch_s": "partitioned-exchange dispatch latency",
     "exchange.dispatches": "partitioned-exchange dispatches",
     "exchange.rounds": "exchange rounds executed",
@@ -458,7 +467,8 @@ METRIC_HELP: dict[str, str] = {
     # ---- which program each kernel family ran (ops/pallas_mode.py)
     **{
         f"kernel.{fam}.{kind}": f"{fam} steps built from {what}"
-        for fam in ("q1", "leaf_agg", "groupby", "join", "strings")
+        for fam in ("q1", "leaf_agg", "groupby", "join", "strings",
+                    "dist_join", "dist_agg")
         for kind, what in (
             ("mosaic", "the Mosaic-compiled Pallas kernel"),
             ("interpret", "the Pallas kernel in interpret mode (no chip)"),

@@ -88,6 +88,14 @@ SESSION_PROPERTIES: dict[str, PropertyDef] = {
             _non_negative,
         ),
         PropertyDef(
+            "mesh_devices", int, None,
+            "The deployment's worker count: a session built without an "
+            "explicit mesh= runs every query distributed over a mesh of "
+            "the first N devices (parallel.mesh.make_mesh). Unset or 1 "
+            "runs single-device. Read once, when the session is built.",
+            _positive,
+        ),
+        PropertyDef(
             "gather_row_limit", int, 1 << 22,
             "Guard on replicate-everything fallbacks (global-partition "
             "windows, degenerate-key sorts, unsharded build sides): "

@@ -93,7 +93,8 @@ class Session:
                  memory_pool=None):
         """``mesh=None`` runs single-device (the LocalQueryRunner shape);
         passing a ``jax.sharding.Mesh`` runs every query distributed
-        over its ``workers`` axis (the DistributedQueryRunner shape).
+        over its ``workers`` axis (the DistributedQueryRunner shape), as
+        does the ``mesh_devices`` property where no mesh is passed.
         Session properties override engine defaults per query, the
         reference's SystemSessionProperties rule [SURVEY §5.6].
         ``memory_pool`` shares an explicit ``runtime.memory.MemoryPool``
@@ -111,6 +112,11 @@ class Session:
         self.catalog = Catalog(conns)
         self.analyzer = Analyzer(self.catalog)
         self.properties = validate_properties(dict(properties or {}))
+        workers = self.prop("mesh_devices")
+        if mesh is None and workers is not None and workers > 1:
+            from presto_tpu.parallel.mesh import make_mesh
+
+            mesh = make_mesh(workers)
         self.mesh = mesh
         self.trace_token = trace_token
         self.events = EventDispatcher()

@@ -561,3 +561,109 @@ def test_batched_dispatch_off_by_default_for_embedded_sessions():
     qs = QueryServer({"tpch": CONN},
                      properties={"health_monitor": False})
     assert qs.session.prop("batched_dispatch") is True
+
+
+# ---------------------------------------------------------------------------
+# a served deployment states its mesh (benchmark config tpch_sf1_mesh4)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def mesh4():
+    """The benchmark's four-chip configuration as its own file states
+    it, served over HTTP on 4 of the suite's virtual devices at SF 0.01:
+    (cell spec, connector, server, base url)."""
+    from benchmark.harness import cell as C
+
+    spec = C.load_cell("tpch_sf1_mesh4_1s")
+    conn = TpchConnector(sf=0.01, seed=20260927)
+    qs = QueryServer({spec["config"]["catalog"]: conn},
+                     properties=dict(spec["config"]["properties"],
+                                     health_monitor=False))
+    http = HttpFrontend(qs, port=0).start_background()
+    try:
+        yield spec, conn, qs, f"http://127.0.0.1:{http.port}"
+    finally:
+        http.shutdown()
+        qs.shutdown(drain_timeout_s=10)
+
+
+def _q3_sql(spec):
+    from benchmark.harness import cell as C
+
+    return C.render_sql(spec["templates"]["tpch/q3"],
+                        C.binding(spec["traffic"], "tpch/q3", 0))
+
+
+def test_mesh_devices_serves_through_the_distributed_executor(mesh4):
+    from presto_tpu.exec.distributed import DistributedExecutor
+
+    spec, _, qs, _ = mesh4
+    assert spec["config"]["properties"]["mesh_devices"] == 4
+    assert qs.session.mesh.devices.shape == (4,)
+    assert isinstance(qs.session.executor, DistributedExecutor)
+    assert qs.session.prop("batched_dispatch") is True
+
+
+def test_served_mesh_q3_equals_the_reference_to_the_cent(mesh4,
+                                                         monkeypatch):
+    from benchmark.harness import client, compare, runner
+    from presto_tpu.exec.distributed import DistributedExecutor
+
+    spec, conn, qs, base = mesh4
+    scanned = []
+    orig = DistributedExecutor._exec_tablescan
+
+    def spy(self, node, scalars):
+        out = orig(self, node, scalars)
+        scanned.append(out.batch)
+        return out
+
+    monkeypatch.setattr(DistributedExecutor, "_exec_tablescan", spy)
+    before = REGISTRY.snapshot()
+    rec = client.run_query(base, _q3_sql(spec), 0.01)
+    moved = runner.delta(REGISTRY.snapshot(), before)
+    assert rec["ok"], rec["error"]
+    want = runner.reference_rows(
+        spec, runner.reference_frames(conn, spec["templates"]))
+    got = compare.compare_page(rec["data"], want[("tpch/q3", 0)],
+                               spec["templates"]["tpch/q3"]["columns"])
+    assert got["exact_mismatches"] == 0 and got["max_cent_gap"] <= 0.1, got
+    assert len(rec["data"]) == 10
+    # it took the mesh: both joins repartitioned, none broadcast, bytes
+    # on both collectives, nothing answered by the local executor
+    assert moved["join.distribution.repartition"] == 2
+    assert "join.distribution.broadcast" not in moved
+    assert moved["exchange.bytes.a2a"] > 0 < moved["exchange.bytes.gather"]
+    assert moved["exchange.bytes"] == (moved["exchange.bytes.a2a"]
+                                       + moved["exchange.bytes.gather"])
+    assert "query.degraded_to_local" not in moved
+    assert len(scanned) == 3
+    for b in scanned:
+        col = b[b.names[0]].data
+        assert len(col.sharding.device_set) == 4
+    # each exchange dispatch is a live span of the query's recorder
+    spans = [s for s in runner.harvest_spans(qs, rec["id"])
+             if s["cat"] == "exchange"]
+    assert {s["name"] for s in spans} >= {"exchange:join",
+                                          "exchange:aggregate"}
+    assert all(s["t1"] > s["t0"] for s in spans)
+
+
+def test_a_mesh_failure_is_a_failed_query_not_a_local_answer(mesh4):
+    from benchmark.harness import client
+    from presto_tpu.runtime import faults
+
+    spec, _, qs, base = mesh4
+    assert qs.session.prop("degrade_to_local") is False
+    before = counter("query.degraded_to_local")
+    inj = faults.FaultInjector()
+    inj.inject("exchange.join", times=None)     # the mesh never works
+    with faults.injected(inj):
+        rec = client.run_query(base, _q3_sql(spec), 0.01)
+    assert inj.fired() >= 1
+    assert rec["state"] == "FAILED" and not rec["ok"]
+    assert "TRANSIENT_FAILURE" in rec["error"]
+    assert counter("query.degraded_to_local") == before
+    # the deployment still answers once the mesh does
+    assert client.run_query(base, _q3_sql(spec), 0.01)["ok"]

@@ -168,3 +168,84 @@ def test_cli_file_split_respects_quoted_semicolons():
     assert stmts[0].strip() == "select r_name from region where r_name like '%;%'"
     assert stmts[1].strip() == "select 1"
     assert stmts[2].strip() == "select ';' from region"
+
+
+# ---------------------------------------------------------------------------
+# mesh_devices: the deployment's worker count, stated as a property
+# ---------------------------------------------------------------------------
+
+Q_KEYED = ("select l_returnflag, count(*) c from lineitem "
+           "group by l_returnflag")
+
+
+def test_mesh_devices_unset_or_one_is_the_local_executor():
+    from presto_tpu.exec.local_planner import LocalExecutor
+
+    conn = TpchConnector(sf=0.01)
+    for props in ({}, {"mesh_devices": 1}):
+        s = Session({"tpch": conn}, properties=props)
+        assert s.mesh is None
+        assert isinstance(s.executor, LocalExecutor)
+
+
+def test_mesh_devices_keys_as_an_explicit_mesh_does():
+    from presto_tpu.cache.fingerprint import plan_fingerprint
+    from presto_tpu.exec.distributed import DistributedExecutor
+    from presto_tpu.parallel.mesh import make_mesh
+
+    conn = TpchConnector(sf=0.01)
+    stated = Session({"tpch": conn}, properties={"mesh_devices": "4"})
+    passed = Session({"tpch": conn}, mesh=make_mesh(4))
+    assert stated.mesh.devices.shape == (4,)
+    assert list(stated.mesh.devices.flat) == list(passed.mesh.devices.flat)
+    ex = stated.executor
+    assert isinstance(ex, DistributedExecutor) and ex.nworkers == 4
+    # the exec cache keys a distributed step by the mesh's fingerprint,
+    # the result cache and the templates by the plan's
+    assert ex._mesh_fp == passed.executor._mesh_fp
+    fps = [plan_fingerprint(s.plan(Q_KEYED), s.catalog, s.properties, s.mesh)
+           for s in (stated, passed)]
+    assert fps[0] is not None and fps[0] == fps[1]
+    local = Session({"tpch": conn})
+    assert plan_fingerprint(local.plan(Q_KEYED), local.catalog,
+                            local.properties, local.mesh) != fps[0]
+    a, b = stated.sql(Q_KEYED), passed.sql(Q_KEYED)
+    assert sorted(map(tuple, a.values)) == sorted(map(tuple, b.values))
+
+
+def test_mesh_devices_beyond_the_backend_is_a_user_error():
+    import jax
+
+    from presto_tpu.runtime.errors import UserError
+
+    with pytest.raises(UserError, match="devices, have"):
+        Session({"tpch": TpchConnector(sf=0.01)},
+                properties={"mesh_devices": len(jax.devices()) + 1})
+    with pytest.raises(UserError, match="must be positive"):
+        Session({"tpch": TpchConnector(sf=0.01)},
+                properties={"mesh_devices": 0})
+
+
+def test_an_explicit_mesh_wins_over_mesh_devices():
+    from presto_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(2)
+    s = Session({"tpch": TpchConnector(sf=0.01)}, mesh=mesh,
+                properties={"mesh_devices": 4})
+    assert s.mesh is mesh and s.executor.nworkers == 2
+
+
+def test_cli_mesh_goes_through_the_property(monkeypatch, capsys):
+    import presto_tpu.__main__ as cli
+
+    built = []
+    orig = Session.__init__
+
+    def spy(self, connectors, properties=None, mesh=None, **kw):
+        built.append((dict(properties or {}), mesh))
+        orig(self, connectors, properties=properties, mesh=mesh, **kw)
+
+    monkeypatch.setattr(Session, "__init__", spy)
+    cli.main(["--mesh", "4", "-e", "select count(*) c from nation"])
+    assert "25" in capsys.readouterr().out
+    assert built == [({"mesh_devices": 4}, None)]
