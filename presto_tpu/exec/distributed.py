@@ -34,6 +34,7 @@ surfaces as a flag; the host retries the step with doubled capacity.
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Sequence
@@ -99,6 +100,23 @@ from presto_tpu.types import TypeKind, check_narrow_range
 MAX_RETRIES = 6
 
 
+def exchange_capacity(max_count: int, nparts: int) -> int:
+    """Per-device capacity bucket of a compacted exchange input whose
+    fullest device holds ``max_count`` live rows. The step derives the
+    wire quota (capacity / P) and the receive capacity (P x quota) from
+    it, so the room is reckoned on a sender's share per destination
+    (``max_count / P``): an eighth for key skew — five times the largest
+    ``exchange.skew`` excess TPC-H Q3 shows at SF1 (1.024) — plus six
+    square roots for the spread of a hashed share (what decides at a
+    few hundred rows). With that a sender's share stays inside one wire
+    round and a destination's inside the receive capacity, without the
+    doubling retry; never below ``max_count``, so the compaction itself
+    cannot overflow."""
+    share = -(-max_count // nparts)
+    share += share // 8 + 6 * math.isqrt(share)
+    return batch_capacity(max(nparts * share, 16), minimum=64)
+
+
 @dataclass
 class DistBatch:
     """One global Batch + its distribution over the workers axis."""
@@ -124,13 +142,29 @@ import functools
 def _compact_step(mesh, out_cap: int):
     """Compiled per-device compaction, cached per (mesh, capacity) so
     repeated guarded replications reuse the XLA program."""
+    from presto_tpu.cache.exec_cache import trace_probe
+
     ax = worker_axes(mesh)
     @partial(shard_map, mesh=mesh, in_specs=(P(ax),), out_specs=P(ax),
              check_vma=False)
     def dist_compact_step(local):
+        trace_probe()
         return compact_batch(local, out_cap)
 
     return jax.jit(dist_compact_step)
+
+
+@functools.lru_cache(maxsize=8)
+def _live_counts_step(mesh):
+    """Compiled per-device live-row count: a row-sharded live mask ->
+    the ``[P]`` vector of each device's own count (no collective)."""
+    ax = worker_axes(mesh)
+    @partial(shard_map, mesh=mesh, in_specs=(P(ax),), out_specs=P(ax),
+             check_vma=False)
+    def dist_live_counts_step(live):
+        return jnp.sum(live.astype(jnp.int32))[None]
+
+    return jax.jit(dist_live_counts_step)
 
 
 def _pad_rows(b: Batch, cap: int) -> Batch:
@@ -407,6 +441,50 @@ class DistributedExecutor(OomLadderMixin):
     def _shard(self, b: Batch) -> Batch:
         return jax.device_put(b, row_sharding(self.mesh))
 
+    def _device_live_counts(self, d: DistBatch) -> np.ndarray:
+        """ONE device read (``sync:live_count``): the live rows each
+        device holds of a sharded batch (``[P]``; ``[1]`` for a
+        replicated one). The sum is what ``live_count`` returns."""
+        if not d.sharded:
+            return np.asarray([live_count(d.batch)])
+        with trace_sync("live_count"):
+            return np.asarray(_live_counts_step(self.mesh)(d.batch.live))
+
+    def _compact_for_exchange(self, d: DistBatch, site: str,
+                              counts: np.ndarray | None = None):
+        """Before a hash exchange: compact a sharded input whose live
+        rows are far fewer than its slots, per device (``shard_map`` —
+        no collective), so the exchange's quotas, receive buffers and
+        every capacity the step derives from ``batch.capacity`` follow
+        what is live, not what the producer allocated.
+
+        The new capacity is ``exchange_capacity`` of the LARGEST
+        per-device count (``counts``, read here unless the caller
+        already has them): the compaction cannot overflow whatever the
+        placement skew, and the quotas keep their room. Engages
+        only when it at least halves the capacity, so dense inputs pay
+        the one small read and keep their shapes. Returns
+        ``(batch, total live rows)``."""
+        if counts is None:
+            counts = self._device_live_counts(d)
+        rows = int(counts.sum())
+        b = d.batch
+        if not d.sharded:
+            return d, rows
+        cap2 = exchange_capacity(int(counts.max()), self.nworkers)
+        slots_out = self.nworkers * cap2
+        if 2 * slots_out > b.capacity:
+            REGISTRY.counter("exchange.compact_skipped").add()
+            return d, rows
+        with trace_span("step:exchange_compact", "step",
+                        {"site": site, "slots_in": b.capacity,
+                         "slots_out": slots_out}):
+            out = _compact_step(self.mesh, cap2)(b)
+        REGISTRY.counter("exchange.compacted").add()
+        REGISTRY.counter("exchange.compact_slots_in").add(b.capacity)
+        REGISTRY.counter("exchange.compact_slots_out").add(slots_out)
+        return DistBatch(out, sharded=True), rows
+
     # ---- exchange-skew telemetry -----------------------------------------
     def _note_exchange_skew(self, site: str, node, dest, row_bytes: int):
         """Bank one exchange's per-destination device histogram for the
@@ -644,6 +722,13 @@ class DistributedExecutor(OomLadderMixin):
 
         from presto_tpu.exec.local_planner import pick_group_strategy
 
+        # the one read of the input's live rows, per device: the
+        # strategy's row estimate is their sum, and a mostly-dead input
+        # (a selective join's output) is compacted here so the direct,
+        # partial and bypass branches below all size from what is live
+        src = d
+        d, rows = self._compact_for_exchange(d, "aggregate")
+        compacted = d is not src
         first = d.batch
 
         def dict_len(name: str):
@@ -655,7 +740,7 @@ class DistributedExecutor(OomLadderMixin):
 
         bound = group_bound(node, self.catalog)
         strategy = pick_group_strategy(
-            keys, pax, dict_len, live_count(first), bound,
+            keys, pax, dict_len, rows, bound,
             direct_limit=self.direct_group_limit,
         )
         if isinstance(strategy, DirectStrategy):
@@ -676,8 +761,7 @@ class DistributedExecutor(OomLadderMixin):
                 # the packed direct domain has no NULL slot (same replan
                 # the local planner does): fall through to the sort path
                 strategy = pick_group_strategy(
-                    keys, pax, dict_len, live_count(first), bound,
-                    direct_limit=0)
+                    keys, pax, dict_len, rows, bound, direct_limit=0)
         if not d.sharded:
             for _ in range(MAX_RETRIES):
                 op = HashAggregationOperator(keys, aggs, strategy, passengers=pax,
@@ -718,6 +802,11 @@ class DistributedExecutor(OomLadderMixin):
         REGISTRY.counter(
             "agg.strategy.bypass" if bypass else "agg.strategy.partial"
         ).add()
+        if bypass and compacted:
+            # the local bypass's pair (PR 26): every compacted slot is
+            # one "partial" of the exchange and the final group sort
+            REGISTRY.counter("agg.strategy.bypass_compacted").add()
+            REGISTRY.counter("agg.strategy.sort_live_rows").add(rows)
         return self._dist_grouped_agg(d.batch, keys, aggs, pax,
                                       bypass=bypass, node=node)
 
@@ -728,7 +817,15 @@ class DistributedExecutor(OomLadderMixin):
         The exchange is the skew-aware multi-round shuffle: the wire
         quota stays fixed (sized for the balanced case = one round);
         retries double only the *receive* capacity, which overflows only
-        when one device genuinely owns more groups than planned."""
+        when one device genuinely owns more groups than planned.
+
+        Every capacity here (``mg_partial``, ``quota``, ``mg_final``)
+        follows ``b.capacity``, and ``_exec_aggregate`` hands over a
+        batch already compacted to its per-device live count
+        (``_compact_for_exchange``): the step's shapes follow what is
+        live — one bucket per power of two of the count — while the
+        wire bytes it reports stay capacity-based (``a2a_wire_bytes``
+        of the quota, padding included)."""
         fault_point("step.agg")
         fault_point("exchange.aggregate")
         Pn = self.nworkers
@@ -995,7 +1092,10 @@ class DistributedExecutor(OomLadderMixin):
                                         verify,
                                         rows_hint=info.join_rows_ub.get(
                                             id(node)))
-        build_rows = live_count(right.batch)
+        # per device, so a repartition join can size its build side's
+        # compaction from this same read
+        rcounts = self._device_live_counts(right)
+        build_rows = int(rcounts.sum())
         # budget on the ACTUAL materialized build size (the batch is in
         # hand — a stats overestimate must not force a host spill of a
         # build that fits)
@@ -1035,7 +1135,7 @@ class DistributedExecutor(OomLadderMixin):
                                      and node.kind != "full"):
             salt = None  # stale decision for a changed mesh: ignore
         return self._repartition_join(node, left, right, lkey, rkey, verify,
-                                      salt=salt)
+                                      salt=salt, rcounts=rcounts)
 
     def _concat_sharded(self, d: DistBatch, extra: Batch) -> DistBatch:
         """Append an (unsharded) batch to a DistBatch: shard the extra
@@ -1143,12 +1243,20 @@ class DistributedExecutor(OomLadderMixin):
         return self._concat_sharded(DistBatch(out, left.sharded), tail)
 
     def _repartition_join(self, node, left: DistBatch, right: DistBatch,
-                          lkey, rkey, verify=(), salt=None):
+                          lkey, rkey, verify=(), salt=None, rcounts=None):
         """FIXED_HASH distribution: all_to_all both sides on the join
         key so matching rows colocate, then join device-locally. After
         the exchange every build row lives on exactly ONE device, so
         FULL OUTER's unmatched-build tail is computed and appended
         device-locally inside the same compiled step.
+
+        Both sides are first compacted to their per-device live counts
+        where that at least halves them (``_compact_for_exchange``;
+        ``rcounts`` = the build side's counts if the caller has read
+        them): the wire quotas, receive capacities and ``out_cap``
+        below follow the compacted capacities — and so does the
+        output's, which the next exchange inherits — while the wire
+        bytes reported stay capacity-based (quota x row bytes).
 
         ``salt`` (an adaptive ``salt`` decision, or None) rewrites the
         exchange for a history-proven hot destination: probe rows bound
@@ -1179,6 +1287,8 @@ class DistributedExecutor(OomLadderMixin):
                     "codes are not comparable across dictionaries"
                 )
         fault_point("exchange.join")
+        left, _ = self._compact_for_exchange(left, "join.probe")
+        right, _ = self._compact_for_exchange(right, "join.build", rcounts)
         Pn = self.nworkers
         lcap = left.batch.capacity // Pn
         rcap = right.batch.capacity // Pn
@@ -1800,7 +1910,8 @@ class DistributedExecutor(OomLadderMixin):
             raise NotImplementedError("wide string semi-join keys")
         from presto_tpu.runtime.memory import node_row_bytes
 
-        build_rows = live_count(right.batch)
+        rcounts = self._device_live_counts(right)
+        build_rows = int(rcounts.sum())
         est = build_rows * node_row_bytes(node.right, self.catalog)
         if est > self.join_build_budget or self.oom_rung > 0:
             # bucketing is exact for semi AND anti: a probe key's
@@ -1828,7 +1939,8 @@ class DistributedExecutor(OomLadderMixin):
             )
             return DistBatch(op.process(left.batch)[0], left.sharded)
         shim = _SemiShim(node)
-        return self._repartition_join(shim, left, right, lkey, rkey)
+        return self._repartition_join(shim, left, right, lkey, rkey,
+                                      rcounts=rcounts)
 
     # ---- set operations --------------------------------------------------
     def _exec_union(self, node: N.Union, scalars) -> DistBatch:

@@ -259,6 +259,15 @@ def any_flag(flag, axes=WORKERS):
 # time is the host-observed wall of the enclosing compiled step — the
 # collective is fused inside it, so the step IS the exchange dispatch
 # unit (SURVEY §7.1).
+#
+# Quotas follow the input's capacity, and the distributed executor
+# compacts a mostly-dead input to its per-device live count before a
+# hash exchange (``DistributedExecutor._compact_for_exchange``; counters
+# ``exchange.compacted`` / ``.compact_skipped`` / ``.compact_slots_in`` /
+# ``.compact_slots_out``): the quota a step is built with — and with it
+# these capacity-based bytes — shrinks with the live rows, while the
+# accounting rule itself does not change. Live rows delivered are
+# ``exchange.rows.<site>``.
 
 
 def a2a_wire_bytes(row_bytes: int, num_partitions: int, quota: int,

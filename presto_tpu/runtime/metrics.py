@@ -399,6 +399,18 @@ METRIC_HELP: dict[str, str] = {
     "exchange.bytes.gather": (
         "replication bytes: each shard's capacity to the P-1 other "
         "devices"),
+    "exchange.compacted": (
+        "hash-exchange inputs compacted per device to the bucket of "
+        "their largest per-device live count before the exchange"),
+    "exchange.compact_skipped": (
+        "hash-exchange inputs whose live counts were read and whose "
+        "capacity would not at least halve: exchanged as they came"),
+    "exchange.compact_slots_in": (
+        "row slots (all devices) of the inputs counted by "
+        "exchange.compacted, before the compaction"),
+    "exchange.compact_slots_out": (
+        "row slots (all devices) of the inputs counted by "
+        "exchange.compacted, after the compaction"),
     "exchange.dispatch_s": "partitioned-exchange dispatch latency",
     "exchange.dispatches": "partitioned-exchange dispatches",
     "exchange.rounds": "exchange rounds executed",
