@@ -151,7 +151,7 @@ def embedded_cases():
 # ---------------------------------------------------------------------------
 
 MUST_STAY_ZERO = ("exec.q1_route_fallback", "exec.leaf_route_fallback",
-                  "join.pallas_fallback", "query.oom_degraded")
+                  "query.oom_degraded")
 PROGRAM_NAMES = {"mosaic": "Mosaic kernel", "xla": "XLA",
                  "interpret": "Pallas interpreter"}
 
@@ -263,7 +263,7 @@ def phase_embedded(conn, tables) -> None:
              rows=len(cold_df), matches_oracle=True, programs=families[name],
              cold_traces=s1.get("exec.traces", 0) - s0.get("exec.traces", 0),
              warm_traces=warm_traces,
-             routes=delta(s1, s0, ("exec.q1_", "exec.leaf_", "exec.pallas_",
+             routes=delta(s1, s0, ("exec.q1_", "exec.leaf_",
                                    "join.strategy.", "agg.strategy.")),
              memory=device_memory())
     # which cached steps paid the cold seconds (slowest invocation =

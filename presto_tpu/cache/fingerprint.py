@@ -50,13 +50,10 @@ CODEGEN_PROPERTIES = (
     "join_build_budget_bytes",
     "direct_group_limit",
     "pallas_strings",
-    # approx_join CHANGES results (Bloom-sketch semi joins may keep
-    # false-positive rows): exact and approximate runs must never share
-    # cached results. runtime_join_filters / pallas_join are deliberately
-    # NOT here — both are bit-identical to their fallbacks.
-    "approx_join",
+    # runtime_join_filters is deliberately NOT here — it is bit-identical
+    # to its fallback.
     # approx_scan_fraction < 1 drops splits (sampled scans): sampled and
-    # exact runs must never share cached results either
+    # exact runs must never share cached results
     "approx_scan_fraction",
     # narrow_storage is deliberately NOT here: the fingerprint folds the
     # RESOLVED physical scan schemas (physical_scan_schemas below), which

@@ -53,11 +53,10 @@ class CacheEntry:
     df: object  # the stored pandas DataFrame (never handed out directly)
     versions: "tuple[tuple[str, int], ...]"  # (table, version) at populate
     nbytes: int
-    #: the populating run probed an approximate join sketch — a hit
-    #: must restore QueryInfo.approximate exactly as the original run
-    #: reported it (never inferred from the session property: an
-    #: approx-enabled session still produces EXACT results when no
-    #: sketch ever fired)
+    #: the populating run sampled its scans — a hit must restore
+    #: QueryInfo.approximate exactly as the original run reported it
+    #: (never inferred from which session served it: an approx-tier
+    #: session still produces EXACT results when no scan was sampled)
     approximate: bool = False
 
 

@@ -217,15 +217,6 @@ class DistributedExecutor(OomLadderMixin):
         #: (see LocalExecutor.params): traced step argument + ambient
         #: scope for the whole run
         self.params: tuple = ()
-        # The fused Pallas join probe (ops/pallas_join) never runs on
-        # this tier: the distributed probe steps are GSPMD-sharded
-        # jits where a pallas_call would not partition — the fused
-        # route fires on the LOCAL tier (and on distributed->local
-        # degraded runs, which read the session's pallas_join property
-        # directly), so no spec is ever passed to the broadcast build
-        # below. The OOM ladder keeps its contract either way: rung>0
-        # forces grouped (bucketed) joins, which never build fused
-        # tables — the robustness backstop stays the backstop.
         #: QUERY-scoped join-key min/max memo (reset per run; hits
         #: fire joinkeys.minmax_memo_hits — see exec/joinkeys.py)
         self._minmax_memo: dict = {}

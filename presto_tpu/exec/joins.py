@@ -49,7 +49,6 @@ from presto_tpu.ops.join import (
     probe_unique,
     probe_unique_dense,
 )
-from presto_tpu.ops import pallas_join
 from presto_tpu.ops.hashing import bloom_build
 from presto_tpu.ops.pallas_mode import count_program
 from presto_tpu.runtime.metrics import REGISTRY
@@ -130,7 +129,6 @@ class JoinBuildOperator(CollectingOperator):
         capacity: int | None = None,
         dense_domain: tuple[int, int] | None = None,
         key_max: int | None = None,
-        pallas: "pallas_join.PallasJoinSpec | None" = None,
         filter_bits: int = 0,
         params: Sequence = (),
     ):
@@ -147,12 +145,6 @@ class JoinBuildOperator(CollectingOperator):
         violating key trips ``sentinel_hit`` and the query refuses
         loudly rather than mispacking.
 
-        ``pallas``: planner-chosen fused-probe spec (ops/pallas_join) —
-        VMEM-replicated lookup tables built alongside the sorted side.
-        Advisory like dense_domain: a domain-violating or NULL-carrying
-        payload discards the tables (``join.pallas_fallback`` counter)
-        and the XLA probes take over — loud, never wrong.
-
         ``filter_bits``: when > 0, the build additionally derives the
         sideways-information-passing products — build-key min/max plus
         a two-hash Bloom bitmask of this many bits — published as
@@ -165,12 +157,10 @@ class JoinBuildOperator(CollectingOperator):
         self.capacity = capacity
         self.dense_domain = dense_domain
         self.key_max = key_max
-        self.pallas = pallas
         self.filter_bits = filter_bits
         self.pack_bits: int | None = None
         self.build_side: BuildSide | None = None
         self.dense_side: DenseSide | None = None
-        self.pallas_side: tuple | None = None
         #: (min, max) 0-d device scalars over live build keys, and the
         #: Bloom words array — the runtime-join-filter products (set
         #: when filter_bits > 0 and the build is non-empty)
@@ -180,27 +170,6 @@ class JoinBuildOperator(CollectingOperator):
         #: True when some sorted-key run exceeds VERIFY_CANDIDATES —
         #: hash-key verified probes must refuse (see finish())
         self.long_dup_runs: bool = False
-
-    def _eligible_pallas_spec(self, batch: Batch):
-        """The planner's spec is stats-based; storage is only visible
-        now. Payload columns must be 1-D integer <= 32-bit (the narrow
-        scan representation) — anything else falls back loudly."""
-        spec = self.pallas
-        if spec is None:
-            return None
-        if spec.mode == "payload":
-            for c in spec.payload:
-                if c not in batch:
-                    spec = None
-                    break
-                data = batch[c].data
-                if data.ndim != 1 or not pallas_join.key_dtype_ok(data.dtype):
-                    spec = None
-                    break
-        if spec is None:
-            REGISTRY.counter("join.pallas_fallback").add()
-            self.pallas = None
-        return spec
 
     def finish(self) -> list[Batch]:
         if not self.batches:
@@ -218,7 +187,6 @@ class JoinBuildOperator(CollectingOperator):
         from presto_tpu.cache.exec_cache import EXEC_CACHE, trace_probe
 
         key_expr, pack_bits = self.key, self.pack_bits
-        spec = self._eligible_pallas_spec(batch)
         fbits = self.filter_bits
 
         def make_build():
@@ -233,25 +201,6 @@ class JoinBuildOperator(CollectingOperator):
                 live = b.live & v.valid
                 side = build_lookup(v.data, live, cap, pack_bits=pack_bits)
                 dense = build_dense(v.data, live, dd[0], dd[1]) if dd else None
-                ptables, poob, pnull = None, None, None
-                if spec is not None:
-                    if spec.mode == "exists":
-                        t, poob = pallas_join.build_exists_table(
-                            v.data, live, spec.key_min, spec.key_max)
-                        ptables = (t,)
-                    elif spec.mode == "sketch":
-                        ptables = (pallas_join.build_sketch_table(
-                            v.data, live, spec.nbits),)
-                    else:
-                        # a live payload NULL has no slot in the value
-                        # tables; discard the fused side rather than
-                        # conjure a 0 (checked host-side below)
-                        pnull = jnp.any(jnp.stack([
-                            jnp.any(live & ~b[c].valid) for c in spec.payload
-                        ]))
-                        ptables, poob = pallas_join.build_payload_tables(
-                            v.data, live, spec.key_min, spec.key_max,
-                            [b[c].data for c in spec.payload])
                 filt = None
                 if fbits:
                     k64 = v.data.astype(jnp.int64)
@@ -264,39 +213,27 @@ class JoinBuildOperator(CollectingOperator):
                 # 63-bit hash — astronomically unlikely) must be refused,
                 # not silently mis-probed
                 return (side, dense, long_dup_runs_flag(side.sorted_keys),
-                        ptables, poob, pnull, filt)
+                        filt)
 
             return join_build_step
 
         # shared across queries: the closure bakes in only (key expr,
-        # capacity, dense domain, pack bits, pallas spec, filter bits)
+        # capacity, dense domain, pack bits, filter bits)
         # — all in the content key
         build = EXEC_CACHE.get_or_build(
             EXEC_CACHE.key_of("join_build", key_expr, cap, dd, pack_bits,
-                              spec.key() if spec else None, fbits),
+                              fbits),
             make_build,
         )
         with trace_span("step:join_build", "step", {"capacity": cap}):
-            side, dense, long_runs, ptables, poob, pnull, filt = build(
-                batch, self._params)
+            side, dense, long_runs, filt = build(batch, self._params)
         # the build's flags are the first host reads after its dispatch:
         # the host waits here for the whole build (sort included)
         with trace_sync("join_build"):
-            pallas_bad = spec is not None and (
-                (poob is not None and bool(poob))
-                or (pnull is not None and bool(pnull)))
             overflow = bool(side.overflow)
             sentinel_hit = bool(side.sentinel_hit)
             long_dup_runs = bool(long_runs)
             dense_ok = dense is not None and not bool(dense.overflow)
-        if spec is not None:
-            if pallas_bad:
-                # advisory stats violated (or a NULL payload): the
-                # generic probes take over — loud, never wrong
-                REGISTRY.counter("join.pallas_fallback").add()
-                self.pallas = None
-            else:
-                self.pallas_side = ptables
         if filt is not None:
             self.filter_minmax = (filt[0], filt[1])
             self.filter_bloom = filt[2]
@@ -375,103 +312,15 @@ class LookupJoinOperator(Operator):
         self.verify = list(verify)
         self._step = None
         self._full_step = None
-        self._pallas_step = None
         self._strategy = None
 
     def _record_strategy(self, name: str):
         """Count the chosen probe strategy ONCE per operator (the
-        ``join.strategy.*`` observability counters; ``pallas`` also
-        fires the tier-1 gate's route-hit counter)."""
+        ``join.strategy.*`` observability counters)."""
         if self._strategy is None:
             self._strategy = name
             REGISTRY.counter(f"join.strategy.{name}").add()
-            count_program("join", name == "pallas")
-            if name == "pallas":
-                REGISTRY.counter("exec.pallas_join_route").add()
-
-    # ---- fused Pallas probe (ops/pallas_join) ------------------------
-    def _pallas_usable(self, batch: Batch) -> bool:
-        """Host-side per-batch routing decision: the build published
-        VMEM tables AND this batch's key storage/capacity block. Any
-        miss falls back to the XLA probes below — results identical."""
-        build = self.build
-        spec = build.pallas
-        if build.pallas_side is None or spec is None or self.verify:
-            return False
-        jt = self.join_type
-        if spec.mode == "payload":
-            if not (self.unique and jt in ("inner", "left")):
-                return False
-            if spec.payload != tuple(bo.source for bo in self.build_outputs):
-                return False
-        elif spec.mode == "exists":
-            # existence is duplicate-safe (semi/anti); a no-payload
-            # INNER additionally needs unique build keys (duplicates
-            # would multiply rows)
-            if not (jt in ("semi", "anti")
-                    or (self.unique and jt == "inner"
-                        and not self.build_outputs)):
-                return False
-        else:  # sketch: false positives ADD rows — semi only, never
-            # anti (a false positive would silently DROP rows)
-            if jt != "semi":
-                return False
-        k = self.probe_key
-        if not (isinstance(k, InputRef) and k.name in batch):
-            return False
-        if not pallas_join.key_dtype_ok(batch[k.name].data.dtype):
-            return False
-        return pallas_join.probe_block(batch.capacity) is not None
-
-    def _ensure_pallas_step(self):
-        from presto_tpu.cache.exec_cache import EXEC_CACHE, trace_probe
-
-        if self._pallas_step is not None:
-            return
-        spec = self.build.pallas
-        key = self.probe_key
-        outs = tuple(self.build_outputs)
-        jt = self.join_type
-
-        def make():
-            def step(tables, payload: Batch, batch: Batch, params=()) -> Batch:
-                trace_probe()
-                with param_scope(params):
-                    return body(tables, payload, batch)
-
-            def body(tables, payload: Batch, batch: Batch) -> Batch:
-                v = evaluate(key, batch)
-                plive = batch.live & v.valid
-                if spec.mode == "payload":
-                    matched, vals = pallas_join.payload_probe(
-                        tables, spec.key_min, spec.key_max, v.data, plive)
-                    cols = dict(batch.columns)
-                    for bo, pv in zip(outs, vals):
-                        src = payload[bo.source]
-                        # payload NULL-freedom was proven at build, so
-                        # validity is exactly the match mask (the
-                        # generic step's gather(valid) & matched)
-                        cols[bo.name] = Column(pv.astype(src.data.dtype),
-                                               matched, src.dtype,
-                                               src.dictionary)
-                    live = batch.live & matched if jt == "inner" else batch.live
-                    return Batch(cols, live)
-                if spec.mode == "sketch":
-                    matched = pallas_join.sketch_probe(
-                        tables[0], spec.nbits, v.data, plive)
-                else:
-                    matched = pallas_join.exists_probe(
-                        tables[0], spec.key_min, spec.key_max, v.data, plive)
-                keep = ~matched if jt == "anti" else matched
-                return batch.with_live(batch.live & keep)
-
-            step.__name__ = f"probe_{jt}_step"
-            return jax.jit(step)
-
-        self._pallas_step = EXEC_CACHE.get_or_build(
-            EXEC_CACHE.key_of("lookup_pallas", key, outs, jt, spec.key()),
-            make,
-        )
+            count_program("join", False)
 
     def _make_unique_probe(self, use_dense: bool):
         """Probe-aligned unique lookup closure: (build_row, matched).
@@ -655,18 +504,6 @@ class LookupJoinOperator(Operator):
     def process(self, batch: Batch) -> list[Batch]:
         assert self.build.build_side is not None, "build side not finished"
         self._check_probe_dict(batch)
-        if self._pallas_usable(batch):
-            self._ensure_pallas_step()
-            self._record_strategy("pallas")
-            with trace_span(f"step:probe_{self.join_type}", "step",
-                            {"strategy": "pallas"}):
-                return [self._pallas_step(self.build.pallas_side,
-                                          self.build.payload, batch,
-                                          self._params)]
-        if self.build.pallas_side is not None:
-            # the build published fused tables but THIS batch cannot
-            # ride them (key storage / capacity block): degrade loudly
-            REGISTRY.counter("join.pallas_fallback").add()
         self._ensure_step()
         if self.unique or self.join_type in ("semi", "anti"):
             side = (

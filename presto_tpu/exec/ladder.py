@@ -143,7 +143,6 @@ class OomLadderMixin:
         "salt": "adaptive.salted",
         "join_flip": "adaptive.join_flip",
         "bucket": "adaptive.bucket_override",
-        "route": "adaptive.route_disabled",
     }
 
     def _adaptive_decision(self, node, kind: str):
@@ -173,14 +172,3 @@ class OomLadderMixin:
                 ev["action"] = action
             events.append(ev)
 
-    def _note_route_fallback(self, node) -> None:
-        """A planner-chosen fused route fell back at runtime: mark the
-        node's stats so the fingerprint's history carries the lie
-        (stats.record_route_fallback — telemetry, never raises)."""
-        recorder = getattr(self, "recorder", None)
-        if recorder is None:
-            return
-        try:
-            recorder.record_route_fallback(getattr(node, "plan_node", node))
-        except Exception:  # noqa: BLE001 — telemetry never raises
-            pass

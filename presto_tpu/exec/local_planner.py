@@ -197,8 +197,6 @@ class LocalExecutor(OomLadderMixin):
     def __init__(self, catalog: Catalog, join_build_budget: int | None = None,
                  direct_group_limit: int = DIRECT_LIMIT,
                  runtime_join_filters: bool = True,
-                 pallas_join_enabled: bool = False,
-                 approx_join: bool = False,
                  scan_sample_fraction: float = 1.0,
                  spill_host_budget: int | None = None):
         self.catalog = catalog
@@ -213,11 +211,6 @@ class LocalExecutor(OomLadderMixin):
         #: sideways information passing: push join-build key bounds +
         #: Bloom bitmasks into probe-side scans (semantics-preserving)
         self.runtime_join_filters = runtime_join_filters
-        #: prefer the fused VMEM-table Pallas probe where stats permit
-        self.pallas_join_enabled = pallas_join_enabled
-        #: allow the APPROXIMATE sketch probe (semi joins; false
-        #: positives possible) where the exact table cannot fit
-        self.approx_join = approx_join
         #: APPROXIMATE sampled scans (the approx_scan_fraction session
         #: property): below 1.0, _exec_tablescan keeps only an evenly
         #: strided fraction of each table's splits and marks the run
@@ -230,10 +223,9 @@ class LocalExecutor(OomLadderMixin):
         #: join_key_exprs call in one plan run (reset per run_batches;
         #: hits fire joinkeys.minmax_memo_hits — see exec/joinkeys.py)
         self._minmax_memo: dict = {}
-        #: True when this run handed a SKETCH (approximate) spec to a
-        #: finished build that published tables: the query's semi-join
-        #: membership may contain Bloom false positives, and QueryInfo
-        #: must say so (never silently approximate)
+        #: True when this run sampled a scan (scan_sample_fraction
+        #: dropped splits): QueryInfo must say so (never silently
+        #: approximate)
         self.used_approx = False
         #: optional StatsRecorder for the current query (set by the
         #: Session; powers QueryInfo node stats and EXPLAIN ANALYZE)
@@ -879,42 +871,7 @@ class LocalExecutor(OomLadderMixin):
             return (iv[0], int(domain))
         return None
 
-    # ---- fused Pallas probe + sideways information passing ---------------
-    _PALLAS_PAYLOAD_KINDS = (TypeKind.INTEGER, TypeKind.BIGINT, TypeKind.DATE,
-                             TypeKind.DECIMAL, TypeKind.VARCHAR,
-                             TypeKind.BOOLEAN)
-
-    def _pallas_spec(self, iv, outs: tuple, rfields, unique: bool, kind: str):
-        """The fused-probe configuration for a join whose build-key
-        stats interval is ``iv`` (ops/pallas_join.PallasJoinSpec), or
-        None when no kernel mode fits. Exact modes first; the sketch
-        (approximate) mode only under ``approx_join``, only for semi
-        joins, and only when no exact table fits."""
-        from presto_tpu.ops import pallas_join
-
-        if not (self.pallas_join_enabled and pallas_join.available()):
-            return None
-        if iv is not None and pallas_join.interval_ok(int(iv[0]), int(iv[1])):
-            lo, hi = int(iv[0]), int(iv[1])
-            domain = hi - lo + 1
-            if outs:
-                kinds_ok = all(
-                    rfields.get(c) is not None
-                    and rfields[c].kind in self._PALLAS_PAYLOAD_KINDS
-                    for c in outs
-                )
-                if (unique and kind in ("inner", "left") and kinds_ok
-                        and pallas_join.payload_rows(domain, len(outs))):
-                    return pallas_join.PallasJoinSpec(
-                        "payload", lo, hi, payload=tuple(outs))
-            elif ((kind in ("semi", "anti") or (unique and kind == "inner"))
-                    and pallas_join.exists_words(domain)):
-                return pallas_join.PallasJoinSpec("exists", lo, hi)
-        if self.approx_join and kind == "semi" and not outs:
-            return pallas_join.PallasJoinSpec(
-                "sketch", nbits=pallas_join.SKETCH_BITS)
-        return None
-
+    # ---- sideways information passing ------------------------------------
     def _register_join_filter(self, node):
         """Create + register the probe-scan filter slot for an
         INNER/SEMI join BEFORE its probe subtree executes. Structural
@@ -1111,36 +1068,14 @@ class LocalExecutor(OomLadderMixin):
             )
         iv = (self._build_key_interval(node.right, node.right_keys)
               if node.unique else None)
-        # the fused Pallas probe (ops/pallas_join) is the PREFERRED
-        # strategy whenever stats bound the key domain inside the VMEM
-        # table budget; dense/packed stay as the next rungs (and the
-        # per-batch fallback targets) — hash-verified keys never route
-        # history route guard (plan/adaptive.py): a fingerprint whose
-        # fused route already fell back at runtime (lying advisory
-        # stats) stops re-attempting it — no rebuilt tables that only
-        # get discarded again
-        rdec = self._adaptive_decision(node, "route")
-        if rdec is not None:
-            self._note_adaptive(node, rdec, action="pallas route disabled")
-        spec = (None if verify or node.kind == "full" or rdec is not None
-                else self._pallas_spec(
-                    iv, tuple(node.output_right),
-                    {f.name: f.dtype for f in node.right.fields},
-                    node.unique, node.kind))
         # dense/packed only help the UNIQUE probe; other probe kinds
         # would pay the advisory-stats refusal for no benefit
         build = JoinBuildOperator(
             rkey, dense_domain=self._dense_domain(iv, right),
             key_max=self._key_upper_bound(iv) if node.unique else None,
-            pallas=spec,
             filter_bits=self._filter_bits(node.right) if fslot else 0,
             params=self.params)
         Pipeline(BatchSource(right), [build]).run()
-        if spec is not None and build.pallas is None:
-            # the planner's fused route fell back at build time
-            # (advisory stats violated): ride the history so adaptive
-            # execution stops re-attempting it for this fingerprint
-            self._note_route_fallback(node)
         self._fill_join_filter(fslot, build, node.right, rkey)
         outs = [BuildOutput(n, n) for n in node.output_right]
         if node.kind == "full":
@@ -1459,32 +1394,16 @@ class LocalExecutor(OomLadderMixin):
             # existence probes have no build_row to verify against;
             # hash collisions could flip semi/anti membership
             raise NotImplementedError("wide string semi-join keys")
-        # semi/anti existence probes prefer the fused Pallas bitmask
-        # (duplicate-safe), then the dense table when stats allow; the
-        # packed build would be dead weight (probe_exists has no
-        # packed path)
+        # semi/anti existence probes prefer the dense table when stats
+        # allow; the packed build would be dead weight (probe_exists
+        # has no packed path)
         iv = self._build_key_interval(node.right, node.right_keys)
-        rdec = self._adaptive_decision(node, "route")
-        if rdec is not None:
-            self._note_adaptive(node, rdec, action="pallas route disabled")
-        spec = (None if rdec is not None
-                else self._pallas_spec(iv, (), {}, True, jt))
         build = JoinBuildOperator(
-            rkey, dense_domain=self._dense_domain(iv, right), pallas=spec,
+            rkey, dense_domain=self._dense_domain(iv, right),
             filter_bits=self._filter_bits(node.right) if fslot else 0,
             params=self.params)
         Pipeline(BatchSource(right), [build]).run()
-        if spec is not None and build.pallas is None:
-            self._note_route_fallback(node)
         self._fill_join_filter(fslot, build, node.right, rkey)
-        if (spec is not None and spec.mode == "sketch"
-                and build.pallas_side is not None):
-            # the sketch tables were published: eligible probe batches
-            # will ride the Bloom sketch, so this query's result may
-            # carry false-positive rows — QueryInfo flags it
-            # (conservative: a per-batch capacity fallback could still
-            # make the run exact in practice; flagged is flagged)
-            self.used_approx = True
         op = LookupJoinOperator(build, lkey, (), jt, params=self.params)
         return left.map(lambda b: op.process(b)[0])
 

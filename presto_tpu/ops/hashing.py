@@ -48,12 +48,10 @@ def partition_ids(columns, num_partitions: int) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# 32-bit mixing for the join-sketch / runtime-filter Bloom bitmasks.
-# Everything below must trace under BOTH XLA and Mosaic (Pallas): int32
-# arithmetic only, arithmetic shifts masked back to logical, np.int32
-# literals (weak Python ints trace as i64 scalars Mosaic rejects — see
-# ops/pallas_groupby.py). Build (XLA scatter) and probe (in-kernel)
-# MUST use the same functions or bits and tests would disagree.
+# 32-bit mixing for the runtime-join-filter Bloom bitmasks: int32
+# arithmetic only, arithmetic shifts masked back to logical. Build
+# (bloom_build) and scan-side test (bloom_test) MUST use the same
+# functions or bits and tests would disagree.
 # ---------------------------------------------------------------------------
 
 _M32A = np.int32(np.uint32(0x85EBCA6B).view(np.int32))

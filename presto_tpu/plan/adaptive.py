@@ -2,7 +2,7 @@
 into plan decisions (ROADMAP item 2 — the loop-closing half of the
 plan-stats history that PR 8/10 only *reported*).
 
-Three coupled decision kinds, one controller:
+Two coupled decision kinds, one controller:
 
 - ``salt`` — skew-salted repartitioning. When a recurring plan
   fingerprint's history shows a hot exchange destination on a
@@ -24,9 +24,6 @@ Three coupled decision kinds, one controller:
   rows, flipping grouped execution back to in-memory when the build
   actually fits (and vice versa), and resizing grouped bucket counts
   from actuals instead of guesses.
-- ``route`` — a Pallas-routed join whose advisory stats LIED (the
-  build fell back at runtime: ``join.pallas_fallback``) stops
-  re-attempting the fused route for that fingerprint.
 
 Every decision passes the **compile-budget gate** before it is
 allowed: a re-specialization changes an executable-cache key, so its
@@ -104,7 +101,7 @@ def salt_factor(skew: float, nworkers: int, salt_max: int) -> int:
 class AdaptiveDecision:
     """One steering decision for one plan node of one fingerprint."""
 
-    kind: str  # "salt" | "join_flip" | "bucket" | "route"
+    kind: str  # "salt" | "join_flip" | "bucket"
     node_id: int
     #: salt partition count (kind == "salt")
     salt: int = 0
@@ -160,7 +157,6 @@ _COST_KIND = {
     "salt": "dist_repart_join",
     "join_flip": "join_build",
     "bucket": "global_agg",
-    "route": "join_build",
 }
 
 
@@ -186,7 +182,7 @@ class AdaptiveController:
         """One decision pass: {id(live node) -> {kind ->
         AdaptiveDecision}} for the executor (the ``plan_hints`` wiring
         shape; a node can carry several independent kinds — a salted
-        repartition join may also have its Pallas route disabled).
+        repartition join may also have its build size corrected).
         ``hints`` is ``Session._plan_hints`` output — present only when
         the fingerprint has recurred (runs >= 2), so the corridor's
         gate is inherited. ``for_render`` computes WOULD-BE decisions
@@ -271,12 +267,6 @@ class AdaptiveController:
                             hot_partition=hot,
                             trigger=f"skew {skew:.1f}x hot={hot}",
                         ), runs, wall, win_frac=1.0 - 1.0 / s)
-                    if not replayed(node, "route", nid) and \
-                            rec.get("route_fallback"):
-                        admit(node, AdaptiveDecision(
-                            "route", nid,
-                            trigger="pallas route fell back (lying stats)",
-                        ), runs, wall, win_frac=0.5)
                 # build-size correction reads the BUILD CHILD's actuals
                 brec = hints.get(id(node.right))
                 if brec is not None:

@@ -132,30 +132,16 @@ def filter_edges(plan: N.PlanNode) -> list[tuple[object, N.TableScan, str]]:
 
 def planned_join_strategy(node, catalog,
                           join_build_budget: int | None = None,
-                          approx_join: bool = False,
-                          memo: "dict | None" = None,
-                          pallas_join_enabled: bool = False) -> str:
+                          memo: "dict | None" = None) -> str:
     """The probe strategy the executors will pick for this join, from
-    stats alone: grouped (build over budget) > pallas (fused VMEM
-    probe) > dense (direct-address table) > unique (sorted probe) >
-    expand. Advisory like every stats decision — runtime ineligibility
-    (storage dtypes, capacity blocks, domain violations) degrades one
-    rung with a ``join.pallas_fallback`` counter, never silently.
-
-    ``approx_join``: mirrors the session property — a non-negated SEMI
-    join whose exact fused table cannot fit then plans as
-    ``sketch(approx)``, rendering the APPROXIMATE mode distinctly in
-    EXPLAIN (the other half of the never-silently-approximate
-    contract; QueryInfo.approximate is the runtime half).
-
-    ``pallas_join_enabled``: mirrors the ``pallas_join`` session
-    property (off by default), as the executor's ``_pallas_spec`` reads
-    it.
+    stats alone: hybrid/grouped (build over budget) > dense
+    (direct-address table) > unique (sorted probe) > expand. Advisory
+    like every stats decision — a key outside its declared domain at
+    runtime discards the dense side and the sorted probe answers.
 
     ``memo``: optional per-walk estimate/interval cache
     (plan/bounds) — the estimate snapshot passes one dict over the
     whole plan so its per-join strategy calls stay linear."""
-    from presto_tpu.ops import pallas_join
     from presto_tpu.plan.bounds import expr_interval, node_intervals
     from presto_tpu.runtime.memory import (
         device_budget_bytes,
@@ -177,28 +163,6 @@ def planned_join_strategy(node, catalog,
     if len(node.right_keys) == 1:
         iv = expr_interval(node.right_keys[0],
                            node_intervals(node.right, catalog, memo))
-    unique = True if semi else node.unique
-    # the fused probes run only where the session asks for them AND
-    # Mosaic does not refuse their gather (every TPU backend does today
-    # — pallas_join.available): otherwise the strategy reported is the
-    # XLA probe that really runs
-    fused = pallas_join_enabled and pallas_join.available()
-    if fused and iv is not None and pallas_join.interval_ok(iv[0], iv[1]):
-        domain = iv[1] - iv[0] + 1
-        outs = () if semi else node.output_right
-        if not outs and (semi or (unique and node.kind == "inner")) \
-                and pallas_join.exists_words(domain):
-            return "pallas"
-        if outs and unique and node.kind in ("inner", "left") \
-                and pallas_join.payload_rows(domain, len(outs)):
-            return "pallas"
-    if fused and approx_join and semi and not node.negated:
-        # no exact fused table fit above: the executor's _pallas_spec
-        # will hand the build a Bloom sketch — approximate, and said so
-        return "sketch(approx)"
-    if iv is not None and unique and not semi:
-        if 0 < iv[1] - iv[0] + 1 <= (1 << 31) - 1:
-            return "dense"
-    if semi or unique:
+    if semi or node.unique:
         return "dense" if iv is not None else "unique"
     return "expand"

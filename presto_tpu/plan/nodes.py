@@ -320,26 +320,19 @@ def scan_physical_types(node: "TableScan", catalog) -> dict:
 
 
 def plan_tree_str(node: PlanNode, indent: int = 0, catalog=None,
-                  _filters=None, approx_join: bool = False,
-                  plan_hints=None, agg_bypass: bool = True,
-                  join_build_budget=None, adaptive=None,
-                  pallas_join: bool = False) -> str:
+                  _filters=None, plan_hints=None, agg_bypass: bool = True,
+                  join_build_budget=None, adaptive=None) -> str:
     """EXPLAIN-style rendering (reference: PlanPrinter). With a
     ``catalog``, scan columns render their chosen PHYSICAL storage
     (``l_shipdate:date:int16``), joins render the stats-planned probe
-    strategy (``strategy=pallas|dense|unique|expand|grouped``),
+    strategy (``strategy=dense|unique|expand|hybrid|grouped``),
     aggregates render the adaptive aggregation strategy
     (``agg_strategy=fused|bypass|partial|single`` — exec/leaf_route.py,
     fed by ``plan_hints``: plan-stats history records for a recurring
     fingerprint, keyed by ``id(plan node)``), and probe-side scans
     render the runtime join filters that will be pushed into them
     (``runtime_filter=[l_orderkey]``) — the sideways information
-    passing placement, visible before execution. ``pallas_join``
-    mirrors the session property of that name (``strategy=pallas``
-    renders only where it is on and the backend admits the kernels).
-    With ``approx_join`` (the session property), semi joins that would
-    probe the Bloom sketch render ``strategy=sketch(approx)`` — the
-    APPROXIMATE mode is never silent in EXPLAIN."""
+    passing placement, visible before execution."""
     if _filters is None and catalog is not None:
         from presto_tpu.plan.joinfilters import filter_edges
 
@@ -374,8 +367,7 @@ def plan_tree_str(node: PlanNode, indent: int = 0, catalog=None,
                 detail += f" agg_strategy={s}"
     elif isinstance(node, (Join,)):
         detail = f" {node.kind}{' unique' if node.unique else ''}"
-        detail += _strategy_str(node, catalog, approx_join, join_build_budget,
-                                pallas_join)
+        detail += _strategy_str(node, catalog, join_build_budget)
         # adaptive skew-salting decision (plan/adaptive.py, keyed by
         # id(live node) like plan_hints): the rewritten exchange is
         # never silent in EXPLAIN
@@ -386,8 +378,7 @@ def plan_tree_str(node: PlanNode, indent: int = 0, catalog=None,
         detail = f" funcs={[f.name for f in node.funcs]} frame={node.frame}"
     elif isinstance(node, SemiJoin):
         detail = f"{' anti' if node.negated else ''}"
-        detail += _strategy_str(node, catalog, approx_join, join_build_budget,
-                                pallas_join)
+        detail += _strategy_str(node, catalog, join_build_budget)
     elif isinstance(node, (TopN,)):
         detail = f" n={node.count}"
     elif isinstance(node, Limit):
@@ -399,24 +390,21 @@ def plan_tree_str(node: PlanNode, indent: int = 0, catalog=None,
     out = f"{pad}{name}{detail}\n"
     for c in node.children:
         out += plan_tree_str(c, indent + 1, catalog=catalog,
-                             _filters=_filters or {}, approx_join=approx_join,
+                             _filters=_filters or {},
                              plan_hints=plan_hints, agg_bypass=agg_bypass,
                              join_build_budget=join_build_budget,
-                             adaptive=adaptive, pallas_join=pallas_join)
+                             adaptive=adaptive)
     return out
 
 
-def _strategy_str(node, catalog, approx_join: bool = False,
-                  join_build_budget=None, pallas_join: bool = False) -> str:
+def _strategy_str(node, catalog, join_build_budget=None) -> str:
     if catalog is None:
         return ""
     from presto_tpu.plan.joinfilters import planned_join_strategy
 
     try:
         s = planned_join_strategy(node, catalog,
-                                  join_build_budget=join_build_budget,
-                                  approx_join=approx_join,
-                                  pallas_join_enabled=pallas_join)
+                                  join_build_budget=join_build_budget)
     except Exception:  # noqa: BLE001 — EXPLAIN must render partial plans
         return ""
     out = f" strategy={s}"

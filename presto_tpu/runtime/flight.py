@@ -213,8 +213,6 @@ class FlightRecorder:
 
             render = plan_tree_str(
                 plan, catalog=session.catalog,
-                approx_join=bool(session.prop("approx_join")),
-                pallas_join=bool(session.prop("pallas_join")),
                 plan_hints=getattr(executor, "plan_hints", None) or None,
                 agg_bypass=bool(getattr(executor, "agg_bypass", True)),
                 join_build_budget=getattr(executor, "join_build_budget",

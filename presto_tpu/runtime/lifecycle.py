@@ -726,10 +726,10 @@ class QueryManager:
                 result = executor.run(plan)
                 if rung > 0 and budget is not None:
                     budget.record_success()
-                # approximate-join visibility: the executor records
-                # whether this run published a sketch (Bloom) probe —
-                # QueryInfo must flag possibly-approximate results so
-                # exactness is never silently degraded (ISSUE-7)
+                # approximate visibility: the executor records whether
+                # this run sampled a scan — QueryInfo must flag
+                # possibly-approximate results so exactness is never
+                # silently degraded (ISSUE-7)
                 info.approximate = bool(
                     getattr(executor, "used_approx", False))
                 self._note_planned_spills(executor, info)
@@ -807,8 +807,6 @@ class QueryManager:
             join_build_budget=self.session.prop("join_build_budget_bytes"),
             direct_group_limit=self.session.prop("direct_group_limit"),
             runtime_join_filters=self.session.prop("runtime_join_filters"),
-            pallas_join_enabled=self.session.prop("pallas_join"),
-            approx_join=self.session.prop("approx_join"),
             spill_host_budget=self.session.prop("spill_host_budget_bytes"),
         )
         if recorder is not None:

@@ -426,7 +426,6 @@ METRIC_HELP: dict[str, str] = {
     "exec.leaf_route_fallback": (
         "leaf fused-route bailouts to the general path (reasons: "
         "exec.leaf_route_fallback.*)"),
-    "exec.pallas_join_route": "joins routed through the Pallas kernel",
     "exec.q1_fused_route": (
         "aggregation queries routed through the fused Q1-shape kernel"),
     "exec.q1_route_fallback": (
@@ -494,8 +493,6 @@ METRIC_HELP: dict[str, str] = {
         "probe rows pruned by join-pushdown filters"),
     "join.filter_selectivity": (
         "observed selectivity of join-pushdown filters"),
-    "join.pallas_fallback": (
-        "Pallas join routes that fell back to the general kernel"),
     # ---- memory pool
     "memory.queue_timeouts": (
         "pool admissions that timed out waiting for capacity"),
@@ -515,9 +512,6 @@ METRIC_HELP: dict[str, str] = {
     "adaptive.bucket_override": (
         "grouped aggregations re-sized from recorded actuals "
         "(bucket counts from history, not the static estimate)"),
-    "adaptive.route_disabled": (
-        "fused (Pallas) join routes disabled because the "
-        "fingerprint's route fell back at runtime (lying stats)"),
     "adaptive.compile_budget_refused": (
         "adaptive re-specializations refused because predicted "
         "compile cost exceeded predicted win at the observed "

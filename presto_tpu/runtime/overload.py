@@ -33,7 +33,7 @@ costs the client:
 4. **Brown-out** (last rung before refusing everyone): a health-breach
    event latches :class:`OverloadController`, and tenants that opted
    in via ``TenantSpec.brownout`` have NEW traffic routed to the
-   approx tier (flagged honestly via ``QueryInfo.approximate``) or
+   approx tier (sampled scans, flagged via ``QueryInfo.approximate``) or
    shed outright — fidelity is spent before availability, per the
    approximate-join degradation argument in PAPERS.md. Recovery
    latches back after a breach-free cooldown.

@@ -400,33 +400,6 @@ SESSION_PROPERTIES: dict[str, PropertyDef] = {
             "metrics and the join_filter trace span.",
         ),
         PropertyDef(
-            "pallas_join", bool, False,
-            "Use the fused Pallas VMEM-table probe for equi-joins "
-            "on narrow stats-bounded keys (build->probe->project in "
-            "one kernel; ops/pallas_join.py). Off by default: the "
-            "chip's compiler refuses the kernels' gather, so on a TPU "
-            "backend they are statically out of the route whatever "
-            "this says (pallas_join.available) and the XLA "
-            "dense/sorted/expansion probes run; off a TPU, on runs "
-            "them in Pallas interpret mode (what the tests do). "
-            "Ineligible joins — wide keys, over-budget domains, "
-            "unblockable capacities — take the XLA probes with a "
-            "join.pallas_fallback counter; results are bit-identical "
-            "either way.",
-        ),
-        PropertyDef(
-            "approx_join", bool, False,
-            "APPROXIMATE semi joins (needs pallas_join on: the sketch "
-            "is one of its fused probes): when the exact fused table "
-            "cannot fit VMEM, probe a two-hash Bloom sketch instead — "
-            "false positives possible (extra rows at roughly "
-            "(1-exp(-2n/m))^2 for n build keys in m=2^19 bits), never "
-            "false negatives, never row loss (anti joins are excluded "
-            "by construction). Changes results: the plan fingerprint "
-            "folds this property, so cached results never leak across "
-            "the exact/approximate boundary.",
-        ),
-        PropertyDef(
             "approx_scan_fraction", float, 1.0,
             "APPROXIMATE scans: execute only this deterministic "
             "fraction of each table's splits (evenly strided, so the "

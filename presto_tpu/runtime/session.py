@@ -310,8 +310,6 @@ class Session:
                 join_build_budget=budget,
                 direct_group_limit=self.prop("direct_group_limit"),
                 runtime_join_filters=self.prop("runtime_join_filters"),
-                pallas_join_enabled=self.prop("pallas_join"),
-                approx_join=self.prop("approx_join"),
                 scan_sample_fraction=self.prop("approx_scan_fraction"),
                 spill_host_budget=self.prop("spill_host_budget_bytes"),
             )
@@ -381,8 +379,6 @@ class Session:
         plan, bound = self._plan_binding(stmt)
         hints = self._plan_hints(plan)
         out = plan_tree_str(plan, catalog=self.catalog,
-                            approx_join=bool(self.prop("approx_join")),
-                            pallas_join=bool(self.prop("pallas_join")),
                             plan_hints=hints,
                             agg_bypass=bool(self.prop("partial_agg_bypass")),
                             join_build_budget=self.prop(
@@ -790,10 +786,11 @@ class Session:
                 info.state = "FINISHED"
                 info.cache_hit = True
                 # restore the flag the POPULATING run recorded — an
-                # approx-enabled session still produces exact results
-                # when no sketch fired, and the hit must not re-label
-                # them (the fingerprint folds approx_join, so exact
-                # and approximate sessions can never share entries)
+                # approx-tier session still produces exact results
+                # when no scan was sampled, and the hit must not
+                # re-label them (the fingerprint folds
+                # approx_scan_fraction, so exact and sampled sessions
+                # can never share entries)
                 info.approximate = hit[1].approximate
                 info.output_rows = len(cached)
                 info.finished_at = time.time()
@@ -818,8 +815,6 @@ class Session:
                 recorder.attach_estimates(
                     plan, self.catalog,
                     join_build_budget=self.prop("join_build_budget_bytes"),
-                    approx_join=bool(self.prop("approx_join")),
-                    pallas_join=bool(self.prop("pallas_join")),
                     plan_hints=hints,
                     agg_bypass=bool(self.prop("partial_agg_bypass")),
                 )

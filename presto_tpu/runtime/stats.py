@@ -143,10 +143,6 @@ class NodeStats:
     exchange_rows: int = 0
     #: hottest partition id of the worst-skew exchange (-1: none seen)
     hot_partition: int = -1
-    #: True when a planner-chosen fused (Pallas) route fell back at
-    #: runtime — advisory stats lied; adaptive execution reads this to
-    #: stop re-attempting the route for recurring fingerprints
-    route_fallback: bool = False
     #: executed out-of-core mode ("" = resident / no spill tier ran)
     spill_mode: str = ""
     #: spill partition count (0 outside the spill tier)
@@ -180,7 +176,6 @@ class NodeStats:
             "skew": round(self.skew, 3),
             "exchange_rows": self.exchange_rows,
             "hot_partition": self.hot_partition,
-            "route_fallback": self.route_fallback,
             "spill_mode": self.spill_mode,
             "spill_partitions": self.spill_partitions,
             "spill_resident": self.spill_resident,
@@ -205,10 +200,8 @@ class StatsRecorder:
 
     def attach_estimates(self, plan, catalog,
                          join_build_budget: Optional[int] = None,
-                         approx_join: bool = False,
                          plan_hints: Optional[dict] = None,
-                         agg_bypass: bool = True,
-                         pallas_join: bool = False) -> None:
+                         agg_bypass: bool = True) -> None:
         """Snapshot the planner's per-node predictions BEFORE execution,
         keyed by the same stable node ids the actuals use: estimated
         rows (bounds.estimate_rows), the sound upper bound + exactness
@@ -244,8 +237,7 @@ class StatsRecorder:
                 try:
                     strategy = planned_join_strategy(
                         node, catalog, join_build_budget=join_build_budget,
-                        approx_join=approx_join, memo=memo,
-                        pallas_join_enabled=pallas_join)
+                        memo=memo)
                 except Exception:  # noqa: BLE001
                     strategy = ""
             elif isinstance(node, N.Aggregate):
@@ -325,19 +317,6 @@ class StatsRecorder:
             st.hot_partition = int(hot)
         st.skew = max(st.skew, float(ratio))
         st.exchange_rows += int(rows)
-
-    def record_route_fallback(self, node) -> None:
-        """Mark a node whose planner-chosen fused (Pallas) route fell
-        back at runtime — the build's advisory stats were violated.
-        Rides the plan-stats history so adaptive execution stops
-        re-attempting the route for this fingerprint (the lying-stats
-        posture: degrade once, remember, stay on the generic tier)."""
-        key = self.ids.of(node)
-        st = self.nodes.get(key)
-        if st is None:
-            st = NodeStats(type(node).__name__, node_id=key)
-            self.nodes[key] = st
-        st.route_fallback = True
 
     def record_spill(self, node, mode: str, partitions: int,
                      resident: int, host_bytes: int) -> None:
@@ -424,13 +403,10 @@ class StatsRecorder:
                 # hybrid resident set from measured skew
                 "hot_partition": -1 if st is None else st.hot_partition,
                 "spill_mode": "" if st is None else st.spill_mode,
-                # measured node wall + runtime route fallback ride the
-                # history for the adaptive controller: wall_s prices
-                # the compile-budget gate's predicted win, and a lying
-                # fused-route fragment stops being re-attempted
+                # measured node wall rides the history for the
+                # adaptive controller: wall_s prices the compile-budget
+                # gate's predicted win
                 "wall_s": 0.0 if st is None else round(st.wall_s, 6),
-                "route_fallback": (False if st is None
-                                   else bool(st.route_fallback)),
             })
         return out
 
@@ -499,11 +475,10 @@ class QueryInfo:
     #: unless the ``tenant`` session property is set) — the per-tenant
     #: attribution column of system.query_history
     tenant: str = ""
-    #: True when the run probed an APPROXIMATE join sketch (the
-    #: ``approx_join`` session property routed a semi join through the
-    #: Bloom sketch): the result may contain false-positive rows.
-    #: Exact results are NEVER silently degraded — this flag (and the
-    #: EXPLAIN ``strategy=sketch(approx)`` rendering) is the contract
+    #: True when the run sampled its scans (``approx_scan_fraction``
+    #: below 1 dropped splits): the result covers a subset of the
+    #: rows. Exact results are NEVER silently degraded — this flag is
+    #: the contract
     approximate: bool = False
     output_rows: int = -1
     node_stats: list = field(default_factory=list)  # list[NodeStats.to_dict()]
@@ -513,7 +488,7 @@ class QueryInfo:
     #: under concurrency — cache hits skip run_plan and stay empty
     metrics: dict = field(default_factory=dict)
     #: strategies of the joins this run actually executed (comma-joined
-    #: ``join.strategy.*`` delta names, e.g. "grouped,pallas"; "")
+    #: ``join.strategy.*`` delta names, e.g. "dense,grouped"; "")
     join_strategy: str = ""
     #: mean runtime-join-filter selectivity observed (fraction of probe
     #: scan rows KEPT; -1.0 when no filter fired)

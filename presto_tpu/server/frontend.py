@@ -171,8 +171,7 @@ class QueryServer:
         #: for a server that serves only ad-hoc statements
         self.subscriptions = SubscriptionManager(self)
         #: extra session properties for the APPROXIMATE sibling
-        #: session (mode="approx" subscriptions) — e.g. a tiny
-        #: join_build_budget_bytes to force the sketch path, or
+        #: session (mode="approx" subscriptions) — e.g.
         #: approx_scan_fraction for sampled scans
         self._approx_properties = dict(approx_properties or {})
         self._approx_session = None
@@ -666,10 +665,10 @@ class QueryServer:
     # ---- continuous queries (presto_tpu/stream/) ------------------------
     def approx_session(self):
         """The APPROXIMATE sibling session (built lazily): same
-        connectors and memory pool as the main session, but with
-        ``approx_join`` on (Bloom-sketch semi joins) plus any
-        ``approx_properties`` overrides. Its plan fingerprints fold
-        the approx knobs, so exact and approximate executions never
+        connectors and memory pool as the main session, plus the
+        ``approx_properties`` overrides (``approx_scan_fraction`` for
+        sampled scans). Its plan fingerprints fold the approx knobs,
+        so exact and approximate executions never
         share cached results — and its own catalog hooks the shared
         memory connector's DDL listeners, so appends invalidate both
         sessions' caches scoped per table."""
@@ -680,7 +679,7 @@ class QueryServer:
                 conns = {n: c for n, c in
                          self.session.catalog.connectors.items()
                          if n != "system"}
-                props = {"batched_dispatch": True, "approx_join": True}
+                props = {"batched_dispatch": True}
                 props.update(self._approx_properties)
                 self._approx_session = Session(
                     conns, memory_pool=self.session.pool(),

@@ -21,8 +21,8 @@ neither coalesce onto nor cache-hit any pre-append run — and delivery
 re-asserts monotonicity (``subscription.stale_blocked``: always 0).
 
 ``mode="approx"`` subscriptions prepare against the server's sibling
-approx session (``approx_join`` on, optionally sampled scans), whose
-results arrive flagged ``approximate`` — never silently.
+approx session (sampled scans where ``approx_scan_fraction`` is set),
+whose sampled results arrive flagged ``approximate`` — never silently.
 """
 
 from __future__ import annotations

@@ -243,9 +243,8 @@ PY
 
 timeout -k 10 300 env JAX_PLATFORMS=cpu JAX_ENABLE_X64=1 python - <<'PY' || exit $?
 # Join smoke (ISSUE-7 acceptance): TPC-H Q3 with runtime join filters
-# ON vs OFF must return identical rows, the fused Pallas join route
-# must fire (exec.pallas_join_route) with measured scan pruning, and a
-# warm repeat must re-trace ZERO steps. Session-property driven — the
+# ON vs OFF must return identical rows with measured scan pruning,
+# and a warm repeat must re-trace ZERO steps. Session-property driven — the
 # process-global env vars (PRESTO_TPU_NARROW) are left exactly as
 # found (the tests/test_narrowing.py env-restore discipline).
 import os
@@ -261,11 +260,9 @@ from presto_tpu.runtime.session import Session
 conn = TpchConnector(sf=0.005)
 q = QUERIES["q3"]
 s_on = Session({"tpch": conn}, properties={
-    "result_cache_enabled": False, "pallas_join": True})
+    "result_cache_enabled": False})
 a = s_on.sql(q)
 snap = REGISTRY.snapshot()
-assert snap.get("exec.pallas_join_route", 0) >= 1, \
-    "Q3 did not hit the fused Pallas join route"
 assert snap.get("join.filter_rows_pruned", 0) > 0, \
     "runtime join filters pruned no probe rows"
 t0 = snap.get("exec.traces", 0)
@@ -273,12 +270,11 @@ b = s_on.sql(q)
 t1 = REGISTRY.snapshot().get("exec.traces", 0)
 assert t1 == t0, f"warm join repeat re-traced ({t1 - t0} new traces)"
 s_off = Session({"tpch": conn}, properties={
-    "result_cache_enabled": False, "runtime_join_filters": False,
-    "pallas_join": False})
+    "result_cache_enabled": False, "runtime_join_filters": False})
 c = s_off.sql(q)
 assert a.equals(b) and a.equals(c), \
-    "runtime filters / fused kernel changed Q3 results"
-print("join smoke: filters on/off identical, pallas route hit, "
+    "runtime filters changed Q3 results"
+print("join smoke: filters on/off identical, "
       "%d rows pruned, 0 warm re-traces"
       % int(REGISTRY.snapshot().get("join.filter_rows_pruned", 0)))
 PY

@@ -71,8 +71,7 @@ def _salt_hints(join, *, skew=7.0, hot=3, wall=5.0, runs=4):
     return {id(join): {
         "node_id": 5, "node_type": "Join", "skew": skew,
         "hot_partition": hot, "wall_s": wall, "runs": runs,
-        "route_fallback": False, "misest": 1.0, "actual_rows": 100,
-        "est_rows": 100,
+        "misest": 1.0, "actual_rows": 100, "est_rows": 100,
     }}
 
 

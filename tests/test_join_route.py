@@ -1,16 +1,18 @@
-"""Differential suite for the fused Pallas join route + runtime join
+"""Differential suite for the join probe strategies + runtime join
 filters (sideways information passing) — ISSUE-7.
 
-Contract under test: the fused VMEM-table probe and the probe-scan
-runtime filters are OPTIMIZATIONS — results must be bit-identical to
-the generic XLA join paths with both toggles in every combination,
-across narrow/wide keys, NULL keys, empty build sides, skewed keys,
-narrowed dtypes at their bound edges, route-ineligible shapes, and
-the OOM ladder's forced-grouped rung (the route counters assert which
-path actually ran). Degradation must be loud (typed fallback +
-``join.pallas_fallback`` counter), never silent; the APPROXIMATE
-sketch mode must be flagged in QueryInfo and EXPLAIN, never implied.
+Contract under test: the dense direct-address probe, the sorted probe
+and the probe-scan runtime filters must all answer like a pandas
+``merge`` / ``isin`` over the same rows — across join types, NULL keys
+on both sides, NULL payloads, empty build sides, skewed keys, narrowed
+dtypes at their bound edges, a violated advisory domain, and the OOM
+ladder's forced out-of-core rung (the ``join.strategy.*`` counters
+assert which path actually ran). The strategy EXPLAIN prints must be
+the strategy the run takes.
 """
+
+import collections
+import re
 
 import jax.numpy as jnp
 import numpy as np
@@ -23,13 +25,15 @@ from presto_tpu.connectors.tpch.queries import QUERIES
 from presto_tpu.exec.joins import BuildOutput, JoinBuildOperator, LookupJoinOperator
 from presto_tpu.exec.pipeline import BatchSource, Pipeline
 from presto_tpu.expr import col
-from presto_tpu.ops import pallas_join
 from presto_tpu.ops.hashing import bloom_build, bloom_test
 from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.runtime.session import Session
 from presto_tpu.types import BIGINT, INTEGER
 
 SF = 0.005
+
+#: every probe strategy a join can take (``join.strategy.*``)
+STRATEGIES = ("dense", "unique", "expand", "hybrid", "grouped")
 
 
 @pytest.fixture(scope="module")
@@ -38,12 +42,8 @@ def conn():
 
 
 def _session(conn, **props):
-    """The fused probes are off by default (the chip's compiler refuses
-    them); off a TPU ``pallas_join`` runs them in interpret mode, which
-    is what this file tests."""
     return Session({"tpch": conn},
-                   properties={"result_cache_enabled": False,
-                               "pallas_join": True, **props})
+                   properties={"result_cache_enabled": False, **props})
 
 
 def _frames_equal(a: pd.DataFrame, b: pd.DataFrame):
@@ -51,71 +51,139 @@ def _frames_equal(a: pd.DataFrame, b: pd.DataFrame):
 
 
 # ---------------------------------------------------------------------------
-# Operator-level: kernel vs generic, every eligible mode
+# Operator-level: dense build and sorted build, each vs pandas
 # ---------------------------------------------------------------------------
 
 
-def _run_probe(build_arrays, probe_arrays, spec, jt, outs=(), unique=True,
-               cap=2048, build_valids=None, probe_valids=None,
-               build_count=None):
-    """One join through JoinBuildOperator/LookupJoinOperator with an
-    explicit pallas spec; returns (DataFrame, strategy). INTEGER
-    (int32) storage throughout — the narrow representation the kernel
-    accepts (int64 canonical keys are a fallback case, tested
-    separately)."""
-    types = {k: INTEGER for k in build_arrays} | {k: INTEGER for k in probe_arrays}
+def _rows(df: pd.DataFrame) -> list:
+    """Order-free canonical rows of an integer frame, NULL as None."""
+    rows = [tuple(None if pd.isna(v) else int(v) for v in r)
+            for r in df.itertuples(index=False)]
+    return sorted(rows, key=lambda r: tuple((v is None, v or 0) for v in r))
+
+
+def _run_probe(build_arrays, probe_arrays, jt, dense_domain=None, outs=(),
+               unique=True, cap=2048, build_valids=None, probe_valids=None,
+               build_count=None, types=None):
+    """One join through JoinBuildOperator/LookupJoinOperator, over the
+    dense build (``dense_domain=(key_min, domain)``) or the sorted one
+    (None); returns (canonical rows, strategy). INTEGER (int32) storage
+    unless ``types`` says otherwise."""
+    types = types or ({k: INTEGER for k in build_arrays}
+                      | {k: INTEGER for k in probe_arrays})
     bb = Batch.from_numpy(build_arrays, types, capacity=1024,
                           valids=build_valids, count=build_count)
     pb = Batch.from_numpy(probe_arrays, types, capacity=cap,
                           valids=probe_valids)
-    b = JoinBuildOperator(col("bk", INTEGER), pallas=spec)
+    b = JoinBuildOperator(col("bk", types["bk"]), dense_domain=dense_domain)
     Pipeline(BatchSource([bb]), [b]).run()
-    op = LookupJoinOperator(b, col("pk", INTEGER), outs, jt, unique=unique,
+    op = LookupJoinOperator(b, col("pk", types["pk"]), outs, jt, unique=unique,
                             out_capacity=None if unique or jt in ("semi", "anti")
                             else 4 * cap)
     out = Pipeline(BatchSource([pb]), [op]).run()
-    df = pd.concat([o.to_pandas() for o in out]).reset_index(drop=True)
-    return df.sort_values(list(df.columns)).reset_index(drop=True), op._strategy
+    return _rows(pd.concat([o.to_pandas() for o in out])), op._strategy
+
+
+def _reference(build_arrays, probe_arrays, jt, outs=(), build_valids=None,
+               probe_valids=None, build_count=None, **_engine_only):
+    """The same join in pandas: ``isin`` for existence, ``merge`` where
+    payload comes back. A NULL key matches nothing on either side.
+    Takes ``_run_probe``'s arguments; those that only shape the
+    engine's batches (``unique``, ``cap``, ``types``) are ignored."""
+    def frame(arrays, valids, count):
+        n = len(next(iter(arrays.values())))
+        df = pd.DataFrame({
+            k: pd.array(np.asarray(v, dtype=np.int64), dtype="Int64")
+            for k, v in arrays.items()})
+        for k, ok in (valids or {}).items():
+            df[k] = df[k].mask(~np.asarray(ok))
+        return df.iloc[:n if count is None else count]
+
+    build = frame(build_arrays, build_valids, build_count)
+    build = build[build["bk"].notna()]
+    probe = frame(probe_arrays, probe_valids, None)
+    payload = [bo.source for bo in outs]
+    if jt in ("semi", "anti") or not payload:
+        hit = probe["pk"].notna() & probe["pk"].isin(build["bk"].tolist())
+        return _rows(probe[~hit if jt == "anti" else hit])
+    # pandas joins NULL keys to each other; SQL joins them to nothing
+    keyed = probe[probe["pk"].notna()].merge(
+        build[["bk"] + payload], left_on="pk", right_on="bk", how=jt)
+    if jt == "left":
+        keyed = pd.concat([keyed, probe[probe["pk"].isna()]])
+    return _rows(keyed[list(probe.columns) + payload])
+
+
+def _both_builds_match_pandas(dense_domain, **args):
+    """Run one join over the dense build and over the sorted build;
+    both must equal the pandas answer. Returns the two strategies."""
+    want = _reference(**args)
+    dense, dstrat = _run_probe(dense_domain=dense_domain, **args)
+    srt, sstrat = _run_probe(dense_domain=None, **args)
+    assert dense == want, f"dense build differs from pandas ({dstrat})"
+    assert srt == want, f"sorted build differs from pandas ({sstrat})"
+    return dstrat, sstrat
 
 
 CASES = [
-    ("semi", (), "exists"),
-    ("anti", (), "exists"),
-    ("inner", (), "exists"),
-    ("inner", (BuildOutput("bval", "bval"),), "payload"),
-    ("left", (BuildOutput("bval", "bval"),), "payload"),
+    ("semi", ()),
+    ("anti", ()),
+    ("inner", ()),
+    ("inner", (BuildOutput("bval", "bval"),)),
+    ("left", (BuildOutput("bval", "bval"),)),
 ]
 
 
-@pytest.mark.parametrize("jt,outs,mode", CASES)
-def test_kernel_vs_generic_bit_identical(jt, outs, mode, rng):
-    """Every pallas mode against the generic probe on the same data —
-    including NULL probe keys and a NULL-masked build key."""
+@pytest.mark.parametrize(
+    "jt,outs", CASES,
+    ids=[f"{jt}-{'payload' if outs else 'exists'}" for jt, outs in CASES])
+def test_probe_strategies_match_pandas(jt, outs, rng):
+    """Every join type over the dense and the sorted build against
+    pandas — including NULL probe keys and NULL-masked build keys."""
     n_b, n_p = 150, 1500
     bk = rng.choice(np.arange(-40, 400), size=n_b, replace=False)
     bval = rng.integers(-(1 << 30), 1 << 30, size=n_b)
     pk = rng.integers(-80, 460, size=n_p)
     pvalid = rng.random(n_p) < 0.9  # NULL probe keys
     bvalid = rng.random(n_b) < 0.9  # NULL build keys
-    spec = pallas_join.PallasJoinSpec(mode, -40, 399,
-                                      payload=tuple(bo.source for bo in outs))
-    args = dict(
+    strats = _both_builds_match_pandas(
+        (-40, 440),
         build_arrays={"bk": bk, "bval": bval},
         probe_arrays={"pk": pk, "pval": np.arange(n_p)},
         jt=jt, outs=outs,
-        build_valids={"bk": bvalid}, probe_valids={"pk": pvalid},
-    )
-    got, strat = _run_probe(spec=spec, **args)
-    assert strat == "pallas", "fused route did not fire"
-    want, gstrat = _run_probe(spec=None, **args)
-    assert gstrat != "pallas"
-    _frames_equal(got, want)
+        build_valids={"bk": bvalid}, probe_valids={"pk": pvalid})
+    assert strats == ("dense", "unique")
+
+
+@pytest.mark.parametrize("build", ["dense", "sorted"])
+def test_null_build_payload_survives_unique_probe(build, rng):
+    """A MATCHED probe row whose build payload is NULL must come out
+    NULL, not 0 — inner and left, over either build."""
+    bk = np.arange(10, 60)
+    bval = np.arange(10, 60) * 7
+    bnull = np.arange(50) % 3 == 0  # every third payload is NULL
+    pk = rng.integers(0, 70, size=800)
+    for jt in ("inner", "left"):
+        args = dict(build_arrays={"bk": bk, "bval": bval},
+                    probe_arrays={"pk": pk, "pval": np.arange(len(pk))},
+                    jt=jt, outs=(BuildOutput("bval", "bval"),),
+                    build_valids={"bval": ~bnull})
+        got, strat = _run_probe(
+            dense_domain=(0, 64) if build == "dense" else None, **args)
+        assert strat == ("dense" if build == "dense" else "unique")
+        assert got == _reference(**args)
+        null_keys = set(bk[bnull].tolist())
+        hit = [r for r in got if r[0] in null_keys]
+        assert hit and all(r[2] is None for r in hit), \
+            "a NULL build payload came out as a value"
+        assert all(r[2] == r[0] * 7 for r in got
+                   if r[0] not in null_keys and 10 <= r[0] < 60)
 
 
 def test_bound_edge_keys_int16_storage(rng):
     """NARROWED int16 storage at its bound edges: keys span the full
-    int16 domain, kernel vs generic identical (the in-range comparison
-    must not wrap)."""
+    int16 domain, dense and sorted builds both answer like pandas (the
+    in-range comparison must not wrap)."""
     from presto_tpu.types import narrow_physical
 
     # -32768 is the int16 extreme, which narrowing keeps free (exact
@@ -125,104 +193,81 @@ def test_bound_edge_keys_int16_storage(rng):
     bk = np.array([-32767, -1, 0, 1, 32767], dtype=np.int64)
     pk = np.array([-32767, -32766, -2, 0, 2, 32766, 32767] * 200,
                   dtype=np.int64)
-    spec = pallas_join.PallasJoinSpec("exists", -32767, 32767)
-    # exists at full int16 domain: 65536 keys -> 2048 words, in budget
-    assert pallas_join.exists_words(1 << 16)
-    types = {"bk": t16, "bval": BIGINT, "pk": t16, "pval": BIGINT}
-    bb = Batch.from_numpy({"bk": bk, "bval": bk}, types, capacity=1024)
-    pb = Batch.from_numpy({"pk": pk, "pval": np.arange(len(pk))}, types,
-                          capacity=2048)
-
-    def run(spec):
-        b = JoinBuildOperator(col("bk", t16), pallas=spec)
-        Pipeline(BatchSource([bb]), [b]).run()
-        op = LookupJoinOperator(b, col("pk", t16), (), "semi")
-        out = Pipeline(BatchSource([pb]), [op]).run()
-        df = pd.concat([o.to_pandas() for o in out]).reset_index(drop=True)
-        return df.sort_values(list(df.columns)).reset_index(drop=True), \
-            op._strategy
-
-    got, strat = run(spec)
-    assert strat == "pallas"
-    want, gstrat = run(None)
-    assert gstrat != "pallas"
-    _frames_equal(got, want)
-
-
-def test_int64_canonical_keys_fall_back(rng):
-    """Canonical int64 key storage is OUTSIDE the kernel contract:
-    the probe must degrade loudly to the generic path, identical
-    results."""
-    bk = np.arange(1, 64, dtype=np.int64)
-    pk = np.arange(0, 128, dtype=np.int64).repeat(16)
-    types = {"bk": BIGINT, "bval": BIGINT, "pk": BIGINT, "pval": BIGINT}
-    bb = Batch.from_numpy({"bk": bk, "bval": bk}, types, capacity=1024)
-    pb = Batch.from_numpy({"pk": pk, "pval": np.arange(len(pk))}, types,
-                          capacity=2048)
-    before = REGISTRY.snapshot().get("join.pallas_fallback", 0)
-    b = JoinBuildOperator(col("bk", BIGINT),
-                          pallas=pallas_join.PallasJoinSpec("exists", 1, 64))
-    Pipeline(BatchSource([bb]), [b]).run()
-    op = LookupJoinOperator(b, col("pk", BIGINT), (), "semi")
-    out = Pipeline(BatchSource([pb]), [op]).run()
-    assert op._strategy != "pallas"
-    assert REGISTRY.snapshot().get("join.pallas_fallback", 0) > before
-    got = pd.concat([o.to_pandas() for o in out])
-    assert sorted(got["pk"].unique().tolist()) == bk.tolist()
+    strats = _both_builds_match_pandas(
+        (-32767, 65535),
+        build_arrays={"bk": bk, "bval": bk},
+        probe_arrays={"pk": pk, "pval": np.arange(len(pk))},
+        jt="semi",
+        types={"bk": t16, "bval": BIGINT, "pk": t16, "pval": BIGINT})
+    assert strats == ("dense", "unique")
 
 
 def test_empty_build_side(rng):
-    """A build batch with ZERO live rows: pallas and generic agree
-    (semi keeps nothing, anti keeps everything)."""
+    """A build batch with ZERO live rows: dense and sorted builds agree
+    with pandas (semi keeps nothing, anti keeps everything)."""
     bk = np.array([1, 2, 3], dtype=np.int64)
     pk = np.array([1, 2, 3, 4] * 300, dtype=np.int64)
     for jt in ("semi", "anti"):
-        args = dict(build_arrays={"bk": bk, "bval": bk},
-                    probe_arrays={"pk": pk, "pval": np.arange(len(pk))},
-                    jt=jt, outs=(), build_count=0)
-        got, strat = _run_probe(
-            spec=pallas_join.PallasJoinSpec("exists", 1, 16), **args)
-        assert strat == "pallas"
-        want, _ = _run_probe(spec=None, **args)
-        _frames_equal(got, want)
+        _both_builds_match_pandas(
+            (1, 16),
+            build_arrays={"bk": bk, "bval": bk},
+            probe_arrays={"pk": pk, "pval": np.arange(len(pk))},
+            jt=jt, build_count=0)
 
 
 def test_domain_violation_falls_back_loudly(rng):
     """A live build key OUTSIDE the advisory stats domain discards the
-    fused tables (counter fires) and the generic probe answers."""
+    dense side and the sorted probe answers (``join.strategy.unique``)."""
     bk = np.array([1, 5, 999], dtype=np.int64)  # 999 violates [1, 100]
     pk = np.array([1, 5, 999, 7] * 300, dtype=np.int64)
-    before = REGISTRY.snapshot().get("join.pallas_fallback", 0)
+    before = REGISTRY.snapshot()
     args = dict(build_arrays={"bk": bk, "bval": bk},
                 probe_arrays={"pk": pk, "pval": np.arange(len(pk))},
-                jt="semi", outs=())
-    got, strat = _run_probe(
-        spec=pallas_join.PallasJoinSpec("exists", 1, 100), **args)
-    assert strat != "pallas", "violated domain must not route pallas"
-    assert REGISTRY.snapshot().get("join.pallas_fallback", 0) > before
-    want, _ = _run_probe(spec=None, **args)
-    _frames_equal(got, want)
+                jt="semi")
+    got, strat = _run_probe(dense_domain=(1, 100), **args)
+    after = REGISTRY.snapshot()
+    assert strat == "unique", "violated domain must not probe the dense table"
+    assert after.get("join.strategy.unique", 0) == before.get(
+        "join.strategy.unique", 0) + 1
+    assert after.get("join.strategy.dense", 0) == before.get(
+        "join.strategy.dense", 0)
+    assert got == _reference(**args)
 
 
-def test_unblockable_capacity_falls_back(rng):
-    """A probe batch whose capacity cannot block (cap 512 < 1024)
-    degrades to the generic probe per batch, loudly."""
-    bk = np.arange(1, 40, dtype=np.int64)
-    pk = np.arange(0, 60, dtype=np.int64)
-    before = REGISTRY.snapshot().get("join.pallas_fallback", 0)
-    args = dict(build_arrays={"bk": bk, "bval": bk},
-                probe_arrays={"pk": pk, "pval": np.arange(len(pk))},
-                jt="semi", outs=(), cap=512)
-    got, strat = _run_probe(
-        spec=pallas_join.PallasJoinSpec("exists", 1, 64), **args)
-    assert strat != "pallas"
-    assert REGISTRY.snapshot().get("join.pallas_fallback", 0) > before
-    want, _ = _run_probe(spec=None, **args)
-    _frames_equal(got, want)
+def test_skewed_keys_bit_identical(rng):
+    """Heavily SKEWED distributions on both sides: ~90% of probe rows
+    share one hot key (present in the build) and the duplicate-build
+    expansion path sees a hot build key too — dense and sorted builds
+    must both answer like pandas, and duplicate builds must take the
+    expansion probe whatever dense side was built."""
+    n_p = 2000
+    # probe: 90% hot key 7, the rest uniform over [0, 256)
+    hot = rng.random(n_p) < 0.9
+    pk = np.where(hot, 7, rng.integers(0, 256, size=n_p)).astype(np.int64)
+    bk = np.concatenate([[7], rng.choice(np.arange(8, 200), size=40,
+                                         replace=False)]).astype(np.int64)
+    strats = _both_builds_match_pandas(
+        (0, 256),
+        build_arrays={"bk": bk, "bval": bk * 10},
+        probe_arrays={"pk": pk, "pval": np.arange(n_p)},
+        jt="semi")
+    assert strats == ("dense", "unique")
+    # duplicate-heavy build (hot build key 7 repeated) through the
+    # non-unique expansion join
+    bk_dup = np.concatenate([np.full(3, 7), np.arange(100, 140)]).astype(
+        np.int64)
+    strats = _both_builds_match_pandas(
+        (0, 256),
+        build_arrays={"bk": bk_dup, "bval": np.arange(len(bk_dup))},
+        probe_arrays={"pk": pk, "pval": np.arange(n_p)},
+        jt="inner", outs=(BuildOutput("bval", "bval"),),
+        unique=False, cap=2048)
+    assert strats == ("expand", "expand"), \
+        "duplicate build keys must take the expansion probe"
 
 
 # ---------------------------------------------------------------------------
-# SQL-level differentials: filters x kernel toggles, 2x2
+# SQL-level: runtime filters on/off, planned vs executed strategy
 # ---------------------------------------------------------------------------
 
 _JOIN_QUERIES = {
@@ -239,26 +284,46 @@ _JOIN_QUERIES = {
 }
 
 
+def _planned_strategies(explain: str) -> collections.Counter:
+    """``strategy=`` of every join EXPLAIN rendered (not
+    ``agg_strategy=``)."""
+    return collections.Counter(
+        re.findall(r"(?<![a-z_])strategy=(\w+)", explain))
+
+
+def _ran_strategies(metrics: dict) -> collections.Counter:
+    return collections.Counter({
+        m: int(metrics[f"join.strategy.{m}"]) for m in STRATEGIES
+        if metrics.get(f"join.strategy.{m}", 0)})
+
+
 @pytest.mark.parametrize("qname", sorted(_JOIN_QUERIES))
 def test_sql_toggles_bit_identical(conn, qname):
     q = _JOIN_QUERIES[qname]
-    frames = []
-    for filters in (True, False):
-        for kernel in (True, False):
-            s = _session(conn, runtime_join_filters=filters,
-                         pallas_join=kernel)
-            frames.append(s.sql(q))
-    for f in frames[1:]:
-        _frames_equal(frames[0], f)
+    on = _session(conn, runtime_join_filters=True).sql(q)
+    off = _session(conn, runtime_join_filters=False).sql(q)
+    _frames_equal(on, off)
 
 
-def test_q3_routes_pallas_and_prunes(conn):
+@pytest.mark.parametrize("qname", sorted(_JOIN_QUERIES))
+def test_planned_strategy_is_the_strategy_that_ran(conn, qname):
+    """The join strategy is decided twice — ``planned_join_strategy``
+    for EXPLAIN, the executor at its build — and the two must agree:
+    every ``strategy=`` EXPLAIN prints is a ``join.strategy.*`` counter
+    the run moved, join for join."""
+    q = _JOIN_QUERIES[qname]
+    s = _session(conn)
+    planned = _planned_strategies(s.explain(q))
+    assert planned, "EXPLAIN rendered no join strategy"
+    _df, info = s.execute(q)
+    assert _ran_strategies(info.metrics) == planned
+
+
+def test_q3_prunes_with_runtime_filters(conn):
     before = REGISTRY.snapshot()
     s = _session(conn)
     s.sql(QUERIES["q3"])
     after = REGISTRY.snapshot()
-    assert after.get("exec.pallas_join_route", 0) > before.get(
-        "exec.pallas_join_route", 0), "Q3 did not hit the fused join route"
     assert after.get("join.filter_rows_pruned", 0) > before.get(
         "join.filter_rows_pruned", 0), "Q3 runtime filter pruned nothing"
     assert after.get("join.filter_selectivity.count", 0) > before.get(
@@ -267,10 +332,9 @@ def test_q3_routes_pallas_and_prunes(conn):
 
 def test_forced_grouped_oom_rung(conn):
     """The OOM ladder's forced out-of-core rung: results identical to
-    the un-degraded run, and the fused route is NOT taken (the spill
-    tier is the robustness backstop). Rung 1 re-plans into hybrid
-    (shrunk resident set) rather than fully-grouped — either spill
-    mode satisfies the backstop contract."""
+    the un-degraded run (the spill tier is the robustness backstop).
+    Rung 1 re-plans into hybrid (shrunk resident set) rather than
+    fully-grouped — either spill mode satisfies the backstop contract."""
     from presto_tpu.plan.prune import prune
 
     s = _session(conn)
@@ -288,26 +352,22 @@ def test_forced_grouped_oom_rung(conn):
                   - before.get(f"join.strategy.{m}", 0)
                   for m in ("hybrid", "grouped"))
     assert spilled > 0, "OOM rung did not route the spill tier"
-    assert after.get("exec.pallas_join_route", 0) == before.get(
-        "exec.pallas_join_route", 0), "forced spill rung must not route pallas"
 
 
 def test_default_session_plans_the_xla_probes(conn):
-    """``pallas_join`` is off by default, so a default plan reads the
-    same on the CPU and on the chip: EXPLAIN names the XLA probe that
-    runs and the fused route is not taken; the property opts in."""
-    s = Session({"tpch": conn}, properties={"result_cache_enabled": False})
-    assert "strategy=pallas" not in s.explain(QUERIES["q3"])
-    before = REGISTRY.snapshot()
-    want = s.sql(QUERIES["q3"])
-    after = REGISTRY.snapshot()
-    assert after.get("exec.pallas_join_route", 0) == before.get(
-        "exec.pallas_join_route", 0)
-    assert after.get("join.strategy.pallas", 0) == before.get(
-        "join.strategy.pallas", 0)
-    on = _session(conn)
-    assert "strategy=pallas" in on.explain(QUERIES["q3"])
-    _frames_equal(want, on.sql(QUERIES["q3"]))
+    """Joins have one probe family, the XLA steps: a default plan reads
+    the same on the CPU and on the chip. EXPLAIN names only strategies
+    of that family, the run reports the same ones in
+    ``QueryInfo.join_strategy``, and every join's program is counted
+    as ``kernel.join.xla`` — never as a Pallas kernel."""
+    s = _session(conn)
+    planned = _planned_strategies(s.explain(QUERIES["q3"]))
+    assert sum(planned.values()) == 2 and set(planned) <= set(STRATEGIES)
+    _df, info = s.execute(QUERIES["q3"])
+    assert set(info.join_strategy.split(",")) == set(planned)
+    assert info.metrics.get("kernel.join.xla", 0) == 2
+    assert not any(k.startswith("kernel.join.") and k != "kernel.join.xla"
+                   for k in info.metrics)
 
 
 def test_explain_renders_strategy_and_filters(conn):
@@ -315,60 +375,6 @@ def test_explain_renders_strategy_and_filters(conn):
     out = s.explain(QUERIES["q3"])
     assert "strategy=" in out
     assert "runtime_filter=['l_orderkey']" in out
-
-
-# ---------------------------------------------------------------------------
-# approx_join (sketch mode)
-# ---------------------------------------------------------------------------
-
-
-def test_approx_join_superset_semantics(rng):
-    """Sketch-mode semi join: every true match survives (no false
-    negatives); any extras are Bloom false positives, i.e. the result
-    is a superset of the exact one."""
-    bk = rng.choice(np.arange(0, 1 << 22), size=500, replace=False)
-    pk = rng.integers(0, 1 << 22, size=3000)
-    spec = pallas_join.PallasJoinSpec("sketch", nbits=pallas_join.SKETCH_BITS)
-    args = dict(build_arrays={"bk": bk.astype(np.int64), "bval": bk.astype(np.int64)},
-                probe_arrays={"pk": pk.astype(np.int64),
-                              "pval": np.arange(len(pk))},
-                jt="semi", outs=(), cap=4096)
-    got, strat = _run_probe(spec=spec, **args)
-    assert strat == "pallas"
-    want, _ = _run_probe(spec=None, **args)
-    got_keys = set(map(tuple, got.to_numpy().tolist()))
-    want_keys = set(map(tuple, want.to_numpy().tolist()))
-    assert want_keys <= got_keys, "sketch dropped a true match"
-
-
-def test_approx_join_property_changes_fingerprint(conn):
-    from presto_tpu.cache.fingerprint import plan_fingerprint
-
-    s = _session(conn)
-    plan = s.plan(_JOIN_QUERIES["semi"])
-    exact = plan_fingerprint(plan, s.catalog, {"approx_join": False}, None)
-    approx = plan_fingerprint(plan, s.catalog, {"approx_join": True}, None)
-    assert exact != approx, "approx results could leak into exact caches"
-
-
-def test_anti_never_routes_sketch(rng):
-    """A sketch false positive would DROP anti-join rows: the operator
-    must refuse the sketch for anti even when handed a spec."""
-    bk = np.arange(0, 50, dtype=np.int64)
-    pk = np.arange(0, 2000, dtype=np.int64)
-    spec = pallas_join.PallasJoinSpec("sketch", nbits=pallas_join.SKETCH_BITS)
-    got, strat = _run_probe(
-        spec=spec,
-        build_arrays={"bk": bk, "bval": bk},
-        probe_arrays={"pk": pk, "pval": np.arange(len(pk))},
-        jt="anti", outs=())
-    assert strat != "pallas"
-    want, _ = _run_probe(
-        spec=None,
-        build_arrays={"bk": bk, "bval": bk},
-        probe_arrays={"pk": pk, "pval": np.arange(len(pk))},
-        jt="anti", outs=())
-    _frames_equal(got, want)
 
 
 # ---------------------------------------------------------------------------
@@ -382,80 +388,6 @@ def test_bloom_no_false_negatives(rng):
     words = bloom_build(jnp.asarray(keys), jnp.asarray(live), 1 << 15)
     hit = np.asarray(bloom_test(words, jnp.asarray(keys)))
     assert hit[live].all(), "bloom_test missed an inserted key"
-
-
-def test_skewed_keys_bit_identical(rng):
-    """Heavily SKEWED distributions on both sides: ~90% of probe rows
-    share one hot key (present in the build) and the duplicate-build
-    expansion path sees a hot build key too — fused vs generic must
-    stay bit-identical, and duplicate builds must never route the
-    unique-only payload mode."""
-    n_p = 2000
-    # probe: 90% hot key 7, the rest uniform over [0, 256)
-    hot = rng.random(n_p) < 0.9
-    pk = np.where(hot, 7, rng.integers(0, 256, size=n_p)).astype(np.int64)
-    bk = np.concatenate([[7], rng.choice(np.arange(8, 200), size=40,
-                                         replace=False)]).astype(np.int64)
-    args = dict(build_arrays={"bk": bk, "bval": bk * 10},
-                probe_arrays={"pk": pk, "pval": np.arange(n_p)},
-                jt="semi", outs=())
-    got, strat = _run_probe(
-        spec=pallas_join.PallasJoinSpec("exists", 0, 255), **args)
-    assert strat == "pallas", "skewed probe keys must still route fused"
-    want, gstrat = _run_probe(spec=None, **args)
-    assert gstrat != "pallas"
-    _frames_equal(got, want)
-    # duplicate-heavy build (hot build key 7 repeated) through the
-    # non-unique expansion join: payload mode is unique-only, so the
-    # operator must refuse the fused route and expand identically
-    bk_dup = np.concatenate([np.full(3, 7), np.arange(100, 140)]).astype(
-        np.int64)
-    args = dict(build_arrays={"bk": bk_dup, "bval": np.arange(len(bk_dup))},
-                probe_arrays={"pk": pk, "pval": np.arange(n_p)},
-                jt="inner", outs=(BuildOutput("bval", "bval"),),
-                unique=False, cap=2048)
-    got, strat = _run_probe(
-        spec=pallas_join.PallasJoinSpec("payload", 0, 255,
-                                        payload=("bval",)), **args)
-    assert strat == "expand", "duplicate build keys must not route payload"
-    want, _ = _run_probe(spec=None, **args)
-    _frames_equal(got, want)
-
-
-def test_approx_flagged_in_queryinfo_and_explain(conn):
-    """ISSUE-7 acceptance: the approximate mode is reported DISTINCTLY
-    — ``QueryInfo.approximate`` on the run that probed a sketch, and
-    ``strategy=sketch(approx)`` in EXPLAIN — so exact results are
-    never silently degraded. The build key domain here (2^21) exceeds
-    the exact exists-table budget (2^19), forcing the sketch."""
-    import pandas as pd
-
-    s = _session(conn, approx_join=True)
-    mem = s.catalog.connector("memory")
-    mem.create_table("bigdom", pd.DataFrame(
-        {"k": np.array([0, 1 << 21], dtype=np.int64)}))
-    mem.create_table("bigprobe", pd.DataFrame(
-        {"pk": (np.arange(1500, dtype=np.int64) * 131) % (1 << 21)}))
-    q = "select count(*) c from bigprobe where pk in (select k from bigdom)"
-    assert "strategy=sketch(approx)" in s.explain(q)
-    before = REGISTRY.snapshot().get("exec.pallas_join_route", 0)
-    df, info = s.execute(q)
-    assert info.approximate, "sketch run must flag QueryInfo.approximate"
-    assert '"approximate": true' in info.to_json()
-    assert REGISTRY.snapshot().get("exec.pallas_join_route", 0) > before
-    # the exact session: same tables, no sketch, no flag, and the
-    # approximate count can only ever be >= the exact one (Bloom
-    # false positives ADD rows, never drop them)
-    s2 = _session(conn)
-    mem2 = s2.catalog.connector("memory")
-    mem2.create_table("bigdom", pd.DataFrame(
-        {"k": np.array([0, 1 << 21], dtype=np.int64)}))
-    mem2.create_table("bigprobe", pd.DataFrame(
-        {"pk": (np.arange(1500, dtype=np.int64) * 131) % (1 << 21)}))
-    exact_df, exact_info = s2.execute(q)
-    assert not exact_info.approximate
-    assert "sketch" not in s2.explain(q)
-    assert int(df["c"][0]) >= int(exact_df["c"][0])
 
 
 def test_minmax_memo_shared_across_joins(conn):

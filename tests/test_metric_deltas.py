@@ -114,7 +114,7 @@ def test_queryinfo_attribute_metrics_derivations():
     info = QueryInfo(query_id="q", sql="", state="FINISHED",
                      created_at=0.0)
     info.attribute_metrics({
-        "join.strategy.pallas": 2.0,
+        "join.strategy.unique": 2.0,
         "join.strategy.grouped": 1.0,
         "join.strategy.dense": 0.0,  # zero: not executed, not listed
         "join.filter_selectivity.count": 2.0,
@@ -122,7 +122,7 @@ def test_queryinfo_attribute_metrics_derivations():
         "query.oom_degraded": 3.0,
         "exec.traces": 0.0,  # zero-valued deltas are dropped
     })
-    assert info.join_strategy == "grouped,pallas"
+    assert info.join_strategy == "grouped,unique"
     assert info.filter_selectivity == pytest.approx(0.25)
     assert info.oom_rung == 3
     assert "exec.traces" not in info.metrics
@@ -144,14 +144,13 @@ def test_queryinfo_no_filter_observations_reports_minus_one():
 
 def test_query_info_carries_join_strategy_deltas(conn):
     s = Session({"tpch": conn},
-                properties={"result_cache_enabled": False,
-                            "pallas_join": True})
+                properties={"result_cache_enabled": False})
     _df, info = s.execute(_q3())
-    assert info.metrics.get("join.strategy.pallas", 0) >= 1
-    assert "pallas" in info.join_strategy
+    assert info.metrics.get("join.strategy.dense", 0) >= 1
+    assert "dense" in info.join_strategy
     j = json.loads(info.to_json())
     assert j["joinStrategy"] == info.join_strategy
-    assert j["metrics"]["join.strategy.pallas"] >= 1
+    assert j["metrics"]["join.strategy.dense"] >= 1
     assert "oomRung" in j and "filterSelectivity" in j
 
 
@@ -168,12 +167,12 @@ def test_cache_hit_query_has_empty_metrics(conn):
 
 def test_concurrent_queries_report_disjoint_strategies(conn):
     """The acceptance scenario: two queries run CONCURRENTLY on the one
-    process-global registry — a fused-probe Q3 and a forced-grouped
+    process-global registry — a dense-probe Q3 and a forced-grouped
     join — and each QueryInfo carries exactly its own
     ``join.strategy.*`` moves."""
     grouped_q = ("select count(*) c from lineitem "
                  "join orders on l_orderkey = o_orderkey")
-    props_a = {"result_cache_enabled": False, "pallas_join": True}
+    props_a = {"result_cache_enabled": False}
     props_b = {"result_cache_enabled": False,
                "join_build_budget_bytes": 1}
     # warm both signatures so the concurrent phase measures execution,
@@ -195,7 +194,7 @@ def test_concurrent_queries_report_disjoint_strategies(conn):
             errors.append(f"{name}: {type(e).__name__}: {e}")
 
     threads = [
-        threading.Thread(target=run, args=("pallas", props_a, _q3())),
+        threading.Thread(target=run, args=("dense", props_a, _q3())),
         threading.Thread(target=run,
                          args=("grouped", props_b, grouped_q)),
     ]
@@ -205,26 +204,25 @@ def test_concurrent_queries_report_disjoint_strategies(conn):
         t.join(timeout=300)
         assert not t.is_alive(), "concurrent query hung"
     assert not errors, errors
-    pal, grp = results["pallas"].metrics, results["grouped"].metrics
-    assert pal.get("join.strategy.pallas", 0) >= 1
-    assert pal.get("join.strategy.grouped", 0) == 0
+    dns, grp = results["dense"].metrics, results["grouped"].metrics
+    assert dns.get("join.strategy.dense", 0) >= 1
+    assert dns.get("join.strategy.grouped", 0) == 0
     assert grp.get("join.strategy.grouped", 0) >= 1
-    assert grp.get("join.strategy.pallas", 0) == 0
-    assert "grouped" not in results["pallas"].join_strategy
+    assert grp.get("join.strategy.dense", 0) == 0
+    assert "grouped" not in results["dense"].join_strategy
     # the grouped tier's per-bucket probes record their own strategy
-    # (unique) beside the forced grouped decision — but never pallas
+    # (unique) beside the forced grouped decision — but never dense
     assert "grouped" in results["grouped"].join_strategy
-    assert "pallas" not in results["grouped"].join_strategy
+    assert "dense" not in results["grouped"].join_strategy
 
 
 def test_query_history_carries_attribution_columns(conn):
     s = Session({"tpch": conn},
-                properties={"result_cache_enabled": False,
-                            "pallas_join": True})
+                properties={"result_cache_enabled": False})
     s.execute(_q3())
     df = s.sql("select query_id, oom_rung, join_strategy, "
                "filter_selectivity from query_history")
-    rows = df[df["join_strategy"].str.contains("pallas")]
+    rows = df[df["join_strategy"].str.contains("dense")]
     assert len(rows) >= 1
     assert (rows["oom_rung"] >= 0).all()
 

@@ -134,7 +134,7 @@ def test_join_estimate_snapshots_planned_strategy(session):
         if e.node_type in ("Join", "SemiJoin")
     ]
     assert strategies and all(s for s in strategies)
-    assert any(s in ("pallas", "dense", "unique", "expand", "grouped")
+    assert any(s in ("dense", "unique", "expand", "hybrid", "grouped")
                for s in strategies)
     # aggregates carry the adaptive aggregation strategy (ISSUE-9);
     # every other non-join node stays strategy-free

@@ -62,9 +62,12 @@ def test_nested_query_from_event_listener_keeps_outer_stats():
 # ---------------------------------------------------------------------------
 
 
-def test_unknown_session_property_rejected():
+# pallas_join / approx_join: removed with the fused join probe — a
+# session that still names one fails at the door, no alias
+@pytest.mark.parametrize("name", ["nope", "pallas_join", "approx_join"])
+def test_unknown_session_property_rejected(name):
     with pytest.raises(ValueError, match="unknown session property"):
-        Session({"tpch": TpchConnector(sf=0.01)}, properties={"nope": 1})
+        Session({"tpch": TpchConnector(sf=0.01)}, properties={name: True})
 
 
 def test_property_type_coercion_and_validation():

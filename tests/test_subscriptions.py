@@ -9,8 +9,8 @@ The contract under test:
   fired (the freshness contract, asserted via ``wait_for_epoch``).
 - N same-template subscriptions woken by one append meet at the
   ``TemplateBatchGate`` and stack into one vmapped dispatch.
-- ``mode="approx"`` rides the sketch-join / sampled-scan machinery and
-  arrives flagged ``approximate`` — never silently.
+- ``mode="approx"`` rides the sampled-scan machinery and arrives
+  flagged ``approximate`` whenever it sampled — never silently.
 - The HTTP surface (subscribe / poll / cancel) and graceful drain
   behave like the rest of the serving layer.
 """
@@ -243,9 +243,8 @@ def test_same_template_subscriptions_batch_through_gate(monkeypatch):
 
 
 def _wide_domain_tables(w: StreamWriter, seed=7, n=4000, nkeys=500):
-    """Semi-join shape whose build keys span ~1e12: the exact
-    exists-bitmap can't admit the domain, so ``approx_join`` routes
-    the probe through the Bloom sketch."""
+    """Semi-join shape whose build keys span ~1e12: too wide for a
+    dense table, so the sorted probe answers."""
     rng = np.random.default_rng(seed)
     ckeys = rng.integers(0, 1_000_000_000_000, nkeys).astype(np.int64)
     w.append("orders", pd.DataFrame({
@@ -263,24 +262,19 @@ def _wide_domain_tables(w: StreamWriter, seed=7, n=4000, nkeys=500):
             "(select ckey from cust where grp = 3)")
 
 
-def test_approx_subscription_sketch_join_superset_flagged():
-    """ISSUE-17 acceptance: an approx-mode subscription's semi join
-    rides the Bloom sketch — its result is a superset of exact (false
-    positives only, never dropped rows) and arrives flagged
-    ``approximate``."""
-    # no budget override needed: the wide key domain alone disqualifies
-    # the exact exists-bitmap (a tiny join_build_budget_bytes would
-    # instead re-route the join through the grouped-spill tier, away
-    # from the kernel entirely)
-    # the sketch is a fused Pallas probe: the approx tier opts in
-    _conn, s, server = make_server(approx_properties={"pallas_join": True})
+def test_approx_subscription_without_sampling_is_exact_unflagged():
+    """An approx-mode subscription whose tier samples nothing
+    (``approx_scan_fraction`` at its default) answers its semi join
+    exactly and arrives UNFLAGGED: ``approximate`` says what the run
+    did, not which session served it."""
+    _conn, s, server = make_server()
     sql = _wide_domain_tables(StreamWriter(s))
     exact = int(server.execute(sql, "t0")["n"][0])
     sub = server.subscribe(sql, "t0", mode="approx")
     try:
         got = sub.wait_for_seq(1, timeout_s=WAIT_S)
-        assert got.approximate, "sketch-join refresh not flagged"
-        assert int(got.df["n"][0]) >= exact, "approx dropped rows"
+        assert not got.approximate, "an exact refresh arrived flagged"
+        assert int(got.df["n"][0]) == exact
     finally:
         server.shutdown()
     # the exact ad-hoc run through the same server stayed unflagged

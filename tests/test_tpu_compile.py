@@ -21,7 +21,6 @@ from presto_tpu.batch import Batch, Column
 from presto_tpu.ops import (
     pallas_agg,
     pallas_groupby,
-    pallas_join,
     pallas_q1,
     pallas_strings,
 )
@@ -198,22 +197,3 @@ def test_strings_kernel_compiles(one_chip, kind, pattern, width):
              ((1 << 17, width), jnp.uint8),
              kernel="strings_like" if kind == "like"
              else "strings_starts_with")
-
-
-def test_join_probe_is_refused_and_the_refusal_reaches_the_caller(one_chip):
-    """Why ``pallas_join.available()`` is False on a TPU backend: the
-    installed Mosaic gather rule asserts table and index block share
-    one shape, which the lane-replicated [w, 128] table does not. And
-    what every kernel now does with a refusal: nothing catches it, the
-    compiler's own exception comes out of the jit. When a newer jaxlib
-    makes this compile, re-admit the kernels in ``available()``."""
-    w = 64  # table words; the probe block is [512, 128]
-
-    def fn(tab, keys, live):
-        return pallas_join.exists_probe(tab, 0, w * 32 - 1, keys, live,
-                                        interpret=False)
-
-    with pytest.raises(AssertionError):
-        _compile(fn, one_chip, ((w, 128), jnp.int32),
-                 ((1 << 16,), jnp.int32), ((1 << 16,), jnp.bool_),
-                 kernel="join_probe_exists")
