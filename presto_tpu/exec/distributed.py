@@ -70,7 +70,7 @@ from presto_tpu.exec.operators import (
 from presto_tpu.exec.ladder import OomLadderMixin
 from presto_tpu.exec.pipeline import BatchSource, Pipeline
 from presto_tpu.expr import BIGINT, evaluate, bind_scalars, param_scope
-from presto_tpu.ops.groupby import gather_padded, group_ids_sort, segment_agg
+from presto_tpu.ops.groupby import gather_padded, sorted_group_reduce
 from presto_tpu.ops.hashing import partition_ids
 from presto_tpu.ops.pallas_mode import count_program
 from presto_tpu.ops.sort import sort_indices
@@ -915,7 +915,25 @@ class DistributedExecutor(OomLadderMixin):
             pvals = [evaluate(e, b) for _, e in pax]
             sortables = [v.valid.astype(jnp.int8) for v in kvals] + [
                 c for v in kvals for c in _sortables(v)]
-            gids, rep, ng, ovf = group_ids_sort(sortables, b.live, mg)
+            # per aggregate its value and its $n merge count
+            reduces = []
+            for a in aggs:
+                if a.kind == "count_star" or a.input is None:
+                    vals = jnp.ones(b.capacity, jnp.int64)
+                    contrib = b.live
+                elif a.kind == "count":
+                    v = evaluate(a.input, b)
+                    vals = jnp.ones(b.capacity, jnp.int64)
+                    contrib = b.live & v.valid
+                else:
+                    v = evaluate(a.input, b)
+                    vals, contrib = v.data, b.live & v.valid
+                kind = "sum" if a.kind in ("count", "count_star") else a.kind
+                reduces.append((vals.astype(_phys_dtype(a)), contrib, kind))
+                reduces.append((None, contrib, "count"))
+            REGISTRY.counter("agg.strategy.sorted_reduce").add()
+            rep, ng, ovf, reduced = sorted_group_reduce(
+                sortables, b.live, mg, reduces)
             cols: dict[str, Column] = {}
             for (n, e), v in zip(keys, kvals):
                 cols[n] = Column(
@@ -929,21 +947,7 @@ class DistributedExecutor(OomLadderMixin):
                     gather_padded(v.valid, rep, False),
                     e.dtype, v.dictionary,
                 )
-            for a in aggs:
-                dt = _phys_dtype(a)
-                if a.kind == "count_star" or a.input is None:
-                    vals = jnp.ones(b.capacity, jnp.int64)
-                    contrib = b.live
-                elif a.kind == "count":
-                    v = evaluate(a.input, b)
-                    vals = jnp.ones(b.capacity, jnp.int64)
-                    contrib = b.live & v.valid
-                else:
-                    v = evaluate(a.input, b)
-                    vals, contrib = v.data, b.live & v.valid
-                kind = "sum" if a.kind in ("count", "count_star") else a.kind
-                agg = segment_agg(vals.astype(dt), contrib, gids, mg, kind)
-                n_c = segment_agg(vals, contrib, gids, mg, "count")
+            for a, agg, n_c in zip(aggs, reduced[::2], reduced[1::2]):
                 cols[a.name] = Column(agg, jnp.ones(mg, jnp.bool_), a.dtype)
                 cols[a.name + "$n"] = Column(n_c, jnp.ones(mg, jnp.bool_), BIGINT)
             live = jnp.arange(mg) < ng
@@ -955,7 +959,15 @@ class DistributedExecutor(OomLadderMixin):
             kvals = [b[n] for n, _ in keys]
             sortables = [v.valid.astype(jnp.int8) for v in kvals] + [
                 c for v in kvals for c in _sortables(v)]
-            gids, rep, ng, ovf = group_ids_sort(sortables, b.live, mgf)
+            reduces = []
+            for a in aggs:
+                ncol = b[a.name + "$n"].data
+                reduces.append(
+                    (b[a.name].data, b.live & (ncol > 0), a.merge_kind))
+                reduces.append((ncol, b.live, "sum"))
+            REGISTRY.counter("agg.strategy.sorted_reduce").add()
+            rep, ng, ovf, reduced = sorted_group_reduce(
+                sortables, b.live, mgf, reduces)
             cols: dict[str, Column] = {}
             for (n, e), v in zip(keys, kvals):
                 cols[n] = Column(
@@ -970,12 +982,7 @@ class DistributedExecutor(OomLadderMixin):
                     gather_padded(v.valid, rep, False),
                     e.dtype, v.dictionary,
                 )
-            for a in aggs:
-                vals = b[a.name].data
-                ncol = b[a.name + "$n"].data
-                contrib = b.live & (ncol > 0)
-                agg = segment_agg(vals, contrib, gids, mgf, a.merge_kind)
-                ntot = segment_agg(ncol, b.live, gids, mgf, "sum")
+            for a, agg, ntot in zip(aggs, reduced[::2], reduced[1::2]):
                 if a.kind in ("count", "count_star"):
                     valid = jnp.ones(mgf, jnp.bool_)
                     agg = jnp.where(valid, agg, 0)
@@ -996,14 +1003,17 @@ class DistributedExecutor(OomLadderMixin):
             trace_probe()
             count_program("dist_agg", False)
             with param_scope(params):
-                part, ovf1 = (bypass_phase(b) if bypass else partial_phase(b))
+                with jax.named_scope("agg_partial_phase"):
+                    part, ovf1 = (bypass_phase(b) if bypass
+                                  else partial_phase(b))
                 key_sort = [c for n, _ in keys for c in _sortables(part[n])]
                 pids = partition_ids(key_sort, Pn)
                 exch, ovf2, rounds, dest = exchange_multiround(
                     part, pids, Pn, quota, mgf, axes=axes, with_rounds=True,
                     with_stats=True,
                 )
-                out, ovf3 = final_phase(exch)
+                with jax.named_scope("agg_final_phase"):
+                    out, ovf3 = final_phase(exch)
                 # the exchange receive overflow rides out separately:
                 # only IT means "a destination was hot" (the group-
                 # capacity flags retry the same loop but are not skew)

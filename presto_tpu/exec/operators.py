@@ -40,8 +40,8 @@ from presto_tpu.ops.groupby import (
     fused_small_sums,
     gather_padded,
     group_ids_direct,
-    group_ids_sort,
     segment_agg,
+    sorted_group_reduce,
 )
 from presto_tpu.ops.sort import sort_indices, top_n_indices
 from presto_tpu.runtime.errors import InternalError, ResourceExhausted
@@ -478,8 +478,10 @@ class HashAggregationOperator(Operator):
 
     def _sort_update_impl(self, state, batch: Batch):
         """Fold a batch into the state by concatenating the state rows
-        (as a pseudo-batch) with the batch's rows, then re-grouping —
-        bounded memory, one multi-key sort per batch."""
+        (as a pseudo-batch) with the batch's rows, then re-grouping and
+        re-reducing in sorted order — bounded memory, one multi-key sort
+        per batch. State rows come first, so a group's representative
+        (its first member) is its state row whenever it has one."""
         from presto_tpu.cache.exec_cache import trace_probe
 
         trace_probe()
@@ -519,7 +521,18 @@ class HashAggregationOperator(Operator):
                 cat_data.append(cat)
                 sort_names.append(key)
         cat_live = jnp.concatenate([state["present"], batch.live])
-        gids, rep, ng, ovf = group_ids_sort(cat_sort, cat_live, g)
+        # per aggregate its value and its $n count, reduced with the sort
+        reduces = []
+        for a, (vals, contrib) in zip(self.aggs, inputs):
+            cat_vals = jnp.concatenate(
+                [state[a.name], vals.astype(_phys_dtype(a))])
+            cat_contrib = jnp.concatenate([state[a.name + "$has"], contrib])
+            cnt = jnp.concatenate(
+                [state[a.name + "$n"], contrib.astype(jnp.int64)])
+            reduces.append((cat_vals, cat_contrib, self._agg_kind(a)))
+            reduces.append((cnt, cat_live, "sum"))
+        rep, ng, ovf, reduced = sorted_group_reduce(
+            cat_sort, cat_live, g, reduces)
 
         def gat(cat, fill=0):
             if cat.ndim > 1:
@@ -544,16 +557,7 @@ class HashAggregationOperator(Operator):
             new["paxv$" + n] = gather_padded(cat_pv, rep, False)
         present = jnp.arange(g) < ng
         new["present"] = present
-        for a, (vals, contrib) in zip(self.aggs, inputs):
-            kind = self._agg_kind(a)
-            dt = _phys_dtype(a)
-            cat_vals = jnp.concatenate([state[a.name], vals.astype(dt)])
-            cat_contrib = jnp.concatenate([state[a.name + "$has"], contrib])
-            agg = segment_agg(cat_vals, cat_contrib, gids, g, kind)
-            cnt = jnp.concatenate(
-                [state[a.name + "$n"], contrib.astype(jnp.int64)]
-            )
-            ncnt = segment_agg(cnt, cat_live, gids, g, "sum")
+        for a, agg, ncnt in zip(self.aggs, reduced[::2], reduced[1::2]):
             new[a.name] = agg
             new[a.name + "$n"] = ncnt
             new[a.name + "$has"] = ncnt > 0
@@ -601,6 +605,7 @@ class HashAggregationOperator(Operator):
             # the sort's operand, from static shapes (no device read)
             REGISTRY.counter("agg.strategy.sort_rows").add(
                 self.strategy.max_groups + batch.capacity)
+            REGISTRY.counter("agg.strategy.sorted_reduce").add()
         # the carrier hands back the dictionaries THIS trace signature
         # saw (correct even when jit's signature cache skipped the
         # body — the output treedef is stored per signature)

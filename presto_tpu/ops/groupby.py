@@ -11,13 +11,15 @@ scatter-serialized on TPU, so grouping is
   known (dictionary codes, bounded ints): gid = bit-packed key. The
   analog of BigintGroupByHash's array-based fast path — Q1's
   returnflag x linestatus lands here, zero sorting.
-- **sort-based** otherwise: stable multi-key argsort, adjacent-diff
-  boundaries, cumsum group ids — O(n log n) but built entirely from
-  TPU-friendly sort/gather/scan primitives.
+- **sort-based** otherwise (``sorted_group_reduce``): ONE multi-key
+  ``lax.sort``, adjacent-diff boundaries, and the aggregates reduced
+  in sorted order (a segmented scan read at each run's end) — no array
+  of per-row group ids exists, so nothing is scattered.
 
-Aggregation is ``jax.ops.segment_*`` over the group ids with one extra
-"trash" segment that absorbs dead rows; outputs have a static
-``max_groups`` capacity with an overflow flag (SURVEY §7.4 #1).
+Where rows have ids without sorting (direct addressing), aggregation
+is ``jax.ops.segment_*`` over the ids with one extra "trash" segment
+that absorbs dead rows. Outputs have a static ``max_groups`` capacity
+with an overflow flag (SURVEY §7.4 #1).
 """
 
 from __future__ import annotations
@@ -27,7 +29,9 @@ from functools import reduce
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
+from presto_tpu.ops.compact import compact_indices
 from presto_tpu.runtime.errors import InternalError
 
 
@@ -65,39 +69,6 @@ def group_ids_direct(key_cols, mins, strides, live, num_groups: int):
             jnp.zeros(num_groups + 1, dtype=jnp.bool_).at[gid].set(True)[:num_groups]
         )
     return gid, present
-
-
-def group_ids_sort(key_cols, live, max_groups: int):
-    """Sort-based gids for arbitrary keys.
-
-    Returns (gids[cap], rep_idx[max_groups], ngroups, overflow):
-    - gids: per-row group id in [0, max_groups) for live rows,
-      ``max_groups`` (trash) for dead rows;
-    - rep_idx: original row index of each group's first member
-      (sentinel ``cap`` for unused slots) — gather key columns through
-      it to materialize group keys;
-    - overflow: True when distinct live keys exceeded max_groups.
-    """
-    cap = live.shape[0]
-    order = jnp.arange(cap)
-    for k in reversed(list(key_cols)):
-        order = order[jnp.argsort(k[order], stable=True)]
-    # liveness is the most significant key: live rows first
-    order = order[jnp.argsort(~live[order], stable=True)]
-
-    sl = live[order]
-    diffs = [k[order][1:] != k[order][:-1] for k in key_cols]
-    any_diff = reduce(jnp.logical_or, diffs) if diffs else jnp.zeros(cap - 1, bool)
-    boundary = any_diff | ~sl[:-1]
-    newgrp = jnp.concatenate([sl[:1], boundary & sl[1:]])
-    ngroups = jnp.sum(newgrp.astype(jnp.int32))
-    gid_sorted = jnp.cumsum(newgrp.astype(jnp.int32)) - 1
-    gid_sorted = jnp.where(sl, jnp.minimum(gid_sorted, max_groups), max_groups)
-    gids = jnp.zeros(cap, dtype=jnp.int32).at[order].set(gid_sorted)
-
-    rep_sorted = jnp.nonzero(newgrp, size=max_groups, fill_value=cap)[0]
-    rep_idx = gather_padded(order, rep_sorted, cap)
-    return gids, rep_idx, ngroups, ngroups > max_groups
 
 
 # ---------------------------------------------------------------------------
@@ -403,3 +374,124 @@ def segment_agg(
         vals = jnp.where(contrib, values, _identity("max", values.dtype))
         return jax.ops.segment_max(vals, g, num_segments=nseg)[:max_groups]
     raise InternalError(f"unknown aggregate kind {kind!r}")
+
+
+# ---------------------------------------------------------------------------
+# sort-based grouping + aggregation in sorted order
+# ---------------------------------------------------------------------------
+
+
+_SCAN_OPS = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+
+
+def _segmented_scans(starts, columns):
+    """Inclusive scans that restart wherever ``starts`` is True, one per
+    ``(op, identity, values)`` column over the same segments: log2(n)
+    passes, each combining a row with the row ``d`` before it (shifts by
+    a static ``d``: plain slices, which the TPU compiles in seconds where
+    ``lax.associative_scan``'s strided ones take minutes at 2 M rows —
+    PERF.md §6, PR 30)."""
+    n = starts.shape[0]
+    values = [v for _op, _ident, v in columns]
+    d = 1
+    while d < n:
+        values = [
+            jnp.where(starts, v, op(
+                jnp.concatenate([jnp.full(d, ident, v.dtype), v[:-d]]), v))
+            for (op, ident, _), v in zip(columns, values)]
+        starts = starts | jnp.concatenate(
+            [jnp.ones(d, jnp.bool_), starts[:-d]])
+        d *= 2
+    return values
+
+
+def _pack_sort_keys(live, key_cols):
+    """The sort's key operands: the dead flag and every narrow (<= 16
+    bit, integer or bool) column bit-packed into uint32 words, dead rows' top bit set so
+    they sort last; wider columns as they are. Grouping needs equal
+    keys adjacent, not any particular order, so the packing only has to
+    be injective — and the TPU compiles and runs a sort by its operand
+    count (PERF.md §6, PR 30)."""
+    word = (~live).astype(jnp.uint32) << np.uint32(31)
+    free = 31  # bits of ``word`` below the dead flag
+    words, wide = [], []
+    for k in key_cols:
+        bits = 8 * k.dtype.itemsize
+        if bits > 16 or jnp.issubdtype(k.dtype, jnp.floating):
+            wide.append(k)
+            continue
+        if bits > free:
+            words.append(word)
+            word, free = jnp.zeros_like(word), 32
+        free -= bits
+        field = k.astype(jnp.int32) & np.int32((1 << bits) - 1)
+        word = word | (field.astype(jnp.uint32) << np.uint32(free))
+    return [*words, word, *wide]
+
+
+def sorted_group_reduce(key_cols, live, max_groups: int, aggs):
+    """Group arbitrary keys AND aggregate per group with one sort.
+
+    key_cols: the sort columns (key data and per-key validity flags).
+    aggs: ``(values, contrib, kind)`` per aggregate — contrib the bool
+    mask of rows that contribute (live AND value-valid), kind 'sum' |
+    'count' | 'min' | 'max' ('count' ignores values).
+
+    Returns (rep_idx[max_groups], ngroups, overflow, results):
+    - rep_idx: original row index of each group's FIRST member (sentinel
+      ``cap`` for unused slots) — gather key columns through it to
+      materialize group keys;
+    - overflow: True when distinct live keys exceeded max_groups;
+    - results[i]: array [max_groups] of aggs[i] per group. Integer sums
+      come back int64 (SQL types sum(int) as bigint); groups with no
+      contributing rows, and unused slots, yield the kind's identity.
+
+    One ``lax.sort`` keyed by (dead, key columns..., row index) puts
+    live rows first, a group's rows adjacent and its first member
+    first, and carries the masked aggregate inputs along as payload.
+    A group is then the run between two boundaries, and its aggregate
+    is a segmented scan read at the run's last row — the same for
+    every kind, exact for integers and without the cancellation a
+    difference of prefix sums would bring to floats. Rows are never
+    scattered, and only ``max_groups`` positions are gathered (on the
+    TPU a scatter costs ~70-120 ns a row, a gather ~16, the sort and
+    the scan under 1 each: PERF.md §6, PR 30).
+    """
+    cap = live.shape[0]
+    iota = jnp.arange(cap, dtype=jnp.int32)
+    scans, payload = [], []
+    for values, contrib, kind in aggs:
+        if kind == "count":
+            kind, values = "sum", contrib.astype(jnp.int64)
+        if kind not in _SCAN_OPS:
+            raise InternalError(f"unknown aggregate kind {kind!r}")
+        ident = _identity(kind, values.dtype)
+        v = jnp.where(contrib, values, ident)
+        if kind == "sum" and not jnp.issubdtype(v.dtype, jnp.floating):
+            v = v.astype(jnp.int64)  # running sums outgrow int32
+        scans.append((_SCAN_OPS[kind], ident.astype(v.dtype)))
+        payload.append(v)
+    keys = _pack_sort_keys(live, key_cols)
+    # the row index is the last key: every key tuple is distinct, so
+    # the sort need not be stable (a stable one compiles ~1.7x longer)
+    out = lax.sort((*keys, iota, *payload), num_keys=len(keys) + 1,
+                   is_stable=False)
+    sorted_keys, order, payload = (
+        out[:len(keys)], out[len(keys)], out[len(keys) + 1:])
+
+    nlive = jnp.sum(live.astype(jnp.int32))
+    differs = reduce(jnp.logical_or,
+                     [k[1:] != k[:-1] for k in sorted_keys],
+                     jnp.zeros(max(cap - 1, 0), jnp.bool_))
+    newgrp = (iota < nlive) & jnp.concatenate([jnp.ones(1, jnp.bool_), differs])
+    # a group's run ends where the next one starts: one start more than
+    # max_groups closes the last slot's run
+    starts, ngroups, _ = compact_indices(newgrp, max_groups + 1)
+    used = jnp.arange(max_groups) < ngroups
+    last = jnp.where(used, jnp.minimum(starts[1:], nlive) - 1, 0)
+    scanned = _segmented_scans(
+        newgrp, [(op, ident, v) for (op, ident), v in zip(scans, payload)])
+    results = [jnp.where(used, v[last], ident)
+               for (_op, ident), v in zip(scans, scanned)]
+    rep_idx = gather_padded(order, starts[:-1], cap)
+    return rep_idx, ngroups, ngroups > max_groups, results

@@ -337,6 +337,10 @@ METRIC_HELP: dict[str, str] = {
     "agg.strategy.sort_rows": (
         "rows handed to the sort-strategy update (group capacity + "
         "batch capacity, summed over calls; static shapes)"),
+    "agg.strategy.sorted_reduce": (
+        "sort-strategy updates grouped and reduced in sorted order by "
+        "ops.groupby.sorted_group_reduce (one per local dispatch; one "
+        "per phase at trace time of the distributed step)"),
     "agg.strategy.sort_live_rows": (
         "live rows among agg.strategy.sort_rows where the executor holds the "
         "count (the bypass)"),

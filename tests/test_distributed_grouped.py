@@ -197,6 +197,14 @@ BOUNDED_KEY_QUERIES = {
         "join lineitem on o_orderkey = l_orderkey "
         "group by o_orderpriority, o_shippriority"
     ),
+    # every kind the sorted reduction has (count, a decimal sum, min,
+    # max, a float sum), NULL inputs from the outer join, both phases
+    "every_reduce_kind": (
+        "select c_custkey, count(o_orderkey) c, sum(o_totalprice) s, "
+        "min(o_totalprice) lo, max(o_orderdate) hi, "
+        "sum(cast(o_shippriority as double)) f from customer "
+        "left join orders on c_custkey = o_custkey group by c_custkey"
+    ),
 }
 
 

@@ -10,8 +10,8 @@ from presto_tpu.ops.compact import compact_indices
 from presto_tpu.ops.groupby import (
     gather_padded,
     group_ids_direct,
-    group_ids_sort,
     segment_agg,
+    sorted_group_reduce,
 )
 from presto_tpu.ops.hashing import hash_columns, partition_ids
 from presto_tpu.ops.join import (
@@ -61,32 +61,132 @@ def test_hash_determinism_and_order_sensitivity():
     assert len(np.unique(np.asarray(p))) == 8
 
 
-def test_group_ids_sort_vs_numpy(rng):
-    cap, n, maxg = 64, 50, 32
-    k1 = rng.integers(0, 5, cap).astype(np.int64)
+def _reference_group_reduce(keys, live, aggs):
+    """Plain loops: groups keyed by tuple in order of first member ->
+    (first row, [aggregate per agg]); integer sums wrap like int64."""
+    groups: dict = {}
+    for i in np.flatnonzero(live):
+        groups.setdefault(tuple(k[i].item() for k in keys), []).append(i)
+    out = {}
+    for key, rows in groups.items():
+        res = []
+        for values, contrib, kind in aggs:
+            rows_in = [r for r in rows if contrib[r]]
+            if kind == "count":
+                res.append(len(rows_in))
+            elif kind == "sum" and values.dtype.kind == "f":
+                res.append(float(np.sum(values[rows_in])) if rows_in else 0.0)
+            elif kind == "sum":
+                tot = sum(int(values[r]) for r in rows_in)
+                res.append((tot + 2**63) % 2**64 - 2**63)
+            elif not rows_in:
+                ident = (np.inf if values.dtype.kind == "f"
+                         else np.iinfo(values.dtype).max)
+                lo = (-np.inf if values.dtype.kind == "f"
+                      else np.iinfo(values.dtype).min)
+                res.append(ident if kind == "min" else lo)
+            else:
+                res.append((min if kind == "min" else max)(
+                    values[r].item() for r in rows_in))
+        out[key] = (rows[0], res)
+    return out
+
+
+def _sgr_case(name, rng):
+    """(keys, live, max_groups, aggs) per named case."""
+    cap = 96
+    k1 = rng.integers(0, 5, cap).astype(np.int32)
     k2 = rng.integers(0, 3, cap).astype(np.int64)
-    live = _live(n, cap)
-    gids, rep, ng, ovf = group_ids_sort([jnp.asarray(k1), jnp.asarray(k2)], live, maxg)
-    want_groups = set(zip(k1[:n].tolist(), k2[:n].tolist()))
-    assert int(ng) == len(want_groups)
-    assert not bool(ovf)
-    # all rows of the same (k1,k2) share a gid; distinct pairs differ
-    df = pd.DataFrame({"k1": k1[:n], "k2": k2[:n], "g": np.asarray(gids)[:n]})
-    assert (df.groupby(["k1", "k2"])["g"].nunique() == 1).all()
-    assert df["g"].nunique() == len(want_groups)
-    # rep indices point at rows with matching keys
-    rep = np.asarray(rep)
-    for g in range(int(ng)):
-        r = rep[g]
-        assert r < cap
-        assert np.asarray(gids)[r] == g
+    k3 = rng.integers(-2, 2, cap).astype(np.int8)
+    live = rng.random(cap) < 0.7                      # dead rows interleaved
+    v = rng.integers(-50, 50, cap).astype(np.int64)
+    ok = live & (rng.random(cap) > 0.2)
+    sums = [(v, ok, "sum"), (v, ok, "count")]
+    if name == "one_key":
+        return [k1], live, 16, sums
+    if name == "two_keys":
+        return [k1, k2], live, 32, sums
+    if name == "three_keys_null_validity":
+        # a validity flag per key: NULL data is zero-filled, so only
+        # the flag parts the NULL group from the real 0
+        valid = rng.random(cap) > 0.3
+        return [valid.astype(np.int8), np.where(valid, k1, 0).astype(np.int32),
+                k2, k3], live, 96, sums
+    if name == "all_dead":
+        return [k1, k2], np.zeros(cap, bool), 8, sums
+    if name == "one_group":
+        return [np.full(cap, 7, np.int32)], live, 4, sums
+    if name == "ngroups_equals_max_groups":
+        keys = (np.arange(cap) % 12).astype(np.int32)
+        return [keys], np.ones(cap, bool), 12, sums
+    if name == "ngroups_over_max_groups":
+        keys = (np.arange(cap) % 13).astype(np.int32)
+        return [keys], np.ones(cap, bool), 12, sums
+    if name == "running_total_wraps_int64":
+        # six groups of 2^62 each: a running total over the rows passes
+        # 2^63 at the second group, every group's own sum fits
+        keys = (np.arange(cap) % 6).astype(np.int32)
+        big = np.full(cap, (1 << 62) // (cap // 6), np.int64)
+        allc = np.ones(cap, bool)
+        return [keys], allc, 8, [(big, allc, "sum"), (-big, allc, "sum")]
+    if name == "min_max":
+        v32 = v.astype(np.int32)
+        return [k1, k2], live, 32, [(v32, ok, "min"), (v32, ok, "max"),
+                                    (v, ok, "min"), (v, ok, "count")]
+    if name == "float64_sum":
+        # one group of 1e18s before groups of 0.1s: a sum that did not
+        # restart at the group's first row would lose every 0.1
+        keys = np.where(np.arange(cap) < 8, 0, 1 + np.arange(cap) % 5).astype(np.int32)
+        f = np.where(keys == 0, 1e18, 0.1).astype(np.float64)
+        allc = np.ones(cap, bool)
+        return [keys], allc, 8, [(f, allc, "sum"), (f, ok | (keys == 0), "max")]
+    if name == "state_rows_first":
+        # rows [0, 8) are state group rows (distinct keys, three of them
+        # absent), the rest a batch that hits old and new groups
+        keys = np.concatenate([np.arange(8), rng.integers(0, 12, cap - 8)]).astype(np.int32)
+        live = np.concatenate([np.array([1, 1, 0, 1, 1, 0, 0, 1], bool),
+                               rng.random(cap - 8) < 0.8])
+        return [keys], live, 16, [(v, live, "sum"), (v, live, "count")]
+    raise AssertionError(name)
 
 
-def test_group_ids_sort_overflow():
-    cap = 32
-    keys = jnp.asarray(np.arange(cap, dtype=np.int64))
-    gids, rep, ng, ovf = group_ids_sort([keys], _live(cap, cap), 8)
-    assert bool(ovf) and int(ng) == 32
+@pytest.mark.parametrize("name", [
+    "one_key", "two_keys", "three_keys_null_validity", "all_dead",
+    "one_group", "ngroups_equals_max_groups", "ngroups_over_max_groups",
+    "running_total_wraps_int64", "min_max", "float64_sum", "state_rows_first",
+])
+def test_sorted_group_reduce_vs_reference(rng, name):
+    keys, live, maxg, aggs = _sgr_case(name, rng)
+    want = _reference_group_reduce(keys, live, aggs)
+    rep, ng, ovf, res = sorted_group_reduce(
+        [jnp.asarray(k) for k in keys], jnp.asarray(live), maxg,
+        [(jnp.asarray(v), jnp.asarray(c), kind) for v, c, kind in aggs])
+    rep, res = np.asarray(rep), [np.asarray(r) for r in res]
+    cap = live.shape[0]
+    assert int(ng) == len(want)
+    assert bool(ovf) == (len(want) > maxg)
+    assert rep.shape == (maxg,) and all(r.shape == (maxg,) for r in res)
+    if bool(ovf):
+        return          # the caller retries at a larger capacity
+    # every group once, represented by its first member in row order
+    assert sorted(rep[:len(want)].tolist()) == sorted(w[0] for w in want.values())
+    assert (rep[len(want):] == cap).all()
+    for g in range(len(want)):
+        first, expect = want[tuple(k[rep[g]].item() for k in keys)]
+        assert rep[g] == first
+        for (values, _c, kind), got, exp in zip(aggs, res, expect):
+            if values.dtype.kind == "f" and kind == "sum":
+                assert got.dtype == np.float64
+                np.testing.assert_allclose(got[g], exp, rtol=1e-12)
+            else:
+                assert got[g].item() == exp, (kind, g)
+    # unused slots hold the kind's identity (a count of zero)
+    for (values, _c, kind), got in zip(aggs, res):
+        if kind in ("sum", "count"):
+            assert (got[len(want):] == 0).all()
+    if name == "state_rows_first":
+        old = {k for k in range(8) if live[k]}
+        assert {int(r) for r in rep[:len(want)] if r < 8} == old
 
 
 def test_segment_agg_vs_pandas(rng):
@@ -95,7 +195,8 @@ def test_segment_agg_vs_pandas(rng):
     v = rng.integers(-50, 50, cap).astype(np.int64)
     valid = rng.random(cap) > 0.2
     live = _live(n, cap)
-    gids, rep, ng, _ = group_ids_sort([jnp.asarray(k)], live, maxg)
+    # ids without sorting, as the direct strategy has them; dead -> trash
+    gids = jnp.where(live, jnp.asarray(k).astype(jnp.int32), maxg)
     contrib = jnp.asarray(valid) & live
     s = segment_agg(jnp.asarray(v), contrib, gids, maxg, "sum")
     c = segment_agg(jnp.asarray(v), contrib, gids, maxg, "count")
@@ -104,9 +205,8 @@ def test_segment_agg_vs_pandas(rng):
     df = pd.DataFrame({"k": k[:n], "v": v[:n], "ok": valid[:n]})
     df = df[df.ok]
     want = df.groupby("k")["v"].agg(["sum", "count", "min", "max"])
-    gmap = {int(k[np.asarray(rep)[g]]): g for g in range(int(ng))}
     for key, row in want.iterrows():
-        g = gmap[int(key)]
+        g = int(key)
         assert int(np.asarray(s)[g]) == row["sum"]
         assert int(np.asarray(c)[g]) == row["count"]
         assert int(np.asarray(mn)[g]) == row["min"]
