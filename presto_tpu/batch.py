@@ -25,7 +25,7 @@ compiler instead.
 
 from __future__ import annotations
 
-from typing import Iterator, Mapping, Sequence
+from typing import Iterator, Mapping, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -136,6 +136,16 @@ jax.tree_util.register_pytree_node(
 )
 
 
+class HostColumns(NamedTuple):
+    """What :meth:`Batch.pad_numpy` hands :meth:`Batch.upload`: the
+    capacity-sized host copies, before anything is on the device."""
+
+    padded: dict  # column -> zero-padded array at its physical dtype
+    masks: dict   # column -> validity mask; absent for a NULL-free column
+    live: np.ndarray
+    n: int        # input rows (the live prefix, unless ``count`` said less)
+
+
 class Batch:
     """A fixed-capacity batch of rows: named columns + a live-row mask."""
 
@@ -215,15 +225,31 @@ class Batch:
         input here: connector stats are *declared* bounds, and a value
         outside the narrowed dtype must fail loudly, never wrap.
 
-        Two spans part the work: ``batch:pad`` (range check, ``astype``,
-        the zero-filled capacity-sized copies and the masks, all
-        columns) and then ``batch:upload`` (the ``jnp.asarray`` calls,
-        all columns). ``batch:upload`` is the host's time inside those
+        Two halves, a span each: :meth:`pad_numpy` is ``batch:pad``
+        (range check, ``astype``, the zero-filled capacity-sized copies
+        and the masks, all columns) and :meth:`upload` is
+        ``batch:upload`` (the ``jnp.asarray`` calls, all columns).
+        ``batch:upload`` is the host's time inside those
         calls; the transfer itself may complete later, and then shows
         as a wait in the first ``sync:*`` span that needs the data.
         ``exec.h2d.bytes`` / ``exec.h2d.arrays`` count what was handed
         over, padding and masks included.
         """
+        host = cls.pad_numpy(arrays, types, count, valids, capacity)
+        return cls.upload(host, types, dictionaries)
+
+    @staticmethod
+    def pad_numpy(
+        arrays: Mapping[str, np.ndarray],
+        types: Mapping[str, DataType],
+        count: int | None = None,
+        valids: Mapping[str, np.ndarray] | None = None,
+        capacity: int | None = None,
+    ) -> HostColumns:
+        """The host half of :meth:`from_numpy` (the ``batch:pad`` span):
+        range check, ``astype``, the zero-filled capacity-sized copies
+        and the masks. Nothing here depends on the device, so a scan
+        may keep the result (``spi.SplitStore``)."""
         n = len(next(iter(arrays.values())))
         count = n if count is None else count
         cap = capacity or n
@@ -259,8 +285,22 @@ class Batch:
                     v[:n] = True
                     masks[name] = v
                 # else a NULL-free column: it shares the live mask object
+        return HostColumns(padded, masks, live, n)
+
+    @classmethod
+    def upload(
+        cls,
+        host: HostColumns,
+        types: Mapping[str, DataType],
+        dictionaries: Mapping[str, Dictionary] | None = None,
+    ) -> "Batch":
+        """The upload half of :meth:`from_numpy` (the ``batch:upload``
+        span and the two ``exec.h2d.*`` counters): the one place a
+        column without a mask is given the batch's live array as its
+        validity, for fresh and for kept host columns alike."""
+        padded, masks = host.padded, host.masks
         with trace.span("batch:upload", "scan"):
-            live = jnp.asarray(live)
+            live = jnp.asarray(host.live)
             cols = {}
             for name, p in padded.items():
                 v = jnp.asarray(masks[name]) if name in masks else live
@@ -268,7 +308,7 @@ class Batch:
                 cols[name] = Column(jnp.asarray(p), v, types[name], d)
         REGISTRY.counter("exec.h2d.arrays").add(1 + len(padded) + len(masks))
         REGISTRY.counter("exec.h2d.bytes").add(
-            cap + sum(p.nbytes for p in padded.values())
+            host.live.nbytes + sum(p.nbytes for p in padded.values())
             + sum(v.nbytes for v in masks.values()))
         return cls(cols, live)
 

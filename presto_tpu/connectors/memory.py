@@ -38,6 +38,7 @@ from presto_tpu.spi import (
     ColumnStats,
     Split,
     batch_capacity,
+    count_delivered,
     generate_split,
     narrowed_schema,
     split_valids,
@@ -471,6 +472,7 @@ class MemoryConnector:
     ) -> Batch:
         t = self._tables[split.table]
         arrays, valids = split_valids(generate_split(self, split, columns))
+        count_delivered(1, len(next(iter(arrays.values()))) if arrays else 0)
         n = split.hi - split.lo
         cap = capacity or batch_capacity(max(n, 1))
         types = self.physical_schema(split.table, list(arrays))

@@ -20,9 +20,9 @@ from presto_tpu.connectors.tpch import schema as S
 from presto_tpu.connectors.tpch.generator import TpchGenerator
 from presto_tpu.spi import (
     Split,
-    batch_capacity,
-    generate_split,
+    SplitStore,
     narrowed_schema,
+    scan_stored,
 )
 from presto_tpu.types import DataType
 
@@ -38,6 +38,9 @@ class TpchConnector:
         self.sf = sf
         self.gen = TpchGenerator(sf, seed)
         self.units_per_split = units_per_split or self.DEFAULT_UNITS_PER_SPLIT
+        #: the tables are a pure function of (sf, seed): padded host
+        #: columns are kept per split (spi.scan_stored)
+        self.scan_store = SplitStore()
 
     # ---- metadata -------------------------------------------------------
     def tables(self) -> Sequence[str]:
@@ -95,12 +98,7 @@ class TpchConnector:
         columns: Sequence[str] | None = None,
         capacity: int | None = None,
     ) -> Batch:
-        arrays = generate_split(self, split, columns)
-        n = len(next(iter(arrays.values())))
-        cap = capacity or batch_capacity(n)
-        types = self.physical_schema(split.table, list(arrays))
-        dicts = {c: d for c, d in S.table_dicts(split.table).items() if c in arrays}
-        return Batch.from_numpy(arrays, types, capacity=cap, dictionaries=dicts)
+        return scan_stored(self, split, columns, capacity)
 
     # ---- whole-table convenience (tests / oracle) -----------------------
     def table_numpy(self, table: str, columns: Sequence[str] | None = None):

@@ -45,7 +45,7 @@ import numpy as np
 from presto_tpu.parallel.mesh import shard_map
 from jax.sharding import PartitionSpec as P
 
-from presto_tpu.batch import Batch, Column, live_count
+from presto_tpu.batch import Batch, Column, HostColumns, live_count
 from presto_tpu.exec.joins import (
     BuildOutput,
     JoinBuildOperator,
@@ -560,68 +560,92 @@ class DistributedExecutor(OomLadderMixin):
         # process to contribute just its local pieces). Single-process
         # meshes address every device, so this is the old loop there.
         proc = jax.process_index()
-        from presto_tpu.spi import generate_split, split_valids
+        from presto_tpu.spi import (
+            count_delivered,
+            generate_split,
+            split_valids,
+        )
 
+        # the generated connectors keep a device's shard buffers per
+        # column in their SplitStore, as the local tier's scan keeps a
+        # split's: a warm scan goes straight to the device_put loop
+        store = getattr(conn, "scan_store", None)
         data_shards: dict[str, list] = {c: [] for c in src_cols}
         valid_shards: dict[str, list] = {c: [] for c in src_cols}
         live_shards: list = []
         for d, sp in enumerate(assign):
             if devices[d].process_index != proc:
                 continue
-            # streamed per-split scan (round-4 VERDICT ask #3): each
-            # split's arrays are generated, written into the padded
-            # transfer buffer and dropped before the next split is
-            # touched — peak host allocation beyond the buffer itself
-            # is ONE split, not the whole shard plus a concat copy
-            # the local tier's three scan spans (Batch.from_numpy), per
-            # device shard: batch:pad is the zero-filled buffers here
-            # and each split's copy into them below
-            padded = {}
-            vmasks = {}
-            with trace_span("batch:pad", "scan"):
-                for c in src_cols:
-                    t = types[c]
-                    tail = (t.width,) if t.kind is TypeKind.BYTES else ()
-                    padded[c] = np.zeros((cap_dev,) + tail, dtype=t.np_dtype)
-                    vmasks[c] = np.zeros(cap_dev, np.bool_)
-            rows = 0
-            for s in sp:
-                # per-split deadline boundary, matching the local tier's
-                # scan loop — a long multi-split scan must notice an
-                # expired query_max_run_time between splits
-                check_deadline("scan")
-                arrays, valids = split_valids(generate_split(conn, s, src_cols))
-                srows = len(next(iter(arrays.values()))) if arrays else 0
-                if rows + srows > cap_dev:
-                    raise CapacityOverflow("TableScan shard", cap_dev,
-                                           rows + srows)
+
+            def make(cols, sp=sp) -> HostColumns:
+                # streamed per-split scan (round-4 VERDICT ask #3): each
+                # split's arrays are generated, written into the padded
+                # transfer buffer and dropped before the next split is
+                # touched — peak host allocation beyond the buffer itself
+                # is ONE split, not the whole shard plus a concat copy
+                # the local tier's three scan spans (Batch.from_numpy), per
+                # device shard: batch:pad is the zero-filled buffers here
+                # and each split's copy into them below
+                padded = {}
+                vmasks = {}
                 with trace_span("batch:pad", "scan"):
-                    for c in src_cols:
-                        a = arrays.get(c)
-                        if a is not None:
-                            if a.ndim > 1:  # BYTES rows may be narrower
-                                padded[c][rows : rows + srows,
-                                          : a.shape[1]] = a
-                            else:
-                                check_narrow_range(c, types[c], a)
-                                padded[c][rows : rows + srows] = a
-                        vm = valids.get(c)
-                        vmasks[c][rows : rows + srows] = (
-                            True if vm is None else vm)
-                rows += srows
-            lv = np.zeros(cap_dev, np.bool_)
-            lv[:rows] = True
+                    for c in cols:
+                        t = types[c]
+                        tail = (t.width,) if t.kind is TypeKind.BYTES else ()
+                        padded[c] = np.zeros((cap_dev,) + tail,
+                                             dtype=t.np_dtype)
+                        vmasks[c] = np.zeros(cap_dev, np.bool_)
+                rows = 0
+                for s in sp:
+                    # per-split deadline boundary, matching the local
+                    # tier's scan loop — a long multi-split scan must
+                    # notice an expired query_max_run_time between splits
+                    check_deadline("scan")
+                    arrays, valids = split_valids(
+                        generate_split(conn, s, cols))
+                    srows = len(next(iter(arrays.values()))) if arrays else 0
+                    if rows + srows > cap_dev:
+                        raise CapacityOverflow("TableScan shard", cap_dev,
+                                               rows + srows)
+                    with trace_span("batch:pad", "scan"):
+                        for c in cols:
+                            a = arrays.get(c)
+                            if a is not None:
+                                if a.ndim > 1:  # BYTES rows may be narrower
+                                    padded[c][rows : rows + srows,
+                                              : a.shape[1]] = a
+                                else:
+                                    check_narrow_range(c, types[c], a)
+                                    padded[c][rows : rows + srows] = a
+                            vm = valids.get(c)
+                            vmasks[c][rows : rows + srows] = (
+                                True if vm is None else vm)
+                    rows += srows
+                lv = np.zeros(cap_dev, np.bool_)
+                lv[:rows] = True
+                return HostColumns(padded, vmasks, lv, rows)
+
+            if store is None:
+                host = make(src_cols)
+            else:
+                shard = ("shard", node.table,
+                         tuple((s.chunk, s.lo, s.hi) for s in sp), cap_dev)
+                host = store.columns(
+                    shard, {c: shard + (c, types[c].np_dtype.str)
+                            for c in src_cols}, make)
+            count_delivered(len(sp), host.n)
             with trace_span("batch:upload", "scan"):
                 for c in src_cols:
                     data_shards[c].append(
-                        jax.device_put(padded[c], devices[d]))
+                        jax.device_put(host.padded[c], devices[d]))
                     valid_shards[c].append(
-                        jax.device_put(vmasks[c], devices[d]))
-                live_shards.append(jax.device_put(lv, devices[d]))
+                        jax.device_put(host.masks[c], devices[d]))
+                live_shards.append(jax.device_put(host.live, devices[d]))
             REGISTRY.counter("exec.h2d.arrays").add(2 * len(src_cols) + 1)
             REGISTRY.counter("exec.h2d.bytes").add(
-                lv.nbytes + sum(padded[c].nbytes + vmasks[c].nbytes
-                                for c in src_cols))
+                host.live.nbytes
+                + sum(host.padded[c].nbytes + host.masks[c].nbytes
+                      for c in src_cols))
 
         sh = row_sharding(self.mesh)
 

@@ -434,12 +434,29 @@ METRIC_HELP: dict[str, str] = {
         "aggregation queries routed through the fused Q1-shape kernel"),
     "exec.q1_route_fallback": (
         "Q1-shape route bailouts to the general aggregation path"),
-    "exec.scan.splits": "splits generated by a connector scan",
-    "exec.scan.rows": "live rows generated by connector scans",
+    "exec.scan.splits": (
+        "splits a connector scan delivered to the upload, generated "
+        "now or kept by the connector's split store"),
+    "exec.scan.rows": (
+        "live rows connector scans delivered to the upload, generated "
+        "now or kept"),
+    "exec.scan.store.hits": (
+        "column-split lookups a generated connector's split store "
+        "answered: nothing generated, range-checked or padded"),
+    "exec.scan.store.misses": (
+        "column-split lookups the split store could not answer: the "
+        "column was generated and padded (scan:generate, batch:pad)"),
+    "exec.scan.store.bypassed": (
+        "split-store inserts refused because they would pass the "
+        "store's share of the host's available memory: that scan was "
+        "served from fresh arrays and dropped them"),
+    "exec.scan.store.bytes": (
+        "bytes of padded host columns the split stores have taken in "
+        "(nothing is evicted: what is held)"),
     "exec.h2d.bytes": (
-        "bytes handed to the device by Batch.from_numpy (capacity "
+        "bytes handed to the device by Batch.upload (capacity "
         "padding and masks included)"),
-    "exec.h2d.arrays": "host arrays handed to the device by Batch.from_numpy",
+    "exec.h2d.arrays": "host arrays handed to the device by Batch.upload",
     "exec.sync.reads": (
         "places the host read a device value and waited for it "
         "(one per sync:* span)"),

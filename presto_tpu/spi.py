@@ -15,12 +15,13 @@ from __future__ import annotations
 
 import math
 import os
+import threading
 from dataclasses import dataclass
 from typing import Callable, Mapping, Protocol, Sequence
 
 import numpy as np
 
-from presto_tpu.batch import Batch, Dictionary
+from presto_tpu.batch import Batch, Dictionary, HostColumns
 from presto_tpu.runtime import trace
 from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.types import DataType, TypeKind, narrow_physical
@@ -61,16 +62,159 @@ class Connector(Protocol):
 
 def generate_split(conn, split: Split,
                    columns: Sequence[str] | None = None) -> dict:
-    """``conn.scan_numpy`` as every connector's ``scan`` runs it: under
-    the ``scan:generate`` span (making the split's host arrays, before
-    ``Batch.from_numpy`` pads and uploads them), counting the split
-    (``exec.scan.splits``) and its live rows (``exec.scan.rows``)."""
+    """``conn.scan_numpy`` under the ``scan:generate`` span: making the
+    split's host arrays, before ``Batch.pad_numpy`` pads them. What a
+    scan DELIVERS is counted apart (:func:`count_delivered`): a split
+    served from the connector's :class:`SplitStore` generates nothing."""
     with trace.span("scan:generate", "scan", {"table": split.table}):
-        arrays = dict(conn.scan_numpy(split, columns))
-    REGISTRY.counter("exec.scan.splits").add()
-    REGISTRY.counter("exec.scan.rows").add(
-        len(next(iter(arrays.values()))) if arrays else 0)
-    return arrays
+        return dict(conn.scan_numpy(split, columns))
+
+
+def count_delivered(splits: int, rows: int) -> None:
+    """``exec.scan.splits`` / ``exec.scan.rows``: the splits and live
+    rows a scan handed to the upload, generated now or kept."""
+    REGISTRY.counter("exec.scan.splits").add(splits)
+    REGISTRY.counter("exec.scan.rows").add(rows)
+
+
+def host_available_bytes() -> int:
+    """Memory this process could still take without pushing the host
+    into swap, as the kernel estimates it (``MemAvailable``), no more
+    than what the cgroup's limit leaves; 0 where it cannot be read."""
+    avail = 0
+    try:
+        with open("/proc/meminfo") as f:
+            for line in f:
+                if line.startswith("MemAvailable:"):
+                    avail = int(line.split()[1]) * 1024
+                    break
+        with open("/sys/fs/cgroup/memory.max") as f:
+            limit = f.read().strip()
+        if limit != "max":
+            with open("/sys/fs/cgroup/memory.current") as f:
+                avail = min(avail, max(int(limit) - int(f.read()), 0))
+    except (OSError, ValueError):
+        pass
+    return avail
+
+
+class SplitStore:
+    """The padded host columns of an IMMUTABLE connector's splits, made
+    once and kept: a warm scan is a lookup and an upload.
+
+    One entry a column — ``key -> (padded array, validity mask or
+    None)`` — and one a split for what its columns share — ``side key
+    -> (live mask, rows)``; the keys are the caller's (a split's range,
+    the column, its physical dtype, the capacity asked for; the mesh's
+    scan keys a device's shard the same way). Per column, not per
+    column set: queries read overlapping columns of one table.
+
+    Admission, not eviction: the bytes held are counted, and an insert
+    that would take the store past ``SHARE`` of what the host has
+    available (free now plus held already) is not made — that scan is
+    served from the arrays it has just made and drops them, as a
+    connector without a store does. A table scanned front to back
+    through an LRU smaller than itself would evict every entry before
+    its reuse. Entries are read-only arrays (``jnp.asarray`` may alias
+    host memory on the CPU backend) and are inserted whole under one
+    lock; generation runs outside it, so two threads that miss the same
+    split both generate and the first insert is the one both serve."""
+
+    #: share of the host's available memory the store may come to hold
+    SHARE = 0.25
+
+    def __init__(self, available: Callable[[], int] = host_available_bytes):
+        self._lock = threading.Lock()
+        self._entries: dict = {}
+        self._available = available
+        self.bytes = 0
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._entries.clear()
+            self.bytes = 0
+
+    def columns(self, side_key: tuple, col_keys: Mapping[str, tuple],
+                make: Callable[[list], HostColumns]) -> HostColumns:
+        """The host columns ``col_keys`` names, in its order: those held
+        as they are, the others from ``make(missing columns)`` (the
+        caller's generate-and-pad), which are then kept if the bound
+        admits them."""
+        with self._lock:
+            side = self._entries.get(side_key)
+            held = {c: self._entries.get(k) for c, k in col_keys.items()}
+        missing = [c for c, e in held.items() if e is None]
+        REGISTRY.counter("exec.scan.store.hits").add(len(held) - len(missing))
+        if missing:     # a split's side entry is inserted with its first column
+            REGISTRY.counter("exec.scan.store.misses").add(len(missing))
+            host = make(missing)
+            fresh = {col_keys[c]: (host.padded[c], host.masks.get(c))
+                     for c in missing}
+            fresh[side_key] = (host.live, host.n)
+            fresh = self._admit(fresh)
+            side = fresh[side_key]
+            held.update((c, fresh[col_keys[c]]) for c in missing)
+        return HostColumns(
+            {c: e[0] for c, e in held.items()},
+            {c: e[1] for c, e in held.items() if e[1] is not None},
+            *side)
+
+    def _admit(self, fresh: dict) -> dict:
+        """Insert ``fresh`` (all of it or none) unless the bound refuses;
+        returns what the scan is to serve: per key the entry now held —
+        an earlier thread's where one got there first — or, refused,
+        ``fresh`` itself."""
+        def arrays(entries):
+            return [a for e in entries.values() for a in e
+                    if isinstance(a, np.ndarray)]
+
+        avail = self._available()  # reads /proc: not under the lock
+        with self._lock:
+            new = {k: e for k, e in fresh.items() if k not in self._entries}
+            need = sum(a.nbytes for a in arrays(new))
+            if self.bytes + need > self.SHARE * (avail + self.bytes):
+                REGISTRY.counter("exec.scan.store.bypassed").add(len(new))
+                return fresh
+            for a in arrays(new):
+                a.setflags(write=False)
+            self._entries.update(new)
+            self.bytes += need
+            out = {k: self._entries[k] for k in fresh}
+        REGISTRY.counter("exec.scan.store.bytes").add(need)
+        return out
+
+
+def scan_stored(conn, split: Split, columns: Sequence[str] | None = None,
+                capacity: int | None = None) -> Batch:
+    """The ``scan`` of the generated connectors (tpch, ssb, tpcds), whose
+    tables are a pure function of ``(sf, seed)``: each column of the
+    split is looked up in ``conn.scan_store``; the missing ones — and
+    only they: ``scan_numpy`` is deterministic per column — are
+    generated, range-checked and padded (``scan:generate``,
+    ``batch:pad``: recorded on a miss only) and kept; then the one
+    upload half (``batch:upload``, ``exec.h2d.*``) runs over kept and
+    fresh columns alike, so ``valid is batch.live`` holds for a
+    NULL-free column on a hit exactly as on a miss."""
+    table = split.table
+    cols = list(columns) if columns is not None else list(conn.schema(table))
+    types = conn.physical_schema(table, cols)
+    dicts = {c: d for c, d in conn.dictionaries(table).items() if c in types}
+    at = (table, split.chunk, split.lo, split.hi)
+
+    def make(missing):
+        arrays, valids = split_valids(generate_split(conn, split, missing))
+        n = len(next(iter(arrays.values())))
+        return Batch.pad_numpy(arrays, types, valids=valids,
+                               capacity=capacity or batch_capacity(n))
+
+    host = conn.scan_store.columns(
+        at + (capacity,),
+        {c: at + (c, types[c].np_dtype.str, capacity) for c in cols}, make)
+    count_delivered(1, host.n)
+    return Batch.upload(host, types, dicts)
 
 
 def split_valids(arrays: Mapping[str, np.ndarray]):
