@@ -53,6 +53,9 @@ class TpcdsConnector:
     def row_count(self, table: str) -> int:
         return S.row_count(table, self.sf)
 
+    def stats(self, table: str, column: str):
+        return S.column_stats(table, column, self.sf)
+
     def unique_keys(self, table: str):
         return S.UNIQUE_KEYS.get(table, ())
 
@@ -61,8 +64,8 @@ class TpcdsConnector:
 
     def physical_schema(self, table: str,
                         columns: Sequence[str] | None = None) -> dict:
-        """Per-column physical types: TPC-DS declares no numeric column
-        stats yet, so only dictionary-encoded VARCHAR columns narrow
+        """Per-column physical types: the declared stats are join-key
+        domains only, so only dictionary-encoded VARCHAR columns narrow
         (their code domain is exactly the dictionary length — int8/int16
         instead of int32 for every low-cardinality dimension string)."""
         cols = list(columns) if columns is not None else list(S.TABLES[table])

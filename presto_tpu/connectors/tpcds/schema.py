@@ -656,3 +656,23 @@ def row_count(table: str, sf: float) -> int:
             "customer_address": 50, "warehouse": 3, "call_center": 2,
             "web_site": 2, "web_page": 4}
     return max(int(base * sf), mins.get(table, 1))
+
+
+def column_stats(table: str, column: str, sf: float):
+    """Declared domains of the ``store_sales`` star's dimension keys
+    (the presto-tpcds connector's table statistics, as far as this
+    generator's formulas give them exactly): ``date_dim`` numbers its
+    days from ``DATE_SK_BASE``, ``item`` and ``store`` their rows from
+    1. A bounded build key makes a join a direct-address probe (one
+    gather: no sort to run, and none for the TPU's compiler to take
+    most of a minute over, a probe program). None for every other
+    column: no estimate is better than a guessed one."""
+    from presto_tpu.spi import ColumnStats
+
+    if (table, column) == ("date_dim", "d_date_sk"):
+        return ColumnStats(DATE_DIM_ROWS, DATE_SK_BASE,
+                           DATE_SK_BASE + DATE_DIM_ROWS - 1)
+    if (table, column) in (("item", "i_item_sk"), ("store", "s_store_sk")):
+        n = row_count(table, sf)
+        return ColumnStats(n, 1, n)
+    return None

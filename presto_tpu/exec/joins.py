@@ -225,8 +225,17 @@ class JoinBuildOperator(CollectingOperator):
                               fbits),
             make_build,
         )
+        # the step reads the key's columns alone, so one program serves
+        # every payload a build of this key and capacity carries (a
+        # grouping-set expansion builds one dimension once a branch,
+        # each with other columns: nine programs of a sort were one)
+        from presto_tpu.plan.prune import expr_refs
+
+        refs: set = set()
+        expr_refs(key_expr, refs)
+        keyed = batch.select([n for n in batch.names if n in refs])
         with trace_span("step:join_build", "step", {"capacity": cap}):
-            side, dense, long_runs, filt = build(batch, self._params)
+            side, dense, long_runs, filt = build(keyed, self._params)
         # the build's flags are the first host reads after its dispatch:
         # the host waits here for the whole build (sort included)
         with trace_sync("join_build"):

@@ -54,6 +54,13 @@ from presto_tpu.types import TypeKind
 DIRECT_LIMIT = 4096
 MAX_GROUP_CAP = 1 << 20
 MAX_RETRIES = 6
+#: a TopN or a window step whose input has at least this many slots
+#: reads its live count and sorts the live rows' bucket. The bucket is
+#: the data's, so another binding of the same template can bring another
+#: and trace again: under 2^20 slots the sort a query saves (q67's 5.77 M
+#: slots cost its TopN ~3.6 s and its window step ~3.5 s of a v5e's time,
+#: PERF.md §6 PR 34: ~0.6 s by 2^20) is less than that one compile costs
+SORT_COMPACT_SLOTS = 1 << 20
 
 
 class JoinFilterSlot:
@@ -1491,7 +1498,30 @@ class LocalExecutor(OomLadderMixin):
         from presto_tpu.exec.operators import window_operator_from_node
 
         op = window_operator_from_node(node, scalars, params=self.params)
+        # the step sorts its whole input by (partition, order) keys and
+        # gathers every column by the permutation: over a ROLLUP's union
+        # that is the branches' summed capacities, a tenth of them live
+        child = self._compact_large(child, "exec.window.compacted")
         return BatchStream.of(Pipeline(child, [op]).run())
+
+    def _compact_large(self, child: BatchStream, counter: str) -> BatchStream:
+        """A large sort operand follows what is live: a materialised
+        input of ``SORT_COMPACT_SLOTS`` slots or more is compacted to
+        its live rows' capacity bucket where that at least halves the
+        slots (one ``sync:live_count`` read a batch; under the limit
+        nothing is read and the input is handed on as it is)."""
+        batches = child.materialize()
+        slots = sum(b.capacity for b in batches)
+        if slots >= SORT_COMPACT_SLOTS:
+            rows = sum(live_count(b) for b in batches)
+            cap = batch_capacity(max(rows, 16))
+            if 2 * cap <= slots:
+                from presto_tpu.exec.operators import compact_batches
+                from presto_tpu.runtime.metrics import REGISTRY
+
+                batches = [compact_batches(batches, cap)]
+                REGISTRY.counter(counter).add()
+        return BatchStream.of(batches)
 
     def _exec_values(self, node: N.Values, scalars) -> BatchStream:
         return BatchStream.of([Batch({}, jnp.ones(1, jnp.bool_))])
@@ -1504,7 +1534,15 @@ class LocalExecutor(OomLadderMixin):
         bucket). VARCHAR columns whose children carry different
         dictionaries are re-encoded into a merged target dictionary
         (codes are only comparable within one dictionary)."""
+        from presto_tpu.runtime.metrics import REGISTRY
+
         children = [self._exec(c, scalars) for c in node.inputs]
+        # a grouping-set expansion is one branch a set, each a pass
+        # over its inputs (a one-pass plan would execute no union); the
+        # analyzer chains unions left-associatively, so a nested union
+        # is not a branch: its own leaves are counted when it executes
+        leaves = [not isinstance(c, N.Union) for c in node.inputs]
+        REGISTRY.counter("exec.union.inputs").add(sum(leaves))
         names = node.field_names()
         targets = union_target_dicts(
             names, [cs.peek() for cs in children]
@@ -1512,8 +1550,10 @@ class LocalExecutor(OomLadderMixin):
         mapping_cache: dict = {}
 
         def make():
-            for cs in children:
+            for cs, leaf in zip(children, leaves):
                 for b in cs:
+                    if leaf:
+                        REGISTRY.counter("exec.union.batches").add()
                     yield align_batch_dicts(b.select(names), targets,
                                             mapping_cache)
 
@@ -1538,6 +1578,9 @@ class LocalExecutor(OomLadderMixin):
             SortKey(bind_scalars(k.expr, scalars), k.descending, k.nulls_first)
             for k in node.keys
         ]
+        # a TopN over a filter over a window (rank() <= 100 of the
+        # window's slots) sorts the live rows' bucket
+        child = self._compact_large(child, "exec.topn.compacted")
         return BatchStream.of(
             Pipeline(child, [TopNOperator(keys, node.count)]).run()
         )

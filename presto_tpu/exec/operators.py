@@ -38,6 +38,7 @@ from presto_tpu.expr import Expr, Val, evaluate, evaluate_predicate, param_scope
 from presto_tpu.ops.groupby import (
     ValueBitsOverflow,
     fused_small_sums,
+    gather_columns,
     gather_padded,
     group_ids_direct,
     segment_agg,
@@ -49,6 +50,16 @@ from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.runtime.trace import span as trace_span
 from presto_tpu.runtime.trace import sync as trace_sync
 from presto_tpu.types import BIGINT, DOUBLE, DataType, TypeKind
+
+
+#: a sort-strategy aggregation folds its input once the batches held
+#: have this many times the state's slots: an update sorts and gathers
+#: state + input whatever is live, so the state's share of its cost is
+#: under 1 in 1 + SORT_FOLD_FACTOR (q67's 8-key ROLLUP levels: 22
+#: batches of 2^17 slots against a 2^20-slot state, 25.9 M slots sorted
+#: a level one batch at a time, 3.9 M held — PERF.md §6, PR 34), and
+#: no more than that many input slots wait on the device
+SORT_FOLD_FACTOR = 8
 
 
 def null_safe_key(v: "Val") -> "Val":
@@ -247,6 +258,9 @@ class HashAggregationOperator(Operator):
         self.phase = phase
         self.passengers = list(passengers)
         self.state: dict[str, Any] | None = None
+        #: sort strategy: input batches not yet folded into the state
+        self._held: list[Batch] = []
+        self._held_slots = 0
         self._key_types: dict[str, DataType] = {n: e.dtype for n, e in self.group_keys}
         if isinstance(strategy, DirectStrategy) and self.passengers:
             raise InternalError("passenger keys need the sort strategy")
@@ -534,27 +548,20 @@ class HashAggregationOperator(Operator):
         rep, ng, ovf, reduced = sorted_group_reduce(
             cat_sort, cat_live, g, reduces)
 
-        def gat(cat, fill=0):
-            if cat.ndim > 1:
-                safe = jnp.minimum(rep, cat.shape[0] - 1)
-                return jnp.where((rep < cat.shape[0])[:, None], cat[safe], fill)
-            return gather_padded(cat, rep, fill)
-
-        new = dict(state)
-        new["overflow"] = state["overflow"] | ovf
-        for (n, _e) in self.group_keys:
-            new["keyv$" + n] = gather_padded(cat_valids[n], rep, False)
-        for key, cat in zip(sort_names, cat_data):
-            new[key] = gat(cat)
+        # what each group carries on: its representative's row of these
+        carried = {"keyv$" + n: cat_valids[n] for n, _e in self.group_keys}
+        carried.update(zip(sort_names, cat_data))
         for (n, e), v in zip(self.group_keys, kvals):
             if e.dtype.kind is TypeKind.BYTES:
-                cat_raw = jnp.concatenate([state["keyraw$" + n], v.data])
-                new["keyraw$" + n] = gat(cat_raw)
+                carried["keyraw$" + n] = jnp.concatenate(
+                    [state["keyraw$" + n], v.data])
         for (n, e), v in zip(self.passengers, pvals):
-            cat_p = jnp.concatenate([state["pax$" + n], v.data])
-            cat_pv = jnp.concatenate([state["paxv$" + n], v.valid])
-            new["pax$" + n] = gat(cat_p)
-            new["paxv$" + n] = gather_padded(cat_pv, rep, False)
+            carried["pax$" + n] = jnp.concatenate([state["pax$" + n], v.data])
+            carried["paxv$" + n] = jnp.concatenate(
+                [state["paxv$" + n], v.valid])
+        new = dict(state)
+        new["overflow"] = state["overflow"] | ovf
+        new.update(zip(carried, gather_columns(list(carried.values()), rep)))
         present = jnp.arange(g) < ng
         new["present"] = present
         for a, agg, ncnt in zip(self.aggs, reduced[::2], reduced[1::2]):
@@ -596,29 +603,47 @@ class HashAggregationOperator(Operator):
     # -- operator protocol -------------------------------------------------
 
     def process(self, batch: Batch) -> list[Batch]:
-        if self.state is None:
-            if isinstance(self.strategy, DirectStrategy):
-                self.state = self._direct_init()
-            else:
-                self.state = self._sort_init()
         if isinstance(self.strategy, SortStrategy):
-            # the sort's operand, from static shapes (no device read)
-            REGISTRY.counter("agg.strategy.sort_rows").add(
-                self.strategy.max_groups + batch.capacity)
-            REGISTRY.counter("agg.strategy.sorted_reduce").add()
+            # a sort update re-sorts and re-gathers the whole state
+            # beside its input, so input is held until it outweighs the
+            # state SORT_FOLD_FACTOR times (a batch that does so alone
+            # is folded as it comes, in the program it always had)
+            self._held.append(batch)
+            self._held_slots += batch.capacity
+            if (self._held_slots
+                    >= SORT_FOLD_FACTOR * self.strategy.max_groups):
+                self._fold_held()
+            return []
+        if self.state is None:
+            self.state = self._direct_init()
+        self._fold(batch)
+        return []
+
+    def _fold(self, batch: Batch) -> None:
         # the carrier hands back the dictionaries THIS trace signature
         # saw (correct even when jit's signature cache skipped the
         # body — the output treedef is stored per signature)
         self.state, carrier = self._update(self.state, batch, self._params)
         self._dicts = {n: c.dictionary for n, c in carrier.items()}
-        return []
+
+    def _fold_held(self) -> None:
+        if self.state is None:
+            self.state = self._sort_init()
+        if not self._held:
+            return
+        # the sort's operand, from static shapes (no device read)
+        REGISTRY.counter("agg.strategy.sort_rows").add(
+            self.strategy.max_groups + self._held_slots)
+        REGISTRY.counter("agg.strategy.sorted_reduce").add()
+        batch = concat_batches(self._held)
+        self._held, self._held_slots = [], 0
+        self._fold(batch)
 
     def finish(self) -> list[Batch]:
-        if self.state is None:
-            if isinstance(self.strategy, DirectStrategy):
-                self.state = self._direct_init()
-            else:
-                self.state = self._sort_init()
+        if isinstance(self.strategy, SortStrategy):
+            self._fold_held()
+        elif self.state is None:
+            self.state = self._direct_init()
         st = self.state
         # the state's flags are the first host read after the updates:
         # the host waits here for every update dispatched so far
@@ -1258,6 +1283,12 @@ class WindowOperator(CollectingOperator):
     def finish(self) -> list[Batch]:
         if not self.batches:
             return []
+        # ONE step over the concatenation: the sort's operand is the
+        # summed capacities, live or not (static shapes, no device read)
+        REGISTRY.counter("exec.window.dispatches").add()
+        REGISTRY.counter("exec.window.inputs").add(len(self.batches))
+        REGISTRY.counter("exec.window.slots").add(
+            sum(b.capacity for b in self.batches))
         return [self._step(concat_batches(self.batches), self._params)]
 
 

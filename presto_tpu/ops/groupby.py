@@ -42,6 +42,70 @@ def gather_padded(arr, idx, fill):
     return jnp.where(idx < cap, arr[safe], fill)
 
 
+#: above this many columns ``gather_columns`` moves them as one matrix:
+#: on the TPU a gather costs ~20 ns an index whatever the row's width
+#: (26 columns of 2^20 slots: 0.6 s a call one by one — PERF.md §6,
+#: PR 34). The 8 is not measured: it is where the programs of the
+#: benchmark's older cells stay what they were (their updates carry 5
+#: to 8 columns); by the cost above the matrix is no slower at any
+#: count, and making it the only path is ROADMAP A3's follow-up
+ROW_GATHER_COLUMNS = 8
+
+
+def _to_words(c):
+    """A fixed-width column, ``[rows]`` or uint8 ``[rows, width]``, as
+    uint32 ``[rows, words]`` (reversed by ``_from_words``)."""
+    if c.ndim == 2 and c.dtype == jnp.uint32:
+        return c
+    if c.ndim == 2:
+        pad = -c.shape[1] % 4
+        c = jnp.pad(c, ((0, 0), (0, pad)))
+        return lax.bitcast_convert_type(
+            c.reshape(c.shape[0], -1, 4), jnp.uint32)
+    if c.dtype.itemsize == 8:
+        return lax.bitcast_convert_type(c, jnp.uint32)
+    if c.dtype.itemsize == 4:
+        return lax.bitcast_convert_type(c, jnp.uint32)[:, None]
+    return lax.bitcast_convert_type(c.astype(jnp.int32), jnp.uint32)[:, None]
+
+
+def _from_words(w, like):
+    if like.ndim == 2 and like.dtype == jnp.uint32:
+        return w
+    if like.ndim == 2:
+        return lax.bitcast_convert_type(w, jnp.uint8).reshape(
+            w.shape[0], -1)[:, :like.shape[1]]
+    if like.dtype.itemsize == 8:
+        return lax.bitcast_convert_type(w, like.dtype)
+    if like.dtype.itemsize == 4:
+        return lax.bitcast_convert_type(w[:, 0], like.dtype)
+    return lax.bitcast_convert_type(w[:, 0], jnp.int32).astype(like.dtype)
+
+
+def gather_columns(cols, idx, as_rows: bool = False):
+    """``[c[idx] for c in cols]`` with an out-of-range ``idx`` (>= rows)
+    giving zero: columns of one length, ``[rows]`` of any fixed-width
+    dtype, uint8 ``[rows, width]`` or uint32 ``[rows, words]``. More
+    than ``ROW_GATHER_COLUMNS`` of them, or any number with
+    ``as_rows``, are laid side by side as 32-bit words and gathered as
+    ONE matrix of rows."""
+    rows = cols[0].shape[0]
+    safe = jnp.minimum(idx, rows - 1)
+    inside = idx < rows
+    if len(cols) <= ROW_GATHER_COLUMNS and not as_rows:
+        return [gather_padded(c, idx, False if c.dtype == jnp.bool_ else 0)
+                if c.ndim == 1
+                else jnp.where(inside[:, None], c[safe], 0) for c in cols]
+    words = [_to_words(c) for c in cols]
+    got = jnp.where(inside[:, None],
+                    jnp.concatenate(words, axis=1)[safe], 0)
+    out, at = [], 0
+    for c, w in zip(cols, words):
+        out.append(_from_words(got[:, at:at + w.shape[1]], c))
+        at += w.shape[1]
+    return out
+
+
 # ---------------------------------------------------------------------------
 # group-id assignment
 # ---------------------------------------------------------------------------
@@ -426,7 +490,44 @@ def _pack_sort_keys(live, key_cols):
         free -= bits
         field = k.astype(jnp.int32) & np.int32((1 << bits) - 1)
         word = word | (field.astype(jnp.uint32) << np.uint32(free))
-    return [*words, word, *wide]
+    return [*words, word], wide
+
+
+#: above this many 32-bit words of wide integer key columns the sort is
+#: keyed by a hash of them: the TPU's compiler takes longer over a sort
+#: with every key it compares (two BYTES keys of 50 and 16 bytes are ten
+#: int64 keys and over half an hour — PERF.md §6, PR 34). Like
+#: ``ROW_GATHER_COLUMNS`` the 8 is where the older cells' programs stay
+#: what they were, not a measured crossover
+HASHED_KEY_WORDS = 8
+#: and above this many rows whatever the keys: the compiler's time over
+#: a sort that compares several keys and carries the aggregate inputs
+#: grows with its rows (3 keys and 4 inputs, compiled here for a
+#: described v5e: 114 s at 0.66 M rows, 331 s at 3.4 M, against ~60 s
+#: for the one-key sort and its row gather at 3.9 M — PERF.md §6, PR
+#: 34). Again where the older cells' programs stay what they were: their
+#: largest update sorts 2.36 M rows (Q13)
+HASHED_SORT_ROWS = 3 << 20
+
+_H1 = (np.uint32(0x9E3779B1), np.uint32(0x85EBCA77))
+_H2 = (np.uint32(0xC2B2AE3D), np.uint32(0x27D4EB2F))
+
+
+def _hash_rows(words, salt: int):
+    """Two 32-bit hashes of each row of a uint32 matrix (multiply,
+    rotate, xor a word at a time, a murmur3 finalizer at the end):
+    different multipliers and seeds, so a row pair equal in both is a
+    collision of 64 bits."""
+    out = []
+    for (m1, m2), seed in ((_H1, salt), (_H2, ~salt)):
+        h = jnp.full(words.shape[0], np.uint32(seed & 0xFFFFFFFF))
+        for j in range(words.shape[1]):
+            h = (h ^ words[:, j]) * m1
+            h = (h << np.uint32(13)) | (h >> np.uint32(19))
+        h = (h ^ (h >> np.uint32(16))) * m2
+        h = (h ^ (h >> np.uint32(13))) * m1
+        out.append(h ^ (h >> np.uint32(16)))
+    return out
 
 
 def sorted_group_reduce(key_cols, live, max_groups: int, aggs):
@@ -449,6 +550,15 @@ def sorted_group_reduce(key_cols, live, max_groups: int, aggs):
     One ``lax.sort`` keyed by (dead, key columns..., row index) puts
     live rows first, a group's rows adjacent and its first member
     first, and carries the masked aggregate inputs along as payload.
+    Wide integer key columns of more than ``HASHED_KEY_WORDS`` words
+    together, and the integer keys of more than ``HASHED_SORT_ROWS``
+    rows, do not enter the sort: one 64-bit key does (the dead flag
+    over 63 hash bits of every integer key word), and the key words
+    are gathered once by the sort's permutation, the aggregate inputs
+    with them, to compare adjacent rows exactly. Equal hashes over
+    different keys would split a group, so such a pair is reported as
+    ``overflow``: the caller's retry with a larger ``max_groups`` salts
+    the hashes anew.
     A group is then the run between two boundaries, and its aggregate
     is a segmented scan read at the run's last row — the same for
     every kind, exact for integers and without the cancellation a
@@ -471,7 +581,27 @@ def sorted_group_reduce(key_cols, live, max_groups: int, aggs):
             v = v.astype(jnp.int64)  # running sums outgrow int32
         scans.append((_SCAN_OPS[kind], ident.astype(v.dtype)))
         payload.append(v)
-    keys = _pack_sort_keys(live, key_cols)
+    keys, wide = _pack_sort_keys(live, key_cols)
+    hashed = [k for k in wide if jnp.issubdtype(k.dtype, jnp.integer)]
+    words = None
+    if (sum(k.dtype.itemsize // 4 for k in hashed) > HASHED_KEY_WORDS
+            or cap > HASHED_SORT_ROWS):
+        # every integer key word goes into the hashes, the packed narrow
+        # ones too: the sort moves 63 hash bits and the row index, and
+        # the aggregate inputs travel in the row gather that follows
+        words = jnp.concatenate(
+            [k[:, None] for k in keys] + [_to_words(k) for k in hashed],
+            axis=1)
+        h1, h2 = _hash_rows(words, max_groups)
+        h = (h1.astype(jnp.uint64) << np.uint64(32)) | h2.astype(jnp.uint64)
+        dead = (~live).astype(jnp.uint64) << np.uint64(63)
+        # ONE 64-bit key: the TPU's compiler takes 26 s over a sort of
+        # (uint64, row index) and 61 s over (uint32, uint32, row index)
+        keys = [dead | (h >> np.uint64(1))] + [
+            k for k in wide if not jnp.issubdtype(k.dtype, jnp.integer)]
+        carried, payload = payload, []
+    else:
+        keys += wide
     # the row index is the last key: every key tuple is distinct, so
     # the sort need not be stable (a stable one compiles ~1.7x longer)
     out = lax.sort((*keys, iota, *payload), num_keys=len(keys) + 1,
@@ -483,6 +613,12 @@ def sorted_group_reduce(key_cols, live, max_groups: int, aggs):
     differs = reduce(jnp.logical_or,
                      [k[1:] != k[:-1] for k in sorted_keys],
                      jnp.zeros(max(cap - 1, 0), jnp.bool_))
+    collision = None
+    if words is not None:
+        rows, *payload = gather_columns([words, *carried], order,
+                                        as_rows=True)
+        collision = jnp.any(jnp.any(rows[1:] != rows[:-1], axis=1)
+                            & ~differs & (iota[1:] < nlive))
     newgrp = (iota < nlive) & jnp.concatenate([jnp.ones(1, jnp.bool_), differs])
     # a group's run ends where the next one starts: one start more than
     # max_groups closes the last slot's run
@@ -494,4 +630,7 @@ def sorted_group_reduce(key_cols, live, max_groups: int, aggs):
     results = [jnp.where(used, v[last], ident)
                for (_op, ident), v in zip(scans, scanned)]
     rep_idx = gather_padded(order, starts[:-1], cap)
-    return rep_idx, ngroups, ngroups > max_groups, results
+    overflow = ngroups > max_groups
+    if collision is not None:
+        overflow = overflow | collision
+    return rep_idx, ngroups, overflow, results

@@ -10,10 +10,11 @@ accumulator objects; here a window computation is a handful of
 data-parallel primitives over the *whole sorted batch at once*:
 
 - partition / peer boundaries  -> adjacent-diff flags;
-- partition starts, peer-group ends -> ``lax.cummax`` / reversed
-  ``lax.cummin`` of flagged positions;
-- running aggregates           -> segmented inclusive scans
-  (``lax.associative_scan`` with a (value, segment-start) combine);
+- partition starts, peer-group ends -> running max / reversed running
+  min of flagged positions;
+- running aggregates           -> segmented inclusive scans (a (value,
+  segment-start) combine), every scan in log2(n) statically shifted
+  passes;
 - RANGE-frame peer semantics   -> gather the running value at each
   row's last peer index.
 
@@ -23,7 +24,6 @@ control flow — exactly what XLA tiles well.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -46,10 +46,23 @@ def change_flags(cols, valids=None) -> jnp.ndarray:
     return first.at[1:].set(diff)
 
 
+def _scan(op, ident, v: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive scan in log2(n) passes, each combining a row with the
+    row ``d`` before it: shifts by a static ``d`` are plain slices,
+    which the TPU compiles in seconds where ``lax.cummax`` / ``cumsum``
+    / ``associative_scan`` take a minute and more at millions of rows
+    (the sort-based aggregation's scan: PERF.md §6, PR 30 and PR 34)."""
+    d = 1
+    while d < v.shape[0]:
+        v = op(v, jnp.concatenate([jnp.full(d, ident, v.dtype), v[:-d]]))
+        d *= 2
+    return v
+
+
 def segment_starts(flags: jnp.ndarray) -> jnp.ndarray:
     """Per row: index of the most recent True flag at or before it."""
     pos = jnp.arange(flags.shape[0])
-    return jax.lax.cummax(jnp.where(flags, pos, -1))
+    return _scan(jnp.maximum, -1, jnp.where(flags, pos, -1))
 
 
 def segment_ends(next_flags: jnp.ndarray) -> jnp.ndarray:
@@ -59,7 +72,7 @@ def segment_ends(next_flags: jnp.ndarray) -> jnp.ndarray:
     pos = jnp.arange(n)
     is_end = jnp.concatenate([next_flags[1:], jnp.ones(1, jnp.bool_)])
     cand = jnp.where(is_end, pos, n)
-    return jnp.flip(jax.lax.cummin(jnp.flip(cand)))
+    return jnp.flip(_scan(jnp.minimum, n, jnp.flip(cand)))
 
 
 def seg_scan(vals: jnp.ndarray, reset: jnp.ndarray, kind: str) -> jnp.ndarray:
@@ -74,13 +87,17 @@ def seg_scan(vals: jnp.ndarray, reset: jnp.ndarray, kind: str) -> jnp.ndarray:
     else:
         raise InternalError(f"unknown scan kind {kind!r}")
 
-    def combine(a, b):
-        av, af = a
-        bv, bf = b
-        return jnp.where(bf, bv, op(av, bv)), af | bf
-
-    v, _ = jax.lax.associative_scan(combine, (vals, reset))
-    return v
+    # a row's run is restarted by its own flag or by one carried over
+    # from within the last ``d`` rows (the combine of an associative
+    # scan, with ``_scan``'s static shifts)
+    ident = scan_identity(kind, vals.dtype)
+    d = 1
+    while d < vals.shape[0]:
+        vals = jnp.where(reset, vals, op(jnp.concatenate(
+            [jnp.full(d, ident, vals.dtype), vals[:-d]]), vals))
+        reset = reset | jnp.concatenate([jnp.ones(d, jnp.bool_), reset[:-d]])
+        d *= 2
+    return vals
 
 
 def scan_identity(kind: str, dtype):
@@ -107,7 +124,7 @@ def rank_values(part_change, peer_change):
     fpeer = segment_starts(peer_change)
     row_number = pos - pstart + 1
     rank = fpeer - pstart + 1
-    cpeer = jnp.cumsum(peer_change.astype(jnp.int64))
+    cpeer = _scan(jnp.add, 0, peer_change.astype(jnp.int64))
     dense = cpeer - cpeer[pstart] + 1
     return (
         row_number.astype(jnp.int64),

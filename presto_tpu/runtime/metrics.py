@@ -453,6 +453,31 @@ METRIC_HELP: dict[str, str] = {
     "exec.scan.store.bytes": (
         "bytes of padded host columns the split stores have taken in "
         "(nothing is evicted: what is held)"),
+    "exec.window.dispatches": (
+        "window steps dispatched (WindowOperator.finish: one sort by "
+        "partition and order keys, then segmented scans)"),
+    "exec.window.inputs": (
+        "buffered batches the window steps concatenated into their "
+        "one operand"),
+    "exec.window.slots": (
+        "row slots the window steps sorted: the summed capacities of "
+        "the batches concatenated, live or not (static shapes, no "
+        "device read)"),
+    "exec.window.compacted": (
+        "window inputs of 2^20 slots or more compacted to their live "
+        "rows' capacity bucket before the step (as exec.topn.compacted; "
+        "exec.window.slots then counts the bucket)"),
+    "exec.topn.compacted": (
+        "TopN inputs of 2^20 slots or more compacted to their live "
+        "rows' capacity bucket before the sort (where that at least "
+        "halves the slots; the count is one sync:live_count read)"),
+    "exec.union.inputs": (
+        "branch streams of the executed UNION ALLs (a nested union is "
+        "not a branch: its own leaves are counted); a grouping-set "
+        "expansion is one branch a set"),
+    "exec.union.batches": (
+        "batches drawn from the UNION ALLs' branch streams (a nested "
+        "union's peek for the dictionaries draws its first again)"),
     "exec.h2d.bytes": (
         "bytes handed to the device by Batch.upload (capacity "
         "padding and masks included)"),

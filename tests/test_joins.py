@@ -45,6 +45,33 @@ def run_join(join_type, unique=True, outputs=(BuildOutput("bval", "bval"),)):
     return pd.concat([o.to_pandas() for o in out])
 
 
+def test_a_build_step_is_traced_once_whatever_its_payload():
+    """The build's program reads the key's columns alone: the same key
+    and capacity under other payload columns (one dimension built once
+    a grouping-set branch, each carrying other columns) is not traced
+    again, and the payload is still the probe's to gather from."""
+    from presto_tpu.cache.exec_cache import trace_delta
+
+    def build(payload):
+        b = JoinBuildOperator(col("bk", BIGINT))
+        arrays = {"bk": np.array([1, 3, 5, 7], dtype=np.int64)}
+        arrays.update({n: np.arange(4, dtype=np.int64) * 10 for n in payload})
+        Pipeline(BatchSource([_batch(
+            arrays, {n: BIGINT for n in arrays}, cap=8)]), [b]).run()
+        return b
+
+    build(["x"])
+    with trace_delta() as td:
+        b = build(["y", "z"])
+    assert td.traces == 0
+    j = LookupJoinOperator(b, col("pk", BIGINT), (BuildOutput("z", "z"),),
+                           "inner", unique=True)
+    out = Pipeline(BatchSource([probe_batch()]), [j]).run()
+    df = pd.concat([o.to_pandas() for o in out]).sort_values("pk")
+    assert df["pk"].tolist() == [1, 3, 5, 7]
+    assert df["z"].tolist() == [0, 10, 20, 30]
+
+
 def test_inner_unique():
     df = run_join("inner").sort_values("pk")
     assert df["pk"].tolist() == [1, 3, 5, 7]

@@ -112,6 +112,15 @@ def _sgr_case(name, rng):
         valid = rng.random(cap) > 0.3
         return [valid.astype(np.int8), np.where(valid, k1, 0).astype(np.int32),
                 k2, k3], live, 96, sums
+    if name in ("wide_keys_hashed", "wide_keys_hash_collision"):
+        # five int64 and an int32 column: 11 words, over HASHED_KEY_WORDS,
+        # so the sort is keyed by their hashes; the columns differ only
+        # in their high words or only in the last column for some rows
+        few = rng.integers(0, 3, (5, cap)).astype(np.int64)
+        wide = [few[0] << 40, few[1] - 1, few[2] * -(1 << 33), few[3], few[4]]
+        valid = rng.random(cap) > 0.3
+        return [valid.astype(np.int8), *wide, np.where(valid, k1, 0)], \
+            live, 96, sums
     if name == "all_dead":
         return [k1, k2], np.zeros(cap, bool), 8, sums
     if name == "one_group":
@@ -154,10 +163,35 @@ def _sgr_case(name, rng):
     "one_key", "two_keys", "three_keys_null_validity", "all_dead",
     "one_group", "ngroups_equals_max_groups", "ngroups_over_max_groups",
     "running_total_wraps_int64", "min_max", "float64_sum", "state_rows_first",
+    "wide_keys_hashed", "wide_keys_hash_collision",
+    "three_keys_null_validity_many_rows", "min_max_many_rows",
+    "state_rows_first_many_rows",
 ])
-def test_sorted_group_reduce_vs_reference(rng, name):
+def test_sorted_group_reduce_vs_reference(rng, name, monkeypatch):
+    from presto_tpu.ops import groupby
+
+    if name.endswith("_many_rows"):
+        # over HASHED_SORT_ROWS rows narrow keys too are sorted by
+        # their hash and verified in the row gather
+        name = name[:-len("_many_rows")]
+        monkeypatch.setattr(groupby, "HASHED_SORT_ROWS", 95)
     keys, live, maxg, aggs = _sgr_case(name, rng)
     want = _reference_group_reduce(keys, live, aggs)
+    if name.startswith("wide_keys"):
+        words = sum(k.dtype.itemsize // 4 for k in keys if k.dtype.itemsize > 2)
+        assert words > groupby.HASHED_KEY_WORDS
+    if name == "wide_keys_hash_collision":
+        # every row hashes alike under this salt: distinct keys meet in
+        # one run of equal hashes, which is reported, never grouped
+        real, bad = groupby._hash_rows, maxg
+        monkeypatch.setattr(
+            groupby, "_hash_rows", lambda w, salt: [
+                h * np.uint32(salt != bad) for h in real(w, salt)])
+        _rep, _ng, ovf, _res = sorted_group_reduce(
+            [jnp.asarray(k) for k in keys], jnp.asarray(live), maxg,
+            [(jnp.asarray(v), jnp.asarray(c), kind) for v, c, kind in aggs])
+        assert bool(ovf)
+        maxg *= 2       # the caller's retry: another salt, no collision
     rep, ng, ovf, res = sorted_group_reduce(
         [jnp.asarray(k) for k in keys], jnp.asarray(live), maxg,
         [(jnp.asarray(v), jnp.asarray(c), kind) for v, c, kind in aggs])
@@ -437,3 +471,36 @@ def test_integer_sum_never_wraps_input_dtype(rng):
         [jnp.asarray(v32)], [14], [contrib], gids, 2
     )
     assert s3.dtype == jnp.int64 and int(s3[0]) == want and not bool(of)
+
+
+@pytest.mark.parametrize("ncols", [3, 12])
+def test_gather_columns_one_by_one_or_as_rows(rng, ncols):
+    """More than ROW_GATHER_COLUMNS columns travel as one matrix of
+    32-bit words; either way each comes back as ``c[idx]`` with zeros
+    where ``idx`` is out of range, in its own dtype and shape."""
+    from presto_tpu.ops.groupby import ROW_GATHER_COLUMNS, gather_columns
+
+    assert (ncols > ROW_GATHER_COLUMNS) == (ncols == 12)
+    rows = 37
+    kinds = [np.int64, np.int32, np.int16, np.int8, np.bool_, np.float32,
+             np.float64, np.uint8]
+    cols = []
+    for i in range(ncols):
+        dt = kinds[i % len(kinds)]
+        if i % len(kinds) == 7:      # a BYTES column, width not of 4
+            cols.append(rng.integers(0, 256, (rows, 50)).astype(np.uint8))
+        elif dt is np.bool_:
+            cols.append(rng.random(rows) < 0.5)
+        elif np.issubdtype(dt, np.floating):
+            cols.append(rng.normal(size=rows).astype(dt))
+        else:
+            info = np.iinfo(dt)
+            cols.append(rng.integers(info.min, info.max, rows, dtype=dt))
+    idx = np.concatenate([rng.integers(0, rows, 20), [rows, rows + 5]])
+    got = gather_columns([jnp.asarray(c) for c in cols], jnp.asarray(idx))
+    inside = idx < rows
+    for c, g in zip(cols, got):
+        g = np.asarray(g)
+        assert g.dtype == c.dtype and g.shape == (len(idx), *c.shape[1:])
+        np.testing.assert_array_equal(g[inside], c[idx[inside]])
+        assert not g[~inside].any()

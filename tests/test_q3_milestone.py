@@ -223,3 +223,66 @@ def test_q3_bypass_sorts_its_live_rows(monkeypatch):
     np.testing.assert_array_equal(
         got["l_orderkey"].to_numpy().astype(np.int64),
         want["l_orderkey"].to_numpy())
+
+
+def test_topn_sorts_the_live_rows_bucket(monkeypatch):
+    """A TopN over a large input with few live rows is handed them
+    compacted to their capacity bucket (the sort's operand follows what
+    is live, where that at least halves the slots); a full input, and
+    one under ``SORT_COMPACT_SLOTS``, is left as it is — no count is
+    read. Either way the rows equal pandas."""
+    import presto_tpu.exec.local_planner as LP
+    from presto_tpu.runtime.metrics import REGISTRY
+    from presto_tpu.runtime.session import Session
+    from presto_tpu.spi import batch_capacity
+
+    conn = TpchConnector(sf=SF)
+    seen = []
+    real = TopNOperator.process
+
+    def spy(self, batch):
+        seen.append((batch.capacity, int(batch.count())))
+        return real(self, batch)
+
+    monkeypatch.setattr(TopNOperator, "process", spy)
+    s = Session({"tpch": conn}, properties={"result_cache_enabled": False})
+    sql = ("select l_orderkey, l_linenumber, l_extendedprice from lineitem "
+           "where l_quantity = 1 "
+           "order by l_extendedprice desc, l_orderkey, l_linenumber limit 5")
+
+    def moved(name, before):
+        return REGISTRY.snapshot().get(name, 0) - before.get(name, 0)
+
+    li = conn.table_pandas(
+        "lineitem", ["l_orderkey", "l_linenumber", "l_extendedprice",
+                     "l_quantity"])
+    want = li[li.l_quantity == 1].sort_values(
+        ["l_extendedprice", "l_orderkey", "l_linenumber"],
+        ascending=[False, True, True]).head(5)
+
+    def check(got):
+        assert got["l_orderkey"].tolist() == want["l_orderkey"].tolist()
+        assert got["l_linenumber"].tolist() == want["l_linenumber"].tolist()
+
+    # SF 0.01's lineitem is under the limit: nothing is read or moved
+    before = dict(REGISTRY.snapshot())
+    check(s.sql(sql))
+    assert moved("exec.topn.compacted", before) == 0
+    plain_reads = moved("exec.sync.reads", before)
+    ((cap, live),) = seen
+    assert cap > 2 * batch_capacity(live)
+
+    seen.clear()
+    monkeypatch.setattr(LP, "SORT_COMPACT_SLOTS", 1024)
+    before = dict(REGISTRY.snapshot())
+    check(s.sql(sql))
+    ((cap, live),) = seen
+    assert live > 5 and cap == batch_capacity(live)
+    assert moved("exec.topn.compacted", before) == 1
+    assert moved("exec.sync.reads", before) == plain_reads + 1
+
+    seen.clear()
+    got = s.sql("select p_partkey from part order by p_partkey limit 3")
+    assert moved("exec.topn.compacted", before) == 1   # full: left alone
+    assert seen[0][1] == conn.row_count("part")
+    assert got["p_partkey"].tolist() == [1, 2, 3]

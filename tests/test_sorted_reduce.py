@@ -111,12 +111,24 @@ def _bytes(strings, width):
     return out
 
 
-def test_sort_strategy_operator_equals_pandas(rng):
+@pytest.mark.parametrize("width,factor,folds", [
+    (16, 0, 3), (50, 0, 3), (16, 1, 2), (50, 8, 1)])
+def test_sort_strategy_operator_equals_pandas(rng, monkeypatch, width,
+                                              factor, folds):
     """Three batches with overlapping groups folded into one state: a
     nullable int key, a BYTES key wider than one 7-byte sort chunk, a
-    passenger, integer / decimal / float aggregates and dead rows."""
+    passenger, integer / decimal / float aggregates and dead rows. At
+    50 bytes the key is 16 sort words, over ``HASHED_KEY_WORDS``: the
+    sort is keyed by its hashes (``ops.groupby.sorted_group_reduce``).
+    Input is held until it has ``SORT_FOLD_FACTOR`` times the state's
+    slots: at 0 every batch is folded as it comes, at 1 the first two
+    together (96 slots against 64) and the third by ``finish``, at 8
+    all three by ``finish``."""
+    from presto_tpu.exec import operators
+
+    monkeypatch.setattr(operators, "SORT_FOLD_FACTOR", factor)
     names = ["alpha", "alphabet soup", "alphabet soap", "b", "beta-gamma-delta"]
-    width, g, cap = 16, 64, 48
+    g, cap = 64, 48
     op = HashAggregationOperator(
         [("k", col("k", INTEGER)), ("name", col("name", fixed_bytes(width)))],
         [AggSpec("sum", col("v", dec2), "s", decimal(38, 2)),
@@ -152,12 +164,12 @@ def test_sort_strategy_operator_equals_pandas(rng):
         frames.append(pd.DataFrame({
             "k": np.where(k_ok, k, -1)[keep], "name": which[keep],
             "v": np.where(v_ok, v, 0)[keep], "v_ok": v_ok[keep], "f": f[keep]}))
+    (out,) = op.finish()
     after = REGISTRY.snapshot()
     assert (after["agg.strategy.sorted_reduce"]
-            - before.get("agg.strategy.sorted_reduce", 0)) == 3
+            - before.get("agg.strategy.sorted_reduce", 0)) == folds
     assert (after["agg.strategy.sort_rows"]
-            - before.get("agg.strategy.sort_rows", 0)) == 3 * (g + cap)
-    (out,) = op.finish()
+            - before.get("agg.strategy.sort_rows", 0)) == folds * g + 3 * cap
     got = out.to_pandas(logical=False)
     df = pd.concat(frames)
     want = df.groupby(["k", "name"]).agg(
@@ -190,3 +202,39 @@ def test_sort_strategy_operator_equals_pandas(rng):
             assert pd.isna(got["lo"][i])
         # the passenger rides with its group's representative
         assert int(got["pax"][i]) == k * 1000 + nm
+
+
+def test_a_hash_collision_is_an_overflow_and_the_retry_resalts(monkeypatch):
+    """Wide keys are sorted by their hashes. Distinct keys under equal
+    hashes are never grouped together: the state's ``overflow`` flag
+    rises, ``finish`` raises the retryable ``CapacityOverflow``, and the
+    executor's retry at twice the capacity hashes with another salt."""
+    from presto_tpu.exec.operators import CapacityOverflow
+    from presto_tpu.ops import groupby
+
+    g, cap = 32, 24
+    real = groupby._hash_rows
+    monkeypatch.setattr(groupby, "_hash_rows", lambda w, salt: [
+        h * np.uint32(salt != g) for h in real(w, salt)])
+    names = [f"{i:02d} of a product name that is fifty bytes wide"
+             for i in range(6)]
+    which = np.arange(cap) % len(names)
+    batch = Batch.from_numpy(
+        {"name": _bytes([names[i] for i in which], 50),
+         "v": np.arange(cap, dtype=np.int64)},
+        {"name": fixed_bytes(50), "v": dec2})
+
+    def run(max_groups):
+        op = HashAggregationOperator(
+            [("name", col("name", fixed_bytes(50)))],
+            [AggSpec("sum", col("v", dec2), "s", decimal(38, 2))],
+            SortStrategy(max_groups))
+        op.process(batch)
+        return op.finish()
+
+    with pytest.raises(CapacityOverflow):
+        run(g)
+    (out,) = run(2 * g)
+    got = out.to_pandas(logical=False)
+    want = pd.Series(np.arange(cap)).groupby(which).sum()
+    assert sorted(got["s"].astype(np.int64)) == sorted(want)
