@@ -522,6 +522,8 @@ class LookupJoinOperator(Operator):
             )
             self._record_strategy(
                 "dense" if self.build.dense_side is not None else "unique")
+            # what the probe pays for, live or not (a static shape)
+            REGISTRY.counter("exec.probe.slots").add(batch.capacity)
             with trace_span(f"step:probe_{self.join_type}", "step"):
                 return [self._step(side, self.build.payload, batch,
                                    self._params)]

@@ -863,7 +863,16 @@ class LocalExecutor(OomLadderMixin):
         return int(iv[1])
 
     @staticmethod
-    def _dense_domain(iv, right_batches):
+    def _build_rows(iv, right_batches):
+        """The build side's live rows, read where a declared key
+        interval gives them a use (the dense-table choice, and whether
+        the probe side is worth compacting); None without one."""
+        if iv is None:
+            return None
+        return sum(live_count(b) for b in right_batches)
+
+    @staticmethod
+    def _dense_domain(iv, rows):
         """(key_min, domain) when the stats interval is tight enough
         for a dense direct-address table — the planner's stats-driven
         probe-kernel choice (one gather vs a probe-side sort). None
@@ -871,7 +880,6 @@ class LocalExecutor(OomLadderMixin):
         if iv is None:
             return None
         domain = iv[1] - iv[0] + 1
-        rows = sum(live_count(b) for b in right_batches)
         # < 2^31: the probe gathers with int32 indices (ops/join.py —
         # a wider domain would wrap the index and silently mis-match)
         if 0 < domain <= min(max(1 << 20, 16 * rows), (1 << 31) - 1):
@@ -1013,6 +1021,98 @@ class LocalExecutor(OomLadderMixin):
                     REGISTRY.histogram("join.filter_selectivity").add(
                         1.0 - pruned / n_in)
 
+    # ---- probe-side compaction -------------------------------------------
+    @staticmethod
+    def _probe_side_sparse(node, iv, build_rows) -> bool:
+        """May the probe side of this inner/semi unique join have lost
+        most of its rows before the first probe? Decided from what the
+        host already holds, so a join that cannot gain pays no read:
+        the probe side is a scan under a Filter/Project chain
+        (``joinfilters.filter_edge_for``: where the runtime filter
+        lands, once a join chain), and either a predicate sits on that
+        chain or the build's live rows, read for the dense table, are
+        under half the key's declared domain (the runtime filter then
+        prunes the rest). How sparse it IS, ``_compact_probe_side``
+        reads."""
+        from presto_tpu.plan.joinfilters import filter_edge_for
+
+        if filter_edge_for(node) is None:
+            return False
+        n = node.left
+        while not isinstance(n, N.TableScan):
+            if isinstance(n, N.Filter):
+                return True
+            n = n.child
+        if n.predicate is not None:
+            return True
+        return iv is not None and 2 * build_rows < iv[1] - iv[0] + 1
+
+    def _compact_probe_side(self, left: BatchStream) -> BatchStream:
+        """The probe side of a join chain, compacted to its live rows
+        before the first probe: a probe gathers over every slot of its
+        batch, live or not, and so does every join and the aggregation
+        above it.
+
+        Batches are drawn into groups of ``SORT_COMPACT_SLOTS`` slots.
+        A group's live rows are counted on the device as soon as it is
+        drawn and read once (``sync:live_count``), AFTER the next group
+        is drawn — its uploads, scan filters and count dispatched — so
+        the count is an early, small result that waits for none of the
+        probes, and the device has work queued while the host reads.
+        ``_compact_large``'s rule then decides: where the live rows'
+        capacity bucket at least halves the slots the group becomes ONE
+        batch of that bucket, moved by one row gather
+        (``operators.compact_rows``), else it passes as it is — and if
+        that is the stream's first group, the rest of the stream is not
+        read (the selectivity is the predicate's: a later, sparser
+        group is a missed saving, never a wrong answer). A stream that
+        ends under the limit is read nowhere. All of it runs inside the
+        stream's generator: a replay redoes it."""
+        from presto_tpu.exec.operators import compact_rows, live_rows
+        from presto_tpu.runtime.metrics import REGISTRY
+        from presto_tpu.runtime.trace import span as trace_span
+
+        def draw(it, trailing):
+            group, slots = [], 0
+            for b in it:
+                group.append(b)
+                slots += b.capacity
+                if slots >= SORT_COMPACT_SLOTS:
+                    break
+            # counted as soon as it is drawn: a stream that has been
+            # compacting reads its trailing, smaller group too
+            full = slots >= SORT_COMPACT_SLOTS or (trailing and bool(group))
+            return group, slots, live_rows(group) if full else None
+
+        def make():
+            it = iter(left)
+            group, slots, count = draw(it, False)
+            first = True
+            while count is not None:
+                ahead = draw(it, True)
+                with trace_sync("live_count"):
+                    rows = int(count)
+                cap = batch_capacity(max(rows, 16))
+                if 2 * cap <= slots:
+                    with trace_span("step:probe_compact", "step",
+                                    {"slots_in": slots, "slots_out": cap}):
+                        group = [compact_rows(group, cap)]
+                    REGISTRY.counter("exec.probe.compacted").add()
+                    REGISTRY.counter("exec.probe.compact_slots_in").add(slots)
+                    REGISTRY.counter("exec.probe.compact_slots_out").add(cap)
+                else:
+                    REGISTRY.counter("exec.probe.compact_skipped").add()
+                    if first:
+                        group += ahead[0]
+                        break
+                first = False
+                yield from group
+                group, slots, count = ahead
+            yield from group
+            yield from it
+
+        return BatchStream(make)
+
     def _exec_join(self, node: N.Join, scalars):
         fslot = self._register_join_filter(node)
         left = self._exec(node.left, scalars)
@@ -1075,10 +1175,11 @@ class LocalExecutor(OomLadderMixin):
             )
         iv = (self._build_key_interval(node.right, node.right_keys)
               if node.unique else None)
+        rows = self._build_rows(iv, right)
         # dense/packed only help the UNIQUE probe; other probe kinds
         # would pay the advisory-stats refusal for no benefit
         build = JoinBuildOperator(
-            rkey, dense_domain=self._dense_domain(iv, right),
+            rkey, dense_domain=self._dense_domain(iv, rows),
             key_max=self._key_upper_bound(iv) if node.unique else None,
             filter_bits=self._filter_bits(node.right) if fslot else 0,
             params=self.params)
@@ -1091,6 +1192,8 @@ class LocalExecutor(OomLadderMixin):
         if node.unique:
             op = LookupJoinOperator(build, lkey, outs, node.kind, unique=True,
                                     verify=verify, params=self.params)
+            if self._probe_side_sparse(node, iv, rows):
+                left = self._compact_probe_side(left)
             return left.map(lambda b: op.process(b)[0])
         probe = self._retrying_expand_probe(
             build, lkey, outs, node.kind, right,
@@ -1405,13 +1508,16 @@ class LocalExecutor(OomLadderMixin):
         # allow; the packed build would be dead weight (probe_exists
         # has no packed path)
         iv = self._build_key_interval(node.right, node.right_keys)
+        rows = self._build_rows(iv, right)
         build = JoinBuildOperator(
-            rkey, dense_domain=self._dense_domain(iv, right),
+            rkey, dense_domain=self._dense_domain(iv, rows),
             filter_bits=self._filter_bits(node.right) if fslot else 0,
             params=self.params)
         Pipeline(BatchSource(right), [build]).run()
         self._fill_join_filter(fslot, build, node.right, rkey)
         op = LookupJoinOperator(build, lkey, (), jt, params=self.params)
+        if self._probe_side_sparse(node, iv, rows):
+            left = self._compact_probe_side(left)
         return left.map(lambda b: op.process(b)[0])
 
     def _exec_grouped_semijoin(self, node: N.SemiJoin, left, right_stream,

@@ -926,6 +926,51 @@ def compact_batches(batches: Sequence[Batch], out_cap: int) -> Batch:
     return step(tuple(batches))
 
 
+def live_rows(batches: Sequence[Batch]):
+    """The live rows of ``batches`` together, a device scalar (one
+    dispatch; the caller reads it when it has to)."""
+    from presto_tpu.cache.exec_cache import EXEC_CACHE, trace_probe
+
+    def probe_live_count_step(lives):
+        trace_probe()
+        return sum(jnp.sum(m.astype(jnp.int32)) for m in lives)
+
+    step = EXEC_CACHE.get_or_build(
+        EXEC_CACHE.key_of("probe_live_count"),
+        lambda: jax.jit(probe_live_count_step))
+    return step(tuple(b.live for b in batches))
+
+
+def compact_rows(batches: Sequence[Batch], out_cap: int) -> Batch:
+    """The live rows of ``batches`` as ONE batch of capacity
+    ``out_cap`` (caller guarantees total live_count <= out_cap), moved
+    ONCE: every column's data and ``valid`` mask laid side by side as
+    32-bit words and gathered as one matrix of rows by
+    ``compact_indices``' permutation — a gather costs by the index, not
+    by the row's width, where ``compact_batch`` pays two a column."""
+    from presto_tpu.cache.exec_cache import EXEC_CACHE, trace_probe
+    from presto_tpu.ops.compact import compact_indices
+    from presto_tpu.ops.groupby import gather_columns
+
+    def probe_compact_step(batches):
+        trace_probe()
+        b = concat_batches(list(batches))
+        idx, n, _ = compact_indices(b.live, out_cap)
+        cols = list(b.columns.values())
+        moved = gather_columns([c.data for c in cols]
+                               + [c.valid for c in cols], idx, as_rows=True)
+        return Batch(
+            {name: Column(data, valid, c.dtype, c.dictionary)
+             for name, c, data, valid in zip(b.names, cols, moved,
+                                             moved[len(cols):])},
+            jnp.arange(out_cap, dtype=jnp.int32) < n)
+
+    step = EXEC_CACHE.get_or_build(
+        EXEC_CACHE.key_of("probe_compact", out_cap),
+        lambda: jax.jit(probe_compact_step))
+    return step(tuple(batches))
+
+
 def union_target_dicts(names, sample_batches):
     """Per-column target dictionaries for a UNION: where children carry
     different dictionaries for the same column, the target is their

@@ -471,6 +471,24 @@ METRIC_HELP: dict[str, str] = {
         "TopN inputs of 2^20 slots or more compacted to their live "
         "rows' capacity bucket before the sort (where that at least "
         "halves the slots; the count is one sync:live_count read)"),
+    "exec.probe.slots": (
+        "row slots the unique, semi and anti join probes gathered over: "
+        "the capacity of every batch handed to a probe step, live or "
+        "not (static shapes, no device read)"),
+    "exec.probe.compacted": (
+        "groups of 2^20 slots of a join chain's probe side compacted "
+        "to ONE batch of their live rows' capacity bucket before the "
+        "first probe (one sync:live_count read a group, one row gather)"),
+    "exec.probe.compact_skipped": (
+        "probe-side groups read and left as they were (the bucket "
+        "would not halve the slots; a stream's FIRST group left alone "
+        "ends that stream's reads)"),
+    "exec.probe.compact_slots_in": (
+        "row slots of the compacted probe-side groups before "
+        "the compaction"),
+    "exec.probe.compact_slots_out": (
+        "row slots of the compacted probe-side groups after it (the "
+        "live rows' capacity buckets)"),
     "exec.union.inputs": (
         "branch streams of the executed UNION ALLs (a nested union is "
         "not a branch: its own leaves are counted); a grouping-set "

@@ -197,3 +197,61 @@ def test_strings_kernel_compiles(one_chip, kind, pattern, width):
              ((1 << 17, width), jnp.uint8),
              kernel="strings_like" if kind == "like"
              else "strings_starts_with")
+
+
+# the probe side's compaction (exec/operators.compact_rows: one sort of
+# the positions, one row gather) and a dense probe over what it leaves,
+# at the shapes SSB Q2.1 (8 lineorder splits -> 262,144 slots) and
+# TPC-H Q3 (one lineitem split -> 65,536) hand them at SF1. No Pallas
+# kernel on this path: the programs are XLA's alone
+@pytest.mark.parametrize("splits,cap,out_cap,dtypes", [
+    (8, 1 << 17, 1 << 18, (jnp.int16, jnp.int16, jnp.int32, jnp.int32)),
+    (1, SCAN_CAP, 1 << 16, (jnp.int32, jnp.int32, jnp.int8, jnp.int16)),
+], ids=["ssb_q2_1", "tpch_q3"])
+def test_probe_compaction_and_a_compacted_dense_probe_compile(
+        one_chip, splits, cap, out_cap, dtypes):
+    from types import SimpleNamespace
+
+    from presto_tpu.exec.joins import BuildOutput, LookupJoinOperator
+    from presto_tpu.exec.operators import compact_rows
+    from presto_tpu.expr import col
+    from presto_tpu.ops.join import DenseSide
+
+    names = [f"c{i}" for i in range(len(dtypes))]
+    width = 2 * len(names) + 1      # data, valid ..., live: one batch
+
+    def batches_of(arrs):
+        return [Batch({n: Column(b[2 * i], b[2 * i + 1], BIGINT)
+                       for i, n in enumerate(names)}, b[-1])
+                for b in (arrs[j:j + width]
+                          for j in range(0, len(arrs), width))]
+
+    def compact(*arrs):
+        return compact_rows(batches_of(arrs), out_cap)
+
+    one = [s for dt in dtypes for s in (((cap,), dt), ((cap,), jnp.bool_))]
+    one.append(((cap,), jnp.bool_))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in one * splits]
+    text = jax.jit(compact).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in text
+
+    domain, build_cap = 1 << 21, 1 << 20
+    op = LookupJoinOperator(
+        SimpleNamespace(dense_side=True, pack_bits=None, long_dup_runs=False),
+        col("c0", BIGINT), [BuildOutput("p", "p")], "inner", unique=True)
+    op._ensure_step()
+
+    def probe(table, payload, payload_valid, build_live, *arrs):
+        side = DenseSide(table, jnp.int64(1), jnp.int32(build_cap),
+                         jnp.int32(0), jnp.bool_(False))
+        build = Batch({"p": Column(payload, payload_valid, BIGINT)},
+                      build_live)
+        return op._step(side, build, batches_of(arrs)[0], ())
+
+    one = [(((out_cap,) + s[1:]), d) for s, d in one]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in [
+        ((domain,), jnp.int32), ((build_cap,), jnp.int16),
+        ((build_cap,), jnp.bool_), ((build_cap,), jnp.bool_)] + one]
+    text = jax.jit(probe).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in text and "gather" in text
