@@ -106,8 +106,9 @@ class trace_delta:
 class CacheEntry:
     """One cached step plus its ledger row (see module docstring)."""
 
-    __slots__ = ("value", "kind", "key", "hits", "calls", "created_at",
-                 "last_used", "cold_call_s", "warm_call_s", "_lock")
+    __slots__ = ("value", "kind", "key", "hits", "calls", "total_call_s",
+                 "created_at", "last_used", "cold_call_s", "warm_call_s",
+                 "_lock")
 
     def __init__(self, value, kind: str, key: str):
         self.value = value
@@ -117,6 +118,9 @@ class CacheEntry:
         self.hits = 0
         #: invocations of the (callable) entry
         self.calls = 0
+        #: host seconds inside those invocations, the cold one included
+        #: (which step family the host's dispatch time goes to)
+        self.total_call_s = 0.0
         self.created_at = time.time()
         self.last_used = self.created_at
         #: SLOWEST invocation wall observed — jit is lazy, so the
@@ -143,6 +147,7 @@ class CacheEntry:
     def record_call(self, wall_s: float) -> None:
         with self._lock:
             self.calls += 1
+            self.total_call_s += wall_s
             self.last_used = time.time()
             if wall_s > self.cold_call_s:
                 self.cold_call_s = wall_s
@@ -157,6 +162,7 @@ class CacheEntry:
                 "key": self.key,
                 "hits": self.hits,
                 "calls": self.calls,
+                "total_call_s": round(self.total_call_s, 6),
                 "cold_call_s": round(max(self.cold_call_s, 0.0), 6),
                 "warm_call_s": round(max(self.warm_call_s, 0.0), 6),
                 "compile_s_saved": round(self.compile_s_saved, 6),
@@ -172,18 +178,33 @@ class _TimedStep:
     keyed on the identity of the UNDERLYING jitted callable, which
     every call reaches — behaves exactly as before. Exceptions
     (capacity overflows, injected faults) pass through untimed: a
-    failed dispatch's wall is not a compile-cost observation."""
+    failed dispatch's wall is not a compile-cost observation.
+
+    Every cached jitted step is called through here and nowhere else,
+    so this is also where the process counts its dispatches:
+    ``exec.dispatch.calls`` and ``exec.dispatch.seconds`` (the host's
+    time inside the call — argument handling, the signature cache, the
+    enqueue, and whatever the runtime makes the caller wait for; not
+    the device's time, which a later read waits for) from the same two
+    clock reads. An eager ``jnp`` operation outside any step is not a
+    call through here: the spans around it name it."""
 
     __slots__ = ("_fn", "_meta")
 
-    def __init__(self, fn, meta: CacheEntry):
+    def __init__(self, fn, meta: Optional[CacheEntry]):
+        #: ``meta`` None: an uncacheable step, counted and timed but
+        #: with no ledger row to record into
         self._fn = fn
         self._meta = meta
 
     def __call__(self, *args, **kwargs):
         t0 = time.perf_counter()
         out = self._fn(*args, **kwargs)
-        self._meta.record_call(time.perf_counter() - t0)
+        wall_s = time.perf_counter() - t0
+        if self._meta is not None:
+            self._meta.record_call(wall_s)
+        REGISTRY.counter("exec.dispatch.calls").add()
+        REGISTRY.counter("exec.dispatch.seconds").add(wall_s)
         return out
 
     def __getattr__(self, name):
@@ -244,7 +265,12 @@ class ExecutableCache:
 
         if key is None:
             REGISTRY.counter("exec_cache.uncacheable").add()
-            return builder()
+            built = builder()
+            if callable(built) and not isinstance(built, type):
+                # no entry to keep (no ledger row), but a dispatch
+                # like any other for the two counters
+                built = _TimedStep(built, None)
+            return built
         with self._lock:
             entry = self._entries.get(key)
             if entry is not None:

@@ -162,6 +162,9 @@ SCHEMAS: dict[str, dict[str, DataType]] = {
         "key": fixed_bytes(96),
         "hits": BIGINT,
         "calls": BIGINT,
+        # host seconds inside those calls (exec.dispatch.seconds by
+        # entry: which step family the host's dispatch time goes to)
+        "total_call_s": DOUBLE,
         "cold_call_s": DOUBLE,
         "warm_call_s": DOUBLE,
         "compile_s_saved": DOUBLE,
@@ -197,9 +200,10 @@ SCHEMAS: dict[str, dict[str, DataType]] = {
         "queued_queries": BIGINT,
     },
     # live per-device telemetry (runtime/devices.py): allocator
-    # watermarks from jax Device.memory_stats() plus the process
-    # dispatch wall-clock ledger; rows appear on every backend (zeros
-    # where the platform reports no allocator stats, e.g. CPU)
+    # watermarks from jax Device.memory_stats() plus the process's
+    # jitted-step calls and the host seconds inside them
+    # (exec.dispatch.calls / .seconds); rows appear on every backend
+    # (zeros where the platform reports no allocator stats, e.g. CPU)
     "device_stats": {
         "device_id": fixed_bytes(16),
         "platform": fixed_bytes(16),
@@ -251,6 +255,9 @@ SCHEMAS: dict[str, dict[str, DataType]] = {
         "category": fixed_bytes(12),
         "start_s": DOUBLE,
         "duration_s": DOUBLE,
+        # duration_s minus what the span's children cover
+        # (TraceRecorder.self_times)
+        "self_s": DOUBLE,
         "plan_node_id": BIGINT,
         "trace_token": fixed_bytes(32),
     },
@@ -418,6 +425,7 @@ class SystemConnector:
                 [r["key"] for r in rows],
                 [r["hits"] for r in rows],
                 [r["calls"] for r in rows],
+                [r["total_call_s"] for r in rows],
                 [r["cold_call_s"] for r in rows],
                 [r["warm_call_s"] for r in rows],
                 [r["compile_s_saved"] for r in rows],
@@ -444,9 +452,8 @@ class SystemConnector:
                 [snap["queued_queries"]],
             )
         if table == "trace_spans":
-            qids, sids, pids_, names_, cats, starts, durs, nids, toks = (
-                [], [], [], [], [], [], [], [], []
-            )
+            (qids, sids, pids_, names_, cats, starts, durs, selfs, nids,
+             toks) = ([], [], [], [], [], [], [], [], [], [])
             for rec in self._session.traces.recorders():
                 # the ONE span-flattening projection, shared with the
                 # flight recorder (TraceRecorder.to_span_dicts)
@@ -458,10 +465,11 @@ class SystemConnector:
                     cats.append(d["cat"])
                     starts.append(d["start_s"])
                     durs.append(d["duration_s"])
+                    selfs.append(d["self_s"])
                     nids.append(int(d["args"].get("plan_node_id", -1)))
                     toks.append(rec.trace_token or "")
-            return (qids, sids, pids_, names_, cats, starts, durs, nids,
-                    toks)
+            return (qids, sids, pids_, names_, cats, starts, durs, selfs,
+                    nids, toks)
         if table == "runtime_nodes":
             import jax
 
@@ -610,13 +618,14 @@ class SystemConnector:
                 "pool_reserved_bytes": np.asarray(poolb, np.int64),
             }
         elif table == "exec_cache":
-            (kind, key, hits, calls, cold, warm, saved, age,
+            (kind, key, hits, calls, total, cold, warm, saved, age,
              idle) = rows
             arrays = {
                 "kind": _bytes_col(kind, 24),
                 "key": _bytes_col(key, 96),
                 "hits": np.asarray(hits, np.int64),
                 "calls": np.asarray(calls, np.int64),
+                "total_call_s": np.asarray(total, np.float64),
                 "cold_call_s": np.asarray(cold, np.float64),
                 "warm_call_s": np.asarray(warm, np.float64),
                 "compile_s_saved": np.asarray(saved, np.float64),
@@ -692,7 +701,8 @@ class SystemConnector:
                 "reason": _bytes_col(reason, 24),
             }
         elif table == "trace_spans":
-            (qid, sid, pid, name, cat, start, dur, nid, tok) = rows
+            (qid, sid, pid, name, cat, start, dur, self_s, nid,
+             tok) = rows
             arrays = {
                 "query_id": _bytes_col(qid, 24),
                 "span_id": np.asarray(sid, np.int64),
@@ -701,6 +711,7 @@ class SystemConnector:
                 "category": _bytes_col(cat, 12),
                 "start_s": np.asarray(start, np.float64),
                 "duration_s": np.asarray(dur, np.float64),
+                "self_s": np.asarray(self_s, np.float64),
                 "plan_node_id": np.asarray(nid, np.int64),
                 "trace_token": _bytes_col(tok, 32),
             }

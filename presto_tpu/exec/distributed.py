@@ -307,9 +307,10 @@ class DistributedExecutor(OomLadderMixin):
             raise InternalError("top-level plan must be an Output node")
         from presto_tpu.plan.fragmenter import fragment_plan
 
-        self.fragment_info = fragment_plan(
-            plan, self.catalog, self.broadcast_limit,
-            self.join_build_budget)
+        with trace_span("plan:fragment", "planner"):
+            self.fragment_info = fragment_plan(
+                plan, self.catalog, self.broadcast_limit,
+                self.join_build_budget)
         if self.recorder is not None:
             self.recorder.attach_plan(plan)
         # query-scoped join-key min/max memo (see exec/joinkeys.py)
@@ -392,7 +393,8 @@ class DistributedExecutor(OomLadderMixin):
     def _replicate(self, d: DistBatch, guard: str | None = None,
                    rows_hint: int | None = None) -> DistBatch:
         """Reshard rows -> fully replicated (the gather/broadcast
-        exchange; XLA lowers the resharding copy to an all_gather).
+        exchange: ``jax.device_put`` to the replicated sharding, which
+        on the chip copies through the host — ``sync:gather_replicate``).
 
         ``guard``: name of the replicate-everything fallback invoking
         this (window/sort/topN/limit v1 paths) — enforces
@@ -424,7 +426,11 @@ class DistributedExecutor(OomLadderMixin):
         with exchange_dispatch(
                 "gather" if guard is None else f"gather:{guard}",
                 self.nworkers, "gather") as ex:
-            b = jax.device_put(b, replicated(self.mesh))
+            # on the chip this resharding goes through the host: every
+            # array of the batch is read back, then put on each device
+            # (scripts/audit_device_reads.py, PR 37) — a read like any
+            with trace_sync("gather_replicate"):
+                b = jax.device_put(b, replicated(self.mesh))
             ex["bytes"] = gather_wire_bytes(
                 batch_row_bytes(b), b.capacity, self.nworkers)
         return DistBatch(b, sharded=False)
@@ -477,17 +483,20 @@ class DistributedExecutor(OomLadderMixin):
         return DistBatch(out, sharded=True), rows
 
     # ---- exchange-skew telemetry -----------------------------------------
-    def _note_exchange_skew(self, site: str, node, dest, row_bytes: int):
+    def _note_exchange_skew(self, site: str, node, dest, row_bytes: int,
+                            part: int | None = None):
         """Bank one exchange's per-destination device histogram for the
-        end-of-run flush (NEVER a readback here — this sits on the
-        dispatch hot path)."""
-        self._skew_accum.append((site, node, dest, int(row_bytes)))
+        end-of-run flush (NEVER a readback here, nor any device
+        operation — this sits on the dispatch hot path). ``part``
+        picks a row of a stacked histogram, on the host, at the flush."""
+        self._skew_accum.append((site, node, dest, int(row_bytes), part))
 
     def _hot_partition(self, dest) -> int:
         """Hottest destination id of an overflowed exchange (the ONE
         readback the overflow path already pays before recompiling at
         doubled capacity); recorded for post-mortems + metrics."""
-        counts = np.asarray(dest)
+        with trace_sync("hot_partition"):
+            counts = np.asarray(dest)
         hot = int(np.argmax(counts)) if counts.size else -1
         self.hot_partitions.append(hot)
         return hot
@@ -502,27 +511,33 @@ class DistributedExecutor(OomLadderMixin):
         from presto_tpu.runtime.metrics import REGISTRY
 
         summaries = []
-        for site, node, dest, row_bytes in self._skew_accum:
-            try:
-                counts = np.asarray(dest)
-            except Exception:  # noqa: BLE001 — a failed run's buffers
-                continue  # may be poisoned; telemetry never raises
-            rows = int(counts.sum())
-            if rows <= 0:
-                continue
-            ratio = skew_ratio(counts)
-            REGISTRY.counter(f"exchange.rows.{site}").add(rows)
-            REGISTRY.histogram("exchange.skew").add(ratio)
-            summaries.append({
-                "site": site,
-                "rows": rows,
-                "bytes": rows * row_bytes,
-                "skew": round(ratio, 3),
-                "hot_partition": int(np.argmax(counts)),
-            })
-            if node is not None and self.recorder is not None:
-                self.recorder.record_skew(node, ratio, rows,
-                                          hot=int(np.argmax(counts)))
+        # a run of short reads, one an exchange: ONE span owns the gap
+        # of the device they make together
+        with trace_span("flush:exchange_skew", "step"):
+            for site, node, dest, row_bytes, part in self._skew_accum:
+                try:
+                    with trace_sync("exchange_skew"):
+                        counts = np.asarray(dest)
+                except Exception:  # noqa: BLE001 — a failed run's buffers
+                    continue  # may be poisoned; telemetry never raises
+                if part is not None:
+                    counts = counts[part]
+                rows = int(counts.sum())
+                if rows <= 0:
+                    continue
+                ratio = skew_ratio(counts)
+                REGISTRY.counter(f"exchange.rows.{site}").add(rows)
+                REGISTRY.histogram("exchange.skew").add(ratio)
+                summaries.append({
+                    "site": site,
+                    "rows": rows,
+                    "bytes": rows * row_bytes,
+                    "skew": round(ratio, 3),
+                    "hot_partition": int(np.argmax(counts)),
+                })
+                if node is not None and self.recorder is not None:
+                    self.recorder.record_skew(node, ratio, rows,
+                                              hot=int(np.argmax(counts)))
         self._skew_accum.clear()
         self.exchange_skew = summaries
 
@@ -573,79 +588,85 @@ class DistributedExecutor(OomLadderMixin):
         data_shards: dict[str, list] = {c: [] for c in src_cols}
         valid_shards: dict[str, list] = {c: [] for c in src_cols}
         live_shards: list = []
-        for d, sp in enumerate(assign):
-            if devices[d].process_index != proc:
-                continue
+        # every device's shard of the table, looked up (or made) and
+        # uploaded one after another: the device has nothing to do until
+        # they are in, and no single upload covers that gap
+        with trace_span("scan:shards", "scan", {"table": node.table}):
+            for d, sp in enumerate(assign):
+                if devices[d].process_index != proc:
+                    continue
 
-            def make(cols, sp=sp) -> HostColumns:
-                # streamed per-split scan (round-4 VERDICT ask #3): each
-                # split's arrays are generated, written into the padded
-                # transfer buffer and dropped before the next split is
-                # touched — peak host allocation beyond the buffer itself
-                # is ONE split, not the whole shard plus a concat copy
-                # the local tier's three scan spans (Batch.from_numpy), per
-                # device shard: batch:pad is the zero-filled buffers here
-                # and each split's copy into them below
-                padded = {}
-                vmasks = {}
-                with trace_span("batch:pad", "scan"):
-                    for c in cols:
-                        t = types[c]
-                        tail = (t.width,) if t.kind is TypeKind.BYTES else ()
-                        padded[c] = np.zeros((cap_dev,) + tail,
-                                             dtype=t.np_dtype)
-                        vmasks[c] = np.zeros(cap_dev, np.bool_)
-                rows = 0
-                for s in sp:
-                    # per-split deadline boundary, matching the local
-                    # tier's scan loop — a long multi-split scan must
-                    # notice an expired query_max_run_time between splits
-                    check_deadline("scan")
-                    arrays, valids = split_valids(
-                        generate_split(conn, s, cols))
-                    srows = len(next(iter(arrays.values()))) if arrays else 0
-                    if rows + srows > cap_dev:
-                        raise CapacityOverflow("TableScan shard", cap_dev,
-                                               rows + srows)
+                def make(cols, sp=sp) -> HostColumns:
+                    # streamed per-split scan (round-4 VERDICT ask #3): each
+                    # split's arrays are generated, written into the padded
+                    # transfer buffer and dropped before the next split is
+                    # touched — peak host allocation beyond the buffer itself
+                    # is ONE split, not the whole shard plus a concat copy
+                    # the local tier's three scan spans (Batch.from_numpy), per
+                    # device shard: batch:pad is the zero-filled buffers here
+                    # and each split's copy into them below
+                    padded = {}
+                    vmasks = {}
                     with trace_span("batch:pad", "scan"):
                         for c in cols:
-                            a = arrays.get(c)
-                            if a is not None:
-                                if a.ndim > 1:  # BYTES rows may be narrower
-                                    padded[c][rows : rows + srows,
-                                              : a.shape[1]] = a
-                                else:
-                                    check_narrow_range(c, types[c], a)
-                                    padded[c][rows : rows + srows] = a
-                            vm = valids.get(c)
-                            vmasks[c][rows : rows + srows] = (
-                                True if vm is None else vm)
-                    rows += srows
-                lv = np.zeros(cap_dev, np.bool_)
-                lv[:rows] = True
-                return HostColumns(padded, vmasks, lv, rows)
+                            t = types[c]
+                            tail = (t.width,) if t.kind is TypeKind.BYTES else ()
+                            padded[c] = np.zeros((cap_dev,) + tail,
+                                                 dtype=t.np_dtype)
+                            vmasks[c] = np.zeros(cap_dev, np.bool_)
+                    rows = 0
+                    for s in sp:
+                        # per-split deadline boundary, matching the local
+                        # tier's scan loop — a long multi-split scan must
+                        # notice an expired query_max_run_time between splits
+                        check_deadline("scan")
+                        arrays, valids = split_valids(
+                            generate_split(conn, s, cols))
+                        srows = len(next(iter(arrays.values()))) if arrays else 0
+                        if rows + srows > cap_dev:
+                            raise CapacityOverflow("TableScan shard", cap_dev,
+                                                   rows + srows)
+                        with trace_span("batch:pad", "scan"):
+                            for c in cols:
+                                a = arrays.get(c)
+                                if a is not None:
+                                    if a.ndim > 1:  # BYTES rows may be narrower
+                                        padded[c][rows : rows + srows,
+                                                  : a.shape[1]] = a
+                                    else:
+                                        check_narrow_range(c, types[c], a)
+                                        padded[c][rows : rows + srows] = a
+                                vm = valids.get(c)
+                                vmasks[c][rows : rows + srows] = (
+                                    True if vm is None else vm)
+                        rows += srows
+                    lv = np.zeros(cap_dev, np.bool_)
+                    lv[:rows] = True
+                    return HostColumns(padded, vmasks, lv, rows)
 
-            if store is None:
-                host = make(src_cols)
-            else:
-                shard = ("shard", node.table,
-                         tuple((s.chunk, s.lo, s.hi) for s in sp), cap_dev)
-                host = store.columns(
-                    shard, {c: shard + (c, types[c].np_dtype.str)
-                            for c in src_cols}, make)
-            count_delivered(len(sp), host.n)
-            with trace_span("batch:upload", "scan"):
-                for c in src_cols:
-                    data_shards[c].append(
-                        jax.device_put(host.padded[c], devices[d]))
-                    valid_shards[c].append(
-                        jax.device_put(host.masks[c], devices[d]))
-                live_shards.append(jax.device_put(host.live, devices[d]))
-            REGISTRY.counter("exec.h2d.arrays").add(2 * len(src_cols) + 1)
-            REGISTRY.counter("exec.h2d.bytes").add(
-                host.live.nbytes
-                + sum(host.padded[c].nbytes + host.masks[c].nbytes
-                      for c in src_cols))
+                if store is None:
+                    host = make(src_cols)
+                else:
+                    shard = ("shard", node.table,
+                             tuple((s.chunk, s.lo, s.hi) for s in sp), cap_dev)
+                    with trace_span("scan:lookup", "scan",
+                                    {"table": node.table}):
+                        host = store.columns(
+                            shard, {c: shard + (c, types[c].np_dtype.str)
+                                    for c in src_cols}, make)
+                count_delivered(len(sp), host.n)
+                with trace_span("batch:upload", "scan"):
+                    for c in src_cols:
+                        data_shards[c].append(
+                            jax.device_put(host.padded[c], devices[d]))
+                        valid_shards[c].append(
+                            jax.device_put(host.masks[c], devices[d]))
+                    live_shards.append(jax.device_put(host.live, devices[d]))
+                REGISTRY.counter("exec.h2d.arrays").add(2 * len(src_cols) + 1)
+                REGISTRY.counter("exec.h2d.bytes").add(
+                    host.live.nbytes
+                    + sum(host.padded[c].nbytes + host.masks[c].nbytes
+                          for c in src_cols))
 
         sh = row_sharding(self.mesh)
 
@@ -655,14 +676,16 @@ class DistributedExecutor(OomLadderMixin):
                 (n * cap_dev,) + tail, sh, pieces
             )
 
-        cols = {
-            c: Column(
-                assemble(data_shards[c]), assemble(valid_shards[c]),
-                types[c], dicts.get(c),
-            )
-            for c in src_cols
-        }
-        b = Batch(cols, assemble(live_shards))
+        # the per-device pieces as global arrays: host work, two a column
+        with trace_span("scan:assemble", "scan"):
+            cols = {
+                c: Column(
+                    assemble(data_shards[c]), assemble(valid_shards[c]),
+                    types[c], dicts.get(c),
+                )
+                for c in src_cols
+            }
+            b = Batch(cols, assemble(live_shards))
         rename = {s: nn for nn, s in node.columns}
         b = b.rename(rename)
         if node.predicate is not None:
@@ -867,19 +890,27 @@ class DistributedExecutor(OomLadderMixin):
                             {"quota": quota, "recv_cap": mgf}), \
                     exchange_dispatch("aggregate", Pn) as ex:
                 out, overflow, rounds, dest, exch_ovf = step(b, self.params)
-                done = not bool(overflow)
+                # the step's flags are the host's first read after its
+                # dispatch: it waits here for the whole program
+                with trace_sync("exchange_flags"):
+                    done = not bool(overflow)
+                    r = ex["rounds"] = int(np.asarray(rounds))
                 # exchanged rows are partial-agg group rows: the final
                 # output's columns plus one int64 merge-count per agg
                 row_b = batch_row_bytes(out) + 9 * len(aggs)
-                r = ex["rounds"] = int(np.asarray(rounds))
                 ex["bytes"] = a2a_wire_bytes(row_b, Pn, quota, r)
                 # hot-partition capture keys on the EXCHANGE receive
                 # overflow specifically — a partial/final group-capacity
                 # overflow retries through the same loop but is NOT
                 # skew, and must not plant a phantom hot partition in
                 # post-mortems
-                if not done and bool(exch_ovf):
-                    ex["hot_partition"] = self._hot_partition(dest)
+                if not done:
+                    # read on the retry path alone: a step that fitted
+                    # pays for the two flags above and no third
+                    with trace_sync("exchange_flags"):
+                        exch_ovf = bool(exch_ovf)
+                    if exch_ovf:
+                        ex["hot_partition"] = self._hot_partition(dest)
             if done:
                 self._note_exchange_skew("aggregate", node, dest, row_b)
                 return DistBatch(out, sharded=True)
@@ -1061,10 +1092,11 @@ class DistributedExecutor(OomLadderMixin):
             v = evaluate(key, b)
             data = v.data.astype(jnp.int64)
             live = b.live & v.valid
-            return (
-                int(jnp.min(jnp.where(live, data, 0))),
-                int(jnp.max(jnp.where(live, data, 0))),
-            )
+            with trace_sync("join_key_range"):
+                return (
+                    int(jnp.min(jnp.where(live, data, 0))),
+                    int(jnp.max(jnp.where(live, data, 0))),
+                )
 
         def runtime_dict(side: int, key):
             b = (left if side == 0 else right).batch
@@ -1090,7 +1122,9 @@ class DistributedExecutor(OomLadderMixin):
     def _exec_join(self, node: N.Join, scalars) -> DistBatch:
         left = self._exec(node.left, scalars)
         right = self._exec(node.right, scalars)
-        lkey, rkey, verify = self._join_key_exprs(node, left, right, scalars)
+        with trace_span("join:prepare", "step"):
+            lkey, rkey, verify = self._join_key_exprs(node, left, right,
+                                                      scalars)
         if verify and not node.unique and node.kind != "inner":
             raise NotImplementedError(
                 "wide string keys on non-unique OUTER joins (verification "
@@ -1363,10 +1397,11 @@ class DistributedExecutor(OomLadderMixin):
                     exchange_dispatch("join", Pn) as ex:
                 out, overflow, flags, rounds, dest = step(
                     left.batch, right.batch, self.params)
-                long_runs, sentinel, exch_ovf = (
-                    bool(x) for x in np.asarray(flags))
-                ok = not bool(overflow)
-                lr, rr = (int(x) for x in np.asarray(rounds))
+                with trace_sync("exchange_flags"):
+                    long_runs, sentinel, exch_ovf = (
+                        bool(x) for x in np.asarray(flags))
+                    ok = not bool(overflow)
+                    lr, rr = (int(x) for x in np.asarray(rounds))
                 ex["rounds"] = lr + rr
                 ex["bytes"] = (
                     a2a_wire_bytes(batch_row_bytes(left.batch), Pn, lquota,
@@ -1384,11 +1419,11 @@ class DistributedExecutor(OomLadderMixin):
                 # build-side: both exchanges shuffle on the SAME key
                 # hash, so a hot key shows up in each independently
                 self._note_exchange_skew(
-                    "join.probe", node, dest[0],
-                    batch_row_bytes(left.batch))
+                    "join.probe", node, dest,
+                    batch_row_bytes(left.batch), part=0)
                 self._note_exchange_skew(
-                    "join.build", node, dest[1],
-                    batch_row_bytes(right.batch))
+                    "join.build", node, dest,
+                    batch_row_bytes(right.batch), part=1)
             if long_runs:
                 raise NotImplementedError(
                     "hash-key collision run exceeds the verified probe's "
@@ -1641,12 +1676,16 @@ class DistributedExecutor(OomLadderMixin):
             EXEC_CACHE.key_of("dist_spill_bids", key, nbuckets),
             make_bids_step,
         )
-        bids = np.asarray(bids_step(b, self.params))
-        live = np.asarray(b.live)
-        cols = {
-            n: (np.asarray(c.data), np.asarray(c.valid), c.dtype, c.dictionary)
-            for n, c in b.columns.items()
-        }
+        bids = bids_step(b, self.params)
+        # the whole side comes to the host: the spill's own copy-out
+        with trace_sync("spill_copy_out"):
+            bids = np.asarray(bids)
+            live = np.asarray(b.live)
+            cols = {
+                n: (np.asarray(c.data), np.asarray(c.valid), c.dtype,
+                    c.dictionary)
+                for n, c in b.columns.items()
+            }
         return cols, live, bids
 
     def _place_sharded(self, cols: dict, sel: np.ndarray) -> Batch:
@@ -1889,7 +1928,8 @@ class DistributedExecutor(OomLadderMixin):
                               self._mesh_fp),
             make_bids_step,
         )(b, self.params)
-        counts = np.asarray(counts)  # [P, B]
+        with trace_sync("bucket_counts"):
+            counts = np.asarray(counts)  # [P, B]
         cap_pass = batch_capacity(max(int(counts.max()), 16), minimum=64)
 
         def make_filter_step():
@@ -1928,7 +1968,9 @@ class DistributedExecutor(OomLadderMixin):
     def _exec_semijoin(self, node: N.SemiJoin, scalars) -> DistBatch:
         left = self._exec(node.left, scalars)
         right = self._exec(node.right, scalars)
-        lkey, rkey, verify = self._join_key_exprs(node, left, right, scalars)
+        with trace_span("join:prepare", "step"):
+            lkey, rkey, verify = self._join_key_exprs(node, left, right,
+                                                      scalars)
         if verify:
             # existence probes have no build_row to verify against;
             # hash collisions could flip semi/anti membership
@@ -2028,8 +2070,9 @@ class DistributedExecutor(OomLadderMixin):
                             {"quota": quota, "recv_cap": rc}), \
                     exchange_dispatch("window", Pn) as ex:
                 out, overflow, rounds = step(b, self.params)
-                ok = not bool(overflow)
-                r = ex["rounds"] = int(np.asarray(rounds))
+                with trace_sync("exchange_flags"):
+                    ok = not bool(overflow)
+                    r = ex["rounds"] = int(np.asarray(rounds))
                 ex["bytes"] = a2a_wire_bytes(batch_row_bytes(b), Pn, quota, r)
             if ok:
                 return DistBatch(out, sharded=True)
@@ -2185,7 +2228,8 @@ class DistributedExecutor(OomLadderMixin):
                               self._mesh_fp),
             make_step,
         )
-        return DistBatch(step(b, self.params), sharded=True)
+        with trace_span("step:dist_topn", "step", {"cap_out": cap_out}):
+            return DistBatch(step(b, self.params), sharded=True)
 
     def _local_limit(self, d: DistBatch, n: int) -> DistBatch:
         from presto_tpu.ops.compact import compact_indices
@@ -2291,9 +2335,11 @@ class DistributedExecutor(OomLadderMixin):
                               self._mesh_fp),
             make_sample_step,
         )
-        samp, ok = sample(b, self.params)
-        samp = np.asarray(samp).reshape(-1)
-        ok = np.asarray(ok).reshape(-1)
+        with trace_span("step:dist_sort_sample", "step"):
+            samp, ok = sample(b, self.params)
+        with trace_sync("sort_sample"):
+            samp = np.asarray(samp).reshape(-1)
+            ok = np.asarray(ok).reshape(-1)
         pool = np.sort(samp[ok])
         if pool.size == 0:
             return d  # no live rows anywhere: nothing to sort
@@ -2317,8 +2363,9 @@ class DistributedExecutor(OomLadderMixin):
                             {"quota": quota, "recv_cap": rc}), \
                     exchange_dispatch("sort", Pn) as ex:
                 out, overflow, rounds = step(b, splitters, self.params)
-                ok = not bool(overflow)
-                r = ex["rounds"] = int(np.asarray(rounds))
+                with trace_sync("exchange_flags"):
+                    ok = not bool(overflow)
+                    r = ex["rounds"] = int(np.asarray(rounds))
                 ex["bytes"] = a2a_wire_bytes(batch_row_bytes(b), Pn, quota, r)
             if ok:
                 return DistBatch(out, sharded=True)
@@ -2388,11 +2435,13 @@ class DistributedExecutor(OomLadderMixin):
 
             raise UserError("scalar subquery returned more than one row")
         col = b[names[0] if names[0] in b else b.names[0]]
-        live = np.asarray(b.live)
-        idx = int(np.nonzero(live)[0][0])
-        if not bool(np.asarray(col.valid)[idx]):
+        with trace_sync("scalar_value"):
+            live = np.asarray(b.live)
+            idx = int(np.nonzero(live)[0][0])
+            valid = bool(np.asarray(col.valid)[idx])
+            raw = np.asarray(col.data)[idx] if valid else None
+        if not valid:
             return None
-        raw = np.asarray(col.data)[idx]
         return (
             col.dtype.from_physical(raw)
             if col.dtype.kind in (TypeKind.DECIMAL,)

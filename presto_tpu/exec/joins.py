@@ -31,7 +31,7 @@ from presto_tpu.exec.operators import (
     CapacityOverflow,
     CollectingOperator,
     Operator,
-    concat_batches,
+    held_concat,
 )
 from presto_tpu.expr import Expr, InputRef, evaluate, param_scope
 from presto_tpu.runtime.trace import span as trace_span
@@ -175,7 +175,7 @@ class JoinBuildOperator(CollectingOperator):
         if not self.batches:
             # empty build needs planner-synthesized payload schema
             raise RuntimeError("empty build side not yet supported")
-        batch = concat_batches(self.batches)
+        batch = held_concat(self.batches)
         cap = self.capacity or batch_capacity(batch.capacity, minimum=16)
         dd = self.dense_domain
 

@@ -784,6 +784,11 @@ def execute_leaf_route(route: LeafRoute, executor, node, scalars):
             )
         with trace_span("step:leaf_agg", "step"):
             s = step(b, *(() if bitmap is None else (bitmap,)))
+        # the split's device buffers are this loop's alone, so their
+        # release is here and has a name (in a lazy stream it falls to
+        # whichever frame drops the last reference)
+        with trace_span("batch:release", "scan"):
+            del b
         if state is None:
             state = s
         else:

@@ -47,6 +47,7 @@ from presto_tpu.exec.pipeline import BatchSource, BatchStream, Pipeline, ScanSou
 from presto_tpu.expr import BIGINT, Call, Expr, InputRef, Literal, bind_scalars
 from presto_tpu.plan import nodes as N
 from presto_tpu.plan.catalog import Catalog
+from presto_tpu.runtime.trace import span as trace_span
 from presto_tpu.runtime.trace import sync as trace_sync
 from presto_tpu.spi import batch_capacity
 from presto_tpu.types import TypeKind
@@ -194,6 +195,15 @@ def pick_group_strategy(keys, pax, dict_len, est_rows: int,
     return SortStrategy(batch_capacity(group_bound) if by_keys else g)
 
 
+def count_live_rows(batches) -> list[int]:
+    """The live rows of each batch, read one after another
+    (``sync:live_count`` each) under ONE span, ``count:live_rows``: a
+    run of short reads with the device idle between them is one gap of
+    the device, and no single read covers it."""
+    with trace_span("count:live_rows", "step", {"batches": len(batches)}):
+        return [live_count(b) for b in batches]
+
+
 class LocalExecutor(OomLadderMixin):
     #: the cross-query batched dispatcher (server/batcher.py) can stack
     #: this executor's param bindings into one vmapped dispatch — the
@@ -301,17 +311,18 @@ class LocalExecutor(OomLadderMixin):
         # the result's readback: the host waits in the first count for
         # whatever the last steps left running (sync:live_count), then
         # copies the live rows out (sync:result)
-        batches = [b for b in batches if live_count(b) > 0]
+        batches = [b for b, n in zip(batches, count_live_rows(batches))
+                   if n > 0]
         with trace_sync("result"):
             dfs = [b.to_pandas() for b in batches]
         if not dfs:
             return pd.DataFrame(columns=names)
-        return pd.concat(dfs, ignore_index=True)[list(names)]
+        with trace_span("result:frame", "step"):
+            return pd.concat(dfs, ignore_index=True)[list(names)]
 
     def run_batches(self, plan: N.Output):
         from presto_tpu.expr import param_scope
         from presto_tpu.runtime.lifecycle import run_fragment
-        from presto_tpu.runtime.trace import span as trace_span
 
         if self.recorder is not None:
             self.recorder.attach_plan(plan)
@@ -403,7 +414,6 @@ class LocalExecutor(OomLadderMixin):
             batch_device_bytes,
             batch_row_bytes,
         )
-        from presto_tpu.runtime.trace import span as trace_span
 
         m = getattr(self, f"_exec_{type(node).__name__.lower()}", None)
         if m is None:
@@ -498,11 +508,15 @@ class LocalExecutor(OomLadderMixin):
             for split in splits:
                 fault_point("scan")
                 check_deadline("scan")
-                b = conn.scan(split, src_cols, cap).rename(rename)
-                for op in ops:
-                    b = op.process(b)[0]
-                for slot in fslots:
-                    b = self._apply_join_filter(slot, b)
+                # one split's host work, under one name: the lookup, the
+                # upload, the dispatch of its filters — and the release
+                # of the split before it, whose last reference is `b`
+                with trace_span("scan:split", "scan"):
+                    b = conn.scan(split, src_cols, cap).rename(rename)
+                    for op in ops:
+                        b = op.process(b)[0]
+                    for slot in fslots:
+                        b = self._apply_join_filter(slot, b)
                 yield b
 
         return BatchStream(make)
@@ -610,12 +624,14 @@ class LocalExecutor(OomLadderMixin):
             # <= rows: overflow is impossible by construction)
             REGISTRY.counter("agg.strategy.bypass").add()
             batches = child.materialize()
-            rows = sum(live_count(b) for b in batches)
+            rows = sum(count_live_rows(batches))
             cap = batch_capacity(max(rows, 16))
             if batches:
                 from presto_tpu.exec.operators import compact_batches
 
-                child = BatchStream.of([compact_batches(batches, cap)])
+                with trace_span("step:bypass_compact", "step",
+                                {"slots_out": cap}):
+                    child = BatchStream.of([compact_batches(batches, cap)])
                 del batches
                 REGISTRY.counter("agg.strategy.bypass_compacted").add()
                 REGISTRY.counter("agg.strategy.sort_live_rows").add(rows)
@@ -699,7 +715,6 @@ class LocalExecutor(OomLadderMixin):
         from presto_tpu.ops.groupby import ValueBitsOverflow
         from presto_tpu.runtime.memory import node_row_bytes
         from presto_tpu.runtime.metrics import REGISTRY
-        from presto_tpu.runtime.trace import span as trace_span
 
         if any(e.dtype.kind is TypeKind.BYTES for _, e in keys):
             return None
@@ -869,7 +884,7 @@ class LocalExecutor(OomLadderMixin):
         the probe side is worth compacting); None without one."""
         if iv is None:
             return None
-        return sum(live_count(b) for b in right_batches)
+        return sum(count_live_rows(right_batches))
 
     @staticmethod
     def _dense_domain(iv, rows):
@@ -957,9 +972,14 @@ class LocalExecutor(OomLadderMixin):
             return b
         if b[slot.col].data.ndim != 1:
             return b  # defensive: bounds are over 1-D numeric domains
+        # the filter's host work, all of it: the step's lookup by its
+        # content key, the dispatch, the counts' accumulation
+        with trace_span("join_filter", "step", {"column": slot.col}):
+            return self._join_filter_step(slot, b, bounds)
+
+    def _join_filter_step(self, slot: JoinFilterSlot, b: Batch,
+                          bounds) -> Batch:
         from presto_tpu.cache.exec_cache import EXEC_CACHE, trace_probe
-        from presto_tpu.runtime.metrics import REGISTRY
-        from presto_tpu.runtime.trace import span as trace_span
 
         name = slot.col
         words = slot.bloom
@@ -987,13 +1007,13 @@ class LocalExecutor(OomLadderMixin):
             EXEC_CACHE.key_of("join_filter", name, words is not None),
             make,
         )
-        with trace_span("join_filter", "join", {"column": name}):
-            args = (bounds[0], bounds[1]) + ((words,) if words is not None
-                                             else ())
-            nb, n_in, pruned = step(b, *args)
+        args = (bounds[0], bounds[1]) + ((words,) if words is not None
+                                         else ())
+        nb, n_in, pruned = step(b, *args)
         # accumulate on DEVICE: an int() here would block the host on
         # every scan batch (one round-trip per morsel just for
-        # metrics); the single readback happens at query drain
+        # metrics); the single readback happens at query drain. Two
+        # eager additions a batch: dispatches of their own
         slot.stat_in = n_in if slot.stat_in is None else slot.stat_in + n_in
         slot.stat_pruned = (pruned if slot.stat_pruned is None
                             else slot.stat_pruned + pruned)
@@ -1070,7 +1090,6 @@ class LocalExecutor(OomLadderMixin):
         stream's generator: a replay redoes it."""
         from presto_tpu.exec.operators import compact_rows, live_rows
         from presto_tpu.runtime.metrics import REGISTRY
-        from presto_tpu.runtime.trace import span as trace_span
 
         def draw(it, trailing):
             group, slots = [], 0
@@ -1082,7 +1101,10 @@ class LocalExecutor(OomLadderMixin):
             # counted as soon as it is drawn: a stream that has been
             # compacting reads its trailing, smaller group too
             full = slots >= SORT_COMPACT_SLOTS or (trailing and bool(group))
-            return group, slots, live_rows(group) if full else None
+            if not full:
+                return group, slots, None
+            with trace_span("step:probe_live_count", "step"):
+                return group, slots, live_rows(group)
 
         def make():
             it = iter(left)
@@ -1164,25 +1186,30 @@ class LocalExecutor(OomLadderMixin):
         from presto_tpu.runtime.faults import fault_point
 
         fault_point("step.join_build")
-        lkey, rkey, verify = self._join_key_exprs(
-            node.left_keys, node.right_keys, left, right, scalars,
-            node.left, node.right,
-        )
-        if verify and not node.unique and node.kind != "inner":
-            raise NotImplementedError(
-                "wide string keys on non-unique OUTER joins (verification "
-                "cannot re-synthesize the null-extended row)"
+        # the host's planning of the build, between the build side's
+        # drain and the build pipeline: key normalisation, the key's
+        # declared interval, the live rows read for the dense table
+        with trace_span("join:prepare", "step"):
+            lkey, rkey, verify = self._join_key_exprs(
+                node.left_keys, node.right_keys, left, right, scalars,
+                node.left, node.right,
             )
-        iv = (self._build_key_interval(node.right, node.right_keys)
-              if node.unique else None)
-        rows = self._build_rows(iv, right)
-        # dense/packed only help the UNIQUE probe; other probe kinds
-        # would pay the advisory-stats refusal for no benefit
-        build = JoinBuildOperator(
-            rkey, dense_domain=self._dense_domain(iv, rows),
-            key_max=self._key_upper_bound(iv) if node.unique else None,
-            filter_bits=self._filter_bits(node.right) if fslot else 0,
-            params=self.params)
+            if verify and not node.unique and node.kind != "inner":
+                raise NotImplementedError(
+                    "wide string keys on non-unique OUTER joins "
+                    "(verification cannot re-synthesize the null-extended "
+                    "row)"
+                )
+            iv = (self._build_key_interval(node.right, node.right_keys)
+                  if node.unique else None)
+            rows = self._build_rows(iv, right)
+            # dense/packed only help the UNIQUE probe; other probe kinds
+            # would pay the advisory-stats refusal for no benefit
+            build = JoinBuildOperator(
+                rkey, dense_domain=self._dense_domain(iv, rows),
+                key_max=self._key_upper_bound(iv) if node.unique else None,
+                filter_bits=self._filter_bits(node.right) if fslot else 0,
+                params=self.params)
         Pipeline(BatchSource(right), [build]).run()
         self._fill_join_filter(fslot, build, node.right, rkey)
         outs = [BuildOutput(n, n) for n in node.output_right]
@@ -1211,7 +1238,7 @@ class LocalExecutor(OomLadderMixin):
         processed — no peek pass over the upstream pipeline. ``call``
         invokes the operator (plain or flags-threaded FULL probe —
         extra args pass through)."""
-        right_rows = sum(live_count(b) for b in right)
+        right_rows = sum(count_live_rows(right))
         state: dict[str, Any] = {"cap": None, "ops": {}}
 
         def probe(b, *args):
@@ -1347,7 +1374,6 @@ class LocalExecutor(OomLadderMixin):
         from presto_tpu.exec.spill import transfer_iter
         from presto_tpu.runtime.memory import node_row_bytes
         from presto_tpu.runtime.metrics import REGISTRY
-        from presto_tpu.runtime.trace import span as trace_span
 
         row_bytes_r = max(node_row_bytes(node.right, self.catalog), 1)
         # probe chunks sized so a chunk stays well under the budget
@@ -1496,23 +1522,24 @@ class LocalExecutor(OomLadderMixin):
         from presto_tpu.runtime.faults import fault_point
 
         fault_point("step.join_build")
-        lkey, rkey, verify = self._join_key_exprs(
-            node.left_keys, node.right_keys, left, right, scalars,
-            node.left, node.right,
-        )
-        if verify:
-            # existence probes have no build_row to verify against;
-            # hash collisions could flip semi/anti membership
-            raise NotImplementedError("wide string semi-join keys")
-        # semi/anti existence probes prefer the dense table when stats
-        # allow; the packed build would be dead weight (probe_exists
-        # has no packed path)
-        iv = self._build_key_interval(node.right, node.right_keys)
-        rows = self._build_rows(iv, right)
-        build = JoinBuildOperator(
-            rkey, dense_domain=self._dense_domain(iv, rows),
-            filter_bits=self._filter_bits(node.right) if fslot else 0,
-            params=self.params)
+        with trace_span("join:prepare", "step"):
+            lkey, rkey, verify = self._join_key_exprs(
+                node.left_keys, node.right_keys, left, right, scalars,
+                node.left, node.right,
+            )
+            if verify:
+                # existence probes have no build_row to verify against;
+                # hash collisions could flip semi/anti membership
+                raise NotImplementedError("wide string semi-join keys")
+            # semi/anti existence probes prefer the dense table when
+            # stats allow; the packed build would be dead weight
+            # (probe_exists has no packed path)
+            iv = self._build_key_interval(node.right, node.right_keys)
+            rows = self._build_rows(iv, right)
+            build = JoinBuildOperator(
+                rkey, dense_domain=self._dense_domain(iv, rows),
+                filter_bits=self._filter_bits(node.right) if fslot else 0,
+                params=self.params)
         Pipeline(BatchSource(right), [build]).run()
         self._fill_join_filter(fslot, build, node.right, rkey)
         op = LookupJoinOperator(build, lkey, (), jt, params=self.params)
@@ -1533,7 +1560,6 @@ class LocalExecutor(OomLadderMixin):
         from presto_tpu.exec.spill import transfer_iter
         from presto_tpu.runtime.memory import node_row_bytes
         from presto_tpu.runtime.metrics import REGISTRY
-        from presto_tpu.runtime.trace import span as trace_span
 
         row_bytes_r = max(node_row_bytes(node.right, self.catalog), 1)
         probe_chunk = self._oom_probe_chunk(1 << 18)
@@ -1619,13 +1645,15 @@ class LocalExecutor(OomLadderMixin):
         batches = child.materialize()
         slots = sum(b.capacity for b in batches)
         if slots >= SORT_COMPACT_SLOTS:
-            rows = sum(live_count(b) for b in batches)
+            rows = sum(count_live_rows(batches))
             cap = batch_capacity(max(rows, 16))
             if 2 * cap <= slots:
                 from presto_tpu.exec.operators import compact_batches
                 from presto_tpu.runtime.metrics import REGISTRY
 
-                batches = [compact_batches(batches, cap)]
+                with trace_span("step:sort_compact", "step",
+                                {"slots_in": slots, "slots_out": cap}):
+                    batches = [compact_batches(batches, cap)]
                 REGISTRY.counter(counter).add()
         return BatchStream.of(batches)
 
@@ -1660,8 +1688,11 @@ class LocalExecutor(OomLadderMixin):
                 for b in cs:
                     if leaf:
                         REGISTRY.counter("exec.union.batches").add()
-                    yield align_batch_dicts(b.select(names), targets,
-                                            mapping_cache)
+                    b = b.select(names)
+                    if targets:
+                        with trace_span("union:align", "step"):
+                            b = align_batch_dicts(b, targets, mapping_cache)
+                    yield b
 
         return BatchStream(make)
 
@@ -1715,12 +1746,13 @@ class LocalExecutor(OomLadderMixin):
 
                 raise UserError("scalar subquery returned more than one row")
             col = b[names[0] if names[0] in b else b.names[0]]
-            live = np.asarray(b.live)
-            idx = int(np.nonzero(live)[0][0])
-            valid = bool(np.asarray(col.valid)[idx])
+            with trace_sync("scalar_value"):
+                live = np.asarray(b.live)
+                idx = int(np.nonzero(live)[0][0])
+                valid = bool(np.asarray(col.valid)[idx])
+                raw = np.asarray(col.data)[idx] if valid else None
             if not valid:
                 return None
-            raw = np.asarray(col.data)[idx]
             return col.dtype.from_physical(raw) if col.dtype.kind in (
                 TypeKind.DECIMAL,
             ) else raw.item() if hasattr(raw, "item") else raw

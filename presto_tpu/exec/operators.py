@@ -615,7 +615,8 @@ class HashAggregationOperator(Operator):
                 self._fold_held()
             return []
         if self.state is None:
-            self.state = self._direct_init()
+            with trace_span("agg:init_state", "step"):
+                self.state = self._direct_init()
         self._fold(batch)
         return []
 
@@ -628,16 +629,19 @@ class HashAggregationOperator(Operator):
 
     def _fold_held(self) -> None:
         if self.state is None:
-            self.state = self._sort_init()
+            # one eager zeros / full a state column: dispatches
+            with trace_span("agg:init_state", "step"):
+                self.state = self._sort_init()
         if not self._held:
             return
         # the sort's operand, from static shapes (no device read)
         REGISTRY.counter("agg.strategy.sort_rows").add(
             self.strategy.max_groups + self._held_slots)
         REGISTRY.counter("agg.strategy.sorted_reduce").add()
-        batch = concat_batches(self._held)
+        batch = held_concat(self._held)
         self._held, self._held_slots = [], 0
-        self._fold(batch)
+        with trace_span("step:agg_fold", "step"):
+            self._fold(batch)
 
     def finish(self) -> list[Batch]:
         if isinstance(self.strategy, SortStrategy):
@@ -664,6 +668,11 @@ class HashAggregationOperator(Operator):
                 f"runtime in {[a.name for a in self.aggs]} — the planner "
                 "retries with the unbounded 63-bit path"
             )
+        # the state as a result batch: a few eager operations a column
+        with trace_span("agg:result", "step"):
+            return [self._result_batch(st)]
+
+    def _result_batch(self, st) -> Batch:
         cols: dict[str, Column] = {}
         if isinstance(self.strategy, DirectStrategy):
             g = self.strategy.num_groups
@@ -710,7 +719,7 @@ class HashAggregationOperator(Operator):
                 valid = jnp.ones(g, jnp.bool_)
             data = jnp.where(valid, data, 0)
             cols[a.name] = Column(data.astype(a.dtype.jnp_dtype), valid, a.dtype)
-        return [Batch(cols, live)]
+        return Batch(cols, live)
 
 
 def _phys_dtype(a: AggSpec):
@@ -885,6 +894,30 @@ def concat_batches(batches: list[Batch]) -> Batch:
     return Batch(cols, jnp.concatenate([b.live for b in batches]))
 
 
+def held_concat(batches: list[Batch]) -> Batch:
+    """A collecting operator's held batches as one, under the span
+    ``held:concat``: eager concatenations, two a column."""
+    with trace_span("held:concat", "step", {"batches": len(batches)}):
+        return concat_batches(batches)
+
+
+def sorted_rows(batch: Batch, keys: Sequence["SortKey"]):
+    """The row order of ``batch`` by ``keys`` (dead rows last), its
+    two halves each under a span of its own: ``sort:keys`` evaluates
+    the key expressions, ``sort:order`` runs ``sort_indices``' chained
+    stable argsorts — op by op when the caller is not traced."""
+    with trace_span("sort:keys", "step"):
+        vals = [evaluate(k.expr, batch) for k in keys]
+    with trace_span("sort:order", "step"):
+        return sort_indices(
+            [v.data for v in vals],
+            [k.descending for k in keys],
+            batch.live,
+            nulls_first=[k.nulls_first for k in keys],
+            valids=[v.valid for v in vals],
+        )
+
+
 def compact_batch(b: Batch, out_cap: int) -> Batch:
     """Gather live rows into a batch of capacity ``out_cap`` (one
     ``compact_indices`` + per-column gather). Caller guarantees
@@ -1033,27 +1066,21 @@ class OrderByOperator(CollectingOperator):
         """Pure sort of one concatenated batch (shared by ``finish()``
         and the cross-query batched dispatcher — see finish/result
         split note on GlobalAggregationOperator.result_batch)."""
-        vals = [evaluate(k.expr, batch) for k in self.keys]
-        order = sort_indices(
-            [v.data for v in vals],
-            [k.descending for k in self.keys],
-            batch.live,
-            nulls_first=[k.nulls_first for k in self.keys],
-            valids=[v.valid for v in vals],
-        )
-        cols = {
-            n: Column(
-                batch[n].data[order], batch[n].valid[order], batch[n].dtype,
-                batch[n].dictionary,
-            )
-            for n in batch.names
-        }
-        return Batch(cols, batch.live[order])
+        order = sorted_rows(batch, self.keys)
+        with trace_span("sort:gather", "step"):
+            cols = {
+                n: Column(
+                    batch[n].data[order], batch[n].valid[order],
+                    batch[n].dtype, batch[n].dictionary,
+                )
+                for n in batch.names
+            }
+            return Batch(cols, batch.live[order])
 
     def finish(self) -> list[Batch]:
         if not self.batches:
             return []
-        return [self.result_batch(concat_batches(self.batches))]
+        return [self.result_batch(held_concat(self.batches))]
 
 
 class TopNOperator(CollectingOperator):
@@ -1067,21 +1094,12 @@ class TopNOperator(CollectingOperator):
     def finish(self) -> list[Batch]:
         if not self.batches:
             return []
-        return [self.result_batch(concat_batches(self.batches))]
+        return [self.result_batch(held_concat(self.batches))]
 
     def result_batch(self, batch: Batch) -> Batch:
         """Pure top-N of one concatenated batch (shared by ``finish()``
         and the cross-query batched dispatcher)."""
-        vals = [evaluate(k.expr, batch) for k in self.keys]
-        order = sort_indices(
-            [v.data for v in vals],
-            [k.descending for k in self.keys],
-            batch.live,
-            nulls_first=[k.nulls_first for k in self.keys],
-            valids=[v.valid for v in vals],
-        )
-        take = order[: self.n]
-        live = gather_padded(batch.live, take, False)
+        order = sorted_rows(batch, self.keys)
 
         def gat(data):
             if data.ndim > 1:
@@ -1089,16 +1107,19 @@ class TopNOperator(CollectingOperator):
                 return jnp.where((take < data.shape[0])[:, None], data[safe], 0)
             return gather_padded(data, take, 0)
 
-        cols = {
-            n_: Column(
-                gat(batch[n_].data),
-                gather_padded(batch[n_].valid, take, False),
-                batch[n_].dtype,
-                batch[n_].dictionary,
-            )
-            for n_ in batch.names
-        }
-        return Batch(cols, live)
+        with trace_span("sort:gather", "step"):
+            take = order[: self.n]
+            live = gather_padded(batch.live, take, False)
+            cols = {
+                n_: Column(
+                    gat(batch[n_].data),
+                    gather_padded(batch[n_].valid, take, False),
+                    batch[n_].dtype,
+                    batch[n_].dictionary,
+                )
+                for n_ in batch.names
+            }
+            return Batch(cols, live)
 
 
 class WindowOperator(CollectingOperator):
@@ -1334,7 +1355,9 @@ class WindowOperator(CollectingOperator):
         REGISTRY.counter("exec.window.inputs").add(len(self.batches))
         REGISTRY.counter("exec.window.slots").add(
             sum(b.capacity for b in self.batches))
-        return [self._step(concat_batches(self.batches), self._params)]
+        batch = held_concat(self.batches)
+        with trace_span("step:window", "step"):
+            return [self._step(batch, self._params)]
 
 
 def window_operator_from_node(node, scalars, params=()) -> WindowOperator:

@@ -22,6 +22,8 @@ from typing import Callable, Iterable, Iterator, Sequence
 
 from presto_tpu.batch import Batch
 from presto_tpu.exec.operators import Operator
+from presto_tpu.runtime.metrics import REGISTRY
+from presto_tpu.runtime.trace import span as trace_span
 from presto_tpu.spi import Connector, Split, batch_capacity
 
 
@@ -148,8 +150,11 @@ class BatchStream:
     def peek(self) -> "Batch | None":
         """First batch, or None when empty (costs one replayed scan of
         the first split — used for trace-time decisions like dictionary
-        domains)."""
-        return next(iter(self), None)
+        domains). The replay is a hidden re-scan: it is the span
+        ``stream:peek`` and counts ``exec.stream.peeks``."""
+        REGISTRY.counter("exec.stream.peeks").add()
+        with trace_span("stream:peek", "step"):
+            return next(iter(self), None)
 
     def materialize(self) -> list[Batch]:
         return list(self)
@@ -165,7 +170,6 @@ class Pipeline:
 
     def run(self) -> list[Batch]:
         from presto_tpu.runtime.lifecycle import check_deadline
-        from presto_tpu.runtime.trace import span as trace_span
 
         outputs: list[Batch] = []
 

@@ -11,14 +11,14 @@ queryable:
 - ``sample_devices()`` — one row per ``jax.local_devices()`` entry
   with ``memory_stats()`` bytes-in-use / peak watermark / limit
   (CPU-safe: backends without allocator stats report zeros, rows still
-  appear so ``system.device_stats`` is never empty), plus the
-  per-device dispatch wall attributed from the fragment-dispatch choke
-  point in ``runtime/lifecycle.py``.
-- ``DISPATCH_WALL`` — process-wide ledger of time spent inside
-  ``run_fragment`` dispatch. Every local device participates in every
-  SPMD dispatch under the single-controller model, so the wall is
-  attributed evenly across devices at read time (storing one float,
-  not a per-dispatch device list).
+  appear so ``system.device_stats`` is never empty), plus
+  ``dispatches`` and ``dispatch_wall_s``: the process's jitted-step
+  calls and the host seconds inside them, read from the counters
+  ``exec.dispatch.calls`` / ``exec.dispatch.seconds`` that
+  ``cache/exec_cache._TimedStep`` keeps — the one place every cached
+  step is called. Every local device takes part in every
+  single-controller dispatch, so each row carries the call count and
+  an even share of the seconds.
 - ``headroom_bytes()`` — min over devices of ``limit - in_use``; the
   number hybrid-spill residency decisions should be judged against
   (``None`` when no backend reports a limit, e.g. CPU meshes).
@@ -34,42 +34,11 @@ watchdog overhead bound in ``tests/test_health.py`` holds it to <5%.
 
 from __future__ import annotations
 
-import threading
-import time
 from typing import Optional
 
 import jax
 
-
-class _DispatchLedger:
-    """Accumulated wall seconds spent in fragment dispatch, plus the
-    dispatch count — the per-device attribution divides the total by
-    the device count at read time (every local device participates in
-    every single-controller dispatch)."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._total_s = 0.0
-        self._dispatches = 0
-
-    def record(self, seconds: float) -> None:
-        if seconds < 0:
-            return
-        with self._lock:
-            self._total_s += seconds
-            self._dispatches += 1
-
-    def snapshot(self) -> "tuple[float, int]":
-        with self._lock:
-            return self._total_s, self._dispatches
-
-    def reset(self) -> None:
-        with self._lock:
-            self._total_s = 0.0
-            self._dispatches = 0
-
-
-DISPATCH_WALL = _DispatchLedger()
+from presto_tpu.runtime.metrics import REGISTRY
 
 
 def _memory_stats(device) -> dict:
@@ -88,7 +57,8 @@ def sample_devices() -> "list[dict]":
     backing store). Rows appear even when the backend reports no
     allocator stats so the table is populated on CPU meshes too."""
     devs = jax.local_devices()
-    total_s, dispatches = DISPATCH_WALL.snapshot()
+    dispatches = int(REGISTRY.counter("exec.dispatch.calls").total)
+    total_s = REGISTRY.counter("exec.dispatch.seconds").total
     per_device_s = total_s / len(devs) if devs else 0.0
     rows = []
     for d in devs:
@@ -142,13 +112,3 @@ def gauges() -> dict:
         out[f"device.bytes_limit.{did}"] = row["bytes_limit"]
         out[f"device.dispatch_wall_s.{did}"] = row["dispatch_wall_s"]
     return out
-
-
-def timed_dispatch(fn):
-    """Run ``fn()`` recording its wall into the dispatch ledger —
-    the one-liner ``run_fragment`` wraps around every dispatch."""
-    t0 = time.perf_counter()
-    try:
-        return fn()
-    finally:
-        DISPATCH_WALL.record(time.perf_counter() - t0)

@@ -58,7 +58,6 @@ from presto_tpu.runtime.errors import (
     is_backend_oom,
     is_retryable,
 )
-from presto_tpu.runtime.devices import timed_dispatch
 from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.runtime.overload import CancelScope, RetryBudget
 from presto_tpu.runtime.trace import current as trace_current
@@ -171,9 +170,7 @@ def run_fragment(label: str, fn: Callable[[], object]):
     if ctx is None:
         with trace_span(label, "fragment"):
             try:
-                # the dispatch ledger (runtime/devices.py) attributes
-                # wall time to devices from this choke point
-                return timed_dispatch(fn)
+                return fn()
             except Exception as e:
                 oom = _map_backend_oom(e, label)
                 if oom is not None:
@@ -189,7 +186,7 @@ def run_fragment(label: str, fn: Callable[[], object]):
                 label, "fragment",
                 {"attempt": attempt} if attempt else None,
             ), dispatch_h.time():
-                result = timed_dispatch(fn)
+                result = fn()
             if attempt > 0 and budget is not None:
                 # a spent retry paid off — a half-open probe's success
                 # closes the breaker and refills the bucket
@@ -227,6 +224,50 @@ def run_fragment(label: str, fn: Callable[[], object]):
                 time.sleep(sleep_s)
             ctx.check_deadline(label)
     raise AssertionError("unreachable")  # pragma: no cover
+
+
+#: local-variable slots of :func:`on_roomy_stack`'s frame: 8 bytes each,
+#: just over half of 256 KiB, so CPython maps one 256 KiB chunk for it
+#: and leaves ~120 KiB of the chunk to the frames under it
+_ROOMY_SLOTS = 16_400
+_roomy = None
+
+
+def on_roomy_stack(fn: Callable[[], object]):
+    """``fn()`` under one frame so large that the interpreter gives it
+    a data-stack chunk of its own with room for every frame of a
+    query's execution.
+
+    CPython (3.11 on) keeps Python frames in per-thread chunks of
+    16 KiB and FREES a chunk the moment the first frame in it returns
+    (``_PyThreadState_PopFrame``). The executors recurse over the
+    plan — ``_exec`` -> ``run_fragment`` -> ``_exec_<node>`` a level,
+    generators on top — so a query's hot loops run 16-40 KiB deep, and
+    a loop whose callee is the frame that does not fit the current
+    chunk maps and unmaps 16 KiB AT EVERY CALL: 7 us against 0.1 us
+    alone, far more beside the runtime's threads (every ``munmap`` is
+    a TLB shootdown). Which loop that is follows the plan's depth and
+    the exact frames above it: one wrapper frame more or less in
+    ``run_fragment`` moved q67's uploads from 2.9 to 5.7 ms a split
+    and q70's the other way (PERF.md, PR 37). A frame that does not
+    fit the initial chunk gets a chunk sized for it — a power of two —
+    and, being the first frame in it, keeps it mapped until it
+    returns: everything ``fn`` calls finds room there. Past that room
+    (~120 KiB of frames) the interpreter's behaviour is what it was,
+    as it is on an interpreter without such chunks."""
+    global _roomy
+    if _roomy is None:
+        # the slots are locals that a branch never taken assigns: the
+        # compiler counts them, the frame holds them unbound
+        names = " = ".join(f"_{i}" for i in range(_ROOMY_SLOTS))
+        ns: dict = {}
+        exec(compile(
+            "def roomy(fn):\n"
+            "    if fn is None:\n"
+            f"        {names} = None\n"
+            "    return fn()\n", "<on_roomy_stack>", "exec"), ns)
+        _roomy = ns["roomy"]
+    return _roomy(fn)
 
 
 def peak_estimate_bytes(plan, catalog) -> tuple[int, str]:

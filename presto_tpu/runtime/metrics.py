@@ -99,6 +99,12 @@ class CounterStat:
         if d is not None:
             d.add(self.name, v)
 
+    def add_unlocked(self, v: float = 1.0):
+        """For a writer the interpreter already serialises and that may
+        have interrupted a thread inside any lock (the gc hook of
+        ``runtime/trace``): no lock, no per-query delta."""
+        self.total += v
+
 
 @dataclass
 class TimeStat:
@@ -239,10 +245,31 @@ class MetricsRegistry:
         self.histograms: dict[str, HistogramStat] = {}
 
     def counter(self, name: str) -> CounterStat:
+        # a dict read is atomic under the interpreter's lock, so only
+        # the first use of a name (and the first after ``reset``) waits
+        c = self.counters.get(name)
+        if c is not None:
+            return c
         with self._lock:
-            if name not in self.counters:
-                self.counters[name] = CounterStat(name)
-            return self.counters[name]
+            return self._counter_locked(name)
+
+    def _counter_locked(self, name: str) -> CounterStat:
+        if name not in self.counters:
+            self.counters[name] = CounterStat(name)
+        return self.counters[name]
+
+    def counter_nowait(self, name: str) -> Optional[CounterStat]:
+        """``counter(name)`` for a caller that must not wait (the gc
+        hook may run on a thread that holds this registry's lock): the
+        counter if it exists or the lock is free to make it, else None
+        — that one observation is lost."""
+        c = self.counters.get(name)
+        if c is None and self._lock.acquire(blocking=False):
+            try:
+                c = self._counter_locked(name)
+            finally:
+                self._lock.release()
+        return c
 
     def timer(self, name: str) -> TimeStat:
         with self._lock:
@@ -503,6 +530,27 @@ METRIC_HELP: dict[str, str] = {
     "exec.sync.reads": (
         "places the host read a device value and waited for it "
         "(one per sync:* span)"),
+    "exec.dispatch.calls": (
+        "calls of jitted steps (cache/exec_cache._TimedStep: the one "
+        "place every such step is called; successful calls; a step "
+        "built with no cache key counts here and has no "
+        "system.exec_cache row)"),
+    "exec.dispatch.seconds": (
+        "host seconds inside those calls: argument handling, jit's "
+        "signature cache, the enqueue, whatever the runtime makes the "
+        "caller wait for — not the device's time"),
+    "exec.gc.pause_s": (
+        "seconds inside cycle-collector runs of every generation "
+        "(the gc.callbacks hook of runtime/trace)"),
+    "exec.gc.collections.gen0": "cycle-collector runs of generation 0",
+    "exec.gc.collections.gen1": (
+        "cycle-collector runs of generation 1 (also a gc:gen1 span on "
+        "the recorder of the thread it ran on)"),
+    "exec.gc.collections.gen2": (
+        "cycle-collector runs of generation 2 (also a gc:gen2 span)"),
+    "exec.stream.peeks": (
+        "BatchStream.peek calls: each replays the stream's first batch "
+        "(a hidden re-scan of the first split; span stream:peek)"),
     "exec.traces": "actual jit traces executed (the no-retrace probe)",
     "exec.trace_errors": (
         "best-effort trace/observability plumbing failures (the "
@@ -529,6 +577,10 @@ METRIC_HELP: dict[str, str] = {
         "queries that finished only after OOM-ladder degradation"),
     "query.retried": "whole-query retries",
     "query.started": "queries admitted to execution",
+    "query.thread_cpu_s": (
+        "CPU seconds of the query's thread across its root query span "
+        "(time.thread_time): the span minus this minus its sync:* waits "
+        "is time the thread was runnable and not running"),
     # ---- health watchdog / SLOs (runtime/health.py)
     "health.breach": (
         "health-watchdog breaches fired (each arms the flight "

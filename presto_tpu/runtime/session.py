@@ -29,7 +29,7 @@ from presto_tpu.plan.prune import prune
 from presto_tpu.runtime import trace
 from presto_tpu.runtime.errors import UserError, error_code, is_retryable
 from presto_tpu.runtime.events import EventDispatcher, QueryHistoryBuffer
-from presto_tpu.runtime.lifecycle import QueryManager
+from presto_tpu.runtime.lifecycle import QueryManager, on_roomy_stack
 from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.runtime.stats import (
     QueryInfo,
@@ -707,11 +707,19 @@ class Session:
         # Session.cancel reaches a query before run_plan installs it
         # in the in-flight registry
         self.query_manager.open_scope(info.query_id)
+        # this thread's CPU time across the root span: the span minus
+        # this minus its sync:* waits is the time the query's thread was
+        # runnable and not running (another stream held the interpreter)
+        cpu0 = time.thread_time()
         try:
             with trace.span("query", "query", {"query_id": info.query_id}):
-                return self._run_tracked_inner(sql, plan, recorder, info,
-                                               bound=bound)
+                # every frame of the execution in ONE mapped chunk of
+                # the interpreter's frame stack (lifecycle.py)
+                return on_roomy_stack(lambda: self._run_tracked_inner(
+                    sql, plan, recorder, info, bound=bound))
         finally:
+            REGISTRY.counter("query.thread_cpu_s").add(
+                time.thread_time() - cpu0)
             self.query_manager.close_scope(info.query_id)
             if tracer is not None:
                 trace.uninstall(token)
@@ -747,18 +755,23 @@ class Session:
         # fingerprint (template + this query's literal values) keys the
         # result cache and plan stats. Compile work is shared across
         # bindings; results never are.
-        values = logical_values(bound) if bound else ()
-        admissible = ResultCache.admissible(plan, self.catalog)
-        cache_ok = bool(self.prop("result_cache_enabled")) and admissible
-        templates_on = bool(self.prop("plan_templates")) and recorder is None
-        base_fp = None
-        if cache_ok or templates_on:
-            base_fp = plan_fingerprint(plan, self.catalog, self.properties,
-                                       self.mesh)
-        fp = None
-        if base_fp is not None:
-            fp = (try_fingerprint(("binding", base_fp, values))
-                  if bound else base_fp)
+        # (a content hash of the whole plan: host work of the query's
+        # own, named so that it is not the root span's)
+        with trace.span("plan:fingerprint", "planner"):
+            values = logical_values(bound) if bound else ()
+            admissible = ResultCache.admissible(plan, self.catalog)
+            cache_ok = (bool(self.prop("result_cache_enabled"))
+                        and admissible)
+            templates_on = (bool(self.prop("plan_templates"))
+                            and recorder is None)
+            base_fp = None
+            if cache_ok or templates_on:
+                base_fp = plan_fingerprint(plan, self.catalog,
+                                           self.properties, self.mesh)
+            fp = None
+            if base_fp is not None:
+                fp = (try_fingerprint(("binding", base_fp, values))
+                      if bound else base_fp)
         if templates_on and base_fp is not None:
             with self._tmpl_lock:
                 info.template_hit = base_fp in self._seen_templates

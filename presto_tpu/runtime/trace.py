@@ -42,10 +42,27 @@ Design constraints:
   ``frontend:submit``, ``frontend:encode``) are timed where they
   happen under :func:`annotation` and put on the recorder with
   ``add_complete`` from the same two clock reads.
+- **Self time from the spans' own parent links.** Every span carries
+  ``span_id`` / ``parent_id``; :meth:`TraceRecorder.self_times` gives
+  each span its duration minus what its children cover (their union,
+  clipped to the span: an ``add_complete`` child may reach outside its
+  parent), so a container's inclusive time parts into the named
+  activities under it and the remainder no span names. A query's self
+  times sum to its ``query`` span. ``system.trace_spans.self_s`` is the
+  operator's view; the benchmark's ``span_self_time`` reader does the
+  same arithmetic on its own.
+- **The interpreter beside the spans.** One ``gc.callbacks`` hook,
+  installed at import: every collection counts into
+  ``exec.gc.collections.gen<g>`` and ``exec.gc.pause_s``; a collection
+  of generation 1 or 2 is also a ``gc:gen<g>`` span (category
+  ``runtime``) on the recorder of the thread it ran on. Generation 0
+  gets the counters only (span volume). ``query.thread_cpu_s`` is the
+  query thread's CPU time across the root span (``runtime/session``).
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import time
 from collections import deque
@@ -72,6 +89,7 @@ CATEGORIES = (
     "scan",       # host work inside a connector scan: generate / pad / upload
     "sync",       # the host blocked on a device value (see sync())
     "planner",    # parse + analyse + template binding, before the query span
+    "runtime",    # the interpreter itself: a gc:gen<g> collector pause
 )
 
 _TRACE: ContextVar[Optional["TraceRecorder"]] = ContextVar(
@@ -111,7 +129,7 @@ class _SpanCtx:
 
     def __enter__(self) -> Span:
         rec = self.rec
-        rec._stack.append(self.span.span_id)
+        rec._stack.append(self.span)
         if rec.annotate:
             self._ann = _annotation(self.span.name, rec.trace_token)
             if self._ann is not None:
@@ -127,14 +145,25 @@ class _SpanCtx:
         return False
 
 
+#: ``jax.profiler.TraceAnnotation`` once the first annotated span has
+#: asked for it (False: the profiler is unavailable). Kept here so that
+#: the gc hook, which must import nothing, finds it ready.
+_TRACE_ANNOTATION: Any = None
+
+
 def _annotation(name: str, token: Optional[str]):
     """A jax.profiler.TraceAnnotation carrying the trace token, or None
     when the profiler is unavailable (annotation is best-effort)."""
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:  # pragma: no cover - ancient jax
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:  # pragma: no cover - ancient jax
+            TraceAnnotation = False
+        _TRACE_ANNOTATION = TraceAnnotation
+    if not _TRACE_ANNOTATION:
         return None
-    return TraceAnnotation(f"{name}#{token}" if token else name)
+    return _TRACE_ANNOTATION(f"{name}#{token}" if token else name)
 
 
 class TraceRecorder:
@@ -157,7 +186,7 @@ class TraceRecorder:
         #: (perf_counter, time_ns) read together: span times are on
         #: the first clock, a profiler trace on the second
         self.created_clock = (time.perf_counter(), time.time_ns())
-        self._stack: list[int] = []  # open span ids (parents)
+        self._stack: list[Span] = []  # open spans (parents)
         self._seq = 0
 
     # -- recording ---------------------------------------------------------
@@ -167,13 +196,23 @@ class TraceRecorder:
             self.dropped += 1
             REGISTRY.counter("trace.spans_dropped").add()
             return _NOOP
-        parent = self._stack[-1] if self._stack else -1
-        s = Span(self._seq, parent, name, cat)
-        self._seq += 1
+        s = self._new_span(name, cat)
         if args:
             s.args.update(args)
-        self.spans.append(s)
         return _SpanCtx(self, s)
+
+    def _new_span(self, name: str, cat: str,
+                  parent: Optional[int] = None) -> Span:
+        """A recorded span under ``parent`` (default: the innermost open
+        span). The id is taken before the Span is made: the gc hook may
+        add a span of its own from inside any allocation."""
+        sid = self._seq
+        self._seq = sid + 1
+        if parent is None:
+            parent = self._stack[-1].span_id if self._stack else -1
+        s = Span(sid, parent, name, cat)
+        self.spans.append(s)
+        return s
 
     def add_complete(self, name: str, cat: str, t0: float, dur_s: float,
                      args: Optional[dict] = None) -> Optional[Span]:
@@ -183,14 +222,11 @@ class TraceRecorder:
             self.dropped += 1
             REGISTRY.counter("trace.spans_dropped").add()
             return None
-        parent = self._stack[-1] if self._stack else -1
-        s = Span(self._seq, parent, name, cat)
-        self._seq += 1
+        s = self._new_span(name, cat)
         s.t0 = t0
         s.t1 = t0 + dur_s
         if args:
             s.args.update(args)
-        self.spans.append(s)
         return s
 
     # -- introspection -----------------------------------------------------
@@ -198,12 +234,41 @@ class TraceRecorder:
     def t0(self) -> float:
         return self.spans[0].t0 if self.spans else 0.0
 
+    def self_times(self, spans: Optional[list] = None) -> dict[int, float]:
+        """``{span_id: seconds}``: each span's duration minus the union
+        of its children's intervals clipped to it — what the span spent
+        under no child's name. Children of one span follow one another
+        on the one writer thread, but an ``add_complete`` span (``plan``,
+        ``frontend:*``, a ``gc:*`` pause) is put under whatever span was
+        open when it was recorded and may lie partly or wholly outside
+        it: it takes away only what it covers of its parent. Over ONE
+        snapshot of the spans (``spans``, or taken here): on a recorder
+        that is still installed, a collection tripped by this very
+        loop's allocations appends a ``gc:*`` span meanwhile."""
+        if spans is None:
+            spans = list(self.spans)
+        kids: dict[int, list[tuple[float, float]]] = {}
+        for s in spans:
+            kids.setdefault(s.parent_id, []).append((s.t0, s.t1))
+        out = {}
+        for s in spans:
+            covered, edge = 0.0, s.t0
+            for a, b in sorted(kids.get(s.span_id, ())):
+                a, b = max(a, edge), min(b, s.t1)
+                if b > a:
+                    covered += b - a
+                    edge = b
+            out[s.span_id] = max(s.t1 - s.t0, 0.0) - covered
+        return out
+
     def to_span_dicts(self) -> list[dict]:
         """The span tree as plain dicts with query-relative timestamps
         (args shared by reference — callers that persist them, like
         the flight recorder, must deep-copy/coerce). The flattening
         the ``system.trace_spans`` scan and post-mortem capture share."""
         t0 = self.t0
+        spans = list(self.spans)
+        self_s = self.self_times(spans)
         return [
             {
                 "span_id": s.span_id,
@@ -212,9 +277,10 @@ class TraceRecorder:
                 "cat": s.cat,
                 "start_s": round(max(s.t0 - t0, 0.0), 6),
                 "duration_s": round(max(s.t1 - s.t0, 0.0), 6),
+                "self_s": round(self_s[s.span_id], 6),
                 "args": s.args,
             }
-            for s in self.spans
+            for s in spans
         ]
 
     def spans_by_cat(self, cat: str) -> list[Span]:
@@ -308,6 +374,72 @@ def sync(what: str):
     (category ``sync``) and counts ``exec.sync.reads``."""
     REGISTRY.counter("exec.sync.reads").add()
     return span(f"sync:{what}", "sync")
+
+
+# ---------------------------------------------------------------------------
+# The interpreter: collector pauses
+# ---------------------------------------------------------------------------
+
+
+_GC_COLLECTIONS = tuple(f"exec.gc.collections.gen{g}" for g in range(3))
+
+
+class _GcHook:
+    """The ``gc.callbacks`` entry (one a process, installed below).
+
+    A collection interrupts whatever thread asked for the allocation
+    that tripped it, between two bytecodes of ANY code — also code that
+    holds the registry's lock, a counter's or a per-query delta's. So
+    the hook takes no lock and imports nothing: it reads the registry's
+    dict, adds with :meth:`CounterStat.add_unlocked` (collections never
+    overlap, so it is the one writer of its counters) and appends to
+    the recorder of the thread it runs on, which is the thread that
+    writes that recorder."""
+
+    __slots__ = ("t0", "ann")
+
+    def __init__(self):
+        self.t0 = 0.0
+        self.ann = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        gen = info["generation"]
+        rec = _TRACE.get() if gen else None
+        if phase == "start":
+            if rec is not None and rec.annotate and _TRACE_ANNOTATION:
+                self.ann = _annotation(f"gc:gen{gen}", rec.trace_token)
+                self.ann.__enter__()
+            self.t0 = time.perf_counter()
+            return
+        dur = time.perf_counter() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+            self.ann = None
+        for name, v in ((_GC_COLLECTIONS[gen], 1.0),
+                        ("exec.gc.pause_s", dur)):
+            c = REGISTRY.counter_nowait(name)
+            if c is not None:
+                c.add_unlocked(v)
+        if rec is None:
+            return
+        if len(rec.spans) >= rec.max_spans:
+            rec.dropped += 1
+            c = REGISTRY.counter_nowait("trace.spans_dropped")
+            if c is not None:
+                c.add_unlocked(1.0)
+            return
+        # under the innermost span that is really open: the collection
+        # may have run inside a span's own enter or exit, when the top
+        # of the stack has not read its first clock or has read its last
+        parent = next((p.span_id for p in reversed(rec._stack)
+                       if p.t0 and not p.t1), -1)
+        s = rec._new_span(f"gc:gen{gen}", "runtime", parent)
+        s.t0, s.t1 = self.t0, self.t0 + dur
+        s.args["collected"] = info.get("collected", 0)
+
+
+if not any(type(cb).__name__ == "_GcHook" for cb in gc.callbacks):
+    gc.callbacks.append(_GcHook())
 
 
 # ---------------------------------------------------------------------------

@@ -1002,6 +1002,24 @@ class HttpFrontend:
                 except Exception as e:  # noqa: BLE001 — HTTP boundary
                     self._send(500, {"error": f"{type(e).__name__}: {e}"})
 
+        def annotated(handle):
+            """``frontend:request`` on the profiler's clock around one
+            handled request (not the wait for the next on a kept-alive
+            connection): an idle gap of the device under no query span
+            is then either the server answering or the server waiting
+            for the client's next poll. Token ``http``: the request is
+            no one query's."""
+            def handler(self):
+                with trace_annotation(
+                        "frontend:request", "http",
+                        on=bool(qserver.session.prop(
+                            "profile_annotations"))):
+                    handle(self)
+            return handler
+
+        for verb in ("do_GET", "do_DELETE", "do_POST"):
+            setattr(Handler, verb, annotated(getattr(Handler, verb)))
+
         self.server = server
         self.httpd = ThreadingHTTPServer((host, port), Handler)
         self.httpd.daemon_threads = True

@@ -199,10 +199,6 @@ def scan_stored(conn, split: Split, columns: Sequence[str] | None = None,
     fresh columns alike, so ``valid is batch.live`` holds for a
     NULL-free column on a hit exactly as on a miss."""
     table = split.table
-    cols = list(columns) if columns is not None else list(conn.schema(table))
-    types = conn.physical_schema(table, cols)
-    dicts = {c: d for c, d in conn.dictionaries(table).items() if c in types}
-    at = (table, split.chunk, split.lo, split.hi)
 
     def make(missing):
         arrays, valids = split_valids(generate_split(conn, split, missing))
@@ -210,9 +206,20 @@ def scan_stored(conn, split: Split, columns: Sequence[str] | None = None,
         return Batch.pad_numpy(arrays, types, valids=valids,
                                capacity=capacity or batch_capacity(n))
 
-    host = conn.scan_store.columns(
-        at + (capacity,),
-        {c: at + (c, types[c].np_dtype.str, capacity) for c in cols}, make)
+    # the host's work before the upload: the split's schema, physical
+    # types and dictionaries, and the store's lookup a column (on a
+    # miss the two spans of the generation lie inside)
+    with trace.span("scan:lookup", "scan", {"table": table}):
+        cols = (list(columns) if columns is not None
+                else list(conn.schema(table)))
+        types = conn.physical_schema(table, cols)
+        dicts = {c: d for c, d in conn.dictionaries(table).items()
+                 if c in types}
+        at = (table, split.chunk, split.lo, split.hi)
+        host = conn.scan_store.columns(
+            at + (capacity,),
+            {c: at + (c, types[c].np_dtype.str, capacity) for c in cols},
+            make)
     count_delivered(1, host.n)
     return Batch.upload(host, types, dicts)
 

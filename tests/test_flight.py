@@ -297,9 +297,9 @@ def test_exec_cache_ledger_rows_shape():
     rows = EXEC_CACHE.stats_rows()
     assert rows, "process exec cache unexpectedly empty"
     for r in rows[:5]:
-        assert set(r) == {"kind", "key", "hits", "calls", "cold_call_s",
-                          "warm_call_s", "compile_s_saved", "age_s",
-                          "idle_s"}
+        assert set(r) == {"kind", "key", "hits", "calls", "total_call_s",
+                          "cold_call_s", "warm_call_s", "compile_s_saved",
+                          "age_s", "idle_s"}
         assert r["age_s"] >= 0 and r["idle_s"] >= 0
 
 

@@ -5,7 +5,7 @@ The contract under test:
 
 - device telemetry: ``sample_devices()`` reports one row per local
   device even on CPU meshes; ``system.device_stats`` is queryable and
-  the dispatch ledger attributes fragment-dispatch wall per device;
+  its dispatch columns read the ``exec.dispatch.*`` counters;
 - trace propagation: a W3C ``traceparent`` parses to its trace-id
   (malformed degrades, never rejects), and the REQUEST_TRACE context
   honors the client identifier end to end with the documented
@@ -30,7 +30,6 @@ import pytest
 
 from presto_tpu.connectors.tpch import TpchConnector
 from presto_tpu.runtime.devices import (
-    DISPATCH_WALL,
     headroom_bytes,
     peak_bytes,
     sample_devices,
@@ -100,15 +99,22 @@ def test_device_sampling_rows_and_system_table():
     assert isinstance(peak_bytes(), int)
     assert headroom_bytes() is None or isinstance(headroom_bytes(), int)
 
+    def dispatched():
+        return (REGISTRY.counter("exec.dispatch.seconds").total,
+                int(REGISTRY.counter("exec.dispatch.calls").total))
+
     s = make_session()
-    wall0, n0 = DISPATCH_WALL.snapshot()
-    s.sql(Q_FAST)  # at least one fragment dispatch lands in the ledger
-    wall1, n1 = DISPATCH_WALL.snapshot()
-    assert n1 > n0 and wall1 >= wall0
+    wall0, n0 = dispatched()
+    s.sql(Q_FAST)  # at least one jitted step is called (_TimedStep)
+    wall1, n1 = dispatched()
+    assert n1 > n0 and wall1 > wall0
     df = s.sql("select device_id, platform, bytes_in_use, "
                "dispatch_wall_s, dispatches from device_stats")
     assert len(df) == len(rows)
-    assert int(df["dispatches"][0]) >= n1 - n0
+    # the table reads the same two counters: every device the calls,
+    # an even share of the seconds
+    assert int(df["dispatches"][0]) >= n1
+    assert float(df["dispatch_wall_s"].sum()) >= wall1 * 0.999
 
 
 # ---------------------------------------------------------------------------

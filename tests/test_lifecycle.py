@@ -432,3 +432,92 @@ def test_query_info_json_has_lifecycle_fields(session):
         assert key in d
     assert d["fragmentRetries"] == 0
     assert d["degraded"] is False
+
+
+# ---------------------------------------------------------------------------
+# the interpreter's frame stack (lifecycle.on_roomy_stack)
+# ---------------------------------------------------------------------------
+
+
+def _worst_call_cost(wrap, depths=range(0, 320), calls=1500):
+    """On a thread of its own (a fresh frame stack, as a served query
+    has): recurse to each depth, time a loop of plain calls there, and
+    return (median, worst) seconds over the depths — the best of three
+    readings a depth, so that a busy machine's hiccup is not a finding."""
+    import statistics
+    import threading
+
+    def leaf(a, b):
+        c = a + b
+        return c
+
+    def hot():
+        t0 = time.perf_counter()
+        for i in range(calls):
+            leaf(i, 1)
+        return time.perf_counter() - t0
+
+    def rec(d):
+        return hot() if d == 0 else rec(d - 1)
+
+    out = []
+
+    def run():
+        for d in depths:
+            out.append(min(rec(d) for _ in range(3)))
+
+    th = threading.Thread(target=lambda: wrap(run))
+    th.start()
+    th.join()
+    return statistics.median(out), max(out)
+
+
+def test_on_roomy_stack_is_a_plain_call():
+    from presto_tpu.runtime.lifecycle import on_roomy_stack
+
+    assert on_roomy_stack(lambda: 41 + 1) == 42
+    with pytest.raises(ZeroDivisionError):
+        on_roomy_stack(lambda: 1 / 0)
+    # nested (an event listener's query under a query's): each call a
+    # frame of its own
+    assert on_roomy_stack(lambda: on_roomy_stack(lambda: "in")) == "in"
+
+
+def test_no_call_under_a_roomy_frame_maps_a_frame_chunk():
+    """CPython frees a 16 KiB frame-stack chunk when the first frame in
+    it returns, so a loop whose callee does not fit the current chunk
+    maps and unmaps one a call (tens of times a plain call's cost).
+    Under ``on_roomy_stack`` no depth a query reaches is such a depth."""
+    from presto_tpu.runtime import lifecycle
+
+    median, worst = _worst_call_cost(lambda fn: fn())
+    if worst < 10 * median:
+        pytest.skip("this interpreter keeps its frame chunks mapped")
+    median, worst = _worst_call_cost(lifecycle.on_roomy_stack)
+    assert worst < 10 * median
+    # the frame asks for more than half of 256 KiB, so its chunk is
+    # 256 KiB and what is left of it holds the query's frames
+    slots = lifecycle._roomy.__code__.co_nlocals
+    assert 128 * 1024 < 8 * slots < 160 * 1024
+
+
+def test_a_tracked_query_runs_under_the_roomy_frame(session, monkeypatch):
+    import sys
+
+    from presto_tpu.exec import local_planner
+
+    seen = []
+    real = local_planner.LocalExecutor._exec
+
+    def spy(self, node, scalars):
+        f, names = sys._getframe(), []
+        while f is not None:
+            names.append(f.f_code.co_filename)
+            f = f.f_back
+        seen.append("<on_roomy_stack>" in names)
+        return real(self, node, scalars)
+
+    monkeypatch.setattr(local_planner.LocalExecutor, "_exec", spy)
+    df = session.sql("select count(*) c from region")
+    assert int(df["c"][0]) == 5
+    assert seen and all(seen)

@@ -67,7 +67,8 @@ def recorded(fn):
         out = fn()
     finally:
         trace.uninstall(token)
-    return out, [sp.name for sp in rec.spans]
+    # (a collector pause may land anywhere: not the scan's)
+    return out, [sp.name for sp in rec.spans if sp.cat != "runtime"]
 
 
 def kept_arrays(store):
@@ -146,18 +147,21 @@ def test_a_subset_is_all_hits_and_a_superset_generates_the_rest():
     real = conn.scan_numpy
     conn.scan_numpy = lambda s, c=None: asked.append(list(c)) or real(s, c)
     _, names = recorded(lambda: conn.scan(split, cols[:3], 1 << 16))
-    assert names == ["scan:generate", "batch:pad", "batch:upload"]
+    assert names == ["scan:lookup", "scan:generate", "batch:pad",
+                     "batch:upload"]
     assert asked == [cols[:3]]
     # a subset after its superset: nothing is generated or padded
     (sub, c), names = recorded(lambda: counted(
         lambda: conn.scan(split, [cols[2], cols[0]], 1 << 16)))
-    assert names == ["batch:upload"] and asked == [cols[:3]]
+    assert names == ["scan:lookup", "batch:upload"]
+    assert asked == [cols[:3]]
     assert (c["exec.scan.store.hits"], c["exec.scan.store.misses"]) == (2, 0)
     assert sub.names == (cols[2], cols[0])          # the order asked for
     # a superset after a subset: the missing column only
     (sup, c), names = recorded(lambda: counted(
         lambda: conn.scan(split, cols, 1 << 16)))
-    assert names == ["scan:generate", "batch:pad", "batch:upload"]
+    assert names == ["scan:lookup", "scan:generate", "batch:pad",
+                     "batch:upload"]
     assert asked == [cols[:3], cols[3:]]
     assert (c["exec.scan.store.hits"], c["exec.scan.store.misses"]) == (3, 1)
     assert sup.names == tuple(cols)
@@ -190,7 +194,8 @@ def test_a_bound_of_zero_bytes_serves_every_scan_as_before():
         (got, c), names = recorded(lambda: counted(
             lambda: conn.scan(split, cols, 1 << 16)))
         assert_batches_equal(got, want)
-        assert names == ["scan:generate", "batch:pad", "batch:upload"]
+        assert names == ["scan:lookup", "scan:generate", "batch:pad",
+                         "batch:upload"]
         assert c["exec.scan.store.bypassed"] == len(cols) + 1
         assert (c["exec.scan.store.hits"], c["exec.scan.store.bytes"]) == (0, 0)
         assert c["exec.scan.splits"] == 1
@@ -334,8 +339,11 @@ def test_the_mesh_scan_is_the_same_warm_as_cold(mesh):
         lambda: ex._exec_tablescan(lineitem_scan(conn, cols[:3]), {})))
     assert cold.sharded and warm.sharded
     assert_batches_equal(warm.batch, cold.batch)
-    assert set(names0) == {"scan:generate", "batch:pad", "batch:upload"}
-    assert names1 == ["batch:upload"] * 4            # one a device
+    assert set(names0) == {"scan:shards", "scan:lookup", "scan:generate",
+                           "batch:pad", "batch:upload", "scan:assemble"}
+    # warm: a lookup and an upload a device, then the pieces assembled
+    assert names1 == (["scan:shards"] + ["scan:lookup", "batch:upload"] * 4
+                      + ["scan:assemble"])
     assert (c0["exec.scan.store.misses"], c0["exec.scan.store.hits"]) == (12, 0)
     assert (c1["exec.scan.store.misses"], c1["exec.scan.store.hits"]) == (0, 12)
     assert {k: c1[k] for k in DELIVERED} == {k: c0[k] for k in DELIVERED}

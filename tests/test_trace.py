@@ -489,7 +489,8 @@ def test_distributed_q3_trace_acceptance(tmp_path):
     assert len(node_ids) == count_nodes(plan)
     # the distributed scan parts its host work like the local one
     scan_names = {sp.name for sp in rec.spans_by_cat("scan")}
-    assert scan_names == {"scan:generate", "batch:pad", "batch:upload"}
+    assert scan_names == {"scan:shards", "scan:lookup", "scan:generate",
+                          "batch:pad", "batch:upload", "scan:assemble"}
     # exchange spans carry nonzero byte counts
     ex = rec.spans_by_cat("exchange")
     assert ex and sum(sp.args["bytes"] for sp in ex) > 0
@@ -736,3 +737,291 @@ def test_no_jitted_step_is_called_step(lowered_modules):
     assert lowered_modules and not [
         m for m in lowered_modules
         if m in ("jit_step", "jit__step", "jit__lambda_", "jit__lambda")]
+
+
+# ---------------------------------------------------------------------------
+# the host's side of a query (PR 37): self time from the spans' own
+# parent links, every jitted dispatch counted where it is made, the
+# collector's pauses and the query thread's CPU time
+# ---------------------------------------------------------------------------
+
+import gc  # noqa: E402
+
+from benchmark.readers import span_self_time  # noqa: E402
+from presto_tpu.cache.exec_cache import EXEC_CACHE  # noqa: E402
+from presto_tpu.runtime import trace  # noqa: E402
+from presto_tpu.runtime.trace import Span, TraceRecorder  # noqa: E402
+
+
+def _hand_built(tree):
+    """A recorder holding ``(id, parent, name, t0, t1)`` rows as spans."""
+    rec = TraceRecorder("hand-built")
+    for sid, parent, name, t0, t1 in tree:
+        s = Span(sid, parent, name, "step")
+        s.t0, s.t1 = t0, t1
+        rec.spans.append(s)
+    return rec
+
+
+#: nested (query > fragment > finish > two sequential children), an
+#: ``add_complete`` child reaching outside its parent on both sides
+#: (``plan`` before the query span, ``frontend:encode`` after it), two
+#: children that overlap, an empty container
+TREE = [
+    (0, -1, "query", 10.0, 11.0),
+    (1, 0, "fragment:TopN", 10.1, 10.9),
+    (2, 1, "finish:TopNOperator", 10.2, 10.6),
+    (3, 2, "held:concat", 10.2, 10.3),
+    (4, 2, "sort:order", 10.3, 10.55),
+    (5, 1, "node:Empty", 10.6, 10.7),
+    (6, 0, "plan", 9.8, 10.05),
+    (7, 0, "frontend:encode", 10.95, 11.2),
+    (8, 1, "step:a", 10.7, 10.8),
+    (9, 1, "step:b", 10.75, 10.85),
+]
+
+
+def test_self_times_on_a_hand_built_tree_and_the_readers_agree():
+    rec = _hand_built(TREE)
+    own = rec.self_times()
+    ms = {s.name: round(own[s.span_id] * 1e3, 6) for s in rec.spans}
+    assert ms == {
+        # 1000 - the fragment's 800 - plan's 50 inside - encode's 50 inside
+        "query": 100.0,
+        # 800 - finish 400 - empty 100 - the union of a and b, 150
+        "fragment:TopN": 150.0,
+        "finish:TopNOperator": 50.0,    # 400 - 100 - 250
+        "held:concat": 100.0,
+        "sort:order": 250.0,
+        "node:Empty": 100.0,            # an empty container: all its own
+        "plan": 250.0,
+        "frontend:encode": 250.0,
+        "step:a": 100.0,
+        "step:b": 100.0,
+    }
+    # the benchmark's reader does its own arithmetic: the same numbers
+    as_dicts = [{"id": s.span_id, "parent": s.parent_id, "name": s.name,
+                 "cat": s.cat, "t0": s.t0, "t1": s.t1} for s in rec.spans]
+    theirs = span_self_time.self_times(as_dicts)
+    assert theirs == pytest.approx(own)
+    # ... and so does the flattening system.trace_spans shows
+    flat = {d["span_id"]: d["self_s"] for d in rec.to_span_dicts()}
+    assert flat == pytest.approx({k: round(v, 6) for k, v in own.items()})
+
+
+def test_a_real_querys_self_times_sum_to_its_query_span(conn):
+    s = Session({"tpch": conn}, properties={"result_cache_enabled": False})
+    s.sql(Q_AGG)
+    rec = s.traces.latest()
+    own = rec.self_times()
+    by_id = {sp.span_id: sp for sp in rec.spans}
+    root, = [sp for sp in rec.spans if sp.name == "query"]
+
+    def under_root(sp):
+        while sp is not None and sp is not root:
+            sp = by_id.get(sp.parent_id)
+        return sp is root
+
+    total = sum(own[sp.span_id] for sp in rec.spans if under_root(sp))
+    assert total == pytest.approx(root.t1 - root.t0, rel=1e-6)
+    assert all(v >= 0.0 for v in own.values())
+    df = s.sql("select name, duration_s, self_s from trace_spans")
+    assert (df["self_s"] <= df["duration_s"] + 1e-6).all()
+    # the final sort's finish is parted, and the scan's lookup is named
+    names = {sp.name for sp in rec.spans}
+    assert {"sort:keys", "sort:order", "sort:gather",
+            "scan:lookup"} <= names, sorted(names)
+
+
+def _dispatch_calls():
+    return int(REGISTRY.counter("exec.dispatch.calls").total)
+
+
+def _entry_calls():
+    return sum(r["calls"] for r in EXEC_CACHE.stats_rows())
+
+
+@pytest.mark.parametrize("case", sorted(SERVED))
+def test_dispatch_calls_counts_the_cached_steps_a_warm_query_calls(
+        served_conns, case):
+    catalog, sql, _ = SERVED[case]
+    s = Session({catalog: served_conns[catalog]},
+                properties={"result_cache_enabled": False})
+    s.sql(sql)                                  # builds and compiles
+    seen = []
+    for _ in range(3):
+        calls0, entries0 = _dispatch_calls(), _entry_calls()
+        secs0 = REGISTRY.counter("exec.dispatch.seconds").total
+        s.sql(sql)
+        seen.append(_dispatch_calls() - calls0)
+        # exactly the calls the cache's own entries took
+        assert seen[-1] == _entry_calls() - entries0
+        assert REGISTRY.counter("exec.dispatch.seconds").total > secs0
+    assert seen[0] > 0 and len(set(seen)) == 1, seen
+    rows = EXEC_CACHE.stats_rows()
+    assert all(r["total_call_s"] >= r["calls"] * r["warm_call_s"] - 1e-6
+               for r in rows if r["calls"])
+    # a statement answered from the result cache dispatches nothing
+    cached = Session({catalog: served_conns[catalog]})
+    cached.sql(sql)
+    calls0 = _dispatch_calls()
+    cached.sql(sql)
+    assert cached.query_history[-1].cache_hit
+    assert _dispatch_calls() == calls0
+    df = cached.sql("select kind, calls, total_call_s from exec_cache")
+    assert (df["total_call_s"] >= 0).all() and df["calls"].sum() > 0
+
+
+def _gc_counts():
+    snap = REGISTRY.snapshot()
+    return (snap.get("exec.gc.collections.gen2", 0.0),
+            snap.get("exec.gc.pause_s", 0.0))
+
+
+def test_gc_hook_a_collection_inside_a_query_is_a_span_on_its_recorder(conn):
+    class Collects:
+        def query_created(self, info):
+            gc.collect(2)
+
+    s = Session({"tpch": conn}, properties={"result_cache_enabled": False})
+    s.sql(Q_AGG)
+    s.events.add(Collects())
+    n0, pause0 = _gc_counts()
+    s.sql(Q_AGG)
+    n1, pause1 = _gc_counts()
+    rec = s.traces.latest()
+    forced = [sp for sp in rec.spans if sp.name == "gc:gen2"]
+    assert len(forced) >= 1 and n1 - n0 == len(forced)
+    assert pause1 - pause0 >= sum(sp.t1 - sp.t0 for sp in forced) > 0
+    root, = [sp for sp in rec.spans if sp.name == "query"]
+    for sp in forced:
+        assert sp.cat == "runtime" and "runtime" in trace.CATEGORIES
+        assert root.t0 <= sp.t0 <= sp.t1 <= root.t1
+    # the listener's runs directly under the root span
+    assert any(sp.parent_id == root.span_id for sp in forced)
+    assert len({sp.span_id for sp in rec.spans}) == len(rec.spans)
+
+
+def test_gc_hook_outside_any_query_moves_the_counters_only(conn):
+    s = Session({"tpch": conn}, properties={"result_cache_enabled": False})
+    s.sql(Q_AGG)
+    rec = s.traces.latest()
+    spans0 = len(rec.spans)
+    assert trace.current() is None
+    n0, pause0 = _gc_counts()
+    gc.collect(2)
+    n1, pause1 = _gc_counts()
+    assert n1 - n0 == 1 and pause1 > pause0
+    assert len(rec.spans) == spans0
+    # generation 0: the counter, never a span (span volume)
+    r = TraceRecorder("gen0")
+    token = trace.install(r)
+    try:
+        g0 = REGISTRY.snapshot().get("exec.gc.collections.gen0", 0.0)
+        with trace.span("query", "query"):
+            gc.collect(0)
+        assert REGISTRY.snapshot()["exec.gc.collections.gen0"] >= g0 + 1
+    finally:
+        trace.uninstall(token)
+    assert [sp.name for sp in r.spans if sp.name == "gc:gen0"] == []
+    # a full recorder drops the pause like any span, and says so
+    full = TraceRecorder("full", max_spans=1)
+    token = trace.install(full)
+    try:
+        with trace.span("query", "query"):
+            gc.collect(2)
+    finally:
+        trace.uninstall(token)
+    assert [sp.name for sp in full.spans] == ["query"] and full.dropped >= 1
+
+
+def test_query_thread_cpu_is_within_the_query_span(conn):
+    s = Session({"tpch": conn}, properties={"result_cache_enabled": False})
+    s.sql(Q_AGG)
+    cpu0 = REGISTRY.counter("query.thread_cpu_s").total
+    s.sql(Q_AGG)
+    cpu = REGISTRY.counter("query.thread_cpu_s").total - cpu0
+    root, = [sp for sp in s.traces.latest().spans if sp.name == "query"]
+    # thread_time ticks coarser than perf_counter on some kernels
+    assert 0.0 < cpu <= (root.t1 - root.t0) + 0.005
+
+
+def test_join_filter_and_every_new_span_use_a_listed_category(served_conns):
+    s = Session({"tpch": served_conns["tpch"]},
+                properties={"result_cache_enabled": False})
+    s.sql(TPCH["q3"])
+    rec = s.traces.latest()
+    assert {sp.cat for sp in rec.spans} <= set(trace.CATEGORIES)
+    names = {sp.name for sp in rec.spans}
+    assert {"join_filter", "join:prepare", "held:concat", "sort:keys",
+            "sort:order", "sort:gather", "step:agg_fold"} <= names, names
+    assert {sp.cat for sp in rec.spans if sp.name == "join_filter"} == {
+        "step"}
+
+
+@pytest.mark.parametrize("case", sorted(SERVED))
+def test_no_scalar_read_of_a_device_value_outside_a_sync_span(served_conns,
+                                                              case):
+    """``scripts/audit_device_reads.py``'s funnel detector over the
+    served templates: every ``bool()`` / ``int()`` / ``.item()`` of a
+    device value is inside a ``sync:*`` span, so ``exec.sync.reads``
+    means every read (``np.asarray`` and the transfer guard are the
+    chip's to check: neither is seen on the CPU backend)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "audit_device_reads.py")
+    spec = importlib.util.spec_from_file_location("audit_device_reads", path)
+    audit = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(audit)
+    catalog, sql, _ = SERVED[case]
+    s = Session({catalog: served_conns[catalog]},
+                properties={"result_cache_enabled": False})
+    got = audit.audit(s, sql)
+    assert got["error"] is None
+    assert got["inside"] >= 3
+    assert [audit.program_frames(st)[-1] for st in got["outside"]] == []
+    # the detector itself: a bare read is seen, one under a span is not
+    import jax.numpy as jnp
+
+    x = jnp.arange(4) + 1
+    rec = TraceRecorder("audit")
+    token = trace.install(rec)
+    try:
+        with audit.auditing() as found:
+            with trace.sync("probe"):
+                assert int(x[1]) == 2
+            assert found == []
+            assert int(x[2]) == 3
+            assert len(found) == 1
+    finally:
+        trace.uninstall(token)
+    assert TraceRecorder.span.__name__ == "span"
+
+
+def test_span_dicts_survive_a_collector_pause_recorded_meanwhile():
+    """``to_span_dicts`` may run on a recorder that is still installed
+    (the flight recorder's capture of a failing query): a collection
+    tripped by its own allocations then appends a ``gc:*`` span to the
+    list it is walking. It works on one snapshot of the spans."""
+    import gc
+
+    rec = trace.TraceRecorder("q_live", None, max_spans=10_000)
+    token = trace.install(rec)
+    old = gc.get_threshold()
+    try:
+        for _ in range(50):
+            with trace.span("step:x", "step"):
+                pass
+        gc.set_threshold(1, 1, 1)   # every container allocation collects
+        dicts = rec.to_span_dicts()
+    finally:
+        gc.set_threshold(*old)
+        trace.uninstall(token)
+    assert len(dicts) >= 50
+    assert all("self_s" in d for d in dicts)
+    # the pauses it caused are on the recorder for the next reader
+    assert any(s.name.startswith("gc:gen") for s in rec.spans)
+    own = rec.self_times()
+    assert set(own) == {s.span_id for s in rec.spans}
