@@ -187,7 +187,9 @@ class _TimedStep:
     enqueue, and whatever the runtime makes the caller wait for; not
     the device's time, which a later read waits for) from the same two
     clock reads. An eager ``jnp`` operation outside any step is not a
-    call through here: the spans around it name it."""
+    call through here: the spans around it name it (``held:concat``,
+    ``agg:init_state``; the statement's final sort was ~25-70 of them
+    until PR 39 made it the ``order_by`` / ``top_n`` step)."""
 
     __slots__ = ("_fn", "_meta")
 

@@ -2146,7 +2146,8 @@ class DistributedExecutor(OomLadderMixin):
             except CapacityOverflow:
                 pass  # pathological skew: fall through to replicate
         d = self._replicate(d, guard="Sort")
-        out = Pipeline(BatchSource([d.batch]), [OrderByOperator(keys)]).run()
+        out = Pipeline(BatchSource([d.batch]),
+                       [OrderByOperator(keys, params=self.params)]).run()
         return DistBatch(out[0], sharded=False)
 
     def _exec_topn(self, node: N.TopN, scalars) -> DistBatch:
@@ -2161,7 +2162,8 @@ class DistributedExecutor(OomLadderMixin):
         # normally P*n survivors; a huge n degenerates to replicating
         # the table, which the gather guard must still catch
         d = self._replicate(d, guard="TopN")
-        out = Pipeline(BatchSource([d.batch]), [TopNOperator(keys, node.count)]).run()
+        out = Pipeline(BatchSource([d.batch]), [
+            TopNOperator(keys, node.count, params=self.params)]).run()
         return DistBatch(out[0], sharded=False)
 
     def _exec_limit(self, node: N.Limit, scalars) -> DistBatch:

@@ -1705,7 +1705,8 @@ class LocalExecutor(OomLadderMixin):
             SortKey(bind_scalars(k.expr, scalars), k.descending, k.nulls_first)
             for k in node.keys
         ]
-        return BatchStream.of(Pipeline(child, [OrderByOperator(keys)]).run())
+        return BatchStream.of(Pipeline(
+            child, [OrderByOperator(keys, params=self.params)]).run())
 
     def _exec_topn(self, node: N.TopN, scalars):
         child = self._exec(node.child, scalars)
@@ -1719,7 +1720,8 @@ class LocalExecutor(OomLadderMixin):
         # window's slots) sorts the live rows' bucket
         child = self._compact_large(child, "exec.topn.compacted")
         return BatchStream.of(
-            Pipeline(child, [TopNOperator(keys, node.count)]).run()
+            Pipeline(child, [TopNOperator(keys, node.count,
+                                          params=self.params)]).run()
         )
 
     def _exec_limit(self, node: N.Limit, scalars):

@@ -44,7 +44,7 @@ from presto_tpu.ops.groupby import (
     segment_agg,
     sorted_group_reduce,
 )
-from presto_tpu.ops.sort import sort_indices, top_n_indices
+from presto_tpu.ops.sort import packed_sort_order, sort_indices
 from presto_tpu.runtime.errors import InternalError, ResourceExhausted
 from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.runtime.trace import span as trace_span
@@ -896,26 +896,11 @@ def concat_batches(batches: list[Batch]) -> Batch:
 
 def held_concat(batches: list[Batch]) -> Batch:
     """A collecting operator's held batches as one, under the span
-    ``held:concat``: eager concatenations, two a column."""
+    ``held:concat``: eager concatenations, two a column (the
+    aggregation's fold, the window and the join build; ORDER BY and
+    TopN concatenate inside their step)."""
     with trace_span("held:concat", "step", {"batches": len(batches)}):
         return concat_batches(batches)
-
-
-def sorted_rows(batch: Batch, keys: Sequence["SortKey"]):
-    """The row order of ``batch`` by ``keys`` (dead rows last), its
-    two halves each under a span of its own: ``sort:keys`` evaluates
-    the key expressions, ``sort:order`` runs ``sort_indices``' chained
-    stable argsorts — op by op when the caller is not traced."""
-    with trace_span("sort:keys", "step"):
-        vals = [evaluate(k.expr, batch) for k in keys]
-    with trace_span("sort:order", "step"):
-        return sort_indices(
-            [v.data for v in vals],
-            [k.descending for k in keys],
-            batch.live,
-            nulls_first=[k.nulls_first for k in keys],
-            valids=[v.valid for v in vals],
-        )
 
 
 def compact_batch(b: Batch, out_cap: int) -> Batch:
@@ -974,6 +959,21 @@ def live_rows(batches: Sequence[Batch]):
     return step(tuple(b.live for b in batches))
 
 
+def gather_batch_rows(b: Batch, idx, *more):
+    """Rows ``idx`` of every column of ``b`` — data and ``valid`` — and
+    of the ``more`` arrays of its length, moved by ONE gather of rows
+    of 32-bit words (``gather_columns``): the columns, and ``more``'s
+    rows as a list."""
+    cols = list(b.columns.values())
+    moved = gather_columns(
+        [c.data for c in cols] + [c.valid for c in cols] + list(more),
+        idx, as_rows=True)
+    k = len(cols)
+    return ({name: Column(data, valid, c.dtype, c.dictionary)
+             for name, c, data, valid in zip(b.names, cols, moved, moved[k:])},
+            moved[2 * k:])
+
+
 def compact_rows(batches: Sequence[Batch], out_cap: int) -> Batch:
     """The live rows of ``batches`` as ONE batch of capacity
     ``out_cap`` (caller guarantees total live_count <= out_cap), moved
@@ -983,20 +983,13 @@ def compact_rows(batches: Sequence[Batch], out_cap: int) -> Batch:
     by the row's width, where ``compact_batch`` pays two a column."""
     from presto_tpu.cache.exec_cache import EXEC_CACHE, trace_probe
     from presto_tpu.ops.compact import compact_indices
-    from presto_tpu.ops.groupby import gather_columns
 
     def probe_compact_step(batches):
         trace_probe()
         b = concat_batches(list(batches))
         idx, n, _ = compact_indices(b.live, out_cap)
-        cols = list(b.columns.values())
-        moved = gather_columns([c.data for c in cols]
-                               + [c.valid for c in cols], idx, as_rows=True)
-        return Batch(
-            {name: Column(data, valid, c.dtype, c.dictionary)
-             for name, c, data, valid in zip(b.names, cols, moved,
-                                             moved[len(cols):])},
-            jnp.arange(out_cap, dtype=jnp.int32) < n)
+        cols, _ = gather_batch_rows(b, idx)
+        return Batch(cols, jnp.arange(out_cap, dtype=jnp.int32) < n)
 
     step = EXEC_CACHE.get_or_build(
         EXEC_CACHE.key_of("probe_compact", out_cap),
@@ -1055,71 +1048,93 @@ def align_batch_dicts(b: Batch, targets: dict, _cache: dict | None = None) -> Ba
     return Batch(cols, b.live)
 
 
-class OrderByOperator(CollectingOperator):
-    """Full sort (reference: OrderByOperator + PagesIndex.sort)."""
+def _make_sort_step(keys: Sequence[SortKey], n: int | None):
+    """The statement's final sort as ONE program: the held batches
+    concatenated, the key expressions, the order
+    (``ops/sort.packed_sort_order``), and every column's data and
+    ``valid`` and ``live`` moved by one row gather — of the first ``n``
+    rows of the order for a TopN (dead rows are last in it, so an ``n``
+    over the live count brings dead rows and one over the capacity the
+    whole input). The closure reads configuration only."""
+    from presto_tpu.cache.exec_cache import trace_probe
 
-    def __init__(self, keys: Sequence[SortKey]):
-        super().__init__()
-        self.keys = list(keys)
+    def sort_step(batches, params=()) -> Batch:
+        trace_probe()
+        batch = concat_batches(list(batches))
+        with param_scope(params):
+            vals = [evaluate(k.expr, batch) for k in keys]
+        order = packed_sort_order(
+            [v.data for v in vals],
+            [k.descending for k in keys],
+            batch.live,
+            nulls_first=[k.nulls_first for k in keys],
+            valids=[v.valid for v in vals],
+            # a dictionary's codes are 0 .. len - 1
+            code_bits=[None if v.dictionary is None
+                       else max((len(v.dictionary) - 1).bit_length(), 1)
+                       for v in vals],
+        )
+        cols, (live,) = gather_batch_rows(
+            batch, order if n is None else order[:n], batch.live)
+        return Batch(cols, live)
 
-    def result_batch(self, batch: Batch) -> Batch:
-        """Pure sort of one concatenated batch (shared by ``finish()``
-        and the cross-query batched dispatcher — see finish/result
-        split note on GlobalAggregationOperator.result_batch)."""
-        order = sorted_rows(batch, self.keys)
-        with trace_span("sort:gather", "step"):
-            cols = {
-                n: Column(
-                    batch[n].data[order], batch[n].valid[order],
-                    batch[n].dtype, batch[n].dictionary,
-                )
-                for n in batch.names
-            }
-            return Batch(cols, batch.live[order])
-
-    def finish(self) -> list[Batch]:
-        if not self.batches:
-            return []
-        return [self.result_batch(held_concat(self.batches))]
+    return sort_step
 
 
-class TopNOperator(CollectingOperator):
-    """Sort + limit with bounded output (reference: TopNOperator)."""
+class _SortOperator(CollectingOperator):
+    """ORDER BY and TopN: everything is held, and ``finish`` is one
+    dispatch of the cached ``sort_step``."""
 
-    def __init__(self, keys: Sequence[SortKey], n: int):
+    kind: str
+
+    def __init__(self, keys: Sequence[SortKey], n: int | None,
+                 params: Sequence[Any]):
+        from presto_tpu.cache.exec_cache import EXEC_CACHE
+
         super().__init__()
         self.keys = list(keys)
         self.n = n
+        self._params = tuple(params)
+        keys = tuple(self.keys)
+        self._step = EXEC_CACHE.get_or_build(
+            EXEC_CACHE.key_of(self.kind, keys, n),
+            lambda: jax.jit(_make_sort_step(keys, n)),
+        )
+
+    def result_batch(self, batches: Sequence[Batch], params=None) -> Batch:
+        """The sorted rows of ``batches`` (shared by ``finish()`` and
+        the cross-query batched dispatcher, which traces it under
+        ``vmap`` with its own traced ``params`` — see finish/result
+        split note on GlobalAggregationOperator.result_batch)."""
+        return self._step(tuple(batches),
+                          self._params if params is None else params)
 
     def finish(self) -> list[Batch]:
         if not self.batches:
             return []
-        return [self.result_batch(held_concat(self.batches))]
+        REGISTRY.counter("exec.sort.steps").add()
+        with trace_span("step:sort", "step",
+                        {"batches": len(self.batches)}):
+            return [self.result_batch(self.batches)]
 
-    def result_batch(self, batch: Batch) -> Batch:
-        """Pure top-N of one concatenated batch (shared by ``finish()``
-        and the cross-query batched dispatcher)."""
-        order = sorted_rows(batch, self.keys)
 
-        def gat(data):
-            if data.ndim > 1:
-                safe = jnp.minimum(take, data.shape[0] - 1)
-                return jnp.where((take < data.shape[0])[:, None], data[safe], 0)
-            return gather_padded(data, take, 0)
+class OrderByOperator(_SortOperator):
+    """Full sort (reference: OrderByOperator + PagesIndex.sort)."""
 
-        with trace_span("sort:gather", "step"):
-            take = order[: self.n]
-            live = gather_padded(batch.live, take, False)
-            cols = {
-                n_: Column(
-                    gat(batch[n_].data),
-                    gather_padded(batch[n_].valid, take, False),
-                    batch[n_].dtype,
-                    batch[n_].dictionary,
-                )
-                for n_ in batch.names
-            }
-            return Batch(cols, live)
+    kind = "order_by"
+
+    def __init__(self, keys: Sequence[SortKey], params: Sequence[Any] = ()):
+        super().__init__(keys, None, params)
+
+
+class TopNOperator(_SortOperator):
+    """Sort + limit with bounded output (reference: TopNOperator)."""
+
+    kind = "top_n"
+
+    def __init__(self, keys: Sequence[SortKey], n: int,
+                 params: Sequence[Any] = ()):
+        super().__init__(keys, n, params)
 
 
 class WindowOperator(CollectingOperator):

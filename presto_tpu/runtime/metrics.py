@@ -490,14 +490,21 @@ METRIC_HELP: dict[str, str] = {
         "row slots the window steps sorted: the summed capacities of "
         "the batches concatenated, live or not (static shapes, no "
         "device read)"),
+    "exec.sort.steps": (
+        "final-sort steps dispatched (OrderByOperator / TopNOperator."
+        "finish: the held batches' concatenation, the key expressions, "
+        "the order and the row gather as ONE cached program, kinds "
+        "order_by / top_n in system.exec_cache) — one an executed "
+        "ORDER BY or TopN node that held a batch"),
     "exec.window.compacted": (
         "window inputs of 2^20 slots or more compacted to their live "
         "rows' capacity bucket before the step (as exec.topn.compacted; "
         "exec.window.slots then counts the bucket)"),
     "exec.topn.compacted": (
         "TopN inputs of 2^20 slots or more compacted to their live "
-        "rows' capacity bucket before the sort (where that at least "
-        "halves the slots; the count is one sync:live_count read)"),
+        "rows' capacity bucket before the sort step, which sorts every "
+        "slot it is handed (where that at least halves the slots; the "
+        "count is one sync:live_count read)"),
     "exec.probe.slots": (
         "row slots the unique, semi and anti join probes gathered over: "
         "the capacity of every batch handed to a probe step, live or "

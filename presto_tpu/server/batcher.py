@@ -72,7 +72,6 @@ def _lower(node: N.PlanNode, catalog):
         OrderByOperator,
         SortKey,
         TopNOperator,
-        concat_batches,
     )
     from presto_tpu.runtime.errors import InternalError
 
@@ -123,7 +122,7 @@ def _lower(node: N.PlanNode, catalog):
             out = child(bs, params)
             if not out:
                 return []
-            return [op.result_batch(concat_batches(out))]
+            return [op.result_batch(out, params)]
 
         return sort_fn
     raise InternalError(

@@ -22,7 +22,7 @@ from presto_tpu.ops.join import (
     probe_unique,
 )
 from presto_tpu.ops.partition import partition_layout, scatter_to_buffer
-from presto_tpu.ops.sort import sort_indices, top_n_indices
+from presto_tpu.ops.sort import packed_sort_order, sort_indices
 
 
 def _live(n, cap):
@@ -318,29 +318,37 @@ def test_probe_exists():
     np.testing.assert_array_equal(np.asarray(m), [False, True, False, True, False, True])
 
 
-def test_sort_and_topn(rng):
+ORDERERS = pytest.mark.parametrize(
+    "orderer", [sort_indices, packed_sort_order],
+    ids=["chained_argsorts", "packed_words"])
+
+
+@ORDERERS
+def test_sort_and_topn(rng, orderer):
     cap, n = 32, 20
     k1 = rng.integers(0, 5, cap).astype(np.int64)
     k2 = rng.integers(0, 100, cap).astype(np.int64)
     live = _live(n, cap)
-    order = sort_indices([jnp.asarray(k1), jnp.asarray(k2)], [False, True], live)
+    order = orderer([jnp.asarray(k1), jnp.asarray(k2)], [False, True], live)
     o = np.asarray(order)[:n]
     df = pd.DataFrame({"k1": k1[:n], "k2": k2[:n]}).sort_values(
         ["k1", "k2"], ascending=[True, False], kind="stable"
     )
     np.testing.assert_array_equal(k1[o], df["k1"].to_numpy())
     np.testing.assert_array_equal(k2[o], df["k2"].to_numpy())
-    top = top_n_indices([jnp.asarray(k2)], [True], live, 5)
+    # Top-N is the order's static prefix
+    top = orderer([jnp.asarray(k2)], [True], live)[:5]
     want_top = np.sort(k2[:n])[::-1][:5]
-    np.testing.assert_array_equal(np.sort(k2[np.asarray(top)])[::-1], want_top)
+    np.testing.assert_array_equal(k2[np.asarray(top)], want_top)
 
 
-def test_sort_nulls_ordering():
+@ORDERERS
+def test_sort_nulls_ordering(orderer):
     cap = 8
     k = jnp.asarray(np.array([3, 1, 2, 5, 4, 0, 0, 0], dtype=np.int64))
     valid = jnp.asarray(np.array([1, 1, 0, 1, 0, 0, 0, 0], bool))
     live = _live(5, cap)
-    order = sort_indices([k], [False], live, nulls_first=[False], valids=[valid])
+    order = orderer([k], [False], live, nulls_first=[False], valids=[valid])
     o = np.asarray(order)[:5]
     np.testing.assert_array_equal(o, [1, 0, 3, 2, 4])  # 1,3,5 then nulls (2,4)
     order_nf = sort_indices([k], [False], live, nulls_first=[True], valids=[valid])

@@ -255,3 +255,89 @@ def test_probe_compaction_and_a_compacted_dense_probe_compile(
         ((build_cap,), jnp.bool_), ((build_cap,), jnp.bool_)] + one]
     text = jax.jit(probe).lower(*args).compile().as_text()
     assert "tpu_custom_call" not in text and "gather" in text
+
+
+# the statement's final sort (exec/operators._make_sort_step: ONE
+# program for the held batches' concatenation, the keys, the order and
+# the row gather) at the shapes the benchmark's templates hand it at
+# SF1 — capacities, column types and keys read off a CPU run of each
+# template (PERF.md §6, PR 39). The TPU's compiler prices a sort by its
+# operands: every sort here has ONE (a chained argsort carries the row
+# index beside its key: 5 of them took 50 s at Q13's 65,536 rows where
+# this program takes 2.6 s), and the whole step compiles in seconds
+def _dict(n):
+    from presto_tpu.batch import Dictionary
+
+    return Dictionary([f"v{i:05d}" for i in range(n)])
+
+
+def _sort_shapes():
+    from presto_tpu.types import (DATE, INTEGER, VARCHAR, decimal,
+                                  fixed_bytes)
+
+    dec4, dec2 = decimal(38, 4), decimal(38, 2)
+    q3 = {"l_orderkey": BIGINT, "revenue": dec4, "o_orderdate": DATE,
+          "o_shippriority": INTEGER}
+    q3_keys = [("revenue", True), ("o_orderdate", False)]
+    q67 = {"i_category": (VARCHAR, 10), "i_class": (VARCHAR, 100),
+           "i_brand": (VARCHAR, 1000), "i_product_name": fixed_bytes(50),
+           "d_year": INTEGER, "d_qoy": INTEGER, "d_moy": INTEGER,
+           "s_store_id": fixed_bytes(16), "sumsales": dec2, "rk": BIGINT}
+    q70 = {"total_sum": dec2, "s_state": (VARCHAR, 10),
+           "s_county": (VARCHAR, 30), "lochierarchy": INTEGER,
+           "rank_within_parent": BIGINT}
+    return {
+        "tpch_q3_top10_of_32768": (q3, q3_keys, 10, 32768),
+        "tpch_q13_order_by_65536": (
+            {"c_count": BIGINT, "custdist": BIGINT},
+            [("custdist", True), ("c_count", True)], None, 65536),
+        "ssb_q2_1_order_by_8192": (
+            {"revenue": dec2, "d_year": INTEGER,
+             "p_brand1": (VARCHAR, 1000)},
+            [("d_year", False), ("p_brand1", False)], None, 8192),
+        "tpcds_q67_top100_of_2048": (q67, [(n, False) for n in q67], 100,
+                                     2048),
+        "tpcds_q70_top100_of_851": (
+            q70, [("lochierarchy", True), ("s_state", False),
+                  ("rank_within_parent", False), ("s_state", False),
+                  ("s_county", False)], 100, 851),
+        "mesh_q3_top10_of_64": (q3, q3_keys, 10, 64),
+    }
+
+
+@pytest.mark.parametrize("shape", sorted(_sort_shapes()))
+def test_the_final_sort_step_compiles_in_seconds(one_chip, shape):
+    import time
+
+    from presto_tpu.exec.operators import (OrderByOperator, SortKey,
+                                           TopNOperator)
+    from presto_tpu.expr import col
+    from presto_tpu.types import TypeKind
+
+    cols, keys, n, cap = _sort_shapes()[shape]
+    cols = {name: t if isinstance(t, tuple) else (t, None)
+            for name, t in cols.items()}
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    batch = Batch(
+        {name: Column(
+            sds((cap, t.width), jnp.uint8) if t.kind is TypeKind.BYTES
+            else sds((cap,), t.jnp_dtype), sds((cap,), jnp.bool_), t,
+            None if size is None else _dict(size))
+         for name, (t, size) in cols.items()}, sds((cap,), jnp.bool_))
+    sort_keys = [SortKey(col(name, cols[name][0]), desc)
+                 for name, desc in keys]
+    op = (OrderByOperator(sort_keys) if n is None
+          else TopNOperator(sort_keys, n))
+    t0 = time.perf_counter()
+    text = op._step.lower((batch,), ()).compile().as_text()
+    took = time.perf_counter() - t0
+    sorts = re.findall(r"= (\S+) sort\(([^)]*)\)", text)
+    assert sorts and "tpu_custom_call" not in text
+    # one operand each: an array comes back, never a tuple of them
+    assert all(not out.startswith("(") and "," not in args
+               for out, args in sorts), sorts
+    # measured alone: 0.2 - 2.6 s; a tier-1 run compiles six at once
+    assert took < 60.0, took

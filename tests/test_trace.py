@@ -827,10 +827,10 @@ def test_a_real_querys_self_times_sum_to_its_query_span(conn):
     assert all(v >= 0.0 for v in own.values())
     df = s.sql("select name, duration_s, self_s from trace_spans")
     assert (df["self_s"] <= df["duration_s"] + 1e-6).all()
-    # the final sort's finish is parted, and the scan's lookup is named
+    # the final sort's finish is one step, and the scan's lookup is named
     names = {sp.name for sp in rec.spans}
-    assert {"sort:keys", "sort:order", "sort:gather",
-            "scan:lookup"} <= names, sorted(names)
+    assert {"step:sort", "scan:lookup"} <= names, sorted(names)
+    assert not {n for n in names if n.startswith("sort:")}, sorted(names)
 
 
 def _dispatch_calls():
@@ -953,8 +953,8 @@ def test_join_filter_and_every_new_span_use_a_listed_category(served_conns):
     rec = s.traces.latest()
     assert {sp.cat for sp in rec.spans} <= set(trace.CATEGORIES)
     names = {sp.name for sp in rec.spans}
-    assert {"join_filter", "join:prepare", "held:concat", "sort:keys",
-            "sort:order", "sort:gather", "step:agg_fold"} <= names, names
+    assert {"join_filter", "join:prepare", "held:concat", "step:sort",
+            "step:agg_fold"} <= names, names
     assert {sp.cat for sp in rec.spans if sp.name == "join_filter"} == {
         "step"}
 
