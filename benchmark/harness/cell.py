@@ -37,16 +37,23 @@ def load_template(name: str) -> dict:
     return t
 
 
+def quantity(kind: str, name: str) -> str:
+    """The file stem a metric is read by: its own name, or for
+    ``<quantity>.<variant>`` without a file of its own ``<quantity>``."""
+    own = os.path.join(BENCH_DIR, kind, name + ".json")
+    if os.path.exists(own) or "." not in name:
+        return name
+    return name.rsplit(".", 1)[0]
+
+
 def load_metric_file(kind: str, name: str) -> dict:
     """``end_to_end/<name>.json`` or ``layer_metrics/<name>.json``: how
     the number is read. ``<quantity>.<variant>`` without a file of its
     own is read as ``<quantity>`` is: the contract splits a quantity
     whose cells report different end-to-end metrics into one entry per
     ``moves``, and the entries share the measurement."""
-    path = os.path.join(BENCH_DIR, kind, name + ".json")
-    if not os.path.exists(path) and "." in name:
-        path = os.path.join(BENCH_DIR, kind, name.rsplit(".", 1)[0] + ".json")
-    return _load_json(path)
+    return _load_json(os.path.join(BENCH_DIR, kind,
+                                   quantity(kind, name) + ".json"))
 
 
 def apply_env(argv, root: str = ROOT) -> dict:

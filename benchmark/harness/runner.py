@@ -26,7 +26,7 @@ from benchmark.harness import client, compare, metrics
 #: degradation, and are printed on the ``window`` line
 MUST_STAY_ZERO = ("exec.q1_route_fallback",
                   "exec.leaf_route_fallback.value_overflow",
-                  "join.pallas_fallback", "query.oom_degraded")
+                  "query.oom_degraded")
 
 
 class SetupError(RuntimeError):
@@ -70,6 +70,14 @@ def snapshot() -> dict:
 def delta(after: dict, before: dict) -> dict:
     return {k: v - before.get(k, 0) for k, v in sorted(after.items())
             if isinstance(v, (int, float)) and v != before.get(k, 0)}
+
+
+def window_counters(after: dict, before: dict) -> dict:
+    """What the readers get of the registry: the window's deltas
+    (``counters``: what moved) and every name it holds at the window's
+    end (``counter_names``), so that a reader can tell a counter that
+    did not move from one the program does not have."""
+    return {"counters": delta(after, before), "counter_names": sorted(after)}
 
 
 def attach(chips: int, rehearse: bool) -> dict:
@@ -316,7 +324,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         server.shutdown()
 
     records = [r for s in streams for r in s.completions]
-    window = delta(s2, s1)
+    moved = window_counters(s2, s1)
+    window = moved["counters"]
     failed = [r for r in records if not r["ok"]]
     medians = metrics.template_medians_ms(records)
     counts: dict = {}
@@ -369,7 +378,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              by_pair=cnum["by_pair"])
 
     ctx = {"spec": spec, "records": records, "t_first": t_first,
-           "seconds": seconds, "setup_s": setup_s, "counters": window,
+           "seconds": seconds, "setup_s": setup_s, **moved,
            "spans": spans, "scan_log": getattr(served, "log", []),
            "traced": traced, "prof_dir": prof_dir, "conn": conn,
            "memory_peak_bytes": peak, "device": device,
@@ -398,6 +407,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                                                 "unit": v["unit"]}
                              for k, v in result["metrics"].items()}
         result["rehearsal"] = True
+    # each number compared beside its limit: the result's last key, and
+    # (main) the run's last lines on standard error
+    result["compared"] = {k: {"value": v["value"], "limit": v["limit"]}
+                          for k, v in table.items()}
     return result
 
 
@@ -440,5 +453,8 @@ def main(argv, t_start: float, *, rehearse: bool = False) -> int:
     except SetupError as e:
         print(f"benchmark: {e}", file=sys.stderr, flush=True)
         return 1
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0

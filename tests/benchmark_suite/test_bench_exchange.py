@@ -15,11 +15,13 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from benchmark.harness import cell as C  # noqa: E402
 from benchmark.harness import device_trace as DT  # noqa: E402
 from benchmark.harness import exchange_model  # noqa: E402
 from benchmark.readers import device_by_op, exchange_ici  # noqa: E402
+import bench_rules as R  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CELL = "tpch_sf1_mesh4_1s"
@@ -49,14 +51,15 @@ def test_the_cell_resolves_to_its_files():
     assert spec["traffic"]["streams"] == 1
     assert {m["name"] for m in spec["end_to_end"]} == {
         "query_geomean_ms", "rows_per_s", "setup_s"}
+    assert R.family(BENCH, CELL) == "query_geomean_ms"
     listed = {m["name"] for m in spec["per_layer"]}
     assert set(EXCHANGE) <= listed
     # what reads nothing on the mesh is not listed: the distributed scan
     # calls generate_split, not the proxied connector.scan, and the two
     # module metrics' selectors name the local executor's programs
     assert not listed & {"scan_host_ms", "agg_device_ms", "join_device_ms"}
-    # four chips for one cell of the benchmark, no more
-    assert [w["name"] for w in BENCH["workloads"] if w["chips"] == 4] == [CELL]
+    # four chips for this cell, and for at most half of the cells
+    assert CELL in R.FOUR_CHIP and R.four_chip_cells(BENCH) == []
 
 
 @pytest.mark.parametrize("name", sorted(EXCHANGE))
@@ -70,8 +73,12 @@ def test_each_exchange_entry_has_a_file_and_a_reader(name):
         f"benchmark.readers.{spec['reader']}").read)
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == name]
     assert (entry["layer"], entry["unit"]) == (spec["layer"], spec["unit"])
-    assert entry["workloads"] == [CELL]
-    assert entry["moves"] == "query_geomean_ms"
+    # an exchange exists only across chips: the mesh cell, and no cell
+    # on one chip
+    chips = {w["name"]: w["chips"] for w in BENCH["workloads"]}
+    assert CELL in entry["workloads"]
+    assert {chips[c] for c in entry["workloads"]} == {4}
+    assert entry["moves"] == R.family(BENCH, CELL)
 
 
 @pytest.fixture(scope="module")

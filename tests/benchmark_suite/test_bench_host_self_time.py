@@ -1,8 +1,8 @@
 """The host's side of a query (PR 37): the ``span_self_time`` reader on
 made-up spans, the six entries it and ``counter_per_query`` read — each
-one file, one callable reader, a pair of ``BENCHMARK.json`` entries —
-``idle_unnamed_pct.star``, and each cell's CPU rehearsal listing the
-six."""
+one file, one callable reader, one ``BENCHMARK.json`` entry a family of
+cells (``bench_rules.py``) — ``idle_unnamed_pct.host``, and each
+cell's CPU rehearsal listing the six."""
 
 import importlib
 import json
@@ -15,14 +15,13 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from benchmark.harness import cell as C  # noqa: E402
 from benchmark.readers import counter_per_query, span_self_time  # noqa: E402
+import bench_rules as R  # noqa: E402
 
 BENCH = C.load_benchmark()
-ONE_STREAM = ["tpch_sf1_join_1s", "ssb_sf1_star_1s", "tpch_sf1_mesh4_1s",
-              "tpcds_sf1_rollup_rank_1s"]
-SCAN = ["tpch_sf1_scan_agg_2s"]
 #: entry -> (reader, layer, unit, source)
 ENTRIES = {
     "dispatches": ("counter_per_query", "kernels", "count",
@@ -202,17 +201,21 @@ def test_each_entry_has_one_file_a_callable_reader_and_its_pair(name):
         reader, layer, unit)
     assert callable(importlib.import_module(
         f"benchmark.readers.{reader}").read)
-    # the scan cell's twin reads the same file
-    assert C.load_metric_file("layer_metrics", name + ".throughput") == spec
-    pair = {m["name"]: m for m in BENCH["per_layer"]
-            if m["name"] in (name, name + ".throughput")}
-    assert pair[name]["workloads"] == ONE_STREAM
-    assert pair[name]["moves"] == "query_geomean_ms"
-    assert pair[name + ".throughput"]["workloads"] == SCAN
-    assert pair[name + ".throughput"]["moves"] == "query_p90_ms"
-    for m in pair.values():
+    # one entry a family, each read by the same file, listing every cell
+    # that reports what it moves (``bench_rules.every_cell_lists``): the
+    # cells of the family whose latency metric that is
+    entries = R.entries_of(BENCH, name)
+    assert {m["name"] for m in entries} >= {name, name + ".throughput",
+                                            name + ".host"}
+    for m in entries:
+        assert C.load_metric_file("layer_metrics", m["name"]) == spec
+        assert sorted(m["workloads"]) == sorted(
+            R.reporting(BENCH, m["moves"]))
+        assert {R.family(BENCH, c) for c in m["workloads"]} == {m["moves"]}
         assert (m["layer"], m["unit"], m["better"], m["source"]) == (
             layer, unit, "lower", source)
+    assert sorted(c for m in entries for c in m["workloads"]) == sorted(
+        R.cells(BENCH))
 
 
 def test_the_counter_entries_on_a_made_up_window_and_on_the_parents():
@@ -231,21 +234,21 @@ def test_the_counter_entries_on_a_made_up_window_and_on_the_parents():
 
 
 def test_the_star_cell_reads_its_coverage_from_the_file_that_is_there():
-    entry, = [m for m in BENCH["per_layer"]
-              if m["name"] == "idle_unnamed_pct.star"]
-    assert entry == {"name": "idle_unnamed_pct.star", "unit": "%",
-                     "better": "lower", "source": "device_trace",
-                     "layer": "device", "moves": "query_geomean_ms",
-                     "workloads": ["ssb_sf1_star_1s"]}
-    assert C.load_metric_file("layer_metrics", "idle_unnamed_pct.star") == \
+    entry = R.entry_for(BENCH, "idle_unnamed_pct", "ssb_sf1_star_1s")
+    base, = [m for m in BENCH["per_layer"]
+             if m["name"] == "idle_unnamed_pct"]
+    # the base entry's twin for the star cell's family: what the base
+    # moves, under that family's name
+    assert entry == dict(base, name="idle_unnamed_pct.host",
+                         moves=base["moves"] + ".host",
+                         workloads=["ssb_sf1_star_1s"])
+    assert C.load_metric_file("layer_metrics", entry["name"]) == \
         C.load_metric_file("layer_metrics", "idle_unnamed_pct")
     assert not os.path.exists(os.path.join(
-        ROOT, "benchmark", "layer_metrics", "idle_unnamed_pct.star.json"))
-    # the 13 entries are the list's last: nothing before them moved
-    assert [m["name"] for m in BENCH["per_layer"]][-13:] == [
-        n + v for n in ("dispatches", "dispatch_host_ms", "finish_host_ms",
-                        "host_unnamed_ms", "gc_pause_ms", "query_cpu_ms")
-        for v in ("", ".throughput")] + ["idle_unnamed_pct.star"]
+        ROOT, "benchmark", "layer_metrics", entry["name"] + ".json"))
+    # PR 37's 13 entries are found by name, wherever they stand: each is
+    # still there, once
+    assert R.names_kept(BENCH) == [] and len(R.KEPT) == 13
 
 
 @pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
@@ -261,11 +264,12 @@ def test_each_cells_rehearsal_lists_the_six(workload, tmp_path):
     lines = [json.loads(x) for x in p.stdout.strip().splitlines()]
     last = lines[-1]
     assert last["correct"] is True and last["failed"] == 0
-    suffix = ".throughput" if workload in SCAN else ""
     for name in ENTRIES:
-        assert f"rehearsal.{name}{suffix}" in last["metrics"], name
+        entry = R.entry_for(BENCH, name, workload)
+        assert entry is not None, name
+        assert f"rehearsal.{entry['name']}" in last["metrics"], name
     # device-only: no device plane on the CPU
-    assert "rehearsal.idle_unnamed_pct.star" not in last["metrics"]
+    assert not [k for k in last["metrics"] if "idle_unnamed_pct" in k]
     window, = [ln for ln in lines if ln.get("event") == "window"]
     done = window["attempted"] - window["failed"]
     calls = window["counters"]["exec.dispatch.calls"]

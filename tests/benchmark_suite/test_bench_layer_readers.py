@@ -14,6 +14,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from benchmark.harness import cell as C  # noqa: E402
 from benchmark.harness import device_trace as DT  # noqa: E402
@@ -22,6 +23,7 @@ from benchmark.readers import (  # noqa: E402
     device_by_module,
     idle_unnamed,
 )
+import bench_rules as R  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = C.load_benchmark()
@@ -39,10 +41,6 @@ MEASUREMENTS = {
 DEVICE_ONLY = ("agg_device_ms", "join_device_ms", "idle_unnamed_pct")
 
 
-def _quantity(name: str) -> str:
-    return name.rsplit(".", 1)[0] if name.endswith(".throughput") else name
-
-
 @pytest.mark.parametrize("name", sorted(MEASUREMENTS))
 def test_each_measurement_has_one_file_and_a_reader(name):
     path = os.path.join(C.BENCH_DIR, "layer_metrics", name + ".json")
@@ -52,17 +50,19 @@ def test_each_measurement_has_one_file_and_a_reader(name):
     assert spec["reader"] == MEASUREMENTS[name]
     assert callable(importlib.import_module(
         f"benchmark.readers.{spec['reader']}").read)
-    entries = [m for m in BENCH["per_layer"] if _quantity(m["name"]) == name]
+    entries = [m for m in BENCH["per_layer"] if R.quantity(m["name"]) == name]
     assert entries, name
     for m in entries:
         assert (m["layer"], m["unit"]) == (spec["layer"], spec["unit"])
         # a variant has no file of its own
         assert C.load_metric_file("layer_metrics", m["name"]) == spec
-        if m["name"].endswith(".throughput"):
-            assert m["moves"] == "query_p90_ms"
-    # the scan cell reads the variant, the one-stream cells the plain name
-    cells = {w for m in entries for w in m["workloads"]}
-    assert "tpch_sf1_join_1s" in cells
+        # a family's variant moves a metric of that family: one that
+        # every cell it lists reports
+        for cell in m["workloads"]:
+            assert cell in R.reporting(BENCH, m["moves"]), (m["name"], cell)
+    # each family's cells read the entry of their family, once
+    cells = [w for m in entries for w in m["workloads"]]
+    assert "tpch_sf1_join_1s" in cells and len(cells) == len(set(cells))
 
 
 @pytest.fixture(scope="module")
@@ -127,6 +127,30 @@ def test_counter_per_query_on_a_made_up_window(ctx):
     assert counter_per_query.read(dict(ctx, records=[]), sel) is None
 
 
+@pytest.mark.parametrize("names, want", [
+    (None, None),                                   # the parent's runner
+    ([], None),
+    (["exec.traces", "exec.h2d.arrays"], None),     # no such counter
+    (["exec.traces", "exec.h2d.bytes"], 0.0),       # there, and unmoved
+])
+def test_an_unmoved_counter_reads_zero_an_unknown_one_nothing(ctx, names,
+                                                              want):
+    """A resident warm window uploads nothing: ``exec.h2d.bytes`` is in
+    the registry (the warm-up moved it) and not among the window's
+    deltas. The runner hands over the registry's names at the window's
+    end; without them the reader says what the parent's said."""
+    sel = C.load_metric_file("layer_metrics", "h2d_mb")["selector"]
+    still = dict(ctx, counters={"exec.traces": 5})
+    if names is not None:
+        still["counter_names"] = names
+    assert counter_per_query.read(still, sel) == want
+    # a counter that moved reads what it read, named or not
+    moved = dict(ctx) if names is None else dict(ctx, counter_names=names)
+    assert counter_per_query.read(moved, sel) == pytest.approx(1.5)
+    # no completed query: nothing to divide by, whatever is named
+    assert counter_per_query.read(dict(still, records=[]), sel) is None
+
+
 @pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
 def test_each_cells_rehearsal_lists_its_new_entries(workload, tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -140,9 +164,9 @@ def test_each_cells_rehearsal_lists_its_new_entries(workload, tmp_path):
     last = json.loads(p.stdout.strip().splitlines()[-1])
     assert last["correct"] is True and last["failed"] == 0
     new = [m["name"] for m in C.load_cell(workload)["per_layer"]
-           if _quantity(m["name"]) in MEASUREMENTS]
+           if R.quantity(m["name"]) in MEASUREMENTS]
     assert len(new) >= 12
     for name in new:
         listed = f"rehearsal.{name}" in last["metrics"]
         # no device plane on the CPU: nothing is written under those names
-        assert listed == (_quantity(name) not in DEVICE_ONLY), name
+        assert listed == (R.quantity(name) not in DEVICE_ONLY), name

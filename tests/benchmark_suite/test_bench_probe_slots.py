@@ -13,25 +13,32 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from benchmark.harness import cell as C  # noqa: E402
 from benchmark.readers import counter_per_query  # noqa: E402
+import bench_rules as R  # noqa: E402
 
-CELLS = ["tpch_sf1_join_1s", "ssb_sf1_star_1s", "tpcds_sf1_rollup_rank_1s"]
+BENCH = C.load_benchmark()
+#: the cells that list it at the least: a later cell with a unique or
+#: semi probe joins the entry of its family
+CELLS = R.AT_LEAST["probe_slots"]
 
 
 def test_the_entry_names_its_file_its_reader_and_its_cells():
     spec = C.load_metric_file("layer_metrics", "probe_slots")
     assert spec["reader"] == "counter_per_query"
     assert spec["selector"] == {"counters": ["exec.probe.slots"]}
-    entry, = [m for m in C.load_benchmark()["per_layer"]
-              if m["name"] == "probe_slots"]
-    assert (entry["layer"], entry["unit"], entry["better"]) == (
-        spec["layer"], spec["unit"], "lower") == ("kernels", "count", "lower")
-    assert entry["moves"] == "query_geomean_ms"
-    assert entry["workloads"] == CELLS
+    assert R.listed_at_least(BENCH) == [] and len(CELLS) == 3
     for cell in CELLS:
-        assert "probe_slots" in [
+        entry = R.entry_for(BENCH, "probe_slots", cell)
+        assert (entry["layer"], entry["unit"], entry["better"]) == (
+            spec["layer"], spec["unit"], "lower") == (
+            "kernels", "count", "lower")
+        # the arrow ends at the latency metric of the cell's family
+        assert entry["moves"] == R.family(BENCH, cell)
+        assert C.load_metric_file("layer_metrics", entry["name"]) == spec
+        assert entry["name"] in [
             m["name"] for m in C.load_cell(cell)["per_layer"]]
 
 
@@ -44,6 +51,12 @@ def test_the_reader_on_a_made_up_window_and_on_the_parents():
     # the parent has no such counter: nothing to read, and no error
     assert counter_per_query.read(
         dict(ctx, counters={"exec.sync.reads": 9}), sel) is None
+    # ... and a program that has it, in a window that did not move it
+    # (the registry's names at the window's end say which): 0.0
+    assert counter_per_query.read(
+        dict(ctx, counters={"exec.sync.reads": 9},
+             counter_names=["exec.probe.slots", "exec.sync.reads"]),
+        sel) == 0.0
 
 
 def test_the_star_cells_rehearsal_lists_it(tmp_path):
@@ -57,7 +70,8 @@ def test_the_star_cells_rehearsal_lists_it(tmp_path):
     assert p.returncode == 0, p.stderr[-3000:]
     lines = [json.loads(x) for x in p.stdout.strip().splitlines()]
     assert lines[-1]["correct"] is True and lines[-1]["failed"] == 0
-    assert "rehearsal.probe_slots" in lines[-1]["metrics"]
+    name = R.entry_for(BENCH, "probe_slots", "ssb_sf1_star_1s")["name"]
+    assert f"rehearsal.{name}" in lines[-1]["metrics"]
     window, = [x for x in lines if x.get("event") == "window"]
     done = window["attempted"] - window["failed"]
     # three dense probes over each of lineorder's splits, a query: at

@@ -24,18 +24,18 @@ def _run(args, cwd=ROOT, script="benchmark/prove.py"):
     return p, lines
 
 
-def _rehearse(*extra):
+def _rehearse(*extra, stderr=False):
     p, lines = _run(["--rehearse", "--workload", CELL, "--seed",
                      str(2**31 + 4242), "--seconds", "2", *extra])
     assert p.returncode == 0, p.stderr[-3000:]
-    return lines
+    return (lines, p.stderr) if stderr else lines
 
 
 def test_rehearsal_prints_the_contracts_last_line(tmp_path):
-    lines = _rehearse("--control", "--trace", "0")
+    lines, stderr = _rehearse("--control", "--trace", "0", stderr=True)
     last = lines[-1]
     assert set(last) == {"correct", "attempted", "failed", "metrics",
-                         "device", "rehearsal"}
+                         "device", "rehearsal", "compared"}
     assert last["correct"] is True and last["failed"] == 0
     assert last["attempted"] >= 6
     assert last["device"]["platform"] == "cpu"
@@ -47,6 +47,15 @@ def test_rehearsal_prints_the_contracts_last_line(tmp_path):
     # each number compared is printed beside its limit
     assert all({"value", "limit", "ok"} <= set(v)
                for v in by["correct"]["compared"].values())
+    # ... in the result's line too, under its last key, and as the last
+    # lines of standard error
+    assert list(last)[-1] == "compared"
+    assert last["compared"] == {
+        k: {"value": v["value"], "limit": v["limit"]}
+        for k, v in by["correct"]["compared"].items()}
+    assert stderr.strip().splitlines()[-len(last["compared"]):] == [
+        f"compared {k}: {v['value']} (limit {v['limit']})"
+        for k, v in last["compared"].items()]
     # the control (float32 sums) comes out as not correct, on the cents
     assert by["control"]["correct"] is False
     assert not by["control"]["compared"]["max_cent_gap"]["ok"]
