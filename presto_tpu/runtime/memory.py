@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import threading
 import time
+import weakref
 from collections import deque
 
 from presto_tpu.plan import nodes as N
@@ -56,36 +57,62 @@ DEFAULT_POOL_HEADROOM = 64
 #: subtraction exists for).
 _DEFAULT_BUDGET: int | None = None
 
+#: owner (a ``spi.SplitStore``) -> the bytes of the default device it
+#: may keep resident scan columns in. The CONFIGURED bytes, not what
+#: the allocator holds of them: whether the columns were admitted
+#: before or after the snapshot above must not change what is left.
+_RESIDENT: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+_RESIDENT_LOCK = threading.Lock()
+
+
+def reserve_resident(owner, nbytes: int) -> None:
+    """Set aside ``nbytes`` of the default device for ``owner``'s
+    resident columns (0 gives them back; a collected owner's go with
+    it). Called BEFORE the owner may admit anything, and it takes the
+    snapshot first: the allocator's ``bytes_in_use`` then never
+    includes a resident column, and :func:`device_budget_bytes` falls
+    by exactly ``nbytes``."""
+    device_budget_bytes()
+    with _RESIDENT_LOCK:
+        if nbytes:
+            _RESIDENT[owner] = int(nbytes)
+        else:
+            _RESIDENT.pop(owner, None)
+
 
 def device_budget_bytes(device=None) -> int:
     """Usable device memory for resident operator state: half the
     backend's byte limit MINUS what the allocator already held at
     first call (a warm process must not over-admit against memory it
-    cannot get back), floored at :data:`MIN_BUDGET_BYTES`. The default
-    -device value is computed once per process; passing an explicit
-    ``device`` always measures fresh."""
+    cannot get back) MINUS what :func:`reserve_resident` has set aside
+    of the default device, floored at :data:`MIN_BUDGET_BYTES`. The
+    default-device snapshot is taken once per process; passing an
+    explicit ``device`` always measures fresh, nothing set aside."""
     global _DEFAULT_BUDGET
-    if device is None and _DEFAULT_BUDGET is not None:
-        return _DEFAULT_BUDGET
-    import jax
+    if device is not None:
+        return _measured_budget(device)
+    if _DEFAULT_BUDGET is None:
+        import jax
 
-    dev = device or jax.devices()[0]
+        _DEFAULT_BUDGET = _measured_budget(jax.devices()[0])
+    with _RESIDENT_LOCK:
+        resident = sum(_RESIDENT.values())
+    return max(_DEFAULT_BUDGET - resident, MIN_BUDGET_BYTES)
+
+
+def _measured_budget(dev) -> int:
     stats = dev.memory_stats()
     if stats and "bytes_limit" in stats:
         budget = int(stats["bytes_limit"] * 0.5)
         budget -= int(stats.get("bytes_in_use", 0))
-        budget = max(budget, MIN_BUDGET_BYTES)
-    elif dev.platform == "tpu":
+        return max(budget, MIN_BUDGET_BYTES)
+    if dev.platform == "tpu":
         # a chip that does not say how much memory it has is a broken
         # attachment, not a device to size queries against a guess
         raise InternalError(
             f"TPU device {dev} reports no bytes_limit "
             f"(memory_stats() = {stats!r})")
-    else:
-        budget = DEFAULT_BUDGET_BYTES  # the CPU backend reports none
-    if device is None:
-        _DEFAULT_BUDGET = budget
-    return budget
+    return DEFAULT_BUDGET_BYTES  # the CPU backend reports none
 
 
 class MemoryPool:

@@ -480,6 +480,20 @@ METRIC_HELP: dict[str, str] = {
     "exec.scan.store.bytes": (
         "bytes of padded host columns the split stores have taken in "
         "(nothing is evicted: what is held)"),
+    "exec.scan.resident.hits": (
+        "column-split lookups the split store's device tier answered "
+        "(scan_resident_budget_bytes > 0): nothing uploaded"),
+    "exec.scan.resident.misses": (
+        "column-split lookups the device tier could not answer: the "
+        "column came from the host tier or from generation and was "
+        "uploaded (batch:upload)"),
+    "exec.scan.resident.bypassed": (
+        "device-tier inserts refused because they would pass the "
+        "store's byte budget: that scan was served from its own upload "
+        "and the host tier keeps the columns"),
+    "exec.scan.resident.bytes": (
+        "bytes of uploaded columns the split stores' device tiers have "
+        "taken in (nothing is evicted: what is held)"),
     "exec.window.dispatches": (
         "window steps dispatched (WindowOperator.finish: one sort by "
         "partition and order keys, then segmented scans)"),

@@ -121,6 +121,19 @@ SESSION_PROPERTIES: dict[str, PropertyDef] = {
             _positive,
         ),
         PropertyDef(
+            "scan_resident_budget_bytes", int, 0,
+            "Bytes of device memory in which each generated connector "
+            "(tpch, ssb, tpcds) keeps the uploaded columns of its "
+            "splits (spi.SplitStore's device tier): a scan whose "
+            "columns are all held uploads nothing. Admission, not "
+            "eviction; a split past the budget is uploaded a scan as "
+            "without it (exec.scan.resident.bypassed). The budget comes "
+            "out of the device budget the steps are sized by. 0: no "
+            "device tier. Applied when the session is built or the "
+            "property is set.",
+            _non_negative,
+        ),
+        PropertyDef(
             "direct_group_limit", int, DIRECT_LIMIT,
             "Grouped aggregation uses dense direct addressing when the "
             "product of the key dictionary domains is at most this; "

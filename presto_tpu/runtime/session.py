@@ -112,6 +112,10 @@ class Session:
         self.catalog = Catalog(conns)
         self.analyzer = Analyzer(self.catalog)
         self.properties = validate_properties(dict(properties or {}))
+        if "scan_resident_budget_bytes" in self.properties:
+            # (a session that does not set it leaves the stores as they
+            # are: the server's approximate sibling shares connectors)
+            self._set_scan_resident_budget()
         workers = self.prop("mesh_devices")
         if mesh is None and workers is not None and workers > 1:
             from presto_tpu.parallel.mesh import make_mesh
@@ -237,6 +241,8 @@ class Session:
         if name == "flight_recorder_limit":
             # same take-effect rule as the rings above
             self.flight.resize(self.prop(name))
+        if name == "scan_resident_budget_bytes":
+            self._set_scan_resident_budget()
         if name == "memory_pool_bytes":
             # rebuild the private pool here — not lazily in pool() —
             # so concurrent queries always see exactly one pool
@@ -246,6 +252,17 @@ class Session:
             self._private_pool = (
                 None if cap is None else MemoryPool(cap, name="session")
             )
+
+    def _set_scan_resident_budget(self) -> None:
+        """Hand ``scan_resident_budget_bytes`` to every catalog
+        connector that keeps its splits (a connector's ``scan`` takes
+        no session): before the first query sizes a step, since the
+        budget comes out of ``device_budget_bytes``."""
+        budget = self.prop("scan_resident_budget_bytes") or 0
+        for conn in self.catalog.connectors.values():
+            store = getattr(conn, "scan_store", None)
+            if store is not None:
+                store.set_device_budget(budget)
 
     def show_session(self) -> "list[tuple[str, object, str]]":
         """(name, effective value, description) rows, SHOW SESSION."""

@@ -21,7 +21,7 @@ from typing import Callable, Mapping, Protocol, Sequence
 
 import numpy as np
 
-from presto_tpu.batch import Batch, Dictionary, HostColumns
+from presto_tpu.batch import Batch, Column, Dictionary, HostColumns
 from presto_tpu.runtime import trace
 from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.types import DataType, TypeKind, narrow_physical
@@ -98,6 +98,12 @@ def host_available_bytes() -> int:
     return avail
 
 
+def _entry_arrays(entries: Mapping) -> list:
+    """The arrays among a store's entries (a column's ``(data, mask or
+    None)``, a split's ``(live, rows)``), host or device."""
+    return [a for e in entries.values() for a in e if hasattr(a, "nbytes")]
+
+
 class SplitStore:
     """The padded host columns of an IMMUTABLE connector's splits, made
     once and kept: a warm scan is a lookup and an upload.
@@ -118,7 +124,19 @@ class SplitStore:
     its reuse. Entries are read-only arrays (``jnp.asarray`` may alias
     host memory on the CPU backend) and are inserted whole under one
     lock; generation runs outside it, so two threads that miss the same
-    split both generate and the first insert is the one both serve."""
+    split both generate and the first insert is the one both serve.
+
+    The device tier (``device_budget`` > 0; the session property
+    ``scan_resident_budget_bytes``): under the same keys, the UPLOADED
+    arrays of a split — a column's data and its mask where it has one,
+    and the split's live array — so that a scan whose columns are all
+    held uploads nothing. Admission against the byte budget, a split's
+    fresh arrays all or none, and no eviction either; what is admitted
+    here leaves the host tier (``bytes`` falls by it; the split's live
+    mask and row count stay there for the columns still to come), and
+    what the budget refuses is served from the host tier and uploaded
+    a scan, as without the tier. No step donates its input, so a held
+    array is safe to hand to every query."""
 
     #: share of the host's available memory the store may come to hold
     SHARE = 0.25
@@ -128,6 +146,9 @@ class SplitStore:
         self._entries: dict = {}
         self._available = available
         self.bytes = 0
+        self._device: dict = {}
+        self.device_budget = 0
+        self.device_bytes = 0
 
     def __len__(self) -> int:
         return len(self._entries)
@@ -136,6 +157,23 @@ class SplitStore:
         with self._lock:
             self._entries.clear()
             self.bytes = 0
+            self._device.clear()
+            self.device_bytes = 0
+
+    def set_device_budget(self, nbytes: int) -> None:
+        """Bytes of device memory the store may hold (0: no device tier,
+        and what it held is let go). The budget is taken out of
+        ``runtime.memory.device_budget_bytes`` here, before anything can
+        be admitted under it, so the steps' sizing sees the configured
+        bytes whichever of the two comes first."""
+        from presto_tpu.runtime.memory import reserve_resident
+
+        reserve_resident(self, nbytes)
+        with self._lock:
+            self.device_budget = nbytes
+            if not nbytes:
+                self._device.clear()
+                self.device_bytes = 0
 
     def columns(self, side_key: tuple, col_keys: Mapping[str, tuple],
                 make: Callable[[list], HostColumns]) -> HostColumns:
@@ -167,24 +205,67 @@ class SplitStore:
         returns what the scan is to serve: per key the entry now held —
         an earlier thread's where one got there first — or, refused,
         ``fresh`` itself."""
-        def arrays(entries):
-            return [a for e in entries.values() for a in e
-                    if isinstance(a, np.ndarray)]
-
         avail = self._available()  # reads /proc: not under the lock
         with self._lock:
             new = {k: e for k, e in fresh.items() if k not in self._entries}
-            need = sum(a.nbytes for a in arrays(new))
+            need = sum(a.nbytes for a in _entry_arrays(new))
             if self.bytes + need > self.SHARE * (avail + self.bytes):
                 REGISTRY.counter("exec.scan.store.bypassed").add(len(new))
                 return fresh
-            for a in arrays(new):
+            for a in _entry_arrays(new):
                 a.setflags(write=False)
             self._entries.update(new)
             self.bytes += need
             out = {k: self._entries[k] for k in fresh}
         REGISTRY.counter("exec.scan.store.bytes").add(need)
         return out
+
+    def resident(self, side_key: tuple,
+                 col_keys: Mapping[str, tuple]) -> tuple:
+        """``(side entry or None, {column: entry})`` of what the device
+        tier holds of a split: a column's entry is ``(data, mask or
+        None)``, the side's ``(live, rows)``. Without a budget nothing
+        is looked up and nothing counted."""
+        if not self.device_budget:
+            return None, {}
+        with self._lock:
+            side = self._device.get(side_key)
+            held = {c: self._device[k] for c, k in col_keys.items()
+                    if k in self._device}
+        REGISTRY.counter("exec.scan.resident.hits").add(len(held))
+        REGISTRY.counter("exec.scan.resident.misses").add(
+            len(col_keys) - len(held))
+        return side, held
+
+    def admit_resident(self, side_key: tuple, col_keys: Mapping[str, tuple],
+                       batch: Batch, rows: int) -> tuple:
+        """Keep the arrays of ``batch`` — the upload of the columns
+        ``col_keys`` names, the ones :meth:`resident` did not find — if
+        the budget admits them, all or none, and drop their host
+        copies. Returns ``(side entry, {column: entry})`` to serve:
+        what is now held — an earlier thread's where one got there
+        first — or, refused, the batch's own arrays."""
+        fresh = {k: (batch[c].data, None if batch[c].valid is batch.live
+                     else batch[c].valid) for c, k in col_keys.items()}
+        fresh[side_key] = (batch.live, rows)
+        with self._lock:
+            new = {k: e for k, e in fresh.items() if k not in self._device}
+            need = sum(a.nbytes for a in _entry_arrays(new))
+            if self.device_bytes + need > self.device_budget:
+                refused, need = len(new), 0
+            else:
+                refused = 0
+                self._device.update(new)
+                self.device_bytes += need
+            served = {k: self._device.get(k, e) for k, e in fresh.items()}
+            # (a thread that missed before another's admission may have
+            # put the host copies back: every device-held key's goes)
+            gone = {k: self._entries.pop(k) for k in col_keys.values()
+                    if k in self._device and k in self._entries}
+            self.bytes -= sum(a.nbytes for a in _entry_arrays(gone))
+        REGISTRY.counter("exec.scan.resident.bypassed").add(refused)
+        REGISTRY.counter("exec.scan.resident.bytes").add(need)
+        return served[side_key], {c: served[k] for c, k in col_keys.items()}
 
 
 def scan_stored(conn, split: Split, columns: Sequence[str] | None = None,
@@ -197,7 +278,13 @@ def scan_stored(conn, split: Split, columns: Sequence[str] | None = None,
     ``batch:pad``: recorded on a miss only) and kept; then the one
     upload half (``batch:upload``, ``exec.h2d.*``) runs over kept and
     fresh columns alike, so ``valid is batch.live`` holds for a
-    NULL-free column on a hit exactly as on a miss."""
+    NULL-free column on a hit exactly as on a miss.
+
+    Where the store has a device tier, the columns it holds there are
+    left out of all that — a split held whole is assembled under
+    ``scan:resident`` and nothing is uploaded — and the others' upload
+    is offered to it. The batch is then built from the tier's entries,
+    under the same identity."""
     table = split.table
 
     def make(missing):
@@ -206,6 +293,13 @@ def scan_stored(conn, split: Split, columns: Sequence[str] | None = None,
         return Batch.pad_numpy(arrays, types, valids=valids,
                                capacity=capacity or batch_capacity(n))
 
+    def assemble(side, entries):
+        live = side[0]
+        return Batch({c: Column(entries[c][0], live if entries[c][1] is None
+                                else entries[c][1], types[c], dicts.get(c))
+                      for c in cols}, live)
+
+    store = conn.scan_store
     # the host's work before the upload: the split's schema, physical
     # types and dictionaries, and the store's lookup a column (on a
     # miss the two spans of the generation lie inside)
@@ -216,12 +310,25 @@ def scan_stored(conn, split: Split, columns: Sequence[str] | None = None,
         dicts = {c: d for c, d in conn.dictionaries(table).items()
                  if c in types}
         at = (table, split.chunk, split.lo, split.hi)
-        host = conn.scan_store.columns(
-            at + (capacity,),
-            {c: at + (c, types[c].np_dtype.str, capacity) for c in cols},
-            make)
-    count_delivered(1, host.n)
-    return Batch.upload(host, types, dicts)
+        side_key = at + (capacity,)
+        col_keys = {c: at + (c, types[c].np_dtype.str, capacity)
+                    for c in cols}
+        side, held = store.resident(side_key, col_keys)
+        missing = {c: k for c, k in col_keys.items() if c not in held}
+        if missing:
+            host = store.columns(side_key, missing, make)
+            rows = host.n
+        else:
+            rows = side[1]
+            with trace.span("scan:resident", "scan"):
+                batch = assemble(side, held)
+    count_delivered(1, rows)
+    if missing:
+        batch = Batch.upload(host, types, dicts)
+        if store.device_budget:
+            side, fresh = store.admit_resident(side_key, missing, batch, rows)
+            batch = assemble(side, {**held, **fresh})
+    return batch
 
 
 def split_valids(arrays: Mapping[str, np.ndarray]):

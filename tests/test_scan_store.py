@@ -3,8 +3,11 @@
 The generated connectors (tpch, ssb, tpcds) keep each split's padded
 host columns in a ``spi.SplitStore`` behind their ``scan``: a warm scan
 is a lookup and the upload, a miss generates the missing columns only,
-and the bound is what the host has available. SF 0.01 on the CPU; the
-mesh cases on four virtual devices.
+and the bound is what the host has available. Under a byte budget
+(``SplitStore.set_device_budget``; the session property
+``scan_resident_budget_bytes``) the UPLOADED arrays are kept too, and a
+warm scan is the lookup alone (PR 41). SF 0.01 on the CPU; the mesh
+cases on four virtual devices.
 """
 
 import dataclasses
@@ -37,6 +40,11 @@ STORE = ("exec.scan.store.hits", "exec.scan.store.misses",
          "exec.scan.store.bypassed", "exec.scan.store.bytes")
 DELIVERED = ("exec.scan.splits", "exec.scan.rows", "exec.h2d.bytes",
              "exec.h2d.arrays")
+RESIDENT = ("exec.scan.resident.hits", "exec.scan.resident.misses",
+            "exec.scan.resident.bypassed", "exec.scan.resident.bytes")
+#: a device budget no scan of this file comes near
+ROOMY = 1 << 28
+COLD = ["scan:lookup", "scan:generate", "batch:pad", "batch:upload"]
 
 #: connector -> (factory, table, columns; tpcds' with two NULL-able FKs)
 GENERATED = {
@@ -51,7 +59,7 @@ GENERATED = {
 }
 
 
-def counted(fn, names=STORE + DELIVERED):
+def counted(fn, names=STORE + DELIVERED + RESIDENT):
     """``fn()`` -> (its result, the named counters' deltas)."""
     before = REGISTRY.snapshot()
     out = fn()
@@ -74,6 +82,17 @@ def recorded(fn):
 def kept_arrays(store):
     return [a for entry in store._entries.values() for a in entry
             if isinstance(a, np.ndarray)]
+
+
+def resident_arrays(store):
+    return [a for entry in store._device.values() for a in entry
+            if hasattr(a, "nbytes")]
+
+
+def budgeted(make, nbytes):
+    conn = make()
+    conn.scan_store.set_device_budget(nbytes)
+    return conn
 
 
 def assert_batches_equal(a, b):
@@ -103,6 +122,10 @@ def test_second_scan_equals_first_and_a_fresh_connectors(name):
     assert (c1["exec.scan.store.misses"], c1["exec.scan.store.hits"]) == (0, 4)
     assert c1["exec.scan.store.bytes"] == 0 < c0["exec.scan.store.bytes"]
     assert c0["exec.scan.store.bytes"] == conn.scan_store.bytes
+    # without a budget there is no device tier: nothing of it moves
+    for c in (c0, c1):
+        assert not any(c[k] for k in RESIDENT)
+    assert conn.scan_store.device_bytes == 0
     # what a scan delivers is counted hit or miss: the upload still runs
     assert {k: c1[k] for k in DELIVERED} == {k: c0[k] for k in DELIVERED}
     assert c1["exec.scan.splits"] == 1 and c1["exec.scan.rows"] > 0
@@ -116,6 +139,123 @@ def test_second_scan_equals_first_and_a_fresh_connectors(name):
     np.testing.assert_array_equal(
         np.asarray(other[cols[0]].data)[: other.capacity],
         np.asarray(warm[cols[0]].data)[: other.capacity])
+
+
+@pytest.mark.parametrize("name", sorted(GENERATED))
+def test_second_scan_under_a_budget_uploads_nothing(name):
+    make, table, cols = GENERATED[name]
+    conn = budgeted(make, ROOMY)
+    store = conn.scan_store
+    split = conn.splits(table)[1]
+    (cold, c0), names0 = recorded(lambda: counted(
+        lambda: conn.scan(split, cols, 1 << 16)))
+    (warm, c1), names1 = recorded(lambda: counted(
+        lambda: conn.scan(split, cols, 1 << 16)))
+    fresh = make().scan(split, cols, 1 << 16)
+    assert_batches_equal(warm, cold)
+    assert_batches_equal(warm, fresh)
+    assert names0 == COLD and names1 == ["scan:lookup", "scan:resident"]
+    assert (c0["exec.scan.resident.misses"], c0["exec.scan.resident.hits"],
+            c1["exec.scan.resident.misses"], c1["exec.scan.resident.hits"]
+            ) == (4, 0, 0, 4)
+    assert c0["exec.scan.resident.bypassed"] == 0
+    # the upload is the admission: every byte handed over is held ...
+    assert (c0["exec.scan.resident.bytes"] == c0["exec.h2d.bytes"]
+            == store.device_bytes
+            == sum(a.nbytes for a in resident_arrays(store)))
+    assert len(resident_arrays(store)) == c0["exec.h2d.arrays"]
+    # ... and a device hit moves nothing but what it delivers
+    assert {k: v for k, v in c1.items() if v} == {
+        "exec.scan.resident.hits": 4, "exec.scan.splits": 1,
+        "exec.scan.rows": c0["exec.scan.rows"]}
+    # the host copies of the admitted columns are gone: the split's
+    # live mask alone stays, for the columns still to come
+    assert len(store) == 1 and store.bytes == 1 << 16
+    assert [a.dtype for a in kept_arrays(store)] == [np.bool_]
+    # the identity narrow consumers key on, and the held arrays served
+    nullable = [c for c in cols if warm[c].valid is not warm.live]
+    assert len(nullable) == (2 if name == "tpcds" else 0)
+    assert all(warm[c].data is cold[c].data for c in cols)
+    assert warm.live is cold.live
+    for c in nullable:
+        assert warm[c].valid is cold[c].valid
+        assert not np.asarray(warm[c].valid).all()
+
+
+def test_a_partly_held_split_uploads_the_missing_columns_only():
+    make, table, cols = GENERATED["tpch"]
+    conn = budgeted(make, ROOMY)
+    split = conn.splits(table)[0]
+    first = conn.scan(split, cols[:3], 1 << 16)
+    # a subset of what is held: nothing is uploaded
+    (sub, c), names = recorded(lambda: counted(
+        lambda: conn.scan(split, [cols[2], cols[0]], 1 << 16)))
+    assert names == ["scan:lookup", "scan:resident"]
+    assert sub.names == (cols[2], cols[0])          # the order asked for
+    assert (c["exec.scan.resident.hits"], c["exec.h2d.arrays"]) == (2, 0)
+    # a superset: the fourth column is generated, uploaded and admitted
+    # (beside the live mask every upload carries), the three served
+    (sup, c), names = recorded(lambda: counted(
+        lambda: conn.scan(split, cols, 1 << 16)))
+    assert names == COLD
+    assert (c["exec.scan.resident.hits"], c["exec.scan.resident.misses"],
+            c["exec.scan.store.misses"], c["exec.h2d.arrays"]) == (3, 1, 1, 2)
+    assert c["exec.scan.resident.bytes"] == sup[cols[3]].data.nbytes
+    assert sup.names == tuple(cols) and sup.live is first.live
+    assert all(sup[c].valid is sup.live for c in cols)
+    assert_batches_equal(sup, make().scan(split, cols, 1 << 16))
+    _, c = counted(lambda: conn.scan(split, cols, 1 << 16))
+    assert (c["exec.scan.resident.hits"], c["exec.h2d.arrays"]) == (4, 0)
+
+
+def test_a_budget_of_one_splits_bytes_admits_one_split():
+    make, table, cols = GENERATED["tpch"]
+    probe = make()
+    splits = probe.splits(table)[:3]
+    _, c = counted(lambda: probe.scan(splits[0], cols, 1 << 16))
+    one = c["exec.h2d.bytes"]
+    conn = budgeted(make, one)
+    for run in range(2):
+        got = [counted(lambda s=s: recorded(
+            lambda: conn.scan(s, cols, 1 << 16))) for s in splits]
+        for i, (s, ((batch, names), c)) in enumerate(zip(splits, got)):
+            assert_batches_equal(batch, probe.scan(s, cols, 1 << 16))
+            if i == 0:          # admitted by the first run, a hit since
+                assert c["exec.scan.resident.bypassed"] == 0
+                assert c["exec.scan.resident.hits"] == (4 if run else 0)
+                continue
+            # past the budget: served as without the tier — host tier
+            # and an upload a scan — and counted, every time
+            assert names == (COLD if run == 0
+                             else ["scan:lookup", "batch:upload"])
+            assert c["exec.scan.resident.misses"] == 4
+            assert c["exec.scan.resident.bypassed"] == len(cols) + 1
+            assert c["exec.scan.resident.bytes"] == 0
+            assert c["exec.h2d.bytes"] == one
+            assert c["exec.scan.store.hits"] == (4 if run else 0)
+    store = conn.scan_store
+    assert store.device_bytes == one == store.device_budget
+    # the refused splits' host copies stay; the admitted split's went
+    assert store.bytes == 2 * one + (1 << 16)
+
+
+def test_a_budget_set_to_zero_lets_the_held_arrays_go():
+    make, table, cols = GENERATED["tpch"]
+    conn = budgeted(make, ROOMY)
+    split = conn.splits(table)[0]
+    conn.scan(split, cols, 1 << 16)
+    assert conn.scan_store.device_bytes > 0
+    conn.scan_store.set_device_budget(0)
+    assert conn.scan_store.device_bytes == 0
+    assert not conn.scan_store._device
+    (got, c), names = recorded(lambda: counted(
+        lambda: conn.scan(split, cols, 1 << 16)))
+    assert names == COLD and not any(c[k] for k in RESIDENT)
+    assert_batches_equal(got, make().scan(split, cols, 1 << 16))
+    conn.scan_store.set_device_budget(ROOMY)
+    conn.scan(split, cols, 1 << 16)
+    conn.scan_store.clear()
+    assert conn.scan_store.device_bytes == 0 == conn.scan_store.bytes
 
 
 def test_a_tpcds_column_with_nulls_keeps_its_mask():
@@ -224,9 +364,10 @@ def test_the_bound_is_a_share_of_what_the_host_has_available():
     assert (c["exec.scan.store.hits"], c["exec.scan.store.bypassed"]) == (1, 1)
 
 
-def test_two_threads_on_one_cold_split_both_get_the_split():
+@pytest.mark.parametrize("budget", [0, ROOMY], ids=["host", "resident"])
+def test_two_threads_on_one_cold_split_both_get_the_split(budget):
     make, table, cols = GENERATED["tpch"]
-    conn = make()
+    conn = budgeted(make, budget)
     split = conn.splits(table)[0]
     gate = threading.Barrier(2)
     real = conn.scan_numpy
@@ -254,9 +395,18 @@ def test_two_threads_on_one_cold_split_both_get_the_split():
     assert_batches_equal(out[0], want)
     assert_batches_equal(out[1], want)
     # one copy is held, whichever thread got there first
-    assert len(conn.scan_store) == len(cols) + 1
-    assert conn.scan_store.bytes == sum(
-        a.nbytes for a in kept_arrays(conn.scan_store))
+    store = conn.scan_store
+    assert store.bytes == sum(a.nbytes for a in kept_arrays(store))
+    if not budget:
+        assert len(store) == len(cols) + 1
+        return
+    # ... on the device too, and both threads serve that one; neither
+    # thread's host copy of a column outlives the admission
+    assert len(store._device) == len(cols) + 1 and len(store) == 1
+    assert store.device_bytes == sum(
+        a.nbytes for a in resident_arrays(store))
+    assert out[0].live is out[1].live
+    assert all(out[0][c].data is out[1][c].data for c in cols)
 
 
 def test_mutable_connectors_have_no_store():
@@ -291,12 +441,15 @@ def tables():
     return out
 
 
+@pytest.mark.parametrize("budget", [0, ROOMY], ids=["host", "resident"])
 @pytest.mark.parametrize("name", sorted(QUERIES))
 def test_a_query_run_twice_equals_its_oracle_and_delivers_the_same(
-        name, tables):
+        name, budget, tables):
     catalog, sql, oracle = QUERIES[name]
     conn = GENERATED[catalog][0]()
-    session = Session({catalog: conn}, properties=NO_CACHE)
+    session = Session({catalog: conn}, properties=dict(
+        NO_CACHE, **({"scan_resident_budget_bytes": budget}
+                     if budget else {})))
     want = oracle(tables[catalog])
     cold, c0 = counted(lambda: session.sql(sql))
     warm, c1 = counted(lambda: session.sql(sql))
@@ -304,6 +457,19 @@ def test_a_query_run_twice_equals_its_oracle_and_delivers_the_same(
     compare(warm, want, f"{name} warm")
     assert c0["exec.scan.store.misses"] > 0
     assert c0["exec.scan.store.hits"] == 0
+    if budget:
+        # every column of every split is held after the first run: the
+        # second looks up what the first missed and uploads nothing
+        assert conn.scan_store.device_budget == budget
+        assert c1["exec.scan.resident.hits"] == (
+            c0["exec.scan.resident.hits"] + c0["exec.scan.resident.misses"])
+        assert c0["exec.scan.resident.bytes"] == conn.scan_store.device_bytes
+        assert {k: v for k, v in c1.items() if v} == {
+            "exec.scan.resident.hits": c1["exec.scan.resident.hits"],
+            "exec.scan.splits": c0["exec.scan.splits"],
+            "exec.scan.rows": c0["exec.scan.rows"]}
+        return
+    assert not any(c[k] for c in (c0, c1) for k in RESIDENT)
     assert c1["exec.scan.store.hits"] == c0["exec.scan.store.misses"]
     assert c1["exec.scan.store.misses"] == c1["exec.scan.store.bypassed"] == 0
     assert c1["exec.scan.store.bytes"] == 0
