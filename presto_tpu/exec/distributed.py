@@ -2026,6 +2026,12 @@ class DistributedExecutor(OomLadderMixin):
             parts.append(b)
         return self._concat_sharded_many(parts, names=list(names))
 
+    def _exec_groupingsets(self, node: N.GroupingSets, scalars) -> DistBatch:
+        """Grouping sets on the mesh: one grouped branch a set, each
+        over the child again (``GroupingSets.as_union``) — the local
+        executor's one-pass fold has no distributed twin (ROADMAP C1)."""
+        return self._exec(node.as_union(), scalars)
+
     # ---- window functions ------------------------------------------------
     def _exec_window(self, node: N.Window, scalars) -> DistBatch:
         """Partition-parallel windows: all_to_all on hash(partition

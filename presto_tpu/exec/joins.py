@@ -226,9 +226,7 @@ class JoinBuildOperator(CollectingOperator):
             make_build,
         )
         # the step reads the key's columns alone, so one program serves
-        # every payload a build of this key and capacity carries (a
-        # grouping-set expansion builds one dimension once a branch,
-        # each with other columns: nine programs of a sort were one)
+        # every payload a build of this key and capacity carries
         from presto_tpu.plan.prune import expr_refs
 
         refs: set = set()

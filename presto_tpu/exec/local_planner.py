@@ -536,7 +536,6 @@ class LocalExecutor(OomLadderMixin):
 
     # ---- aggregation ----------------------------------------------------
     def _exec_aggregate(self, node: N.Aggregate, scalars):
-        from presto_tpu.ops.groupby import ValueBitsOverflow
         from presto_tpu.plan.bounds import agg_value_bits
 
         from presto_tpu.runtime.metrics import REGISTRY
@@ -639,9 +638,21 @@ class LocalExecutor(OomLadderMixin):
         else:
             REGISTRY.counter("agg.strategy.partial").add()
         fault_point("step.agg")
+        return self._run_hash_agg(
+            child, keys, aggs, pax, strategy,
+            lambda: self._pick_group_strategy(keys, pax, node, child,
+                                              force_sort=True))
+
+    def _run_hash_agg(self, child: BatchStream, keys, aggs, pax, strategy,
+                      sort_strategy, phase: str = "single") -> BatchStream:
+        """One keyed aggregation of ``child`` under the re-plan loop its
+        state's flags ask for; ``sort_strategy()`` is the strategy that
+        groups NULL keys."""
+        from presto_tpu.ops.groupby import ValueBitsOverflow
+
         for attempt in range(MAX_RETRIES):
-            op = HashAggregationOperator(keys, aggs, strategy, passengers=pax,
-                                         params=self.params)
+            op = HashAggregationOperator(keys, aggs, strategy, phase=phase,
+                                         passengers=pax, params=self.params)
             try:
                 # draining the (replayable) child stream folds one morsel
                 # at a time into device-resident state — bounded memory
@@ -651,8 +662,7 @@ class LocalExecutor(OomLadderMixin):
             except NullGroupKeys:
                 # the packed direct domain has no NULL slot; re-plan on
                 # the sort strategy, which groups NULL as its own value
-                strategy = self._pick_group_strategy(
-                    keys, pax, node, child, force_sort=True)
+                strategy = sort_strategy()
             except CapacityOverflow as e:
                 # only THIS aggregation's group overflow is retryable
                 # here — an overflow raised by the lazy child stream
@@ -664,6 +674,94 @@ class LocalExecutor(OomLadderMixin):
                     raise
                 strategy = SortStrategy(strategy.max_groups * 2)
         raise CapacityOverflow("Aggregate", strategy.max_groups)
+
+    # ---- grouping sets ----------------------------------------------------
+    def _exec_groupingsets(self, node: N.GroupingSets, scalars):
+        """ROLLUP / CUBE / GROUPING SETS over ONE evaluation of the
+        child: the finest level — a plain aggregation by all the keys,
+        through every strategy ``_exec_aggregate`` has — answers the
+        child's rows, and each set is folded from the smallest level
+        already answered that holds its keys (``node.parents()``: a
+        ROLLUP's levels chain) by a final-phase aggregation of that
+        level's groups, sized by the live count just read. Every
+        aggregate kind an operator takes merges, so no set needs the
+        child's rows again; under count(distinct) the distinct column
+        is a key of every level and a set's rows are its level's groups
+        aggregated once more without it (``node.finals``). A set's rows
+        leave with its absent keys NULL and its ordinal under
+        ``node.gid``."""
+        from presto_tpu.runtime.metrics import REGISTRY
+
+        REGISTRY.counter("exec.grouping_sets.sets").add(len(node.sets))
+        # what the union expansion counted; named so that it reads 0
+        REGISTRY.counter("exec.union.inputs")
+        # level -1 is the finest; a set that is not all the keys is a
+        # level of its own, under its ordinal
+        levels = {-1: self._exec_aggregate(node.finest, scalars)
+                  .materialize()}
+        rows: dict[int, int] = {}
+
+        def live(lv: int) -> int:
+            if lv not in rows:
+                rows[lv] = sum(count_live_rows(levels[lv]))
+            return rows[lv]
+
+        level_of: list[int] = []
+        for i, (s, parent) in enumerate(zip(node.sets, node.parents())):
+            if len(s) == len(node.keys):
+                level_of.append(-1)
+                continue
+            src = -1 if parent < 0 else level_of[parent]
+            levels[i] = self._fold_level(
+                node.key_refs(s), node.aggs, levels[src], live(src), "final")
+            level_of.append(i)
+            REGISTRY.counter("exec.grouping_sets.folds").add()
+        emitted: list[tuple[list[Batch], int | None]] = []
+        for i, (s, lv) in enumerate(zip(node.sets, level_of)):
+            batches, known = levels[lv], rows.get(lv)
+            if node.finals:
+                batches, known = self._fold_level(
+                    node.key_refs(s[:-1]), node.finals, batches, live(lv),
+                    "single"), None
+            op = FilterProjectOperator(None, dict(node.set_exprs(i)),
+                                       params=self.params)
+            emitted.append(([op.process(b)[0] for b in batches], known))
+        # a large output leaves as ONE batch of its live rows' bucket,
+        # whatever that saves (the counts are the folds' own where a
+        # fold read one): a window or a TopN above sorts every slot it
+        # is handed, and compacts its own input only where that halves
+        return self._compact_large(
+            BatchStream.of([b for batches, _ in emitted for b in batches]),
+            "exec.grouping_sets.compacted", factor=1,
+            rows=lambda: sum(
+                sum(count_live_rows(batches)) if known is None else known
+                for batches, known in emitted))
+
+    def _fold_level(self, keys, aggs, source: list[Batch], rows: int,
+                    phase: str) -> list[Batch]:
+        """The groups of ``source`` — a level that holds ``keys`` —
+        aggregated by ``keys``: ``final`` merges the level's aggregates,
+        ``single`` evaluates ``aggs`` over its columns. ``rows``, the
+        source's live groups, bounds what can be live here."""
+        if not keys:
+            from presto_tpu.exec.operators import GlobalAggregationOperator
+
+            op = GlobalAggregationOperator(aggs, phase=phase,
+                                           params=self.params)
+            return Pipeline(source, [op]).run()
+
+        def dict_len(name: str):
+            d = source[0][name].dictionary if source else None
+            return len(d) if d is not None else None
+
+        def strategy(direct_limit: int):
+            return pick_group_strategy(keys, (), dict_len, rows,
+                                       direct_limit=direct_limit)
+
+        return self._run_hash_agg(
+            BatchStream.of(source), keys, list(aggs), (),
+            strategy(self.direct_group_limit), lambda: strategy(0),
+            phase=phase).materialize()
 
     def _use_agg_bypass(self, node: N.Aggregate) -> bool:
         """The adaptive partial-aggregation bypass decision for one
@@ -1636,18 +1734,20 @@ class LocalExecutor(OomLadderMixin):
         child = self._compact_large(child, "exec.window.compacted")
         return BatchStream.of(Pipeline(child, [op]).run())
 
-    def _compact_large(self, child: BatchStream, counter: str) -> BatchStream:
+    def _compact_large(self, child: BatchStream, counter: str, factor: int = 2,
+                       rows=None) -> BatchStream:
         """A large sort operand follows what is live: a materialised
         input of ``SORT_COMPACT_SLOTS`` slots or more is compacted to
-        its live rows' capacity bucket where that at least halves the
-        slots (one ``sync:live_count`` read a batch; under the limit
+        its live rows' capacity bucket where that cuts the slots
+        ``factor`` times at least (one ``sync:live_count`` read a batch,
+        or ``rows()`` where the caller holds the counts; under the limit
         nothing is read and the input is handed on as it is)."""
         batches = child.materialize()
         slots = sum(b.capacity for b in batches)
         if slots >= SORT_COMPACT_SLOTS:
-            rows = sum(count_live_rows(batches))
-            cap = batch_capacity(max(rows, 16))
-            if 2 * cap <= slots:
+            live = sum(count_live_rows(batches)) if rows is None else rows()
+            cap = batch_capacity(max(live, 16))
+            if factor * cap <= slots and cap < slots:
                 from presto_tpu.exec.operators import compact_batches
                 from presto_tpu.runtime.metrics import REGISTRY
 
@@ -1671,10 +1771,9 @@ class LocalExecutor(OomLadderMixin):
         from presto_tpu.runtime.metrics import REGISTRY
 
         children = [self._exec(c, scalars) for c in node.inputs]
-        # a grouping-set expansion is one branch a set, each a pass
-        # over its inputs (a one-pass plan would execute no union); the
-        # analyzer chains unions left-associatively, so a nested union
-        # is not a branch: its own leaves are counted when it executes
+        # the analyzer chains unions left-associatively, so a nested
+        # union is not a branch: its own leaves are counted when it
+        # executes (grouping sets execute no union: _exec_groupingsets)
         leaves = [not isinstance(c, N.Union) for c in node.inputs]
         REGISTRY.counter("exec.union.inputs").add(sum(leaves))
         names = node.field_names()

@@ -213,6 +213,13 @@ def _node_intervals(node: N.PlanNode, catalog,
         for a in node.aggs:
             out[a.name] = None  # running sums: unbounded without row counts
         return out
+    if isinstance(node, N.GroupingSets):
+        # a key a row's set leaves out is NULL there: the physical fill
+        env = node_intervals(node.child, catalog, memo)
+        out = {n: _hull(expr_interval(e, env), (0, 0))
+               for n, e in node.out_keys}
+        out[node.gid] = (0, len(node.sets) - 1)
+        return {f.name: out.get(f.name) for f in node.fields}
     if isinstance(node, N.Union):
         # every input contributes rows to each (same-named) column
         envs = [node_intervals(c, catalog, memo) for c in node.inputs]
@@ -269,6 +276,11 @@ def resolve_source_column(node: N.PlanNode, name: str):
                     return resolve_source_column(node.child, e.name)
                 return None
         return None
+    if isinstance(node, N.GroupingSets):
+        for n, e in node.out_keys:
+            if n == name and isinstance(e, InputRef):
+                return resolve_source_column(node.child, e.name)
+        return None
     if isinstance(node, N.Join):
         if name in {f.name for f in node.left.fields}:
             return resolve_source_column(node.left, name)
@@ -322,6 +334,10 @@ def _estimate_rows(node: N.PlanNode, catalog, memo: Optional[dict]) -> int:
         return max(1, estimate_rows(node.child, catalog, memo) // 3)
     if isinstance(node, N.Aggregate):
         return max(1, estimate_rows(node.child, catalog, memo) // 8)
+    if isinstance(node, N.GroupingSets):
+        # one plain aggregation's estimate a set, as the sets' union had
+        return len(node.sets) * max(
+            1, estimate_rows(node.child, catalog, memo) // 8)
     if isinstance(node, N.Join):
         left = estimate_rows(node.left, catalog, memo)
         if node.unique:

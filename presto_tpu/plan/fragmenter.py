@@ -62,6 +62,11 @@ def upper_bound_rows(node: N.PlanNode, catalog) -> int | None:
             # violate the SOUND-upper-bound contract
             return 1 if c is None else max(1, c)
         return c  # one row per group <= input rows
+    if isinstance(node, N.GroupingSets):
+        # a set's groups are at most the input's rows, and the empty
+        # set's one row is there over an empty input too
+        c = ub(node.child, catalog)
+        return None if c is None else len(node.sets) * max(1, c)
     if isinstance(node, N.Join):
         if node.unique and node.kind in ("inner", "left"):
             # each probe row matches at most one build row; LEFT adds
@@ -106,6 +111,8 @@ def output_partitioned(node: N.PlanNode) -> bool:
         return False
     if isinstance(node, N.Aggregate):
         return bool(node.keys)
+    if isinstance(node, N.GroupingSets):
+        return any(node.sets)
     if isinstance(node, (N.Sort, N.TopN, N.Limit, N.Window)):
         return False
     if isinstance(node, (N.Filter, N.Project, N.BindScalars, N.Output)):
@@ -181,6 +188,9 @@ class FragmentPlan:
                 return f"{t}[{n.connector}.{n.table}]{phys}"
             if isinstance(n, N.Aggregate):
                 return f"{t}[keys={[k for k, _ in n.keys]}]"
+            if isinstance(n, N.GroupingSets):
+                return (f"{t}[keys={[k for k, _ in n.keys]}, "
+                        f"sets={len(n.sets)}]")
             if isinstance(n, N.Join):
                 strat = self.join_strategy.get(id(n))
                 # an unproven broadcast (row UB fits the broadcast limit
@@ -303,7 +313,8 @@ def fragment_plan(plan: N.PlanNode, catalog, broadcast_limit: int,
             visit(node.right, bf)
             visit(node.left, frag)
             return
-        if isinstance(node, N.Aggregate) and node.keys:
+        if (isinstance(node, (N.Aggregate, N.GroupingSets))
+                and node.keys):
             # PARTIAL below the hash exchange, FINAL above (the executor
             # fuses all three into one step; the boundary still exists)
             cf = new_fragment(node.child, "hash")

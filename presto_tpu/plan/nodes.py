@@ -15,11 +15,12 @@ feeding filters).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import cached_property
 from typing import Optional, Sequence
 
 from presto_tpu.exec.operators import AggSpec, SortKey
-from presto_tpu.expr import Expr
-from presto_tpu.types import DataType
+from presto_tpu.expr import Expr, InputRef, Literal
+from presto_tpu.types import INTEGER, DataType
 
 
 @dataclass(frozen=True)
@@ -107,6 +108,109 @@ class Aggregate(PlanNode):
             + tuple(Field(n, e.dtype) for n, e in self.passengers)
             + tuple(Field(a.name, a.dtype) for a in self.aggs)
         )
+
+
+@dataclass(frozen=True)
+class GroupingSets(PlanNode):
+    """GROUP BY ROLLUP / CUBE / GROUPING SETS over ONE evaluation of
+    the child (reference: GroupIdNode under one AggregationNode). A row
+    of the output is one group of one set: every key of ``keys``, NULL
+    where the key is not in the row's set, the aggregates (their inputs
+    see the child's real columns), and under ``gid`` the ordinal of the
+    row's set in ``sets`` — what ``grouping(...)`` reads, and what keeps
+    a subtotal's NULL apart from a NULL in the data. ``sets`` index
+    into ``keys``; a set listed twice is answered twice.
+
+    The executors answer the finest level (all of ``keys``) from the
+    child's rows and every set from the smallest level already answered
+    that holds its keys (``parents``): every aggregate kind merges
+    (sum of sums and counts, min of mins, max of maxes)."""
+
+    child: PlanNode
+    keys: tuple[tuple[str, Expr], ...]  # (output name, key expr over child)
+    sets: tuple[tuple[int, ...], ...]
+    aggs: tuple[AggSpec, ...]
+    #: output name of the set ordinal; None when nothing above reads it
+    gid: Optional[str] = None
+    #: count(distinct x): ``x`` is the LAST of ``keys`` and in every
+    #: set, ``aggs`` are the partial aggregates of the (set, x) groups,
+    #: and a set's rows are those groups aggregated once more by the
+    #: set's own keys with ``finals`` (count(x), sums of the partials)
+    #: — so the empty set's one row is there over an empty input too
+    finals: tuple[AggSpec, ...] = ()
+
+    @property
+    def children(self):
+        return (self.child,)
+
+    @property
+    def out_keys(self) -> tuple[tuple[str, Expr], ...]:
+        return self.keys[:-1] if self.finals else self.keys
+
+    @property
+    def fields(self):
+        return (
+            tuple(Field(n, e.dtype) for n, e in self.out_keys)
+            + tuple(Field(a.name, a.dtype) for a in self.finals or self.aggs)
+            + ((Field(self.gid, INTEGER),) if self.gid is not None else ())
+        )
+
+    @cached_property
+    def finest(self) -> "Aggregate":
+        """The level every set is folded from: a plain aggregation of
+        the child by all of ``keys``. One object a node, so that what
+        the template pass decided about it (a leaf route keeps its
+        literals) is what the executor's matcher sees."""
+        return Aggregate(self.child, self.keys, self.aggs)
+
+    def parents(self) -> tuple[int, ...]:
+        """For each set the level it is folded from: the ordinal of the
+        earliest set with the fewest keys among the strict supersets
+        listed BEFORE it, or -1 for the finest level. A ROLLUP's sets
+        chain; a set equal to all of ``keys`` is the finest level
+        itself (-1, nothing to fold)."""
+        out = []
+        for i, s in enumerate(self.sets):
+            have = set(s)
+            best = -1
+            for j in range(i):
+                t = self.sets[j]
+                if have < set(t) and (
+                        best < 0 or len(t) < len(self.sets[best])):
+                    best = j
+            out.append(best)
+        return tuple(out)
+
+    def key_refs(self, s: Sequence[int]) -> list[tuple[str, Expr]]:
+        """The keys at ``s`` as columns of a level that holds them."""
+        return [(n, InputRef(e.dtype, n))
+                for n, e in (self.keys[k] for k in s)]
+
+    def set_exprs(self, i: int) -> tuple[tuple[str, Expr], ...]:
+        """Set ``i``'s rows as the node's output: its keys, NULL for
+        the keys it leaves out, the aggregates, its ordinal."""
+        s = self.sets[i]
+        exprs = tuple(
+            (n, InputRef(e.dtype, n) if k in s else Literal(e.dtype, None))
+            for k, (n, e) in enumerate(self.out_keys)
+        ) + tuple((a.name, InputRef(a.dtype, a.name))
+                  for a in self.finals or self.aggs)
+        if self.gid is not None:
+            exprs += ((self.gid, Literal(INTEGER, i)),)
+        return exprs
+
+    def as_union(self) -> "Union":
+        """The same rows as one grouped branch a set, each over the
+        child again: the mesh's plan (``exec/distributed.py`` has no
+        one-pass operator; no cell runs grouping sets there)."""
+        branches = []
+        for i, s in enumerate(self.sets):
+            agg = Aggregate(
+                self.child, tuple(self.keys[k] for k in s), self.aggs)
+            if self.finals:
+                agg = Aggregate(agg, tuple(self.key_refs(s[:-1])), self.finals)
+            branches.append(Project(agg, self.set_exprs(i)))
+        return Union(tuple(branches))
 
 
 @dataclass(frozen=True)
@@ -365,6 +469,9 @@ def plan_tree_str(node: PlanNode, indent: int = 0, catalog=None,
                 s = ""
             if s:
                 detail += f" agg_strategy={s}"
+    elif isinstance(node, GroupingSets):
+        detail = (f" keys={[n for n, _ in node.keys]} sets={list(node.sets)}"
+                  f" aggs={[a.name for a in node.aggs]}")
     elif isinstance(node, (Join,)):
         detail = f" {node.kind}{' unique' if node.unique else ''}"
         detail += _strategy_str(node, catalog, join_build_budget)

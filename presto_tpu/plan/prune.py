@@ -66,6 +66,21 @@ def prune(node: N.PlanNode, needed: set[str] | None = None) -> N.PlanNode:
                      + [a.input for a in aggs])
         child = prune(node.child, want)
         return N.Aggregate(child, keys, aggs, pax, node.unique_sets)
+    if isinstance(node, N.GroupingSets):
+        # the ONE child carries what every set's keys and the kept
+        # aggregates read; the set ordinal goes where nothing reads it
+        aggs, finals, gid = node.aggs, node.finals, node.gid
+        if needed is not None:
+            gid = gid if gid in needed else None
+            if finals:
+                finals = tuple(a for a in finals if a.name in needed)
+                kept = _refs(a.input for a in finals)
+                aggs = tuple(a for a in aggs if a.name in kept)
+            else:
+                aggs = tuple(a for a in aggs if a.name in needed)
+        want = _refs([e for _, e in node.keys] + [a.input for a in aggs])
+        return replace(node, child=prune(node.child, want), aggs=aggs,
+                       gid=gid, finals=finals)
     if isinstance(node, N.Join):
         want = set(needed) if needed is not None else set(node.field_names())
         left_fields = {f.name for f in node.left.fields}

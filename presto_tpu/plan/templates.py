@@ -241,6 +241,15 @@ class _Parameterizer:
                 self._agg_specs(node.aggs), self._pairs(node.passengers),
                 node.unique_sets,
             )
+        if isinstance(node, N.GroupingSets):
+            # its finest level is a plain aggregation of the child:
+            # what holds for that one holds here
+            if self._leaf_routes(node.finest):
+                self._count_baked_literals(node, "leaf_route")
+                return node
+            return dataclasses.replace(
+                node, child=self.node(node.child),
+                keys=self._pairs(node.keys), aggs=self._agg_specs(node.aggs))
         if isinstance(node, N.TableScan):
             if node.predicate is None:
                 return node
@@ -373,6 +382,8 @@ def unbatchable_reason(plan: N.PlanNode, catalog) -> Optional[str]:
             if breakers > 1:
                 return "multi_breaker"
             return walk(node.child)
+        if isinstance(node, N.GroupingSets):
+            return "grouped_agg"
         if isinstance(node, (N.Filter, N.Project)):
             return walk(node.child)
         if isinstance(node, N.TableScan):

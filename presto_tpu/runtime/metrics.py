@@ -539,8 +539,21 @@ METRIC_HELP: dict[str, str] = {
         "live rows' capacity buckets)"),
     "exec.union.inputs": (
         "branch streams of the executed UNION ALLs (a nested union is "
-        "not a branch: its own leaves are counted); a grouping-set "
-        "expansion is one branch a set"),
+        "not a branch: its own leaves are counted). Grouping sets are "
+        "one node over one input and move it by 0: the node names the "
+        "counter when it executes, so that it reads 0 and not nothing"),
+    "exec.grouping_sets.sets": (
+        "grouping sets answered by a one-pass grouping-sets node "
+        "(ROLLUP / CUBE / GROUPING SETS over ONE evaluation of their "
+        "input), counted when the node executes: q67 9, q70 3"),
+    "exec.grouping_sets.folds": (
+        "grouping sets folded from the groups of a level already "
+        "answered that holds their keys (the rest are the finest "
+        "level itself)"),
+    "exec.grouping_sets.compacted": (
+        "grouping-sets outputs of 2^20 slots or more handed on as ONE "
+        "batch of their live rows' capacity bucket (the counts are the "
+        "folds' sync:live_count reads)"),
     "exec.union.batches": (
         "batches drawn from the UNION ALLs' branch streams (a nested "
         "union's peek for the dictionaries draws its first again)"),

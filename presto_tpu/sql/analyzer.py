@@ -21,6 +21,12 @@ unique key make the remaining keys of that table "passengers" (carried
 per group, not grouped) — how Q10/Q18 group by BYTES columns without
 sorting byte tensors. Narrow (<=7 byte) BYTES keys group via packed
 int64 surrogates (Q22's cntrycode).
+
+Grouping sets (ROLLUP / CUBE / GROUPING SETS) are ONE
+``plan.nodes.GroupingSets`` over the grouped query's one FROM ... WHERE
+(the reference's GroupIdNode under one AggregationNode): HAVING, the
+SELECT list and any window sit above it and read a subtotal's NULL keys
+and ``grouping(...)`` off its output.
 """
 
 from __future__ import annotations
@@ -164,7 +170,8 @@ WINDOW_ONLY_FUNCS = {"rank", "dense_rank", "row_number"}
 
 
 def _collect_grouping_calls(n, out: list):
-    """``grouping(col)`` calls (fold to 0/1 per grouping-set branch)."""
+    """``grouping(...)`` calls (``_plan_aggregate`` reads them off the
+    grouping-sets node's set ordinal)."""
     if isinstance(n, A.FunctionCall) and n.name == "grouping":
         if n not in out:
             out.append(n)
@@ -210,33 +217,6 @@ def _resolved_refs(n, out: set[str]):
     elif isinstance(n, tuple):
         for v in n:
             _resolved_refs(v, out)
-
-
-def _substitute_outside_aggs(n, mapping):
-    """Like substitute_nodes, but leaves plain aggregate-call subtrees
-    untouched — grouping-sets NULL substitution must not rewrite
-    aggregate arguments. Window calls ARE entered (their partition/
-    order specs reference grouping keys), and the aggregates inside
-    them stay opaque via the same rule."""
-    if isinstance(n, A.FunctionCall) and n.name in AGG_FUNCS and n.over is None:
-        return n
-    if isinstance(n, A.Node) and not isinstance(n, A.Query):
-        try:
-            if n in mapping:
-                return mapping[n]
-        except TypeError:
-            pass
-    if isinstance(n, A.Query) or not isinstance(n, (A.Node, tuple)):
-        return n
-    if isinstance(n, tuple):
-        return tuple(_substitute_outside_aggs(v, mapping) for v in n)
-    changes = {}
-    for f in n.__dataclass_fields__:
-        v = getattr(n, f)
-        nv = _substitute_outside_aggs(v, mapping)
-        if nv is not v:
-            changes[f] = nv
-    return replace(n, **changes) if changes else n
 
 
 def substitute_nodes(n, mapping):
@@ -469,192 +449,9 @@ class Analyzer:
         raise AnalysisError(f"cannot unify UNION column types {e.dtype} and {t}")
 
     # ------------------------------------------------------------------
-    def _expand_grouping_sets(
-        self, q: A.Query, outer, ctes: dict
-    ) -> A.SetQuery | None:
-        """GROUP BY ROLLUP/CUBE/GROUPING SETS -> UNION ALL of one
-        grouped branch per set (the reference plans GroupingSets as a
-        GroupIdNode; re-aggregation per set is the equivalent here).
-        In each branch, grouping columns absent from its set become
-        typed NULL literals in SELECT/HAVING, and grouping(col) folds
-        to its 0/1 constant for that branch."""
-        gs_items = [g for g in q.group_by if isinstance(g, A.GroupingSets)]
-        if not gs_items:
-            return None
-        if len(gs_items) > 1:
-            raise AnalysisError("multiple GROUPING SETS elements not supported")
-        prefix = tuple(g for g in q.group_by if not isinstance(g, A.GroupingSets))
-        gs = gs_items[0]
-        all_keys: list[A.Node] = []
-        for s in gs.sets:
-            for k in s:
-                if k not in all_keys:
-                    all_keys.append(k)
-        # type each grouping key against the FROM scope once, so absent
-        # keys can be replaced by *typed* NULLs (the union type checker
-        # needs them); this pre-analysis of FROM is throwaway
-        ctes2 = dict(ctes)
-        for name, cq in q.ctes:
-            ctes2[name] = cq
-        rels: list[Rel] = []
-        edges: list[dict] = []
-        if q.from_ is not None:
-            self._flatten_from(q.from_, rels, edges, ctes2, outer)
-        probe_scope = Scope([f for r in rels for f in r.scope.fields])
-        key_null: dict[A.Node, A.Node] = {}
-        for k in all_keys:
-            e = self._expr(k, probe_scope, outer, ctes2, [])
-            key_null[k] = A.Resolved(Literal(e.dtype, None))
-        wins: list[A.FunctionCall] = []
-        for it in q.select:
-            collect_windows(it.expr, wins)
-        if wins:
-            # window functions rank/aggregate across ALL grouping sets
-            # (q67: rank over the whole rollup), so they cannot run per
-            # branch — hoist them above the union
-            return self._expand_gs_with_windows(
-                q, gs, prefix, all_keys, key_null
-            )
-        branches = []
-        for s in gs.sets:
-            grouped = set(prefix) | set(s)
-            g_map: dict[A.Node, A.Node] = {}
-            null_map: dict[A.Node, A.Node] = {}
-            for k in all_keys:
-                g_map[A.FunctionCall("grouping", (k,))] = A.NumberLit(
-                    "0" if k in grouped else "1"
-                )
-                if k not in grouped:
-                    null_map[k] = key_null[k]
-
-            def sub(n):
-                # grouping() folds anywhere; key->NULL only OUTSIDE
-                # aggregate arguments (SUM(a) in a subtotal row still
-                # sums the real column, standard grouping-sets
-                # semantics)
-                n = substitute_nodes(n, g_map)
-                return _substitute_outside_aggs(n, null_map)
-
-            branches.append(replace(
-                q,
-                group_by=prefix + tuple(s),
-                select=tuple(sub(it) for it in q.select),
-                having=sub(q.having) if q.having is not None else None,
-                order_by=(),
-                limit=None,
-                ctes=(),
-            ))
-        return A.SetQuery(
-            terms=tuple(branches),
-            ops=("union_all",) * (len(branches) - 1),
-            order_by=q.order_by,
-            limit=q.limit,
-            ctes=q.ctes,
-        )
-
-    def _expand_gs_with_windows(self, q: A.Query, gs, prefix, all_keys,
-                                key_null) -> A.Query:
-        """Grouping sets + window functions: per-branch grouped inner
-        queries (no windows) UNION ALL'd, with the windows applied in an
-        outer query over the union — window partitions/orders see every
-        grouping set at once, matching the reference's GroupIdNode →
-        WindowNode plan order [SURVEY §2.1 planner row].
-
-        Inner branches emit: each grouping key under its terminal
-        column name, every distinct plain-aggregate subtree as
-        ``__agg{i}``, and every ``grouping(...)`` call folded to its
-        per-branch constant as ``__grp{i}``. The outer query is the
-        original select/order/limit with those subtrees replaced by
-        references."""
-        key_items: list[A.Node] = []
-        for k in tuple(prefix) + tuple(all_keys):
-            if k not in key_items:
-                key_items.append(k)
-        for k in key_items:
-            if not isinstance(k, A.Identifier):
-                raise AnalysisError(
-                    "window functions over grouping sets require "
-                    "identifier grouping keys"
-                )
-        key_map = {k: A.Identifier((k.parts[-1],)) for k in key_items}
-
-        aggs: list[A.FunctionCall] = []
-        grps: list[A.FunctionCall] = []
-        for it in q.select:
-            collect_aggs(it.expr, aggs)
-            _collect_grouping_calls(it.expr, grps)
-        for oi in q.order_by:
-            collect_aggs(oi.expr, aggs)
-            _collect_grouping_calls(oi.expr, grps)
-        uniq_aggs: list[A.FunctionCall] = []
-        for a in aggs:
-            if a not in uniq_aggs:
-                uniq_aggs.append(a)
-        uniq_grps: list[A.FunctionCall] = []
-        for g in grps:
-            if g not in uniq_grps:
-                uniq_grps.append(g)
-        agg_map = {a: A.Identifier((f"__agg{i}",))
-                   for i, a in enumerate(uniq_aggs)}
-        grp_map = {g: A.Identifier((f"__grp{i}",))
-                   for i, g in enumerate(uniq_grps)}
-
-        branches = []
-        for s in gs.sets:
-            grouped = set(prefix) | set(s)
-            inner_items = []
-            for k in key_items:
-                e = k if k in grouped else key_null[k]
-                inner_items.append(A.SelectItem(e, k.parts[-1]))
-            for a, ref in agg_map.items():
-                inner_items.append(A.SelectItem(a, ref.parts[0]))
-            for g, ref in grp_map.items():
-                folded = A.NumberLit("0" if g.args[0] in grouped else "1")
-                inner_items.append(A.SelectItem(folded, ref.parts[0]))
-            g_fold = {g: A.NumberLit("0" if g.args[0] in grouped else "1")
-                      for g in uniq_grps}
-            having = q.having
-            if having is not None:
-                having = _substitute_outside_aggs(
-                    substitute_nodes(having, g_fold),
-                    {k: key_null[k] for k in all_keys if k not in grouped},
-                )
-            branches.append(replace(
-                q, select=tuple(inner_items),
-                group_by=tuple(prefix) + tuple(s),
-                having=having, order_by=(), limit=None, ctes=(),
-            ))
-
-        def rewrite(n):
-            return substitute_nodes(
-                substitute_nodes(substitute_nodes(n, agg_map), grp_map),
-                key_map,
-            )
-
-        outer_select = tuple(
-            A.SelectItem(rewrite(it.expr), it.alias) for it in q.select
-        )
-        outer_order = tuple(
-            replace(oi, expr=rewrite(oi.expr)) for oi in q.order_by
-        )
-        inner = A.SetQuery(
-            terms=tuple(branches), ops=("union_all",) * (len(branches) - 1)
-        )
-        return A.Query(
-            select=outer_select,
-            from_=A.SubqueryRelation(inner, self.fresh("gsw")),
-            order_by=outer_order, limit=q.limit, ctes=q.ctes,
-        )
-
-    # ------------------------------------------------------------------
     def _analyze_query(
         self, q: A.Query, outer: Scope | None, ctes: dict[str, A.Query]
     ) -> tuple[N.PlanNode, Scope]:
-        expanded = self._expand_grouping_sets(q, outer, ctes)
-        if isinstance(expanded, A.Query):
-            return self._analyze_query(expanded, outer, ctes)
-        if expanded is not None:
-            return self._analyze_setquery(expanded, outer, ctes)
         ctes = dict(ctes)
         for name, cq in q.ctes:
             ctes[name] = cq
@@ -760,11 +557,26 @@ class Analyzer:
                 for f in win_fields
                 if f.name in ob_refs and f.name not in produced
             ]
-            if q.distinct and hidden:
-                raise AnalysisError(
-                    "DISTINCT with window expressions repeated in ORDER BY "
-                    "is not supported; order by the select alias instead"
-                )
+        # an ORDER BY expression over aggregates or grouping() is the
+        # output column that computes it; one the SELECT list lacks
+        # rides the projection hidden too
+        agg_order: dict[A.Node, Expr] = {}
+        for ob in q.order_by:
+            grp_calls: list[A.FunctionCall] = []
+            _collect_grouping_calls(ob.expr, grp_calls)
+            if agg_map and (grp_calls or contains_agg(ob.expr)):
+                e = self._expr(ob.expr, scope, outer, ctes, scalar_binds,
+                               agg_map=agg_map, key_map=key_map)
+                name = next((n for n, oe in out_exprs if oe == e), None)
+                if name is None:
+                    name = self.fresh("orderkey")
+                    hidden.append((name, e))
+                agg_order[ob.expr] = InputRef(e.dtype, name)
+        if q.distinct and hidden:
+            raise AnalysisError(
+                "DISTINCT with an ORDER BY expression the SELECT list "
+                "lacks is not supported; order by the select alias instead"
+            )
         plan = N.Project(plan, tuple(out_exprs) + tuple(hidden))
         out_scope = Scope(
             [FieldRef(n, e.dtype, "", n) for n, e in out_exprs]
@@ -785,9 +597,11 @@ class Analyzer:
                 e.name: n for n, e in out_exprs if isinstance(e, InputRef)
             }
             for item in q.order_by:
-                e = self._order_expr(item.expr, out_scope, scope, outer, ctes,
-                                     scalar_binds, agg_map, key_map,
-                                     src_map=src_map)
+                e = agg_order.get(item.expr)
+                if e is None:
+                    e = self._order_expr(item.expr, out_scope, scope, outer,
+                                         ctes, scalar_binds, agg_map, key_map,
+                                         src_map=src_map)
                 keys.append(SortKey(e, item.descending, bool(item.nulls_first)))
             if q.limit is not None:
                 plan = N.TopN(plan, tuple(keys), q.limit)
@@ -1620,10 +1434,20 @@ class Analyzer:
     # aggregation planning
     # ------------------------------------------------------------------
     def _plan_aggregate(self, q, plan, scope, outer, ctes, scalar_binds):
-        # group keys
+        # group keys: the plain elements and every key of a ROLLUP /
+        # CUBE / GROUPING SETS element, each once
+        gs_items = [g for g in q.group_by if isinstance(g, A.GroupingSets)]
+        if len(gs_items) > 1:
+            raise AnalysisError("multiple GROUPING SETS elements not supported")
+        key_asts: list[A.Node] = []
+        for g in q.group_by:
+            flat = [k for s in g.sets for k in s] if g in gs_items else [g]
+            for k in flat:
+                if k not in key_asts:
+                    key_asts.append(k)
         keys: list[tuple[str, Expr]] = []
         key_map: dict[A.Node, tuple[str, DataType]] = {}
-        for g in q.group_by:
+        for g in key_asts:
             e = self._expr(g, scope, outer, ctes, scalar_binds)
             if isinstance(g, A.Identifier):
                 f = scope.resolve(g.parts)
@@ -1656,6 +1480,27 @@ class Analyzer:
             specs.extend(specs_e)
             agg_map[a] = mapped
 
+        # grouping sets: ONE node over the one input (the reference's
+        # GroupIdNode under one AggregationNode). Its output carries
+        # every key, NULL where a row's set leaves it out, so HAVING,
+        # the SELECT list and any window above read a subtotal's NULL
+        # through ``key_map`` while aggregate arguments saw the real
+        # columns; ``grouping(...)`` reads the row's set ordinal
+        sets: tuple[tuple[int, ...], ...] = ()
+        gid = None
+        if gs_items:
+            prefix = [k for k in q.group_by if k not in gs_items]
+            sets = tuple(
+                tuple(dict.fromkeys(key_asts.index(k) for k in prefix + list(s)))
+                for s in gs_items[0].sets
+            )
+            gid = self.fresh("groupid")
+            grp_calls: list[A.FunctionCall] = []
+            for part in (q.select, q.having, q.order_by):
+                _collect_grouping_calls(part, grp_calls)
+            for g in grp_calls:
+                agg_map[g] = self._grouping_value(g, key_asts, sets, gid)
+
         if distinct_key_exprs:
             if len(distinct_key_exprs) > 1:
                 raise AnalysisError(
@@ -1673,23 +1518,54 @@ class Analyzer:
             partial: list[AggSpec] = []
             final: list[AggSpec] = []
             for s in plain:
-                if s.kind not in ("sum", "count", "min", "max"):
+                if s.kind not in ("sum", "count", "count_star", "min", "max"):
                     raise AnalysisError(
                         f"{s.kind} cannot combine with DISTINCT aggregates"
                     )
                 pn = self.fresh("pdist")
                 partial.append(AggSpec(s.kind, s.input, pn, s.dtype))
-                outer_kind = "sum" if s.kind in ("sum", "count") else s.kind
+                outer_kind = s.kind if s.kind in ("min", "max") else "sum"
                 final.append(
                     AggSpec(outer_kind, InputRef(s.dtype, pn), s.name, s.dtype)
                 )
-            pre_keys = keys + distinct_key_exprs
-            plan = N.Aggregate(plan, tuple(pre_keys), tuple(partial))
-            keys = [(n, InputRef(e.dtype, n)) for n, e in keys]
             specs = [
                 AggSpec("count", InputRef(de.dtype, dn), s.name, s.dtype)
                 for s in cds
             ] + final
+            # a count is the SUM of its partial counts here, and a sum
+            # over no row is NULL where the count is 0
+            counts = {s.name for s in plain
+                      if s.kind in ("count", "count_star")}
+            for a, m in agg_map.items():
+                if isinstance(m, InputRef) and m.name in counts:
+                    agg_map[a] = Call(BIGINT, "coalesce",
+                                      (m, Literal(BIGINT, 0)))
+            if not gs_items:
+                plan = N.Aggregate(
+                    plan, tuple(keys + distinct_key_exprs), tuple(partial))
+                keys = [(n, InputRef(e.dtype, n)) for n, e in keys]
+
+        new_scope = Scope(
+            [FieldRef(n, e.dtype, self._binding_of(scope, n), self._column_of(scope, n),
+                      self._table_of(scope, n))
+             for n, e in keys]
+            + [FieldRef(s.name, s.dtype, "", s.name) for s in specs]
+        )
+        if gs_items:
+            # no key rides as a passenger here (a key its determinant
+            # leaves a set without is NULL in that set's rows). Under
+            # count(distinct) every set keeps the distinct column beside
+            # its keys, and its rows are those groups aggregated once
+            # more without it
+            if distinct_key_exprs:
+                agg = N.GroupingSets(
+                    plan, tuple(keys + distinct_key_exprs),
+                    tuple(s + (len(keys),) for s in sets), tuple(partial),
+                    gid, finals=tuple(specs))
+            else:
+                agg = N.GroupingSets(
+                    plan, tuple(keys), sets, tuple(specs), gid)
+            return agg, new_scope, agg_map, key_map
 
         # functional dependencies: keys covered by a unique key of the
         # same relation instance become passengers (Q10/Q18 shape)
@@ -1710,13 +1586,31 @@ class Analyzer:
             unique_sets.append(tuple(alt))
         agg = N.Aggregate(plan, tuple(grouping), tuple(specs),
                           tuple(passengers), tuple(unique_sets))
-        new_scope = Scope(
-            [FieldRef(n, e.dtype, self._binding_of(scope, n), self._column_of(scope, n),
-                      self._table_of(scope, n))
-             for n, e in keys]
-            + [FieldRef(s.name, s.dtype, "", s.name) for s in specs]
-        )
         return agg, new_scope, agg_map, key_map
+
+    def _grouping_value(self, g: A.FunctionCall, key_asts, sets, gid) -> Expr:
+        """``grouping(k1, ..., kn)`` over a grouping-sets node: the bit
+        of a key is 1 in the rows of a set that leaves it out, the
+        first argument the highest bit — read off the set ordinal."""
+        ref = InputRef(INTEGER, gid)
+        value: Expr | None = None
+        for j, k in enumerate(g.args):
+            if k not in key_asts:
+                raise AnalysisError(
+                    "grouping() takes grouping keys of this GROUP BY")
+            ki = key_asts.index(k)
+            cond: Expr | None = None
+            for i, s in enumerate(sets):
+                if ki not in s:
+                    eq = Call(BOOLEAN, "eq", (ref, Literal(INTEGER, i)))
+                    cond = eq if cond is None else Call(BOOLEAN, "or", (cond, eq))
+            weight = 1 << (len(g.args) - 1 - j)
+            bit: Expr = Literal(INTEGER, 0) if cond is None else Call(
+                INTEGER, "if", (cond, Literal(INTEGER, weight), Literal(INTEGER, 0)))
+            value = bit if value is None else Call(INTEGER, "add", (value, bit))
+        if value is None:
+            raise AnalysisError("grouping() takes at least one grouping key")
+        return value
 
     def _split_passengers(self, keys, scope):
         """Partition group keys into (grouping, passengers)."""

@@ -341,3 +341,79 @@ def test_the_final_sort_step_compiles_in_seconds(one_chip, shape):
                for out, args in sorts), sorts
     # measured alone: 0.2 - 2.6 s; a tier-1 run compiles six at once
     assert took < 60.0, took
+
+
+# the one-pass grouping sets (LocalExecutor._exec_groupingsets): a set
+# is folded from the level below it by a FINAL-phase aggregation of that
+# level's groups, and an output of 2^20 slots or more leaves as one
+# batch of its live rows' bucket. Shapes read off a CPU run of q67 and
+# q70 at SF1 (seed 7): q67's largest fold is its 7-key level from the
+# finest level's 2^20 slots (490,551 groups; state 2^19; more than 8 key
+# words, so the sort is by ONE hash word), and its nine levels' 2,032,127
+# slots leave as 2^20; q70's folds are direct-addressed (800 (state,
+# county) slots -> 50 states). A cold q67 compiles 456-538 s of the
+# client's 600 (PERF.md §7): what this PR adds to it is priced here
+def _fold_shapes():
+    from presto_tpu.exec.operators import DirectStrategy, SortStrategy
+    from presto_tpu.types import INTEGER, VARCHAR, decimal, fixed_bytes
+
+    dec2 = decimal(38, 2)
+    q67 = {"i_category": (VARCHAR, 10), "i_class": (VARCHAR, 50),
+           "i_brand": (VARCHAR, 500), "i_product_name": fixed_bytes(50),
+           "d_year": INTEGER, "d_qoy": INTEGER, "d_moy": INTEGER,
+           "s_store_id": fixed_bytes(16), "sum$1": dec2}
+    q70 = {"s_state": (VARCHAR, 50), "s_county": (VARCHAR, 16),
+           "sum$17": dec2}
+    return {
+        "tpcds_q67_7_keys_of_1048576": (
+            q67, list(q67)[:7], "sum$1", 1 << 20, SortStrategy(1 << 19),
+            [1 << 20, 1 << 19, 1 << 18, 1 << 17, 1 << 15, 1 << 15, 500,
+             10, 1]),
+        "tpcds_q70_state_of_800": (
+            q70, ["s_state"], "sum$17", 800,
+            DirectStrategy((0,), (1,), 50), None),
+    }
+
+
+@pytest.mark.parametrize("shape", sorted(_fold_shapes()))
+def test_a_grouping_set_fold_compiles_in_seconds(one_chip, shape):
+    import time
+
+    from presto_tpu.exec.operators import (AggSpec, HashAggregationOperator,
+                                           compact_batch, concat_batches)
+    from presto_tpu.expr import col
+    from presto_tpu.types import TypeKind
+
+    cols, keys, agg, cap, strategy, out_caps = _fold_shapes()[shape]
+    cols = {name: t if isinstance(t, tuple) else (t, None)
+            for name, t in cols.items()}
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def level(cap):
+        return Batch(
+            {name: Column(
+                sds((cap, t.width), jnp.uint8) if t.kind is TypeKind.BYTES
+                else sds((cap,), t.jnp_dtype), sds((cap,), jnp.bool_), t,
+                None if size is None else _dict(size))
+             for name, (t, size) in cols.items()}, sds((cap,), jnp.bool_))
+
+    t = cols[agg][0]
+    op = HashAggregationOperator(
+        [(k, col(k, cols[k][0])) for k in keys],
+        [AggSpec("sum", col(agg, t), agg, t)], strategy, phase="final")
+    init = op._sort_init if out_caps else op._direct_init
+    state = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
+                                   jax.eval_shape(init))
+    t0 = time.perf_counter()
+    text = op._update.lower(state, level(cap), ()).compile().as_text()
+    took = time.perf_counter() - t0
+    assert "tpu_custom_call" not in text
+    if out_caps:
+        t0 = time.perf_counter()
+        jax.jit(lambda bs: compact_batch(concat_batches(list(bs)), 1 << 20)
+                ).lower(tuple(level(c) for c in out_caps)).compile()
+        took += time.perf_counter() - t0
+    assert took < 120, f"{shape}: {took:.1f} s"
+    print(f"{shape}: compiled in {took:.1f} s")
