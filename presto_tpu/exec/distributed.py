@@ -576,15 +576,20 @@ class DistributedExecutor(OomLadderMixin):
         # meshes address every device, so this is the old loop there.
         proc = jax.process_index()
         from presto_tpu.spi import (
+            batch_of_entries,
             count_delivered,
             generate_split,
+            scan_through_store,
             split_valids,
         )
 
-        # the generated connectors keep a device's shard buffers per
-        # column in their SplitStore, as the local tier's scan keeps a
-        # split's: a warm scan goes straight to the device_put loop
+        # the generated connectors keep a device's shard per column in
+        # their SplitStore, as the local tier's scan keeps a split's:
+        # the host's padded buffers, so that a warm scan goes straight
+        # to the device_put loop, or (scan_resident_budget_bytes) the
+        # uploaded pieces on their device, so that it uploads nothing
         store = getattr(conn, "scan_store", None)
+        resident = store is not None and store.device_budget > 0
         data_shards: dict[str, list] = {c: [] for c in src_cols}
         valid_shards: dict[str, list] = {c: [] for c in src_cols}
         live_shards: list = []
@@ -595,6 +600,7 @@ class DistributedExecutor(OomLadderMixin):
             for d, sp in enumerate(assign):
                 if devices[d].process_index != proc:
                     continue
+                dev = devices[d]
 
                 def make(cols, sp=sp) -> HostColumns:
                     # streamed per-split scan (round-4 VERDICT ask #3): each
@@ -644,29 +650,51 @@ class DistributedExecutor(OomLadderMixin):
                     lv[:rows] = True
                     return HostColumns(padded, vmasks, lv, rows)
 
+                def upload(host, dev=dev) -> Batch:
+                    # host's columns on dev, one piece each. A piece that
+                    # is to be HELD gives a column whose mask is the live
+                    # mask (it has no NULL) the live piece itself, as
+                    # Batch.upload does: a mask of its own would cost its
+                    # bytes of the budget
+                    sent = [host.live]
+                    with trace_span("batch:upload", "scan"):
+                        live = jax.device_put(host.live, dev)
+                        cols = {}
+                        for c, p in host.padded.items():
+                            mask = host.masks[c]
+                            if resident and np.array_equal(mask, host.live):
+                                valid = live
+                            else:
+                                valid = jax.device_put(mask, dev)
+                                sent.append(mask)
+                            cols[c] = Column(jax.device_put(p, dev), valid,
+                                             types[c], dicts.get(c))
+                            sent.append(p)
+                    REGISTRY.counter("exec.h2d.arrays").add(len(sent))
+                    REGISTRY.counter("exec.h2d.bytes").add(
+                        sum(a.nbytes for a in sent))
+                    return Batch(cols, live)
+
                 if store is None:
                     host = make(src_cols)
+                    count_delivered(len(sp), host.n)
+                    piece = upload(host)
                 else:
                     shard = ("shard", node.table,
                              tuple((s.chunk, s.lo, s.hi) for s in sp), cap_dev)
-                    with trace_span("scan:lookup", "scan",
-                                    {"table": node.table}):
-                        host = store.columns(
-                            shard, {c: shard + (c, types[c].np_dtype.str)
-                                    for c in src_cols}, make)
-                count_delivered(len(sp), host.n)
-                with trace_span("batch:upload", "scan"):
-                    for c in src_cols:
-                        data_shards[c].append(
-                            jax.device_put(host.padded[c], devices[d]))
-                        valid_shards[c].append(
-                            jax.device_put(host.masks[c], devices[d]))
-                    live_shards.append(jax.device_put(host.live, devices[d]))
-                REGISTRY.counter("exec.h2d.arrays").add(2 * len(src_cols) + 1)
-                REGISTRY.counter("exec.h2d.bytes").add(
-                    host.live.nbytes
-                    + sum(host.padded[c].nbytes + host.masks[c].nbytes
-                          for c in src_cols))
+                    piece = scan_through_store(
+                        store, node.table,
+                        lambda shard=shard: (shard, {
+                            c: shard + (c, types[c].np_dtype.str)
+                            for c in src_cols}),
+                        make, upload,
+                        lambda side, entries: batch_of_entries(
+                            src_cols, side, entries, types, dicts),
+                        device=dev, splits=len(sp))
+                for c in src_cols:
+                    data_shards[c].append(piece[c].data)
+                    valid_shards[c].append(piece[c].valid)
+                live_shards.append(piece.live)
 
         sh = row_sharding(self.mesh)
 

@@ -57,8 +57,9 @@ DEFAULT_POOL_HEADROOM = 64
 #: subtraction exists for).
 _DEFAULT_BUDGET: int | None = None
 
-#: owner (a ``spi.SplitStore``) -> the bytes of the default device it
-#: may keep resident scan columns in. The CONFIGURED bytes, not what
+#: owner (a ``spi.SplitStore``) -> the bytes of EACH device it may keep
+#: resident scan columns in (the local scan's on the default device,
+#: the mesh's a shard on every device). The CONFIGURED bytes, not what
 #: the allocator holds of them: whether the columns were admitted
 #: before or after the snapshot above must not change what is left.
 _RESIDENT: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
@@ -66,9 +67,11 @@ _RESIDENT_LOCK = threading.Lock()
 
 
 def reserve_resident(owner, nbytes: int) -> None:
-    """Set aside ``nbytes`` of the default device for ``owner``'s
-    resident columns (0 gives them back; a collected owner's go with
-    it). Called BEFORE the owner may admit anything, and it takes the
+    """Set aside ``nbytes`` of a device — of every device: the budget
+    below is the one number the steps of the local executor AND of
+    each device of a mesh are sized by — for ``owner``'s resident
+    columns (0 gives them back; a collected owner's go with it).
+    Called BEFORE the owner may admit anything, and it takes the
     snapshot first: the allocator's ``bytes_in_use`` then never
     includes a resident column, and :func:`device_budget_bytes` falls
     by exactly ``nbytes``."""
@@ -85,9 +88,11 @@ def device_budget_bytes(device=None) -> int:
     backend's byte limit MINUS what the allocator already held at
     first call (a warm process must not over-admit against memory it
     cannot get back) MINUS what :func:`reserve_resident` has set aside
-    of the default device, floored at :data:`MIN_BUDGET_BYTES`. The
-    default-device snapshot is taken once per process; passing an
-    explicit ``device`` always measures fresh, nothing set aside."""
+    of a device, floored at :data:`MIN_BUDGET_BYTES`. The
+    default-device snapshot is taken once per process and stands for
+    every device of a mesh (``DistributedExecutor`` sizes each
+    device's steps by it); passing an explicit ``device`` always
+    measures fresh, nothing set aside."""
     global _DEFAULT_BUDGET
     if device is not None:
         return _measured_budget(device)

@@ -481,7 +481,8 @@ METRIC_HELP: dict[str, str] = {
         "bytes of padded host columns the split stores have taken in "
         "(nothing is evicted: what is held)"),
     "exec.scan.resident.hits": (
-        "column-split lookups the split store's device tier answered "
+        "column-split lookups (on a mesh: a column of a device's "
+        "shard) the split store's device tier answered "
         "(scan_resident_budget_bytes > 0): nothing uploaded"),
     "exec.scan.resident.misses": (
         "column-split lookups the device tier could not answer: the "
@@ -489,7 +490,7 @@ METRIC_HELP: dict[str, str] = {
         "uploaded (batch:upload)"),
     "exec.scan.resident.bypassed": (
         "device-tier inserts refused because they would pass the "
-        "store's byte budget: that scan was served from its own upload "
+        "budget of the device: that scan was served from its own upload "
         "and the host tier keeps the columns"),
     "exec.scan.resident.bytes": (
         "bytes of uploaded columns the split stores' device tiers have "

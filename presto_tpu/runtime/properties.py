@@ -122,12 +122,14 @@ SESSION_PROPERTIES: dict[str, PropertyDef] = {
         ),
         PropertyDef(
             "scan_resident_budget_bytes", int, 0,
-            "Bytes of device memory in which each generated connector "
-            "(tpch, ssb, tpcds) keeps the uploaded columns of its "
-            "splits (spi.SplitStore's device tier): a scan whose "
+            "Bytes of a device's memory in which each generated "
+            "connector (tpch, ssb, tpcds) keeps the uploaded columns of "
+            "its splits — on a mesh each device's shard of them, on "
+            "that device — (spi.SplitStore's device tier): a scan whose "
             "columns are all held uploads nothing. Admission, not "
-            "eviction; a split past the budget is uploaded a scan as "
-            "without it (exec.scan.resident.bypassed). The budget comes "
+            "eviction; a split or shard past its device's budget is "
+            "uploaded a scan as without it "
+            "(exec.scan.resident.bypassed). The budget comes "
             "out of the device budget the steps are sized by. 0: no "
             "device tier. Applied when the session is built or the "
             "property is set.",

@@ -256,8 +256,9 @@ class Session:
     def _set_scan_resident_budget(self) -> None:
         """Hand ``scan_resident_budget_bytes`` to every catalog
         connector that keeps its splits (a connector's ``scan`` takes
-        no session): before the first query sizes a step, since the
-        budget comes out of ``device_budget_bytes``."""
+        no session, and the mesh's scan reads the same store): bytes a
+        device, before the first query sizes a step, since the budget
+        comes out of ``device_budget_bytes``."""
         budget = self.prop("scan_resident_budget_bytes") or 0
         for conn in self.catalog.connectors.values():
             store = getattr(conn, "scan_store", None)

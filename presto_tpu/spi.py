@@ -136,7 +136,14 @@ class SplitStore:
     mask and row count stay there for the columns still to come), and
     what the budget refuses is served from the host tier and uploaded
     a scan, as without the tier. No step donates its input, so a held
-    array is safe to hand to every query."""
+    array is safe to hand to every query.
+
+    The budget is bytes a DEVICE: the mesh's scan keeps each device's
+    shard on that device (``device=`` names it; the local scan's
+    ``None`` is the default device), an entry is held per device, and
+    admission is against what THAT device holds
+    (``device_bytes_by_device``); ``device_bytes`` is the sum over the
+    devices and ``device_bytes_fullest`` the most any one holds."""
 
     #: share of the host's available memory the store may come to hold
     SHARE = 0.25
@@ -146,9 +153,9 @@ class SplitStore:
         self._entries: dict = {}
         self._available = available
         self.bytes = 0
-        self._device: dict = {}
+        self._device: dict = {}     # (device, key) -> entry
         self.device_budget = 0
-        self.device_bytes = 0
+        self.device_bytes_by_device: dict = {}
 
     def __len__(self) -> int:
         return len(self._entries)
@@ -157,12 +164,26 @@ class SplitStore:
         with self._lock:
             self._entries.clear()
             self.bytes = 0
-            self._device.clear()
-            self.device_bytes = 0
+            self._drop_device_tier_locked()
+
+    def _drop_device_tier_locked(self) -> None:
+        self._device.clear()
+        self.device_bytes_by_device.clear()
+
+    @property
+    def device_bytes(self) -> int:
+        """The bytes held on all devices together."""
+        return sum(self.device_bytes_by_device.values())
+
+    @property
+    def device_bytes_fullest(self) -> int:
+        """The bytes held on the device that holds the most."""
+        return max(self.device_bytes_by_device.values(), default=0)
 
     def set_device_budget(self, nbytes: int) -> None:
-        """Bytes of device memory the store may hold (0: no device tier,
-        and what it held is let go). The budget is taken out of
+        """Bytes of a device's memory the store may hold there (0: no
+        device tier, and what it held on every device is let go). The
+        budget is taken out of
         ``runtime.memory.device_budget_bytes`` here, before anything can
         be admitted under it, so the steps' sizing sees the configured
         bytes whichever of the two comes first."""
@@ -172,8 +193,7 @@ class SplitStore:
         with self._lock:
             self.device_budget = nbytes
             if not nbytes:
-                self._device.clear()
-                self.device_bytes = 0
+                self._drop_device_tier_locked()
 
     def columns(self, side_key: tuple, col_keys: Mapping[str, tuple],
                 make: Callable[[list], HostColumns]) -> HostColumns:
@@ -220,52 +240,108 @@ class SplitStore:
         REGISTRY.counter("exec.scan.store.bytes").add(need)
         return out
 
-    def resident(self, side_key: tuple,
-                 col_keys: Mapping[str, tuple]) -> tuple:
+    def resident(self, side_key: tuple, col_keys: Mapping[str, tuple],
+                 device=None) -> tuple:
         """``(side entry or None, {column: entry})`` of what the device
-        tier holds of a split: a column's entry is ``(data, mask or
-        None)``, the side's ``(live, rows)``. Without a budget nothing
-        is looked up and nothing counted."""
+        tier holds on ``device`` of a split (or of a device's shard): a
+        column's entry is ``(data, mask or None)``, the side's ``(live,
+        rows)``. Without a budget nothing is looked up and nothing
+        counted."""
         if not self.device_budget:
             return None, {}
         with self._lock:
-            side = self._device.get(side_key)
-            held = {c: self._device[k] for c, k in col_keys.items()
-                    if k in self._device}
+            side = self._device.get((device, side_key))
+            held = {c: self._device[device, k] for c, k in col_keys.items()
+                    if (device, k) in self._device}
         REGISTRY.counter("exec.scan.resident.hits").add(len(held))
         REGISTRY.counter("exec.scan.resident.misses").add(
             len(col_keys) - len(held))
         return side, held
 
     def admit_resident(self, side_key: tuple, col_keys: Mapping[str, tuple],
-                       batch: Batch, rows: int) -> tuple:
-        """Keep the arrays of ``batch`` — the upload of the columns
-        ``col_keys`` names, the ones :meth:`resident` did not find — if
-        the budget admits them, all or none, and drop their host
-        copies. Returns ``(side entry, {column: entry})`` to serve:
-        what is now held — an earlier thread's where one got there
-        first — or, refused, the batch's own arrays."""
+                       batch: Batch, rows: int, device=None) -> tuple:
+        """Keep the arrays of ``batch`` — the upload to ``device`` of
+        the columns ``col_keys`` names, the ones :meth:`resident` did
+        not find — if that device's budget admits them, all or none,
+        and drop their host copies. Returns ``(side entry, {column:
+        entry})`` to serve: what is now held — an earlier thread's
+        where one got there first — or, refused, the batch's own
+        arrays."""
         fresh = {k: (batch[c].data, None if batch[c].valid is batch.live
                      else batch[c].valid) for c, k in col_keys.items()}
         fresh[side_key] = (batch.live, rows)
         with self._lock:
-            new = {k: e for k, e in fresh.items() if k not in self._device}
+            new = {(device, k): e for k, e in fresh.items()
+                   if (device, k) not in self._device}
             need = sum(a.nbytes for a in _entry_arrays(new))
-            if self.device_bytes + need > self.device_budget:
+            held = self.device_bytes_by_device.get(device, 0)
+            if held + need > self.device_budget:
                 refused, need = len(new), 0
             else:
                 refused = 0
                 self._device.update(new)
-                self.device_bytes += need
-            served = {k: self._device.get(k, e) for k, e in fresh.items()}
+                self.device_bytes_by_device[device] = held + need
+            served = {k: self._device.get((device, k), e)
+                      for k, e in fresh.items()}
             # (a thread that missed before another's admission may have
             # put the host copies back: every device-held key's goes)
             gone = {k: self._entries.pop(k) for k in col_keys.values()
-                    if k in self._device and k in self._entries}
+                    if (device, k) in self._device and k in self._entries}
             self.bytes -= sum(a.nbytes for a in _entry_arrays(gone))
         REGISTRY.counter("exec.scan.resident.bypassed").add(refused)
         REGISTRY.counter("exec.scan.resident.bytes").add(need)
         return served[side_key], {c: served[k] for c, k in col_keys.items()}
+
+
+def batch_of_entries(cols: Sequence[str], side: tuple, entries: Mapping,
+                     types: Mapping[str, DataType],
+                     dicts: Mapping[str, Dictionary]) -> Batch:
+    """The batch of a store's entries — a split's, or on the mesh the
+    pieces of one device's shard: a column without a mask of its own
+    takes the side entry's live array as its validity."""
+    live = side[0]
+    return Batch({c: Column(entries[c][0], live if entries[c][1] is None
+                            else entries[c][1], types[c], dicts.get(c))
+                  for c in cols}, live)
+
+
+def scan_through_store(store: SplitStore, table: str, keys, make, upload,
+                       assemble, device=None, splits: int = 1):
+    """One lookup / upload / admit of the columns of a split — or, on
+    the mesh, of a device's shard — through ``store``, for the local
+    scan (:func:`scan_stored`) and the mesh's
+    (``DistributedExecutor._exec_tablescan``) alike.
+
+    ``keys()`` gives ``(side key, {column: key})`` (called inside the
+    ``scan:lookup`` span, with the caller's other work before the
+    upload); ``make(missing columns)`` generates and pads them
+    (``SplitStore.columns``); ``upload(HostColumns)`` puts them on
+    ``device`` as a :class:`Batch` and counts ``exec.h2d.*``;
+    ``assemble(side entry, {column: entry})`` builds what the caller
+    wants of the tier's entries. The columns the device tier holds on
+    ``device`` are left out of generation and upload — all held, they
+    are assembled under ``scan:resident`` and nothing is uploaded —
+    and the others' upload is offered to it; without a tier the
+    upload itself is returned."""
+    with trace.span("scan:lookup", "scan", {"table": table}):
+        side_key, col_keys = keys()
+        side, held = store.resident(side_key, col_keys, device)
+        missing = {c: k for c, k in col_keys.items() if c not in held}
+        if missing:
+            host = store.columns(side_key, missing, make)
+            rows = host.n
+        else:
+            rows = side[1]
+            with trace.span("scan:resident", "scan"):
+                out = assemble(side, held)
+    count_delivered(splits, rows)
+    if missing:
+        out = upload(host)
+        if store.device_budget:
+            side, fresh = store.admit_resident(
+                side_key, missing, out, rows, device)
+            out = assemble(side, {**held, **fresh})
+    return out
 
 
 def scan_stored(conn, split: Split, columns: Sequence[str] | None = None,
@@ -281,11 +357,24 @@ def scan_stored(conn, split: Split, columns: Sequence[str] | None = None,
     NULL-free column on a hit exactly as on a miss.
 
     Where the store has a device tier, the columns it holds there are
-    left out of all that — a split held whole is assembled under
-    ``scan:resident`` and nothing is uploaded — and the others' upload
-    is offered to it. The batch is then built from the tier's entries,
-    under the same identity."""
+    left out of all that and the batch is built from the tier's
+    entries, under the same identity (:func:`scan_through_store`)."""
     table = split.table
+    cols = types = dicts = None
+
+    def keys():
+        # the host's work before the upload: the split's schema,
+        # physical types and dictionaries, then the store's lookup a
+        # column (on a miss the two spans of the generation lie inside)
+        nonlocal cols, types, dicts
+        cols = (list(columns) if columns is not None
+                else list(conn.schema(table)))
+        types = conn.physical_schema(table, cols)
+        dicts = {c: d for c, d in conn.dictionaries(table).items()
+                 if c in types}
+        at = (table, split.chunk, split.lo, split.hi)
+        return at + (capacity,), {
+            c: at + (c, types[c].np_dtype.str, capacity) for c in cols}
 
     def make(missing):
         arrays, valids = split_valids(generate_split(conn, split, missing))
@@ -293,42 +382,11 @@ def scan_stored(conn, split: Split, columns: Sequence[str] | None = None,
         return Batch.pad_numpy(arrays, types, valids=valids,
                                capacity=capacity or batch_capacity(n))
 
-    def assemble(side, entries):
-        live = side[0]
-        return Batch({c: Column(entries[c][0], live if entries[c][1] is None
-                                else entries[c][1], types[c], dicts.get(c))
-                      for c in cols}, live)
-
-    store = conn.scan_store
-    # the host's work before the upload: the split's schema, physical
-    # types and dictionaries, and the store's lookup a column (on a
-    # miss the two spans of the generation lie inside)
-    with trace.span("scan:lookup", "scan", {"table": table}):
-        cols = (list(columns) if columns is not None
-                else list(conn.schema(table)))
-        types = conn.physical_schema(table, cols)
-        dicts = {c: d for c, d in conn.dictionaries(table).items()
-                 if c in types}
-        at = (table, split.chunk, split.lo, split.hi)
-        side_key = at + (capacity,)
-        col_keys = {c: at + (c, types[c].np_dtype.str, capacity)
-                    for c in cols}
-        side, held = store.resident(side_key, col_keys)
-        missing = {c: k for c, k in col_keys.items() if c not in held}
-        if missing:
-            host = store.columns(side_key, missing, make)
-            rows = host.n
-        else:
-            rows = side[1]
-            with trace.span("scan:resident", "scan"):
-                batch = assemble(side, held)
-    count_delivered(1, rows)
-    if missing:
-        batch = Batch.upload(host, types, dicts)
-        if store.device_budget:
-            side, fresh = store.admit_resident(side_key, missing, batch, rows)
-            batch = assemble(side, {**held, **fresh})
-    return batch
+    return scan_through_store(
+        conn.scan_store, table, keys, make,
+        lambda host: Batch.upload(host, types, dicts),
+        lambda side, entries: batch_of_entries(cols, side, entries, types,
+                                               dicts))
 
 
 def split_valids(arrays: Mapping[str, np.ndarray]):
