@@ -80,6 +80,7 @@ from presto_tpu.parallel.exchange import (
     any_flag,
     exchange_dispatch,
     exchange_multiround,
+    exchange_row_bytes,
     gather_wire_bytes,
 )
 from presto_tpu.parallel.mesh import replicated, row_sharding, worker_axes
@@ -141,15 +142,19 @@ import functools
 @functools.lru_cache(maxsize=64)
 def _compact_step(mesh, out_cap: int):
     """Compiled per-device compaction, cached per (mesh, capacity) so
-    repeated guarded replications reuse the XLA program."""
+    repeated guarded replications reuse the XLA program: the live rows
+    first, every column's data and ``valid`` moved by ONE gather of
+    packed rows (``ops/partition.take_rows``) — the exchange's mover."""
     from presto_tpu.cache.exec_cache import trace_probe
+    from presto_tpu.ops.compact import compact_indices
+    from presto_tpu.ops.partition import take_rows
 
     ax = worker_axes(mesh)
     @partial(shard_map, mesh=mesh, in_specs=(P(ax),), out_specs=P(ax),
              check_vma=False)
     def dist_compact_step(local):
         trace_probe()
-        return compact_batch(local, out_cap)
+        return take_rows(local, compact_indices(local.live, out_cap)[0])
 
     return jax.jit(dist_compact_step)
 
@@ -923,9 +928,13 @@ class DistributedExecutor(OomLadderMixin):
                 with trace_sync("exchange_flags"):
                     done = not bool(overflow)
                     r = ex["rounds"] = int(np.asarray(rounds))
-                # exchanged rows are partial-agg group rows: the final
-                # output's columns plus one int64 merge-count per agg
-                row_b = batch_row_bytes(out) + 9 * len(aggs)
+                # exchanged rows are partial-agg group rows: the keys
+                # and passengers of the output, then a value and an
+                # int64 merge count per agg
+                row_b = exchange_row_bytes(
+                    out.select([n for n, _ in (*keys, *pax)]),
+                    [jax.ShapeDtypeStruct((1,), d) for a in aggs
+                     for d in (_phys_dtype(a), jnp.int64)])
                 ex["bytes"] = a2a_wire_bytes(row_b, Pn, quota, r)
                 # hot-partition capture keys on the EXCHANGE receive
                 # overflow specifically — a partial/final group-capacity
@@ -1431,11 +1440,10 @@ class DistributedExecutor(OomLadderMixin):
                     ok = not bool(overflow)
                     lr, rr = (int(x) for x in np.asarray(rounds))
                 ex["rounds"] = lr + rr
-                ex["bytes"] = (
-                    a2a_wire_bytes(batch_row_bytes(left.batch), Pn, lquota,
-                                   lr)
-                    + a2a_wire_bytes(batch_row_bytes(right.batch), Pn,
-                                     rquota, rr))
+                lrow = exchange_row_bytes(left.batch)
+                rrow = exchange_row_bytes(right.batch)
+                ex["bytes"] = (a2a_wire_bytes(lrow, Pn, lquota, lr)
+                               + a2a_wire_bytes(rrow, Pn, rquota, rr))
                 # hot-partition capture keys on the exchange RECEIVE
                 # overflow only — probe-expand output overflow retries
                 # through the same loop but is not partition skew
@@ -1447,11 +1455,9 @@ class DistributedExecutor(OomLadderMixin):
                 # build-side: both exchanges shuffle on the SAME key
                 # hash, so a hot key shows up in each independently
                 self._note_exchange_skew(
-                    "join.probe", node, dest,
-                    batch_row_bytes(left.batch), part=0)
+                    "join.probe", node, dest, lrow, part=0)
                 self._note_exchange_skew(
-                    "join.build", node, dest,
-                    batch_row_bytes(right.batch), part=1)
+                    "join.build", node, dest, rrow, part=1)
             if long_runs:
                 raise NotImplementedError(
                     "hash-key collision run exceeds the verified probe's "
@@ -2107,7 +2113,8 @@ class DistributedExecutor(OomLadderMixin):
                 with trace_sync("exchange_flags"):
                     ok = not bool(overflow)
                     r = ex["rounds"] = int(np.asarray(rounds))
-                ex["bytes"] = a2a_wire_bytes(batch_row_bytes(b), Pn, quota, r)
+                ex["bytes"] = a2a_wire_bytes(
+                    exchange_row_bytes(b), Pn, quota, r)
             if ok:
                 return DistBatch(out, sharded=True)
             recv_cap *= 2
@@ -2402,7 +2409,8 @@ class DistributedExecutor(OomLadderMixin):
                 with trace_sync("exchange_flags"):
                     ok = not bool(overflow)
                     r = ex["rounds"] = int(np.asarray(rounds))
-                ex["bytes"] = a2a_wire_bytes(batch_row_bytes(b), Pn, quota, r)
+                ex["bytes"] = a2a_wire_bytes(
+                    exchange_row_bytes(b), Pn, quota, r)
             if ok:
                 return DistBatch(out, sharded=True)
             recv_cap *= 2

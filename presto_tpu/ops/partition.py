@@ -5,64 +5,141 @@ per-partition PageBuilders) and the serialized-page OutputBuffer
 [SURVEY §2.1, §2.5; reference tree unavailable].
 
 TPU-first (SURVEY §2.5): instead of serializing pages into per-consumer
-HTTP buffers, rows are scattered into a dense ``[P, Q]`` send tensor
-(P destinations x Q quota rows) that feeds ``jax.lax.all_to_all``
-directly. Quota overflow (skew) raises the overflow flag so the host
-retries at a bigger quota or falls back to multi-round shuffles
-(SURVEY §7.4 #4).
+HTTP buffers, a batch is laid out ONCE as a matrix of packed rows of
+32-bit words (``pack_rows``) and ordered by destination with ONE sort
+(``destination_order``); the exchange then moves whole rows — one
+gather before its round loop, contiguous slices inside it. On this chip
+a scatter costs 36–124 ns a row and a column gathered by a permutation
+16–20 ns an index; a gather of whole packed rows costs ONE index a row
+whatever its width (3 ns a row at 2^18 rows, 19 at 2^23) and a sort
+under 1 ns a key (PERF.md §6–§7, PR 26 / 30 / 35 / 45).
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
-import numpy as np
+from jax import lax
+
+from presto_tpu.batch import Batch, Column
+from presto_tpu.ops.groupby import _from_words, _to_words
+
+_LIVE = ("live", None)
 
 
-def partition_layout(pids, live, num_partitions: int, quota: int):
-    """Compute each row's slot in the [P, quota] send buffer.
+def _small(data) -> bool:
+    """A 1-D column under 32 bits: it shares a word with others."""
+    return data.ndim == 1 and data.dtype.itemsize < 4
 
-    Returns (slot, counts, overflow):
-    - slot[cap]: flattened destination slot p*quota + rank, or P*quota
-      (dropped) for dead/overflowing rows;
-    - counts[P]: rows destined to each partition (pre-overflow);
-    - overflow: any partition exceeded its quota.
-    """
+
+def _wide_words(data) -> int:
+    """Words ``_to_words`` gives a column that is not ``_small``."""
+    return jax.eval_shape(
+        _to_words, jax.ShapeDtypeStruct(data.shape, data.dtype)).shape[1]
+
+
+def _row_layout(datas):
+    """Where the pieces of a packed row sit, from the columns' data
+    arrays (shapes and dtypes alone): the words of the wide columns
+    first, in column order, then the words the sub-word pieces share —
+    the 16-bit columns, the 8-bit ones, then one bit for each boolean
+    column, for ``live`` and for each column's ``valid``. Widths descend and divide one
+    another, so no piece straddles a word. Returns ``(wide words,
+    [(piece, bits, word, shift)], words of a row)``; a piece is
+    ``("data" | "valid", column index)`` or ``("live", None)``."""
+    wide = sum(_wide_words(d) for d in datas if not _small(d))
+    pieces = sorted(
+        ((1 if d.dtype == jnp.bool_ else 8 * d.dtype.itemsize, ("data", i))
+         for i, d in enumerate(datas) if _small(d)),
+        key=lambda t: -t[0])
+    pieces += [(1, _LIVE)] + [(1, ("valid", i)) for i in range(len(datas))]
+    plan, at = [], 0
+    for bits, piece in pieces:
+        plan.append((piece, bits, wide + at // 32, at % 32))
+        at += bits
+    return wide, plan, wide + -(-at // 32)
+
+
+def packed_row_bytes(datas) -> int:
+    """Bytes of one packed row of a batch whose columns have these data
+    arrays (anything with a shape and a dtype): what the exchange's
+    ``all_to_all`` carries a row slot."""
+    return 4 * _row_layout(list(datas))[2]
+
+
+def pack_rows(batch: Batch):
+    """``batch`` as ONE uint32 ``[capacity, words]`` matrix: every
+    column's data, its ``valid`` mask and ``live`` (``_row_layout``).
+    Reversed by ``unpack_rows``."""
+    cols = list(batch.columns.values())
+    datas = [c.data for c in cols]
+    wide, plan, words = _row_layout(datas)
+    shared = [jnp.zeros(batch.capacity, jnp.uint32)] * (words - wide)
+    for (part, i), bits, word, shift in plan:
+        if part == "data":
+            v = datas[i]
+            if v.dtype != jnp.bool_:
+                v = lax.bitcast_convert_type(v, jnp.dtype(f"uint{bits}"))
+        else:
+            v = batch.live if part == "live" else cols[i].valid
+        shared[word - wide] = shared[word - wide] | (
+            v.astype(jnp.uint32) << shift)
+    return jnp.concatenate(
+        [_to_words(d) for d in datas if not _small(d)]
+        + [w[:, None] for w in shared], axis=1)
+
+
+def unpack_rows(rows, like: Batch) -> Batch:
+    """The batch ``pack_rows`` made ``rows`` of — any number of them —
+    with the columns, dtypes and dictionaries of ``like``."""
+    cols = list(like.columns.values())
+    datas = [c.data for c in cols]
+    _, plan, _ = _row_layout(datas)
+    got, at = {}, 0
+    for i, d in enumerate(datas):
+        if not _small(d):
+            n = _wide_words(d)
+            got["data", i] = _from_words(rows[:, at:at + n], d)
+            at += n
+    for piece, bits, word, shift in plan:
+        v = (rows[:, word] >> shift) & ((1 << bits) - 1)
+        if piece[0] != "data" or datas[piece[1]].dtype == jnp.bool_:
+            got[piece] = v != 0
+        else:
+            got[piece] = lax.bitcast_convert_type(
+                v.astype(jnp.dtype(f"uint{bits}")), datas[piece[1]].dtype)
+    return Batch(
+        {n: Column(got["data", i], got["valid", i], c.dtype, c.dictionary)
+         for i, (n, c) in enumerate(zip(like.names, cols))},
+        got[_LIVE])
+
+
+def take_rows(batch: Batch, idx) -> Batch:
+    """Rows ``idx`` of ``batch`` — every column's data and ``valid``,
+    and ``live`` — moved by ONE gather of packed rows; an out-of-range
+    ``idx`` (>= capacity) gives a dead row of zeros."""
+    cap = batch.capacity
+    rows = pack_rows(batch)[jnp.minimum(idx, cap - 1)]
+    return unpack_rows(jnp.where((idx < cap)[:, None], rows, 0), batch)
+
+
+def destination_order(pids, live, num_partitions: int):
+    """The live rows grouped by destination, in row order within one,
+    by ONE single-operand sort of a packed key (destination in the high
+    bits — ``num_partitions`` for a dead row, so the dead sort last —
+    row index in the low; 64 bits where 32 cannot hold both). Returns
+    ``(order[cap], counts[P])``: the row at each sorted place and the
+    live rows bound for each destination — masked sums, no scatter-add;
+    destination ``p``'s rows are ``order[sum(counts[:p]):][:counts[p]]``."""
     cap = pids.shape[0]
-    p = jnp.where(live, pids, num_partitions)
-    # rank of each row within its partition (stable by row order):
-    # sort rows by partition, rank = position - partition start
-    order = jnp.argsort(p, stable=True)
-    ps = p[order]
-    counts = jnp.zeros(num_partitions + 1, dtype=jnp.int32).at[p].add(1)[
-        :num_partitions
-    ]
-    starts = jnp.cumsum(counts) - counts
-    pos = jnp.arange(cap)
-    start_of_row = jnp.where(ps < num_partitions, starts[jnp.minimum(ps, num_partitions - 1)], 0)
-    rank_sorted = pos - start_of_row
-    rank = jnp.zeros(cap, dtype=jnp.int32).at[order].set(rank_sorted.astype(jnp.int32))
-    ok = live & (rank < quota)
-    slot = jnp.where(ok, p * quota + rank, num_partitions * quota)
-    overflow = jnp.any(counts > quota)
-    return slot, counts, overflow
-
-
-def destination_counts(pids, mask, num_partitions: int):
-    """Per-destination row histogram of the masked rows (int64 [P]).
-
-    The exchange-skew telemetry's device-side primitive: accumulated
-    across shuffle rounds inside the compiled step (never a per-round
-    host readback), psum'd over the worker axis at the end, and read
-    back once per query — the ``_flush_filter_stats`` discipline. The
-    extra slot absorbs masked-off rows (their pid may be garbage)."""
-    dest = jnp.where(mask, pids, num_partitions)
-    return jnp.zeros(num_partitions + 1, jnp.int64).at[dest].add(1)[
-        :num_partitions
-    ]
-
-
-def scatter_to_buffer(values, slot, num_partitions: int, quota: int, fill=0):
-    """Scatter a column into the dense [P, quota] send tensor."""
-    flat = jnp.full((num_partitions * quota + 1,) + values.shape[1:], fill, values.dtype)
-    flat = flat.at[slot].set(values)
-    return flat[:-1].reshape((num_partitions, quota) + values.shape[1:])
+    low = max(1, (cap - 1).bit_length())
+    kt = (jnp.uint32 if num_partitions.bit_length() + low <= 32
+          else jnp.uint64)
+    dest = jnp.where(live, pids, num_partitions).astype(kt)
+    keys = lax.sort((dest << low) | jnp.arange(cap, dtype=kt),
+                    is_stable=False)  # the keys are distinct
+    order = (keys & ((1 << low) - 1)).astype(jnp.int32)
+    counts = jnp.sum(
+        dest[:, None] == jnp.arange(num_partitions, dtype=kt)[None, :],
+        axis=0, dtype=jnp.int32)
+    return order, counts

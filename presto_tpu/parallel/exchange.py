@@ -9,12 +9,13 @@ reference tree unavailable, paths reconstructed].
 TPU-first (SURVEY §2.5, §7.1): the pull-based HTTP page shuffle becomes
 **compiled push-style collectives over ICI**:
 
-- hash-partitioned exchange  -> ``jax.lax.all_to_all`` of a dense
-  ``[P, quota]`` send tensor per column (P = mesh size);
+- hash-partitioned exchange  -> ``jax.lax.all_to_all`` of ONE dense
+  ``[P, quota, words]`` send tensor of packed rows (P = mesh size);
 - broadcast exchange         -> ``jax.lax.all_gather``;
 - single/gather exchange     -> ``all_gather`` + host slice.
 
-Serialization disappears (arrays stay columnar on device); token-based
+Serialization becomes a fixed-width row of 32-bit words, packed and
+unpacked on the device (``ops/partition.pack_rows``); token-based
 flow control becomes static capacity planning: every device reserves a
 ``quota`` of rows per destination, and quota overflow (skew, SURVEY
 §7.4 #4) raises a flag that the host handles by re-running the step at
@@ -38,19 +39,19 @@ import numpy as np
 
 from presto_tpu.batch import Batch, Column
 from presto_tpu.ops.partition import (
-    destination_counts,
-    partition_layout,
-    scatter_to_buffer,
+    destination_order,
+    pack_rows,
+    packed_row_bytes,
+    unpack_rows,
 )
 from presto_tpu.parallel.mesh import WORKERS, worker_axes
 
 
 def _a2a(x, axes=WORKERS):
     """all_to_all along the worker axes (a 2-D dcn/ici mesh passes the
-    axis tuple — XLA splits the collective over DCN + ICI legs); bools
-    ride as uint8."""
-    if x.dtype == jnp.bool_:
-        return _a2a(x.astype(jnp.uint8), axes).astype(jnp.bool_)
+    axis tuple — XLA splits the collective over DCN + ICI legs): block
+    ``p`` of ``x`` goes to device ``p``, block ``s`` of the result came
+    from device ``s``."""
     return jax.lax.all_to_all(x, axes, split_axis=0, concat_axis=0)
 
 
@@ -63,7 +64,7 @@ def _ag(x, axes=WORKERS):
 
 def exchange_local(batch: Batch, pids, num_partitions: int, quota: int,
                    axes=WORKERS):
-    """Per-device hash-partitioned shuffle body.
+    """Per-device hash-partitioned shuffle body, ONE round.
 
     ``pids[cap]``: destination partition of each row (int32, computed by
     the caller — typically ``ops.hashing.partition_ids`` over the
@@ -71,28 +72,13 @@ def exchange_local(batch: Batch, pids, num_partitions: int, quota: int,
 
     Returns ``(received, overflow)``: a local Batch of capacity
     ``num_partitions * quota`` holding every row whose key this device
-    owns, and this device's *send-side* overflow flag (psum it across
-    the axis before acting on it).
+    owns, and the *send-side* overflow flag — some device held more
+    than ``quota`` rows for one destination (psum it across the axis
+    before acting on it). The one-round case of ``exchange_multiround``.
     """
-    slot, _counts, overflow = partition_layout(
-        pids, batch.live, num_partitions, quota
-    )
-
-    def send_recv(values, fill=0):
-        buf = scatter_to_buffer(values, slot, num_partitions, quota, fill)
-        out = _a2a(buf, axes)
-        return out.reshape((num_partitions * quota,) + values.shape[1:])
-
-    cols = {}
-    for name, c in batch.columns.items():
-        cols[name] = Column(
-            send_recv(c.data),
-            send_recv(c.valid, False),
-            c.dtype,
-            c.dictionary,
-        )
-    live = send_recv(batch.live, False)
-    return Batch(cols, live), overflow
+    return exchange_multiround(
+        batch, pids, num_partitions, quota, num_partitions * quota,
+        max_rounds=1, axes=axes)
 
 
 def exchange_multiround(
@@ -108,127 +94,90 @@ def exchange_multiround(
 ):
     """Skew-aware per-device shuffle body: multi-round, fixed wire quota.
 
-    The single-round ``exchange_local`` couples the *wire* quota (rows
-    per destination per ``all_to_all``) to the *receive* capacity
-    (``P * quota``): one hot key forces the host to double the quota and
-    recompile the whole fragment step (SURVEY §7.4 #4). Here the two are
-    decoupled — the moral equivalent of the reference's token-paged
-    ``ExchangeClient`` pulls (a bounded buffer drained over as many
-    round trips as the data needs [SURVEY §2.5]):
+    The *wire* quota (rows per destination per ``all_to_all``) and the
+    *receive* capacity are decoupled — the moral equivalent of the
+    reference's token-paged ``ExchangeClient`` pulls (a bounded buffer
+    drained over as many round trips as the data needs [SURVEY §2.5]):
+    one hot key costs rounds, not a doubled quota and a recompiled
+    fragment step (SURVEY §7.4 #4).
 
-    - every round moves at most ``quota`` rows per (sender, dest) pair
-      through one ``all_to_all``; undelivered rows wait for the next
-      round (``lax.while_loop`` — rounds are data-dependent but the
-      program is compiled once);
-    - receivers append compacted rows into a ``recv_cap`` buffer;
-      overflow now means "this device *owns* more rows than recv_cap"
-      (true placement skew), never "one destination was hot this round".
+    A row moves ONCE, as one packed row of 32-bit words
+    (``ops/partition.pack_rows``), and nothing in the round loop sorts,
+    scatters or gathers:
 
-    Returns ``(received, overflow)`` like ``exchange_local``; overflow
-    is this device's receive-side flag OR an undrained-after-
-    ``max_rounds`` flag (psum across the axis before acting).
-    ``with_rounds=True`` additionally returns the executed round count
-    (int32; identical on every device — the while cond is driven by
-    the global pending flag) so the host can account exact wire bytes
-    (``a2a_wire_bytes`` x rounds) for the exchange metrics.
+    - before the loop, one sort orders the rows by destination
+      (``destination_order``: ``pids`` do not change between rounds),
+      one gather of packed rows lays them out in that order, and one
+      tiny ``all_to_all`` of the ``[P]`` counts tells every receiver
+      how many rows each sender holds for it — so the number of rounds
+      (``ceil(pmax(counts) / quota)``, at most ``max_rounds``), the
+      rows this device will own and both flags are known up front;
+    - round ``r`` sends destination ``p`` the ``quota`` rows from place
+      ``start[p] + r * quota`` on — P contiguous slices, ONE
+      ``all_to_all`` of the ``[P, quota, words]`` tensor — and appends
+      each sender's block at the running offset (what lies past a
+      sender's rows in its block is overwritten by the next block);
+    - after it, the words unpack once into columns.
+
+    The received layout is a function of the input alone (round-major,
+    sender-major, row order within a sender). Returns ``(received,
+    overflow)``; overflow is this device's receive-side flag ("this
+    device *owns* more rows than ``recv_cap``": true placement skew) OR
+    the undrained-after-``max_rounds`` flag (psum across the axis
+    before acting). ``with_rounds=True`` additionally returns the
+    executed round count (int32; identical on every device) so the host
+    can account exact wire bytes (``a2a_wire_bytes``).
     ``with_stats=True`` appends the GLOBAL per-destination delivered
     row counts (int64 [P], psum'd over the axis — identical on every
-    device): the exchange-skew telemetry's raw material, accumulated
-    in the while-loop carry so no round ever pays a host readback.
+    device): the exchange-skew telemetry's raw material.
     """
     P = num_partitions
     cap = batch.live.shape[0]
     if max_rounds is None:
         # a sender drains at most `cap` rows to one destination
         max_rounds = max(1, -(-cap // quota))
-    names = list(batch.columns)
 
-    def empty_buf(c: Column):
-        tail = tuple(c.data.shape[1:])
-        return (
-            jnp.zeros((recv_cap,) + tail, c.data.dtype),
-            jnp.zeros(recv_cap, jnp.bool_),
-        )
+    order, counts = destination_order(pids, batch.live, P)
+    starts = jnp.cumsum(counts) - counts
+    # `quota` spare rows, so that a slice of a destination's last rows
+    # never reaches past the end (a dynamic slice would be shifted back)
+    rows = pack_rows(batch)[
+        jnp.concatenate([order, jnp.zeros(quota, jnp.int32)])]
+    have = _a2a(counts, axes)  # rows each sender holds for this device
+    most = jax.lax.pmax(jnp.max(counts), axes)
+    rounds = jnp.minimum(-(-most // quota), max_rounds).astype(jnp.int32)
+    owned = jnp.sum(jnp.minimum(have, rounds * quota))
 
-    def any_pending(remaining):
-        # psum lives in the body (a collective in the while cond is
-        # not portable); the cond reads the carried flag
-        return jax.lax.psum(jnp.any(remaining).astype(jnp.int32), axes) > 0
+    def one_round(r, state):
+        recv, off = state
+        sent = jnp.stack([
+            jax.lax.dynamic_slice_in_dim(rows, starts[p] + r * quota, quota)
+            for p in range(P)])
+        got = _a2a(sent, axes)
+        lens = jnp.clip(have - r * quota, 0, quota)
+        for s in range(P):
+            # past `recv_cap` (overflow) a block lands in the spare
+            # rows: the start is never clamped back into live ones
+            recv = jax.lax.dynamic_update_slice_in_dim(
+                recv, got[s], jnp.minimum(off, recv_cap), axis=0)
+            off = off + lens[s]
+        return recv, off
 
-    init = (
-        batch.live,  # remaining: rows not yet delivered
-        any_pending(batch.live),  # pending anywhere on the axis
-        jnp.zeros((), jnp.int64),  # receive write offset
-        jnp.zeros((), jnp.bool_),  # receive-side overflow
-        jnp.zeros((), jnp.int32),  # round counter
-        jnp.zeros(P, jnp.int64),  # per-destination delivered rows
-        {n: empty_buf(batch.columns[n]) for n in names},
-    )
-
-    def cond(state):
-        _remaining, pending, _off, _ovf, rnd, _dest, _bufs = state
-        return pending & (rnd < max_rounds)
-
-    def body(state):
-        remaining, _pending, off, ovf, rnd, dest, bufs = state
-        slot, _counts, _ = partition_layout(pids, remaining, P, quota)
-        sent = remaining & (slot < P * quota)
-
-        def send_recv(values, fill=0):
-            buf = scatter_to_buffer(values, slot, P, quota, fill)
-            return _a2a(buf, axes).reshape((P * quota,) + values.shape[1:])
-
-        got = send_recv(sent, False)
-        pos = off + jnp.cumsum(got.astype(jnp.int64)) - 1
-        pos = jnp.where(got, pos, recv_cap)  # dead slots drop
-        total = jnp.sum(got.astype(jnp.int64))
-
-        new_bufs = {}
-        for n in names:
-            c = batch.columns[n]
-            data, valid = bufs[n]
-            rdata = send_recv(c.data)
-            rvalid = send_recv(c.valid, False)
-            new_bufs[n] = (
-                data.at[pos].set(rdata, mode="drop"),
-                valid.at[pos].set(rvalid, mode="drop"),
-            )
-        new_off = off + total
-        new_remaining = remaining & ~sent
-        return (
-            new_remaining,
-            any_pending(new_remaining),
-            new_off,
-            ovf | (new_off > recv_cap),
-            rnd + 1,
-            # skew telemetry: delivered-rows-by-destination, carried on
-            # device across rounds (the host reads the total once).
-            # Gated: stats-less callers (window/sort shuffles) loop the
-            # zeros through untouched — the [P] carry rides for free,
-            # the per-round scatter-add is only paid when someone reads
-            (dest + destination_counts(pids, sent, P) if with_stats
-             else dest),
-            new_bufs,
-        )
-
-    remaining, _pending, off, ovf, rnd, dest, bufs = jax.lax.while_loop(
-        cond, body, init
-    )
-    undrained = jnp.any(remaining)
-    cols = {
-        n: Column(bufs[n][0], bufs[n][1], batch.columns[n].dtype,
-                  batch.columns[n].dictionary)
-        for n in names
-    }
-    live = jnp.arange(recv_cap) < off
-    out = Batch(cols, live)
-    res = (out, ovf | undrained)
+    recv, _ = jax.lax.fori_loop(
+        0, rounds, one_round,
+        (jnp.zeros((recv_cap + quota, rows.shape[1]), jnp.uint32),
+         jnp.zeros((), jnp.int32)))
+    live = jnp.arange(recv_cap) < owned
+    out = unpack_rows(
+        jnp.where(live[:, None], recv[:recv_cap], 0), batch).with_live(live)
+    res = (out, (owned > recv_cap) | (most > max_rounds * quota))
     if with_rounds:
-        res = res + (rnd,)
+        res = res + (rounds,)
     if with_stats:
         # every device sees the same global per-destination totals
-        # (sender-local histograms psum'd over the axis)
-        res = res + (jax.lax.psum(dest, axes),)
+        # (sender-local delivered counts psum'd over the axis)
+        res = res + (jax.lax.psum(
+            jnp.minimum(counts, rounds * quota).astype(jnp.int64), axes),)
     return res
 
 
@@ -252,10 +201,13 @@ def any_flag(flag, axes=WORKERS):
 # ---------------------------------------------------------------------------
 #
 # Wire-byte accounting is *capacity-based and exact for the dense
-# collectives*: an ``all_to_all`` moves the full ``[P, quota]`` send
-# tensor per column per device regardless of row liveness, so bytes =
-# rounds x P senders x (P x quota) rows x row_bytes. ``all_gather``
-# replication moves each device's shard to the P-1 others. Dispatch
+# collectives*: an ``all_to_all`` moves the full ``[P, quota, words]``
+# send tensor of packed rows per device regardless of row liveness, so
+# bytes = rounds x P senders x (P x quota) rows x the packed row's
+# bytes (``ops/partition.packed_row_bytes``), plus the one int32 a
+# (sender, destination) pair that tells the receivers the counts.
+# ``all_gather`` replication moves each device's shard, column by
+# column, to the P-1 others (``batch_row_bytes`` a row). Dispatch
 # time is the host-observed wall of the enclosing compiled step — the
 # collective is fused inside it, so the step IS the exchange dispatch
 # unit (SURVEY §7.1).
@@ -273,8 +225,20 @@ def any_flag(flag, axes=WORKERS):
 def a2a_wire_bytes(row_bytes: int, num_partitions: int, quota: int,
                    rounds: int = 1) -> int:
     """Total bytes one hash-partitioned exchange moved across the mesh
-    (all devices, all rounds)."""
-    return int(rounds) * num_partitions * num_partitions * quota * row_bytes
+    (all devices, all rounds): ``row_bytes`` — the packed row's — a
+    slot of every round's send tensors, and the exchange of the
+    ``[P]`` int32 counts before the first."""
+    pairs = num_partitions * num_partitions
+    return pairs * (int(rounds) * quota * row_bytes + 4)
+
+
+def exchange_row_bytes(batch, more=()) -> int:
+    """Bytes of the packed row a hash exchange of ``batch`` puts in a
+    slot of its send tensor (``a2a_wire_bytes``' ``row_bytes``);
+    ``more``: the data arrays (shape and dtype) of columns the step
+    adds before it exchanges. Shapes alone, no device read."""
+    return packed_row_bytes(
+        [c.data for c in batch.columns.values()] + list(more))
 
 
 def gather_wire_bytes(row_bytes: int, capacity: int, mesh_size: int) -> int:

@@ -449,9 +449,14 @@ if not any(type(cb).__name__ == "_GcHook" for cb in gc.callbacks):
 
 
 def batch_row_bytes(batch) -> int:
-    """Per-row device bytes of a Batch: column payload widths + the
-    validity and live masks (1 byte each as moved on the wire — bools
-    ride as uint8 through the collectives)."""
+    """Per-row device bytes of a Batch as it is HELD and as the
+    column-by-column collectives move it (an all_gather, a device_put):
+    column payload widths + the validity and live masks, 1 byte each —
+    bools ride as uint8. NOT what a hash exchange sends: that moves a
+    packed row of 32-bit words — the data of the 4- and 8-byte columns
+    and BYTES matrices word for word, the narrower columns sharing
+    words, every mask ONE BIT (``ops/partition.pack_rows``) — counted
+    by ``parallel/exchange.exchange_row_bytes``."""
     total = 1  # live mask
     for c in batch.columns.values():
         width = 1

@@ -289,7 +289,10 @@ def test_query_server_warms_recurring_templates(conn):
         server.execute(q)
         server.execute(q)
         deadline = time.monotonic() + 10.0
-        while not server._warmed and time.monotonic() < deadline:
+        # the warmer marks a template BEFORE it runs it and counts it
+        # after: wait for the count, not for the mark
+        while (_counter("adaptive.warmed") == before
+               and time.monotonic() < deadline):
             time.sleep(0.05)
         assert q in server._warmed
         assert _counter("adaptive.warmed") > before

@@ -96,6 +96,43 @@ def test_q3_compacts_and_matches_local(conn, mesh, local, monkeypatch):
     assert c["exec.sync.reads"] == c0["exec.sync.reads"]
 
 
+def test_a2a_bytes_are_the_packed_rows_sent(conn, mesh, monkeypatch):
+    """``exchange.bytes.a2a`` is ``a2a_wire_bytes`` of every exchange's
+    rounds, quota and PACKED row — the width of the matrix the
+    ``all_to_all`` carries, never more than the unpacked row rounded up
+    to a word — and ``exchange.rounds`` holds the rounds it was given."""
+    import jax
+
+    from presto_tpu.ops.partition import pack_rows
+    from presto_tpu.runtime.trace import batch_row_bytes
+
+    calls, rows = [], []
+    wire, row_bytes = D.a2a_wire_bytes, D.exchange_row_bytes
+
+    def counted_wire(row_b, parts, quota, rounds=1):
+        calls.append((row_b, parts, quota, rounds))
+        return wire(row_b, parts, quota, rounds)
+
+    def counted_row(batch, more=()):
+        rows.append((row_bytes(batch, more), batch, more))
+        return rows[-1][0]
+
+    monkeypatch.setattr(D, "a2a_wire_bytes", counted_wire)
+    monkeypatch.setattr(D, "exchange_row_bytes", counted_row)
+    _, c = run_counted(conn, mesh, QUERIES["q3"])
+    assert len(calls) == 5  # two joins x two sides, the aggregation
+    # the result's gather is a dispatch of one round too
+    assert c["exchange.rounds"] == sum(r for *_, r in calls) + 1
+    assert c["exchange.bytes.a2a"] == sum(
+        p * p * (r * q * b + 4) for b, p, q, r in calls)
+    assert sorted(b for b, *_ in calls) == sorted(b for b, *_ in rows)
+    for got, batch, more in rows:
+        assert got % 4 == 0
+        if not more:
+            assert got == 4 * jax.eval_shape(pack_rows, batch).shape[1]
+            assert got <= batch_row_bytes(batch) + 3
+
+
 SKEWED = (
     "select l_orderkey, sum(l_quantity) q, count(*) n, max(o_totalprice) p "
     "from lineitem, orders where l_orderkey = o_orderkey "

@@ -6,6 +6,7 @@ import numpy as np
 import pandas as pd
 import pytest
 
+from presto_tpu.batch import Batch, Column
 from presto_tpu.ops.compact import compact_indices
 from presto_tpu.ops.groupby import (
     gather_padded,
@@ -21,7 +22,13 @@ from presto_tpu.ops.join import (
     probe_expand,
     probe_unique,
 )
-from presto_tpu.ops.partition import partition_layout, scatter_to_buffer
+from presto_tpu.ops.partition import (
+    destination_order,
+    pack_rows,
+    packed_row_bytes,
+    unpack_rows,
+)
+from presto_tpu.types import BIGINT
 from presto_tpu.ops.sort import packed_sort_order, sort_indices
 
 
@@ -356,28 +363,54 @@ def test_sort_nulls_ordering(orderer):
     np.testing.assert_array_equal(onf, [2, 4, 1, 0, 3])
 
 
-def test_partition_roundtrip(rng):
-    cap, n, P, Q = 64, 50, 4, 32
-    keys = rng.integers(0, 1000, cap).astype(np.int64)
-    live = _live(n, cap)
-    pids = partition_ids([jnp.asarray(keys)], P)
-    slot, counts, ovf = partition_layout(pids, live, P, Q)
-    assert not bool(ovf)
-    assert int(np.asarray(counts).sum()) == n
-    buf = scatter_to_buffer(jnp.asarray(keys), slot, P, Q, fill=-1)
-    got = np.asarray(buf)
-    for p in range(P):
-        want = sorted(keys[:n][np.asarray(pids)[:n] == p].tolist())
-        have = sorted(x for x in got[p].tolist() if x != -1)
-        assert want == have
+@pytest.mark.parametrize("num_partitions", [4, 7])
+def test_destination_order_groups_rows_by_destination(rng, num_partitions):
+    """One sort: the live rows grouped by destination in row order, the
+    dead last; the counts are numpy's histogram."""
+    cap, P = 96, num_partitions
+    live = rng.random(cap) < 0.8
+    pids = rng.integers(0, P, cap)
+    order, counts = destination_order(
+        jnp.asarray(pids, jnp.int32), jnp.asarray(live), P)
+    want = np.concatenate(
+        [np.flatnonzero(live & (pids == p)) for p in range(P)]
+        + [np.flatnonzero(~live)])
+    np.testing.assert_array_equal(np.asarray(order), want)
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.bincount(pids[live], minlength=P))
 
 
-def test_partition_overflow():
-    cap, P, Q = 32, 4, 4
-    keys = jnp.asarray(np.full(cap, 7, dtype=np.int64))  # all -> same pid
-    pids = partition_ids([keys], P)
-    slot, counts, ovf = partition_layout(pids, _live(32, cap), P, Q)
-    assert bool(ovf)
+@pytest.mark.parametrize("dtypes, row_bytes", [
+    ((np.int64, np.float32), 16),            # 3 words + the mask bits
+    ((np.int16, np.int16, np.int8), 8),      # 40 bits of data + 4 of masks
+    ((np.int8, np.bool_, np.int16, np.int64), 12),  # 25 + 6 bits: one word
+    ((np.int16,) * 2 + (np.int8,) * 4 + (np.bool_,) * 29, 20),  # 130 bits
+], ids=["wide", "narrow", "mixed", "masks_past_one_word"])
+def test_pack_rows_roundtrip_and_width(rng, dtypes, row_bytes):
+    """A packed row holds every column's data bit for bit, its valid
+    mask and live as single bits, and is no wider than it must be."""
+    cap = 50
+    cols = {}
+    for i, dt in enumerate(dtypes):
+        raw = rng.integers(0, 256, cap * np.dtype(dt).itemsize, np.uint8)
+        data = raw.view(dt) if dt is not np.bool_ else raw[:cap] > 127
+        cols[f"c{i}"] = Column(jnp.asarray(data),
+                               jnp.asarray(rng.random(cap) < 0.7), BIGINT)
+    cols["b"] = Column(jnp.asarray(rng.integers(0, 256, (cap, 5), np.uint8)),
+                       jnp.asarray(rng.random(cap) < 0.7), BIGINT)
+    b = Batch(cols, jnp.asarray(rng.random(cap) < 0.6))
+    rows = pack_rows(b)
+    assert rows.dtype == jnp.uint32
+    assert 4 * rows.shape[1] == packed_row_bytes(
+        [c.data for c in cols.values()]) == row_bytes + 8  # "b": 2 words
+    back = unpack_rows(rows, b)
+    np.testing.assert_array_equal(np.asarray(back.live), np.asarray(b.live))
+    for n, c in cols.items():
+        got = back[n]
+        assert got.data.dtype == c.data.dtype
+        np.testing.assert_array_equal(
+            np.asarray(got.data).view(np.uint8), np.asarray(c.data).view(np.uint8))
+        np.testing.assert_array_equal(np.asarray(got.valid), np.asarray(c.valid))
 
 
 def test_pack_key_columns():
