@@ -60,8 +60,9 @@ from presto_tpu.ops.pallas_agg import (
     agg_step,
     combine_states,
     null_violation,
-    state_keys,
+    pallas_eligible,
 )
+from presto_tpu.ops.pallas_groupby import _MAJOR_ROWS
 from presto_tpu.plan import nodes as N
 from presto_tpu.plan.bounds import expr_interval
 from presto_tpu.spi import batch_capacity, stats_physical_interval
@@ -73,6 +74,10 @@ _INTEGERISH = (TypeKind.INTEGER, TypeKind.BIGINT, TypeKind.DECIMAL,
 #: membership bitmaps cover at most this many key slots (bool array on
 #: device; 2^22 = 4 MiB — the SSB date domain is ~7e4)
 MEMBER_DOMAIN_LIMIT = 1 << 22
+
+#: rows one dispatch of the local route takes: a group of splits, as many
+#: as fill one exact int32 major of the slot kernels
+GROUP_ROWS = _MAJOR_ROWS
 
 #: int32 value domain every routed column must declare bounds inside
 #: (the kernel compares and multiplies in int32)
@@ -648,7 +653,18 @@ def _apply_membership(batch: Batch, probe_col: str, lo: int, hi: int,
 
 def _build_local_step(spec: LeafAggSpec, member: Optional[Membership],
                       pallas_ok: bool):
-    """``pallas_ok`` is the HOISTED kernel decision (evaluated on the
+    """The route's one program: ``leaf_agg_step(state, batches, *bitmap)
+    -> state`` takes the running [groups] state (None before the first
+    group) and a GROUP of 1..K scan batches of one schema and capacity,
+    and folds each batch's partial state into it in split order — the
+    kernel once a batch, unrolled, so that each is a top-level custom
+    call in the compiled program and no byte is copied to join them.
+    jit's own signature cache tells a full group from the tail's. The
+    batch's part is a jit of its own inside it: traced once and lowered
+    once a program where the bare loop traced and lowered the kernel K
+    times (a warm start's seconds, a binding; XLA inlines the calls).
+
+    ``pallas_ok`` is the HOISTED kernel decision (evaluated on the
     first concrete scan batch, outside the trace — tracer identity
     breaks the shared-mask eligibility check in-trace) baked statically
     into the jitted step; it is part of the exec-cache key, so toggling
@@ -660,19 +676,24 @@ def _build_local_step(spec: LeafAggSpec, member: Optional[Membership],
     lo = None if member is None else member.lo
     hi = None if member is None else member.hi
 
-    def leaf_agg_step(batch: Batch, *bitmap):
-        trace_probe()
+    @jax.jit
+    def leaf_agg_batch(batch, *bitmap):
         # declared NULL-freedom's runtime check, on the PRE-membership
         # batch (membership rebuilds validity as the live mask)
-        nulls = null_violation(batch)
-        oob = None
+        flag = null_violation(batch)
         if bitmap:
             batch, oob = _apply_membership(batch, probe_col, lo, hi,
                                            bitmap[0])
-        state = agg_step(spec, batch, pallas_ok=pallas_ok)
-        state["value_overflow"] = state["value_overflow"] | nulls
-        if oob is not None:
-            state["value_overflow"] = state["value_overflow"] | oob
+            flag = flag | oob
+        s = agg_step(spec, batch, pallas_ok=pallas_ok)
+        s["value_overflow"] = s["value_overflow"] | flag
+        return s
+
+    def leaf_agg_step(state, batches, *bitmap):
+        trace_probe()
+        for batch in batches:
+            s = leaf_agg_batch(batch, *bitmap)
+            state = s if state is None else combine_states(spec, state, s)
         return state
 
     return jax.jit(leaf_agg_step)
@@ -710,11 +731,13 @@ def decode_leaf_state(route: LeafRoute, conn, aggs, state) -> Batch:
 
 
 def execute_leaf_route(route: LeafRoute, executor, node, scalars):
-    """Run a matched fragment on the LOCAL executor: stream scan splits
-    through the fused step (membership bitmap applied per batch when the
-    fragment folded a filter-only join), combine states, decode. None on
-    runtime ``value_overflow`` (violated advisory stats) — counted, and
-    the caller falls back to the generic operator route."""
+    """Run a matched fragment on the LOCAL executor: scan the splits one
+    by one, hand them to the fused step a group at a time (membership
+    bitmap applied per batch when the fragment folded a filter-only
+    join; the states combine inside the step), decode. None on runtime
+    ``value_overflow`` in any batch of any group (violated advisory
+    stats) — counted, and the caller falls back to the generic operator
+    route."""
     from presto_tpu.cache.exec_cache import EXEC_CACHE
     from presto_tpu.runtime.faults import fault_point
     from presto_tpu.runtime.lifecycle import check_deadline
@@ -751,49 +774,47 @@ def execute_leaf_route(route: LeafRoute, executor, node, scalars):
     cap = batch_capacity(max(s.row_hint for s in splits))
     mb = (None if route.member is None
           else (route.member.probe_col, route.member.lo, route.member.hi))
-    def build_fold():
-        def leaf_fold_step(a, b):
-            return combine_states(spec, a, b)
-
-        return jax.jit(leaf_fold_step)
-
-    fold = EXEC_CACHE.get_or_build(
-        EXEC_CACHE.key_of("leaf_route_fold", tuple(state_keys(spec))),
-        build_fold,
-    )
+    # the unit of dispatch is a GROUP of splits: as many as make one
+    # exact int32 major of rows, so K follows the splits' capacity (a
+    # one-split table is one call). Bounded, and not the whole table, so
+    # that an uploaded scan holds at most K splits' buffers and the
+    # deadline is still looked at between dispatches
+    per_group = max(1, GROUP_ROWS // cap)
+    extra = () if bitmap is None else (bitmap,)
+    groups = REGISTRY.counter("exec.leaf_route.groups")
+    group_splits = REGISTRY.counter("exec.leaf_route.group_splits")
     state = None
     step = None
-    for split in splits:
-        fault_point("scan")
-        check_deadline("scan")
-        b = conn.scan(split, route.src_cols, cap).rename(route.rename)
-        if step is None:
-            # hoisted Pallas decision: evaluated on the first CONCRETE
-            # batch (identity checks break on tracers) and baked into
-            # the cached step; membership rebuilds validity as the live
-            # mask in-trace, so the pre-membership batch is the sound
-            # proxy. Later splits share the schema and capacity, so the
-            # first-batch decision holds for the whole stream.
-            from presto_tpu.ops.pallas_agg import pallas_eligible
-
-            pallas_ok = pallas_eligible(spec, b)
-            step = EXEC_CACHE.get_or_build(
-                EXEC_CACHE.key_of("leaf_route_step", spec, mb, pallas_ok,
-                                  jax.default_backend()),
-                lambda: _build_local_step(spec, route.member, pallas_ok),
-            )
-        with trace_span("step:leaf_agg", "step"):
-            s = step(b, *(() if bitmap is None else (bitmap,)))
-        # the split's device buffers are this loop's alone, so their
+    for at in range(0, len(splits), per_group):
+        group = []
+        for split in splits[at:at + per_group]:
+            fault_point("scan")
+            check_deadline("scan")
+            group.append(conn.scan(split, route.src_cols, cap)
+                         .rename(route.rename))
+            if step is None:
+                # hoisted Pallas decision: evaluated on the first CONCRETE
+                # batch (identity checks break on tracers) and baked into
+                # the cached step; membership rebuilds validity as the
+                # live mask in-trace, so the pre-membership batch is the
+                # sound proxy. Later splits share the schema and capacity,
+                # so the first-batch decision holds for the whole stream.
+                pallas_ok = pallas_eligible(spec, group[0])
+                step = EXEC_CACHE.get_or_build(
+                    EXEC_CACHE.key_of("leaf_route_step", spec, mb,
+                                      pallas_ok, jax.default_backend()),
+                    lambda: _build_local_step(spec, route.member,
+                                              pallas_ok),
+                )
+        with trace_span("step:leaf_agg", "step", {"splits": len(group)}):
+            state = step(state, tuple(group), *extra)
+        groups.add()
+        group_splits.add(len(group))
+        # the group's device buffers are this loop's alone, so their
         # release is here and has a name (in a lazy stream it falls to
         # whichever frame drops the last reference)
         with trace_span("batch:release", "scan"):
-            del b
-        if state is None:
-            state = s
-        else:
-            with trace_span("step:leaf_fold", "step"):
-                state = fold(state, s)
+            del group
     with trace_sync("leaf_state"):
         overflow = bool(state["value_overflow"])
     if overflow:

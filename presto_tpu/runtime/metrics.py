@@ -457,6 +457,11 @@ METRIC_HELP: dict[str, str] = {
     "exec.leaf_route_fallback": (
         "leaf fused-route bailouts to the general path (reasons: "
         "exec.leaf_route_fallback.*)"),
+    "exec.leaf_route.groups": (
+        "groups of splits the local leaf route dispatched, one fused "
+        "step each"),
+    "exec.leaf_route.group_splits": (
+        "splits those groups held (over .groups: splits a dispatch)"),
     "exec.q1_fused_route": (
         "aggregation queries routed through the fused Q1-shape kernel"),
     "exec.q1_route_fallback": (

@@ -367,6 +367,236 @@ def test_fragment_is_cached_zero_warm_retraces(conns):
     assert td.traces == 0
 
 
+# ---------------------------------------------------------------------------
+# the unit of dispatch is a group of splits (PR 46): one step takes the
+# running state and up to GROUP_ROWS rows of splits and folds inside
+# ---------------------------------------------------------------------------
+
+#: splits a group holds in these tests (GROUP_ROWS is lowered to K x the
+#: table's batch capacity: at its real 2^23 every SF 0.005 table is one
+#: group)
+K = 4
+
+KEYED = ("select l_returnflag, l_linestatus, sum(l_quantity) q, "
+         "sum(l_extendedprice * (1 - l_discount)) rev, count(*) c, "
+         "min(l_discount) mn, max(l_extendedprice) mx from lineitem "
+         "where l_shipdate <= date '1998-09-02' "
+         "group by l_returnflag, l_linestatus "
+         "order by l_returnflag, l_linestatus")
+
+#: shape -> (splits, lineitem's units_per_split (orders), lineorder's
+#: (rows)) at SF 0.005: 7,500 orders, 30,000 lineorder rows
+GROUPINGS = {
+    "full_group_only": (K, 1875, 7500),
+    "full_group_and_tail_of_1": (K + 1, 1500, 6000),
+    "full_group_and_tail_of_K-1": (2 * K - 1, 1072, 4286),
+    "two_full_groups": (2 * K, 938, 3750),
+}
+GROUPED_QUERIES = {
+    "keyless_q6": ("tpch", "lineitem", TPCH["q6"]),
+    "keyed_min_max": ("tpch", "lineitem", KEYED),
+    "membership_ssb_q1_1": ("ssb", "lineorder", SSB["q1_1"]),
+}
+
+
+@pytest.fixture(scope="module")
+def split_conns():
+    """Connectors of many small splits, made once a (catalog, units)."""
+    made = {}
+
+    def get(catalog, units):
+        if (catalog, units) not in made:
+            cls = TpchConnector if catalog == "tpch" else SsbConnector
+            made[catalog, units] = cls(sf=SF, units_per_split=units)
+        return made[catalog, units]
+
+    return get
+
+
+def _lower_group_rows(monkeypatch, conn, table, per_group=K):
+    """GROUP_ROWS such that a group of ``table``'s splits holds
+    ``per_group``; returns the table's splits."""
+    from presto_tpu.exec import leaf_route
+    from presto_tpu.spi import batch_capacity
+
+    splits = conn.splits(table)
+    cap = batch_capacity(max(sp.row_hint for sp in splits))
+    monkeypatch.setattr(leaf_route, "GROUP_ROWS", per_group * cap)
+    return splits
+
+
+def _capture_route(monkeypatch):
+    """What ``execute_leaf_route`` ended with: the route, its connector,
+    the membership bitmap (if the fragment folded a join) and the state
+    it handed to the decode."""
+    from presto_tpu.exec import leaf_route
+
+    seen = {}
+    real_decode = leaf_route.decode_leaf_state
+    real_bitmap = leaf_route._membership_bitmap
+
+    def decode(route, conn, aggs, state):
+        seen.update(route=route, conn=conn, state=state)
+        return real_decode(route, conn, aggs, state)
+
+    def bitmap(member, batches):
+        seen["bitmap"] = real_bitmap(member, batches)
+        return seen["bitmap"]
+
+    monkeypatch.setattr(leaf_route, "decode_leaf_state", decode)
+    monkeypatch.setattr(leaf_route, "_membership_bitmap", bitmap)
+    return seen
+
+
+def _fold_split_by_split(seen):
+    """The route's state as the per-split loop made it: one step a
+    split, outside any jit, folded by ``combine_states`` in split
+    order."""
+    from presto_tpu.exec.leaf_route import _apply_membership
+    from presto_tpu.ops.pallas_agg import (agg_step, combine_states,
+                                           null_violation)
+    from presto_tpu.spi import batch_capacity
+
+    route, conn = seen["route"], seen["conn"]
+    splits = conn.splits(route.scan.table)
+    cap = batch_capacity(max(sp.row_hint for sp in splits))
+    state = None
+    for split in splits:
+        b = conn.scan(split, route.src_cols, cap).rename(route.rename)
+        flag = null_violation(b)
+        if route.member is not None:
+            m = route.member
+            b, oob = _apply_membership(b, m.probe_col, m.lo, m.hi,
+                                       seen["bitmap"])
+            flag = flag | oob
+        s = agg_step(route.spec, b, pallas_ok=False)
+        s["value_overflow"] = s["value_overflow"] | flag
+        state = s if state is None else combine_states(route.spec, state, s)
+    return state
+
+
+@pytest.mark.parametrize("query", sorted(GROUPED_QUERIES))
+@pytest.mark.parametrize("grouping", sorted(GROUPINGS))
+def test_grouped_equals_per_split_bit_for_bit(split_conns, monkeypatch,
+                                              grouping, query):
+    """A table of n splits dispatched ``ceil(n / K)`` times — a full
+    group alone, a full group and a tail of 1, of K-1, two full groups —
+    ends with the very state the per-split fold gives, key for key and
+    dtype for dtype, and answers as the generic route does."""
+    nsplits, tpch_units, ssb_units = GROUPINGS[grouping]
+    catalog, table, sql = GROUPED_QUERIES[query]
+    conn = split_conns(catalog, tpch_units if catalog == "tpch"
+                       else ssb_units)
+    assert len(_lower_group_rows(monkeypatch, conn, table)) == nsplits
+    seen = _capture_route(monkeypatch)
+    s_on = Session({catalog: conn},
+                   properties={"result_cache_enabled": False})
+    before = {k: snap(k) for k in ("exec.leaf_fused_route",
+                                   "exec.leaf_route.groups",
+                                   "exec.leaf_route.group_splits")}
+    got = s_on.sql(sql)
+    assert snap("exec.leaf_fused_route") == before["exec.leaf_fused_route"] + 1
+    assert snap("exec.leaf_route.groups") - before[
+        "exec.leaf_route.groups"] == -(-nsplits // K)
+    assert snap("exec.leaf_route.group_splits") - before[
+        "exec.leaf_route.group_splits"] == nsplits
+    assert seen["route"].kind == "generic"
+    assert (seen["route"].member is not None) == (catalog == "ssb")
+    assert bool(seen["route"].spec.keys) == (query == "keyed_min_max")
+    want_state = _fold_split_by_split(seen)
+    assert sorted(seen["state"]) == sorted(want_state)
+    for key, want in want_state.items():
+        have = seen["state"][key]
+        assert have.dtype == want.dtype and have.shape == want.shape, key
+        np.testing.assert_array_equal(np.asarray(have), np.asarray(want),
+                                      err_msg=key)
+    # the narrow-off comparison last: it flips the process-global env
+    s_off = Session({catalog: conn},
+                    properties={"result_cache_enabled": False,
+                                "narrow_storage": False})
+    pd.testing.assert_frame_equal(got, s_off.sql(sql))
+
+
+def test_violation_in_a_later_batch_of_a_later_group_falls_back(
+        split_conns, monkeypatch):
+    """The carried flag: ONE row outside its declared bounds, in a
+    NON-first batch of a NON-first group, still fails the whole route —
+    None from ``execute_leaf_route``, the per-reason counter moved, the
+    generic route's rows returned."""
+    import jax.numpy as jnp
+
+    from presto_tpu.exec import leaf_route
+
+    conn = split_conns("tpch", 938)
+    splits = _lower_group_rows(monkeypatch, conn, "lineitem")
+    assert len(splits) == 2 * K
+    s = Session({"tpch": conn}, properties={"result_cache_enabled": False})
+    # a filter every row passes: the guard looks at rows the filter keeps
+    sql = ("select sum(l_extendedprice * l_discount) revenue, count(*) c "
+           "from lineitem where l_quantity <= 50")
+    want = s.sql(sql)
+
+    # the scan of split K + 1 (second group, second batch) hands over one
+    # live row whose price is far past the declared maximum
+    victim = splits[K + 1]
+    real_scan = type(conn).scan
+
+    def scan(self, split, columns, capacity=None):
+        b = real_scan(self, split, columns, capacity)
+        if split != victim or "l_extendedprice" not in b.columns:
+            return b
+        c = b["l_extendedprice"]
+        row = int(np.flatnonzero(np.asarray(b.live))[0])
+        cols = dict(b.columns)
+        cols["l_extendedprice"] = type(c)(
+            c.data.at[row].set(jnp.iinfo(c.data.dtype).max), c.valid,
+            c.dtype, c.dictionary)
+        return type(b)(cols, b.live)
+
+    monkeypatch.setattr(type(conn), "scan", scan)
+    outcomes = []
+    real_execute = leaf_route.execute_leaf_route
+
+    def execute(*args):
+        outcomes.append(real_execute(*args))
+        return outcomes[-1]
+
+    monkeypatch.setattr(leaf_route, "execute_leaf_route", execute)
+    before_reason = snap("exec.leaf_route_fallback.value_overflow")
+    before_route = snap("exec.leaf_fused_route")
+    before_groups = snap("exec.leaf_route.groups")
+    s.sql(sql)
+    assert outcomes == [None]
+    assert snap("exec.leaf_route_fallback.value_overflow") == \
+        before_reason + 1
+    assert snap("exec.leaf_fused_route") == before_route
+    # both groups ran: the flag was carried, not read between them
+    assert snap("exec.leaf_route.groups") == before_groups + 2
+    monkeypatch.undo()
+    pd.testing.assert_frame_equal(s.sql(sql), want)
+
+
+def test_groups_and_their_tail_are_cached_zero_warm_retraces(split_conns,
+                                                             monkeypatch):
+    """A first group, a carried-state group and a shorter tail are three
+    signatures of ONE cached step: the cold query traces each once, a
+    warm one (and another session's) none."""
+    from presto_tpu.cache.exec_cache import trace_delta
+
+    conn = split_conns("tpch", 1072)
+    assert len(_lower_group_rows(monkeypatch, conn, "lineitem", 3)) == 7
+    sql = TPCH["q6"].replace("24", "23")        # a spec of this test's own
+    props = {"result_cache_enabled": False}
+    with trace_delta() as td:
+        want = Session({"tpch": conn}, properties=props).sql(sql)
+    assert td.traces == 3           # (None, 3), (state, 3), (state, 1)
+    for _ in range(2):
+        with trace_delta() as td:
+            got = Session({"tpch": conn}, properties=props).sql(sql)
+        assert td.traces == 0
+        pd.testing.assert_frame_equal(got, want)
+
+
 @pytest.mark.slow
 def test_distributed_leaf_route_matches_local(conns):
     """Distributed leaf route (shard_map fused step + psum): identical

@@ -417,3 +417,52 @@ def test_a_grouping_set_fold_compiles_in_seconds(one_chip, shape):
         took += time.perf_counter() - t0
     assert took < 120, f"{shape}: {took:.1f} s"
     print(f"{shape}: compiled in {took:.1f} s")
+
+
+# the local leaf route's unit of dispatch (exec/leaf_route.py): ONE
+# program over a group of K = GROUP_ROWS / SCAN_CAP = 8 scan batches and
+# the carried state, the kernel once a batch, unrolled. The benchmark
+# reads ``leaf_agg_roofline`` off the device trace's top-level
+# ``tpu_custom_call`` events, and an op inside a ``while`` shows there as
+# the ``while``: every one of the K calls has to be the entry
+# computation's own, under the family's name
+@pytest.mark.parametrize("name", sorted(_SPECS))
+def test_leaf_group_step_compiles_unrolled(one_chip, monkeypatch, name):
+    import time
+
+    from presto_tpu.exec.leaf_route import GROUP_ROWS, _build_local_step
+    from presto_tpu.ops import pallas_mode
+
+    # the step asks pallas_mode how its kernel executes: as on the chip
+    monkeypatch.setattr(pallas_mode, "kernel_mode", lambda: "mosaic")
+    spec, dtypes = _SPECS[name]
+    per_group = GROUP_ROWS // SCAN_CAP
+    assert per_group == 8
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    # a scanned batch as the route hands it over: a validity mask a
+    # column (the live mask's twin once flattened) beside the data
+    group = tuple(
+        Batch({n: Column(sds((SCAN_CAP,), dt), sds((SCAN_CAP,), jnp.bool_),
+                         BIGINT) for n, dt in zip(spec.cols, dtypes)},
+              sds((SCAN_CAP,), jnp.bool_))
+        for _ in range(per_group))
+    step = _build_local_step(spec, None, True)
+    state = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
+                                   jax.eval_shape(step, None, group))
+    t0 = time.perf_counter()
+    text = step.lower(state, group).compile().as_text()
+    took = time.perf_counter() - t0
+    calls = re.findall(r"%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert calls == ["leaf_agg"] * per_group, calls
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    assert entry.count('custom_call_target="tpu_custom_call"') == per_group
+    assert not re.search(r"\bwhile\(", text), "a loop in the group step"
+    # measured alone: 0.5 - 1.7 s (1.2 - 3.6 s while the kernel was traced
+    # and lowered once a batch: the batch's part is a nested jit since)
+    assert took < 60.0, took
+    print(f"{name}: group of {per_group} compiled in {took:.1f} s")

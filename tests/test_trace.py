@@ -528,7 +528,7 @@ from presto_tpu.spi import batch_capacity  # noqa: E402
 #: query records)
 SERVED = {
     "q6_leaf_route": ("tpch", TPCH["q6"], (
-        "step:leaf_agg", "step:leaf_fold", "sync:leaf_state",
+        "step:leaf_agg", "sync:leaf_state",
         "decode:leaf_state")),
     "q3": ("tpch", TPCH["q3"], (
         "sync:join_build", "sync:hash_agg_state", "sync:live_count",
@@ -677,8 +677,8 @@ def test_plan_and_encode_are_recorded_and_annotated(served_conns,
 
 
 #: the jitted families the three cells' templates run (local tier)
-FAMILIES = ("leaf_agg_step", "leaf_fold_step", "filter_project_step",
-            "join_build_step", "join_filter_step", "probe_inner_step",
+FAMILIES = ("leaf_agg_step", "filter_project_step", "join_build_step",
+            "join_filter_step", "probe_inner_step",
             "probe_left_step", "_sort_update", "bypass_compact_step")
 
 
@@ -870,6 +870,65 @@ def test_dispatch_calls_counts_the_cached_steps_a_warm_query_calls(
     assert _dispatch_calls() == calls0
     df = cached.sql("select kind, calls, total_call_s from exec_cache")
     assert (df["total_call_s"] >= 0).all() and df["calls"].sum() > 0
+
+
+def _kind_calls(kind):
+    return sum(r["calls"] for r in EXEC_CACHE.stats_rows()
+               if r["kind"] == kind)
+
+
+@pytest.mark.parametrize("per_group", [1, 2, 3, 4, 8])
+def test_leaf_route_dispatches_a_group_of_splits(served_conns, monkeypatch,
+                                                 per_group):
+    """PR 46: the local leaf route's unit of dispatch is a group of
+    splits. A warm Q6 over n splits calls the route's ONE cached step
+    ``ceil(n / K)`` times — the fold is inside it — while the scan's
+    fault point, deadline check and lookup stay a split's."""
+    from presto_tpu.exec import leaf_route
+    from presto_tpu.runtime import faults, lifecycle
+
+    conn = served_conns["tpch"]
+    splits = conn.splits("lineitem")
+    n = len(splits)
+    assert n == 4
+    cap = batch_capacity(max(sp.row_hint for sp in splits))
+    monkeypatch.setattr(leaf_route, "GROUP_ROWS", per_group * cap)
+    reached = collections.Counter()
+    for mod, name in ((faults, "fault_point"),
+                      (lifecycle, "check_deadline")):
+        def counting(site, _real=getattr(mod, name), _name=name):
+            reached[_name, site] += 1
+            return _real(site)
+
+        monkeypatch.setattr(mod, name, counting)
+    s = Session({"tpch": conn}, properties={"result_cache_enabled": False})
+    s.sql(TPCH["q6"])                           # builds and compiles
+    reached.clear()
+    before, calls0 = REGISTRY.snapshot(), _dispatch_calls()
+    route0 = _kind_calls("leaf_route_step")
+    s.sql(TPCH["q6"])
+    after = REGISTRY.snapshot()
+    groups = -(-n // per_group)
+    assert _kind_calls("leaf_route_step") - route0 == groups
+    # the statement's one other step is its output projection
+    assert _dispatch_calls() - calls0 == groups + 1
+    assert {k: after.get(k, 0) - before.get(k, 0) for k in (
+        "exec.leaf_route.groups", "exec.leaf_route.group_splits",
+        "exec.scan.splits", "exec.sync.reads", "exec.traces",
+        "exec.leaf_route_fallback")} == {
+        "exec.leaf_route.groups": groups,
+        "exec.leaf_route.group_splits": n, "exec.scan.splits": n,
+        "exec.sync.reads": 3, "exec.traces": 0,
+        "exec.leaf_route_fallback": 0}
+    assert reached["fault_point", "scan"] == n
+    assert reached["check_deadline", "scan"] == n
+    spans = s.traces.latest().spans
+    names = collections.Counter(sp.name for sp in spans)
+    assert names["step:leaf_fold"] == 0
+    assert names["scan:lookup"] == n
+    assert names["step:leaf_agg"] == names["batch:release"] == groups
+    held = [sp.args["splits"] for sp in spans if sp.name == "step:leaf_agg"]
+    assert held == [min(per_group, n - i) for i in range(0, n, per_group)]
 
 
 def _gc_counts():
