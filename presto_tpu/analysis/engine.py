@@ -36,7 +36,7 @@ from typing import Iterable, Iterator, Optional
 from presto_tpu.analysis.findings import Finding
 
 #: directories never analyzed (generated/vendored/VCS state)
-SKIP_DIRS = {"__pycache__", ".git", ".pytest_cache", "notes", ".claude"}
+SKIP_DIRS = {"__pycache__", ".git", ".pytest_cache", ".claude"}
 
 _SUPPRESS_RE = re.compile(
     r"#\s*presto-lint:\s*ignore\[([A-Za-z0-9*,\s-]+)\]"

@@ -84,7 +84,7 @@ class TpchConnector:
         """Per-column PHYSICAL types for device materialization: the
         generator's exact value domains (column_stats) narrow each
         column to its smallest sufficient signed-int storage — the
-        stats-driven narrow-storage lever (ISSUE-5; notes/PERF.md §6)."""
+        stats-driven narrow-storage lever (ISSUE-5)."""
         cols = list(columns) if columns is not None else list(S.TABLES[table])
         return narrowed_schema(
             {c: S.TABLES[table][c] for c in cols},

@@ -73,9 +73,10 @@ class ScanSource:
 
 def prefetch_enabled() -> bool:
     """Default: on when the host has CPU to spare, off on a 1-core
-    host — measured on the live chip (notes/PERF.md §8): with one
-    host core the worker thread only contends with generation under
-    the GIL (sf1 --stream: 439k rows/s prefetched vs 518k serial).
+    host — measured in round 5, on another runtime, through a hand-fed
+    Q1 stream and not re-measured on the served path: with one host
+    core the worker thread only contends with generation under the
+    GIL (SF1 streamed: 439k rows/s prefetched vs 518k serial).
     ``PRESTO_TPU_PREFETCH=1/0`` overrides either way."""
     import os
 

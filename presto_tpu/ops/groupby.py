@@ -161,7 +161,7 @@ def _identity(kind: str, dtype):
 
 # Below this group count, aggregation avoids scatters entirely (measured
 # ~25x faster on TPU: scatter-add serializes, masked reductions ride the
-# VPU at memory bandwidth — notes/perf_q1_probe.py variant C).
+# VPU at memory bandwidth — round 3's Q1 probe, on another runtime).
 SMALL_GROUP_LIMIT = 32
 
 # Chunk length for the lane-split accumulators: 15-bit lanes x 2^16-row

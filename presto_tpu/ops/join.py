@@ -9,7 +9,7 @@ build compacts live rows and sorts them by key; probe is
 ``searchsorted(method="sort")``, i.e. sort-merge: the probe keys are
 sorted and merged against the build keys (binary-search probing is
 ~17x slower on TPU — its log2(B) dependent gathers serialize, while
-sorts ride the native sort unit; measured in notes/PERF.md).
+sorts ride the native sort unit; round 3, on another runtime).
 Duplicate build keys are handled by (lo, hi) range probes plus a
 prefix-sum expansion with a static output capacity and an overflow
 flag. FK->PK joins (unique build keys: most TPC-H joins) take the
@@ -193,7 +193,7 @@ def probe_expand(
 # row-index array — probe is ONE gather (no probe-side sort at all).
 # The TPU trade: one build-time scatter (build side is the small side)
 # buys gather-only probes; measured on the sorted path, probe cost was
-# dominated by the probe sort + two gathers (notes/PERF.md §5).
+# dominated by the probe sort + two gathers (round 3's anatomy).
 # ---------------------------------------------------------------------------
 
 
@@ -236,7 +236,7 @@ def probe_unique_dense(dense: DenseSide, probe_keys, probe_live) -> UniqueProbe:
 
     The gather index is int32: the table materialized, so domain <
     2^31, and int64 indices measurably slow the TPU gather (~12% on
-    the 60M-row Q3 probe — notes/perf_q3_r5.py; the gather itself is
+    the 60M-row Q3 probe — round 5, another runtime; the gather is
     the wall at ~11 ns/element regardless of table size)."""
     domain = dense.table.shape[0]
     assert domain < (1 << 31), "dense domain must fit int32 gather indices"

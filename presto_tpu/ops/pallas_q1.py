@@ -20,7 +20,7 @@ divmod done in f32 reciprocal + two correction rounds (exact for dp up
 to the reachable (2^24-1)*100 ≈ 1.678e9) and round(x/100) as
 (x*5243)>>19 (exact for x <= 43698; the reachable r*t + 50 tops out at
 12623) — both verified over their full domains
-(notes/perf_q1_r5*.py); q*t itself fits int32 because the guard also
+(round 5's probes); q*t itself fits int32 because the guard also
 pins tax <= 27 (2^24 * 127 + 12700 < 2^31). Per-group lane partials
 stay int32-exact because each output major covers <= 2^23 rows
 (255 * 2^23 < 2^31); majors recombine in int64 outside.

@@ -91,8 +91,8 @@ def salt_factor(skew: float, nworkers: int, salt_max: int) -> int:
     """S for a measured skew ratio: the hot destination held ~``skew``x
     its fair share, so spreading it over ``ceil(skew)`` partitions
     (rounded up to a power of two for stable cache keys) restores
-    balance. Clamped to the mesh size and the session's
-    ``adaptive_salt_max`` — replication cost grows linearly in S."""
+    balance. Clamped to the mesh size and ``salt_max`` (8 from
+    ``decide``) — build-row replication cost grows linearly in S."""
     s = _next_pow2(max(2, -(-int(skew) // 1)))
     return max(2, min(s, nworkers, salt_max))
 

@@ -85,7 +85,8 @@ class SloTracker:
     """Per-tenant service objectives with rolling burn rates.
 
     ``burn rate`` is the breach fraction over the rolling window
-    (0.0 = every observation met its objective, 1.0 = none did) —
+    (``window`` observations a tenant and objective kind; 0.0 = every
+    observation met its objective, 1.0 = none did) —
     the multiplier an error-budget alert would page on.
     """
 
@@ -218,6 +219,17 @@ class HealthMonitor:
     lifecycle's in-flight registry) is captured into the flight
     recorder under the ``health_breach`` trigger with its own live
     tracer — the slow query's post-mortem, not the watchdog's.
+
+    The thresholds' defaults are what a served deployment runs with
+    (``HttpFrontend`` hands over ``interval_s`` alone): ``ring``
+    snapshots kept (the depth of ``system.health``); the baseline is
+    the median p99 of the trailing ``baseline_window`` samples, of
+    which ``min_samples`` must hold latencies before the p99 detector
+    may fire (a cold start must not breach on its first slow query);
+    a breach is a p99 over ``p99_factor`` times that baseline, more
+    than ``queue_limit`` admission waiters, a tenant's burn rate over
+    ``burn_limit`` or a freshness lag over ``stale_lag_s``;
+    ``cooldown_s`` is the least time between two firings.
     """
 
     def __init__(self, session, scheduler=None, subscriptions=None,

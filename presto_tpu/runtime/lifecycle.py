@@ -436,8 +436,6 @@ class QueryManager:
                     capacity=self.session.prop("retry_budget_tokens"),
                     refill_per_s=self.session.prop(
                         "retry_budget_refill_per_s"),
-                    probe_cooldown_s=self.session.prop(
-                        "retry_breaker_cooldown_s"),
                 )
             return self._retry_budget
 

@@ -184,13 +184,6 @@ SESSION_PROPERTIES: dict[str, PropertyDef] = {
             "(presto_tpu.server) turns it on.",
         ),
         PropertyDef(
-            "batch_max_size", int, 8,
-            "Most bindings one cross-query batched dispatch may fuse "
-            "(also the bound on distinct compiled batch widths — jit "
-            "caches one signature per width).",
-            _positive,
-        ),
-        PropertyDef(
             "tenant", str, None,
             "Default tenant identity stamped on this session's "
             "QueryInfo records (system.query_history attribution). The "
@@ -367,15 +360,7 @@ SESSION_PROPERTIES: dict[str, PropertyDef] = {
             "disable after a runtime fallback — all compile-budget "
             "gated against the exec-cache ledger and logged to "
             "system.adaptive. Off = telemetry only (the pre-adaptive "
-            "baseline, also the A/B control in bench.py).",
-        ),
-        PropertyDef(
-            "adaptive_salt_max", int, 8,
-            "Upper bound on the skew-salt partition count S "
-            "(plan/adaptive.salt_factor): a hot destination splits "
-            "across at most this many salted partitions; build-row "
-            "replication cost grows linearly in S.",
-            _positive,
+            "baseline, the control of tests/test_adaptive.py).",
         ),
         PropertyDef(
             "profile_annotations", bool, False,
@@ -396,7 +381,7 @@ SESSION_PROPERTIES: dict[str, PropertyDef] = {
             "Stats-driven narrow physical column storage: scans "
             "materialize int8/int16/int32 device columns wherever "
             "connector value bounds permit (HBM-bandwidth lever, "
-            "~4x on bandwidth-bound aggregation — notes/PERF.md §6). "
+            "~4x on Q1 in round 3, on another runtime; not re-measured). "
             "Process-wide, mirrors the PRESTO_TPU_NARROW environment "
             "variable; default: on. Turn off to bisect narrowing "
             "against canonical int64 storage — results must be "
@@ -457,12 +442,6 @@ SESSION_PROPERTIES: dict[str, PropertyDef] = {
             _positive,
         ),
         PropertyDef(
-            "slo_window", int, 256,
-            "Rolling observation window (per tenant, per objective "
-            "kind) over which SLO burn rates are computed.",
-            _positive,
-        ),
-        PropertyDef(
             "health_monitor", bool, True,
             "Arm the serving-tier anomaly watchdog "
             "(runtime/health.py) when a QueryServer starts: a "
@@ -475,56 +454,6 @@ SESSION_PROPERTIES: dict[str, PropertyDef] = {
             "health_interval_s", float, 0.25,
             "Watchdog sampling cadence (seconds).",
             _positive,
-        ),
-        PropertyDef(
-            "health_ring", int, 128,
-            "Bounded ring of health snapshots retained (the "
-            "system.health table depth).",
-            _positive,
-        ),
-        PropertyDef(
-            "health_baseline_window", int, 8,
-            "Trailing samples forming the watchdog's baseline (median "
-            "p99 over this window is the regression reference).",
-            _positive,
-        ),
-        PropertyDef(
-            "health_min_samples", int, 3,
-            "Baseline samples (with observed latencies) required "
-            "before the p99 regression detector may fire — a cold "
-            "start must not breach on its first slow query.",
-            _positive,
-        ),
-        PropertyDef(
-            "health_p99_factor", float, 3.0,
-            "Breach when the current p99 exceeds this multiple of the "
-            "trailing-baseline p99.",
-            _positive,
-        ),
-        PropertyDef(
-            "health_queue_limit", int, 64,
-            "Breach when the admission queue holds more waiters than "
-            "this.",
-            _positive,
-        ),
-        PropertyDef(
-            "health_burn_limit", float, 0.5,
-            "Breach when any tenant's rolling SLO burn rate (breach "
-            "fraction) exceeds this.",
-            _positive,
-        ),
-        PropertyDef(
-            "health_stale_lag_s", float, 30.0,
-            "Breach when the worst subscription freshness lag exceeds "
-            "this many seconds.",
-            _positive,
-        ),
-        PropertyDef(
-            "health_cooldown_s", float, 5.0,
-            "Minimum seconds between health_breach firings (with the "
-            "clean-sample re-arm latch, one sustained incident fires "
-            "once, not once per sample).",
-            _non_negative,
         ),
         PropertyDef(
             "retry_budget_tokens", float, 16.0,
@@ -541,14 +470,6 @@ SESSION_PROPERTIES: dict[str, PropertyDef] = {
             "independent-failure rate; correlated failures outpace it "
             "and trip the breaker. 0 disables refill (tokens only "
             "return via the half-open probe's success).",
-            _non_negative,
-        ),
-        PropertyDef(
-            "retry_breaker_cooldown_s", float, 1.0,
-            "Seconds an OPEN retry circuit breaker waits before going "
-            "half-open and granting exactly one probe retry; the "
-            "probe's success re-closes the breaker and refills the "
-            "bucket.",
             _non_negative,
         ),
         PropertyDef(

@@ -1025,7 +1025,6 @@ class Session:
         gate = self.query_manager.batch_gate
         wait_s = (self.prop("query_max_run_time")
                   or self.prop("admission_queue_timeout_s"))
-        max_batch = int(self.prop("batch_max_size"))
         member = gate.enqueue(base_fp, bound)
         # lane provenance: the leader's fused dispatch stamps one
         # batch:lane span per member, carrying this origin — linking
@@ -1052,8 +1051,7 @@ class Session:
             with trace.annotation(
                     "batch:gate_wait", info.trace_token,
                     on=bool(self.prop("profile_annotations"))):
-                role, payload = gate.lead_or_wait(base_fp, member, remaining,
-                                                  max_batch=max_batch)
+                role, payload = gate.lead_or_wait(base_fp, member, remaining)
             if role != "retry":
                 # the batch-gate wait, visible in the trace between
                 # submit and dispatch (the serving-tier span chain)
@@ -1189,7 +1187,6 @@ class Session:
             return self.adaptive.decide(
                 plan, hints, self.catalog, fingerprint=fp,
                 nworkers=getattr(executor, "nworkers", 1),
-                salt_max=int(self.prop("adaptive_salt_max")),
                 for_render=for_render,
                 recording=bool(self.prop("flight_record_successes")),
             )

@@ -17,8 +17,7 @@ substrate:
   queries stack their param bindings into ONE vmapped device dispatch.
 - :mod:`presto_tpu.server.frontend` — the HTTP/JSON surface
   (``/v1/statement``, ``/v1/prepared``, ``/metrics``) plus the
-  in-process ``ServerClient`` tests and the bench harness drive
-  without sockets.
+  in-process ``ServerClient`` tests drive without sockets.
 
 Imports are lazy (PEP 562): the runtime imports
 ``presto_tpu.server.batcher`` from ``QueryManager`` without dragging

@@ -32,7 +32,7 @@ Two pieces:
   scan once, dispatch once, split per binding.
 - :class:`TemplateBatchGate` — the meeting point: concurrent bindings
   enqueue per template; whoever acquires the template's executor lock
-  drains the whole queue (bounded by ``batch_max_size``, so distinct
+  drains the whole queue (bounded by ``max_batch``, 8, so distinct
   compiled batch widths stay bounded too) and leads one batched
   dispatch, serving every drained member. Unserved members re-contend,
   so failure semantics mirror the coalescer's.

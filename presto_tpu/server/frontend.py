@@ -8,9 +8,9 @@ session's prepared-statement surface and a ``/metrics`` scrape of the
 existing OpenMetrics exposition. Two surfaces over ONE core:
 
 - :class:`QueryServer` — the in-process serving core (tenant identity,
-  fairness slots, submit/poll bookkeeping, graceful drain). Tests and
-  the bench harness drive it directly as the ``ServerClient`` — no
-  sockets, same code path.
+  fairness slots, submit/poll bookkeeping, graceful drain). Tests
+  drive it directly as the ``ServerClient`` — no sockets, same code
+  path.
 - :class:`HttpFrontend` — a stdlib ``ThreadingHTTPServer`` speaking
   HTTP/JSON on top (no new dependencies). Tenant identity rides the
   ``X-Presto-Tenant`` header, one tenant per connection/request.
@@ -104,9 +104,9 @@ class QueryServer:
 
     ``connectors`` builds a fresh session (with ``batched_dispatch``
     ON — the serving layer exists to exploit load shape); passing an
-    explicit ``session`` serves through it unchanged. Tests and the
-    bench drive this class directly — the HTTP front-end adds only
-    transport."""
+    explicit ``session`` serves through it unchanged. Tests drive
+    this class directly — the HTTP front-end (the benchmark's client
+    speaks to it) adds only transport."""
 
     def __init__(self, connectors: Optional[Mapping[str, object]] = None,
                  *, session=None, tenants=None,
@@ -177,8 +177,9 @@ class QueryServer:
         self._approx_session = None
         self._approx_lock = threading.Lock()
         #: per-tenant SLO burn-rate tracking (runtime/health.py):
-        #: defaults come from the slo_* session properties, per-tenant
-        #: objectives from TenantSpec.slo_latency_s/slo_freshness_s;
+        #: defaults come from the slo_*_objective_s session properties,
+        #: per-tenant objectives from
+        #: TenantSpec.slo_latency_s/slo_freshness_s;
         #: run_plan observes latency, subscription delivery observes
         #: freshness — both through ``session.slo``
         session.slo = SloTracker(
@@ -186,7 +187,6 @@ class QueryServer:
                 session.prop("slo_latency_objective_s")),
             freshness_objective_s=float(
                 session.prop("slo_freshness_objective_s")),
-            window=int(session.prop("slo_window")),
             overrides=self.scheduler.slo_overrides())
         #: the anomaly watchdog (runtime/health.py): samples serving
         #: vitals on its own thread, and on a breach arms the flight
@@ -199,15 +199,6 @@ class QueryServer:
                 session, scheduler=self.scheduler,
                 subscriptions=self.subscriptions,
                 interval_s=float(session.prop("health_interval_s")),
-                ring=int(session.prop("health_ring")),
-                baseline_window=int(
-                    session.prop("health_baseline_window")),
-                min_samples=int(session.prop("health_min_samples")),
-                p99_factor=float(session.prop("health_p99_factor")),
-                queue_limit=int(session.prop("health_queue_limit")),
-                burn_limit=float(session.prop("health_burn_limit")),
-                stale_lag_s=float(session.prop("health_stale_lag_s")),
-                cooldown_s=float(session.prop("health_cooldown_s")),
                 on_breach=self.overload.on_breach)
             self.health.start()
         #: the registry behind system.health (connectors/system.py)
@@ -760,8 +751,8 @@ class QueryServer:
         }
 
 
-#: the no-sockets client surface tests and the bench harness use; it
-#: IS the server core — one name per role, one implementation
+#: the no-sockets client surface tests use; it IS the server core —
+#: one name per role, one implementation
 ServerClient = QueryServer
 
 

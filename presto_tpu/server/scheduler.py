@@ -21,7 +21,7 @@ Mechanics — classic weighted fair queuing over a condition variable:
   and the lowest stamp among quota-eligible waiters runs next — a
   flooding tenant's vtime races ahead, so a lighter tenant's next
   query overtakes the flood's backlog (the p99-protection property
-  the sustained-load bench measures).
+  ``tests/test_server.py`` holds).
 - **Quotas** are hard gates: a tenant at ``max_concurrent`` running
   queries, or holding more than ``max_bytes`` of live memory-pool
   reservations (tenant-tagged in ``runtime/memory.py``), is skipped

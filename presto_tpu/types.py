@@ -76,8 +76,8 @@ class DataType:
     ``phys`` decouples the *physical* device dtype from the logical
     kind: a connector whose stats bound a column's value domain narrows
     its storage (BIGINT carried as int16, DECIMAL cents as int32, ...)
-    — the HBM-bandwidth lever the bench measured at ~4x on Q1
-    (notes/PERF.md §6-§8). The empty string means the canonical
+    — the HBM-bandwidth lever (~4x on Q1 in round 3, on another
+    runtime; not re-measured). The empty string means the canonical
     mapping below. Narrowed types ride Column/Batch pytree aux, so jit
     signatures key on the physical layout; the LOGICAL identity is the
     canonical form — ``common_super_type`` and every coercion resolve

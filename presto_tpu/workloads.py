@@ -3,7 +3,7 @@
 Reference parity: ``presto-benchmark``'s hand-built operator pipelines
 (``HandTpchQuery1`` / ``HandTpchQuery6`` [SURVEY §6]) — the same role:
 benchmark the operator/kernel layer without the SQL frontend. Shared by
-tests, ``bench.py`` and ``__graft_entry__.py``.
+tests, the engine's Q1 routes and ``__graft_entry__.py``.
 """
 
 from __future__ import annotations

@@ -1,5 +1,6 @@
 #!/usr/bin/env bash
-# Static-analysis gate (tier-1 gate 12): the engine-invariant linter
+# Static-analysis gate (run by hand; tests/test_analysis.py holds the
+# same rules and the clean repo in tier 1): the engine-invariant linter
 # (presto_tpu/analysis/) in clean mode, PLUS a seeded-violation
 # self-test proving the gate can actually fail — a lint gate that
 # can't detect its own fixture violations is green paint.

@@ -11,9 +11,9 @@ returns to zero (no reservation leaks); nothing hangs unboundedly.
 Determinism: each round derives its whole schedule (query, session
 properties, fault specs) from one integer seed via a private
 ``random.Random``, and the ``FaultInjector`` draws probability faults
-from its own seeded stream — same seed, same run. The tier-1 smoke
-gate (scripts/tier1.sh) imports :func:`run_chaos_round` and replays a
-fixed seed range; the 200-iteration sweep is slow-marked.
+from its own seeded stream — same seed, same run. The tier-1 run
+replays a fixed seed range through :func:`run_chaos_round`, alone and
+beside two loaded sessions; the 200-iteration sweep is slow-marked.
 """
 
 import random
@@ -391,11 +391,76 @@ def test_oom_surfaces_in_query_history_table(conn):
 
 
 def test_chaos_smoke_seeded(conn, oracle):
-    """A fixed-seed slice of the chaos space on every tier-1 run (the
-    same seeds 0..9 scripts/tier1.sh replays)."""
+    """A fixed-seed slice of the chaos space on every tier-1 run:
+    seeds 0..9, each round correct or typed with its own pool drained,
+    and nothing left reserved in the process-wide pool after them."""
+    from presto_tpu.runtime.memory import global_pool
+
     outcomes = [run_chaos_round(conn, oracle, seed) for seed in range(10)]
     assert len(outcomes) == 10
     assert any(o.startswith("ok:") for o in outcomes)
+    assert global_pool().reserved_bytes == 0
+
+
+def test_chaos_rounds_beside_concurrent_sessions(conn, oracle):
+    """Two sessions on ONE shared pool replay the chaos statements while
+    seeded chaos rounds run beside them. The injector is process-wide,
+    so a round's faults land in the load sessions' dispatches too: a
+    load query then fails TYPED or answers like the oracle, never
+    otherwise; no thread hangs; the shared pool and the process-wide
+    pool end with nothing reserved."""
+    from presto_tpu.runtime.memory import global_pool
+
+    pool = MemoryPool(device_budget_bytes(), name="chaos-load")
+    sessions = [
+        Session({"tpch": conn}, memory_pool=pool,
+                properties={"result_cache_enabled": False,
+                            "admission_queue_timeout_s": 120.0})
+        for _ in range(2)
+    ]
+    for q in CHAOS_QUERIES.values():  # compile outside the faulted window
+        sessions[0].sql(q)
+    stop = threading.Event()
+    ok, typed, broken, rounds = [0, 0], [0, 0], [], []
+
+    def load(wid):
+        rng = random.Random(7100 + wid)
+        while not stop.is_set():
+            qname = rng.choice(sorted(CHAOS_QUERIES))
+            try:
+                df = sessions[wid].sql(CHAOS_QUERIES[qname])
+            except PrestoError:
+                typed[wid] += 1
+            except Exception as e:  # noqa: BLE001 — the contract under test
+                broken.append(f"load{wid}: {type(e).__name__}: {e}")
+                return
+            else:
+                if not frames_equal(df, oracle[qname]):
+                    broken.append(f"load{wid}: WRONG ANSWER on {qname}")
+                    return
+                ok[wid] += 1
+
+    def chaos():
+        try:
+            for seed in range(100, 110):
+                rounds.append(run_chaos_round(conn, oracle, seed))
+        except Exception as e:  # noqa: BLE001 — surfaced to the assert
+            broken.append(f"chaos: {type(e).__name__}: {e}")
+        finally:
+            stop.set()
+
+    threads = [threading.Thread(target=load, args=(i,), daemon=True)
+               for i in range(2)]
+    threads.append(threading.Thread(target=chaos, daemon=True))
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=HANG_BUDGET_S)
+        assert not t.is_alive(), "a worker hung under the chaos schedule"
+    assert not broken, broken
+    assert len(rounds) == 10 and sum(ok) > 0, (rounds, ok, typed)
+    assert pool.reserved_bytes == 0 and pool.queued_count == 0
+    assert global_pool().reserved_bytes == 0
 
 
 @pytest.mark.slow

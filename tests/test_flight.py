@@ -320,8 +320,8 @@ def test_trace_delta_window_semantics(conn):
 
 
 # ---------------------------------------------------------------------------
-# exchange-skew telemetry (virtual 8-device mesh; slow tier like the
-# other distributed suites)
+# exchange-skew telemetry (virtual 8-device mesh; the zipfian case runs
+# in seconds and is in tier 1, the post-mortem case in the slow tier)
 # ---------------------------------------------------------------------------
 
 
@@ -337,7 +337,6 @@ def _skew_frame(n_rows: int, zipf: bool, rng) -> pd.DataFrame:
                          "v": rng.integers(0, 100, n_rows)})
 
 
-@pytest.mark.slow
 def test_zipfian_repartition_skew_visible_everywhere(conn, rng):
     """Skewed keys -> EXPLAIN ANALYZE skew > 2x + exchange.skew
     histogram + plan_stats history + EXPLAIN (TYPE DISTRIBUTED) header;

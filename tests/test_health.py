@@ -65,16 +65,15 @@ def counter(name: str) -> float:
 
 def test_metric_help_covers_every_literal_family():
     """Every literal ``REGISTRY.counter/timer/histogram("name")`` call
-    site in the engine (and the bench harness) must have a METRIC_HELP
-    entry — scrape consumers read the HELP line, and a missing one
-    means a family was added without documenting what it measures.
+    site in the engine must have a METRIC_HELP entry — scrape
+    consumers read the HELP line, and a missing one means a family
+    was added without documenting what it measures.
     f-string families (per-tenant/per-device suffixes) are exempt: the
     pattern only matches plain string literals."""
     root = pathlib.Path(__file__).resolve().parent.parent
     pat = re.compile(
         r'REGISTRY\.(?:counter|timer|histogram)\(\s*"([^"{]+)"')
     files = sorted((root / "presto_tpu").rglob("*.py"))
-    files.append(root / "bench.py")
     fired = set()
     for path in files:
         fired.update(pat.findall(path.read_text()))
@@ -148,6 +147,58 @@ def test_trace_context_precedence():
     # a 32-hex X-Presto-Trace token doubles as the trace id
     ctx = _trace_context(token=tid.upper())
     assert ctx["trace_id"] == tid
+
+
+def test_http_traceparent_is_echoed_and_links_one_trace():
+    """A statement POSTed with a W3C ``traceparent`` gets the same
+    trace-id back on the 201 and on every poll, and its recorder holds
+    ONE linked trace under that id: frontend submit, admission, the
+    batch-gate wait, the engine's own spans and the frontend's poll."""
+    import json
+    import urllib.request
+
+    from presto_tpu.server.frontend import HttpFrontend
+
+    tid = "4bf92f3577b34da6a3ce929d0e0e4736"
+    server = QueryServer({"tpch": CONN},
+                         tenants=[TenantSpec("web", weight=2.0)],
+                         properties={"result_cache_enabled": False,
+                                     "health_monitor": False})
+    http = HttpFrontend(server, port=0).start_background()
+    base = f"http://127.0.0.1:{http.port}"
+    try:
+        resp = urllib.request.urlopen(urllib.request.Request(
+            base + "/v1/statement",
+            data=(b"select l_orderkey, l_linenumber, l_quantity from"
+                  b" lineitem where l_extendedprice < 1500.0"
+                  b" order by l_orderkey, l_linenumber limit 10"),
+            headers={"X-Presto-Tenant": "web",
+                     "traceparent": f"00-{tid}-00f067aa0ba902b7-01"},
+            method="POST"), timeout=60)
+        sub = json.loads(resp.read())
+        assert resp.headers.get("traceparent", "").split("-")[1] == tid
+        assert resp.headers.get("X-Presto-Trace") == tid
+        deadline = time.monotonic() + 120
+        while time.monotonic() < deadline:
+            presp = urllib.request.urlopen(base + sub["nextUri"],
+                                           timeout=60)
+            page = json.loads(presp.read())
+            if page["state"] in ("FINISHED", "FAILED"):
+                break
+            time.sleep(0.02)
+        assert page["state"] == "FINISHED", page
+        assert presp.headers.get("traceparent", "").split("-")[1] == tid
+        engine_qid = server._queries[sub["id"]]["trace"]["query_id"]
+        tracer = server.session.traces.for_query(engine_qid)
+        assert tracer is not None and tracer.trace_token == tid
+        names = [sp.name for sp in tracer.spans]
+        for needed in ("frontend:submit", "admission", "batch:gate_wait",
+                       "frontend:poll"):
+            assert needed in names, (needed, names)
+        assert any(n.startswith(("step:", "fragment:")) for n in names)
+    finally:
+        http.shutdown()
+        server.shutdown(drain_timeout_s=10)
 
 
 # ---------------------------------------------------------------------------

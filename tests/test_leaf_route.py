@@ -355,15 +355,17 @@ def test_explain_renders_strategies(conns):
     assert "agg_strategy=partial" in s.explain(q)
 
 
-def test_fragment_is_cached_zero_warm_retraces(conns):
+@pytest.mark.parametrize("name", ["q1", "q6", "ssb_q1_1"])
+def test_fragment_is_cached_zero_warm_retraces(conns, name):
     """Warm repeats of a routed query re-trace nothing (the fused step
-    lives in the content-keyed executable cache)."""
+    lives in the content-keyed executable cache): Q1's own route, the
+    keyless leaf, and a leaf with a membership join folded in."""
     from presto_tpu.cache.exec_cache import trace_delta
 
     s = make_session(conns)
-    s.sql(TPCH["q6"])
+    s.sql(ROUTED_QUERIES[name])
     with trace_delta() as td:
-        s.sql(TPCH["q6"])
+        s.sql(ROUTED_QUERIES[name])
     assert td.traces == 0
 
 

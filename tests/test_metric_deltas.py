@@ -262,10 +262,13 @@ def _parse_exposition(text: str) -> dict:
 def test_openmetrics_exposition_parses_and_has_known_counters(conn):
     s = Session({"tpch": conn})
     s.sql("select count(*) c from nation")
+    s.execute("select count(*) c from region")  # tracked: plan stats
     text = s.export_metrics()
     samples = _parse_exposition(text)
     assert samples["presto_tpu_query_started_total"] >= 1
     assert samples["presto_tpu_query_completed_total"] >= 1
+    assert samples["presto_tpu_exec_traces_total"] >= 1
+    assert samples["presto_tpu_plan_stats_recorded_total"] >= 1
     # histogram families expose quantiles + count/sum
     assert 'presto_tpu_query_execution_s{quantile="0.5"}' in samples
     assert samples["presto_tpu_query_execution_s_count"] >= 1

@@ -31,6 +31,7 @@ from presto_tpu.runtime.errors import (
     TransientFailure,
     UserError,
 )
+from presto_tpu.runtime.lifecycle import QueryManager
 from presto_tpu.runtime.metrics import REGISTRY
 from presto_tpu.runtime.overload import (
     CancelScope,
@@ -269,18 +270,37 @@ def test_shed_leaves_no_ghost_state():
 # ---------------------------------------------------------------------------
 
 
-def test_cancel_queued_query_is_typed_and_releases_nothing():
-    """DELETE of a QUEUED query: observed at the slot boundary, typed
-    QUERY_CANCELLED on the poll page, pool untouched."""
+@pytest.mark.parametrize("state", ["queued", "running"])
+def test_cancel_is_typed_and_leaves_nothing_reserved(state, monkeypatch):
+    """DELETE of a QUEUED query is observed at the slot boundary, of a
+    RUNNING one at its next cancel checkpoint: either way the poll page
+    reads typed QUERY_CANCELLED and the pool holds nothing of it."""
     srv = QueryServer({"tpch": CONN}, total_slots=1, properties=QUIET)
     try:
-        hold = srv.scheduler.acquire("default")  # pin the only slot
-        try:
+        if state == "queued":
+            hold = srv.scheduler.acquire("default")  # pin the only slot
+            try:
+                qid = srv.submit(JOIN_SQL)
+                out = srv.cancel(qid, reason="test cancel")
+                assert out["cancelled"] is True
+            finally:
+                srv.scheduler.release(hold)
+        else:
+            entered = threading.Event()
+            orig_ladder = QueryManager._run_with_oom_ladder
+
+            def held_ladder(self, executor, plan, info, recorder, ctx):
+                entered.set()
+                time.sleep(0.25)  # the cancel lands while it runs
+                return orig_ladder(self, executor, plan, info, recorder,
+                                   ctx)
+
+            monkeypatch.setattr(QueryManager, "_run_with_oom_ladder",
+                                held_ladder)
             qid = srv.submit(JOIN_SQL)
+            assert entered.wait(120), "query never started"
             out = srv.cancel(qid, reason="test cancel")
             assert out["cancelled"] is True
-        finally:
-            srv.scheduler.release(hold)
         assert srv._queries[qid]["done"].wait(120)
         page = srv.poll(qid)
         assert page["state"] == "FAILED"
@@ -292,6 +312,71 @@ def test_cancel_queued_query_is_typed_and_releases_nothing():
             srv.cancel("nope")
     finally:
         srv.shutdown()
+
+
+def test_shedding_keeps_goodput_under_a_submit_storm(monkeypatch):
+    """One slot, every query 0.25 s, a 1 s deadline from submit, eight
+    submissions at once: the deadline can drain about three. The
+    shedding server's queue ceiling admits the prefix that can meet its
+    deadline and refuses the rest typed and retryable; the server
+    without it queues everyone and the tail dies at its deadline. The
+    admitted prefixes behave alike, so goodput with shedding is at
+    least goodput without — a fact of the structure, not of timing."""
+    orig_ladder = QueryManager._run_with_oom_ladder
+
+    def slow_ladder(self, executor, plan, info, recorder, ctx):
+        time.sleep(0.25)
+        return orig_ladder(self, executor, plan, info, recorder, ctx)
+
+    props = dict(QUIET, batched_dispatch=False)
+    sql = "select count(*) c from nation"
+    warm = QueryServer({"tpch": CONN}, properties=props)
+    warm.execute(sql)
+    warm.shutdown()
+    monkeypatch.setattr(QueryManager, "_run_with_oom_ladder", slow_ladder)
+
+    def storm(shed_on):
+        srv = QueryServer({"tpch": CONN}, total_slots=1,
+                          shed_queue_limit=(3 if shed_on else None),
+                          properties=props)
+        qids, shed = [], 0
+        hold = srv.scheduler.acquire("default")  # the queue builds
+        try:
+            for _ in range(8):
+                try:
+                    qids.append(srv.submit(sql, deadline_s=1.0))
+                except ServerOverloaded as e:
+                    assert e.retryable and e.retry_after_s > 0
+                    shed += 1
+                else:
+                    # admitted workers enqueue asynchronously: let each
+                    # reach the fair queue so the ceiling sees its depth
+                    t0 = time.monotonic()
+                    while (srv.scheduler.queue_depth() < len(qids)
+                           and time.monotonic() - t0 < 10.0):
+                        time.sleep(0.002)
+        finally:
+            srv.scheduler.release(hold)
+        try:
+            good = 0
+            for qid in qids:
+                assert srv._queries[qid]["done"].wait(120), "storm hang"
+                page = srv.poll(qid)
+                if page["state"] == "FINISHED":
+                    good += 1
+                else:
+                    assert page["errorCode"] in (
+                        "EXCEEDED_TIME_LIMIT", "QUERY_CANCELLED",
+                        "SERVER_OVERLOADED"), page
+            assert srv.session.pool().reserved_bytes == 0
+            return good, shed
+        finally:
+            srv.shutdown()
+
+    good_off, shed_off = storm(shed_on=False)
+    good_on, shed_on = storm(shed_on=True)
+    assert shed_off == 0 and shed_on >= 1, (shed_off, shed_on)
+    assert good_on >= good_off, (good_on, good_off)
 
 
 def test_session_cancel_unknown_query_returns_false():

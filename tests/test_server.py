@@ -150,6 +150,7 @@ def test_concurrency_quota_blocks_and_counts():
     assert counter("tenant.over_quota_blocked") == blocked0 + 1
     snap = sched.snapshot()[0]
     assert snap["over_quota_blocked"] == 1
+    assert snap["peak_running"] == 1  # bounded at its cap
     assert snap["queue_timeouts"] == 1
     sched.release(tok)
     sched.release(sched.acquire("t", timeout_s=5))
@@ -452,11 +453,12 @@ def test_http_round_trip():
             headers={"X-Presto-Tenant": "web"},
             method="POST"), timeout=30).read())
         assert got["columns"] == ["c"]
-        # metrics scrape parses (gate-7 exposition contract: # EOF last)
+        # metrics scrape parses (the exposition contract: # EOF last)
         mtext = urllib.request.urlopen(f"{base}/metrics",
                                        timeout=30).read().decode()
         assert mtext.splitlines()[-1] == "# EOF"
         assert "presto_tpu_query_completed_total" in mtext
+        assert "presto_tpu_tenant_admitted_total" in mtext
         # tenant snapshot endpoint
         tens = json.loads(urllib.request.urlopen(
             f"{base}/v1/tenants", timeout=30).read())

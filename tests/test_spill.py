@@ -176,11 +176,15 @@ def test_four_x_over_budget_runs_with_zero_rungs(conn, resident):
     before = REGISTRY.snapshot()
     s = Session({"tpch": conn},
                 properties={"join_build_budget_bytes": 11 << 10})
+    assert "spill=hybrid(" in s.explain(Q3ISH)  # the decision is rendered
     got = s.sql(Q3ISH)
     assert got.equals(resident[Q3ISH])
     assert _delta(before, "spill.planned_hybrid") >= 1
     assert _delta(before, "query.oom_degraded") == 0
+    assert _delta(before, "query.backend_oom") == 0
     assert _delta(before, "spill.partitions_streamed") >= 1
+    assert any(e.get("kind") == "planned_hybrid"
+               for e in s.query_history[-1].rung_history)
     assert s.pool().reserved_bytes == 0
     assert global_host_spill_budget().reserved_bytes == 0
 

@@ -25,6 +25,7 @@ import numpy as np
 import pandas as pd
 import pytest
 
+from presto_tpu.cache.exec_cache import trace_delta
 from presto_tpu.connectors.memory import MemoryConnector
 from presto_tpu.runtime.errors import UserError
 from presto_tpu.runtime.lifecycle import QueryManager
@@ -71,8 +72,10 @@ def test_initial_then_epoch_refresh_is_fresh():
         assert int(first.df["c"][0]) == 10
         assert first.epochs == {"ticks": 1}
 
+        fired0 = counter("subscription.fired")
         r = w.append("ticks", ticks(5, lo=10))
         got = sub.wait_for_epoch("ticks", r.epoch, timeout_s=WAIT_S)
+        assert counter("subscription.fired") > fired0
         # the freshness contract: a result delivered for epoch>=2 must
         # include the epoch-2 rows — never a stale pre-append frame
         assert got.trigger == "epoch"
@@ -211,17 +214,23 @@ def test_same_template_subscriptions_batch_through_gate(monkeypatch):
         monkeypatch.setattr(QueryManager, "run_plan", gated)
         d0 = counter("batch.dispatched")
         q0 = counter("batch.queries")
-        r = w.append("ticks", ticks(50, lo=50))
-        assert first.wait(WAIT_S)
-        deadline = time.monotonic() + WAIT_S
-        while time.monotonic() < deadline:
-            depth = sum(gate.queue_depth(fp) for fp in list(gate._templates))
-            if depth >= len(subs) - 1:
-                break
-            time.sleep(0.01)
-        release.set()
-        got = [sub.wait_for_epoch("ticks", r.epoch, timeout_s=WAIT_S)
-               for sub in subs]
+        # the epoch bump invalidates results, never executables: the
+        # refreshes it fires re-trace nothing (the columns' value
+        # bounds, which a step's signature carries, do not move here)
+        with trace_delta() as td:
+            r = w.append("ticks", ticks(50, lo=50))
+            assert first.wait(WAIT_S)
+            deadline = time.monotonic() + WAIT_S
+            while time.monotonic() < deadline:
+                depth = sum(gate.queue_depth(fp)
+                            for fp in list(gate._templates))
+                if depth >= len(subs) - 1:
+                    break
+                time.sleep(0.01)
+            release.set()
+            got = [sub.wait_for_epoch("ticks", r.epoch, timeout_s=WAIT_S)
+                   for sub in subs]
+        assert td.traces == 0, "a warm refresh re-traced"
         dd = counter("batch.dispatched") - d0
         qd = counter("batch.queries") - q0
         assert dd >= 1, "subscription refreshes never fused at the gate"

@@ -87,6 +87,7 @@ ELIGIBLE_POSITIONS = [
 def test_eligible_position_zero_warm_retraces(name, fmt, lits):
     s = make_session()
     dfs = {lits[0]: s.sql(fmt.format(lits[0]))}  # cold: trace once
+    hits0 = counter("prepare.template_hit")
     # warm bindings all inside ONE trace_delta window (exec.traces is
     # process-global — keep the off-session's runs OUTSIDE the window,
     # or their traces would fake a failure: the PR 9 footgun the
@@ -97,6 +98,7 @@ def test_eligible_position_zero_warm_retraces(name, fmt, lits):
             assert s.query_history[-1].template_hit
     assert td.traces == 0, \
         f"{name}: warm same-template bindings re-traced"
+    assert counter("prepare.template_hit") == hits0 + len(lits) - 1
     off = make_session(plan_templates=False)
     for v, df in dfs.items():
         pd.testing.assert_frame_equal(df, off.sql(fmt.format(v)))

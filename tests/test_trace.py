@@ -456,12 +456,11 @@ def test_trace_overhead_under_5pct_warm_q1(conn):
 
 
 # ---------------------------------------------------------------------------
-# distributed acceptance (virtual mesh; slow tier like the other
-# distributed suites)
+# distributed acceptance (virtual 8-device mesh, SF 0.005: seconds, so
+# it runs in tier 1)
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.slow
 def test_distributed_q3_trace_acceptance(tmp_path):
     from presto_tpu.connectors.tpch.queries import QUERIES
     from presto_tpu.parallel.mesh import make_mesh
