@@ -74,10 +74,10 @@ def test_each_exchange_entry_has_a_file_and_a_reader(name):
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == name]
     assert (entry["layer"], entry["unit"]) == (spec["layer"], spec["unit"])
     # an exchange exists only across chips: the mesh cell, and no cell
-    # on one chip
-    chips = {w["name"]: w["chips"] for w in BENCH["workloads"]}
+    # on one chip (the rule ``across_chips``)
     assert CELL in entry["workloads"]
-    assert {chips[c] for c in entry["workloads"]} == {4}
+    assert set(entry["workloads"]) <= set(R.four_chip(BENCH))
+    assert R.across_chips(BENCH) == []
     assert entry["moves"] == R.family(BENCH, CELL)
 
 

@@ -1,10 +1,11 @@
 """The configuration ``tpch_sf10_mesh4`` and its one cell
 ``tpch_sf10_mesh4_1s`` (PR 44), added as data: the configuration is
 ``tpch_sf1_mesh4``'s in all but scale and residency, the cell is listed
-wherever ``tpch_sf1_mesh4_1s`` is, the four entries that came with it
-load, name readers that exist and read hand-computed values on a
-made-up context, and the cell's CPU rehearsal ends ``correct`` and
-prints every entry it lists that is not the device's alone."""
+at least wherever its pair in scale ``tpch_sf1_mesh4_1s`` is and beyond
+it by entries of the scan layer alone (``bench_rules.scaled_pairs``:
+its four entries came with PR 48, ``test_bench_pr48_entries.py``), and
+the cell's CPU rehearsal ends ``correct`` and prints every entry it
+lists that is not the device's alone."""
 
 import json
 import os
@@ -74,12 +75,11 @@ def test_the_cell_is_listed_wherever_tpch_sf1_mesh4_1s_is():
     assert cells[MESH]["traffic"] == cells[LIKE]["traffic"]
     assert len(cells[MESH]["why"]) <= 200
     assert R.family(BENCH, MESH) == R.family(BENCH, LIKE) == "query_geomean_ms"
-    listed = {m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]
-              if MESH in m.get("workloads", ())}
-    like = {m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]
-            if LIKE in m.get("workloads", ())}
-    # every entry the SF1 mesh cell lists, and no other
-    assert listed == like
+    listed, like = R.listed_by(BENCH, MESH), R.listed_by(BENCH, LIKE)
+    # every entry the SF1 mesh cell lists, and beyond those its
+    # residency alone: the rule of a scaled pair
+    assert R.PAIRS[MESH] == LIKE and R.scaled_pairs(BENCH) == []
+    assert set(like) <= set(listed)
     assert not [n for n in listed if n.endswith((".host", ".throughput"))]
     spec = C.load_cell(MESH)
     assert spec["chips"] == spec["config"]["chips"] == 4
@@ -91,40 +91,37 @@ def test_the_cell_is_listed_wherever_tpch_sf1_mesh4_1s_is():
         m["name"] for m in spec["per_layer"]}
 
 
-def test_the_rules_hold_and_the_pinned_entries_are_still_last():
+def test_the_rules_hold_and_the_pinned_entries_are_still_in_order():
     assert R.broken(BENCH) == {}
-    names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[-3:] == ["resident_mb", "resident_hits", "resident_bypassed"]
-    # ... and list the cell they were added with, alone
-    assert not [m for m in BENCH["per_layer"][-3:] if MESH in m["workloads"]]
-    four = [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
-    assert four[:2] == [LIKE, MESH]
+    # PR 41's three, wherever they stand (``kept_in_order``), list the
+    # cell they were added with and not this one
+    assert R.kept_in_order(BENCH) == [] and set(R.IN_ORDER) <= set(R.KEPT)
+    assert not [m for m in BENCH["per_layer"]
+                if m["name"] in R.IN_ORDER and MESH in m["workloads"]]
     assert "tpch_sf10_q3_1s" not in R.cells(BENCH)     # refused (PR 43)
-    # what only the mesh has is listed by the mesh cells and no other
-    (ici,) = [m for m in BENCH["per_layer"] if m["name"] == "exchange_ici_pct"]
+    # what only the mesh has is listed by the four-chip cells and no
+    # other, in the file's order, whichever those are (``across_chips``)
+    four = R.four_chip(BENCH)
+    assert {LIKE, MESH} <= set(four) and {LIKE, MESH} <= set(R.FOUR_CHIP)
+    assert R.across_chips(BENCH) == [] == R.four_chip_cells(BENCH)
+    (ici,) = R.entries_of(BENCH, "exchange_ici_pct")
     assert ici["workloads"] == four
 
 
 def test_the_file_is_the_parents_with_the_cell_added_by_the_rules_helper():
     """Taking the cell and its configuration away leaves a file that
-    ``with_cell`` turns back into this one: nothing else of an entry
-    moved."""
-    before = {
-        **BENCH,
-        "configs": [c for c in BENCH["configs"]
-                    if c["name"] != "tpch_sf10_mesh4"],
-        "workloads": [w for w in BENCH["workloads"] if w["name"] != MESH],
-        "end_to_end": [
-            dict(m, workloads=[c for c in m["workloads"] if c != MESH])
-            if "workloads" in m else m for m in BENCH["end_to_end"]],
-        "per_layer": [
-            dict(m, workloads=[c for c in m["workloads"] if c != MESH])
-            for m in BENCH["per_layer"]]}
+    breaks no rule and that ``with_cell`` turns back into this one,
+    entry by entry as sets: nothing else of an entry moved
+    (``bench_rules.round_trip``, which ``test_bench_rules.py`` asks of
+    every cell)."""
+    before = R.without_cell(BENCH, MESH)
+    assert MESH not in R.cells(before)
+    assert "tpch_sf10_mesh4" not in [c["name"] for c in before["configs"]]
+    assert MESH not in [c for m in before["end_to_end"] + before["per_layer"]
+                        for c in m.get("workloads", ())]
     assert R.broken(before) == {}
-    (cell,) = [w for w in BENCH["workloads"] if w["name"] == MESH]
-    again = R.with_cell(before, cell, like=LIKE)
-    for key in ("workloads", "end_to_end", "per_layer"):
-        assert again[key] == BENCH[key]
+    assert R.stands_beside(BENCH, MESH) == LIKE
+    assert R.round_trip(BENCH, MESH) == []
 
 
 #: entries that only a device trace or the device's allocator can give

@@ -56,14 +56,19 @@ def test_each_entry_has_one_file_a_reader_and_the_one_cell(name):
     assert set(spec) == {"layer", "unit", "reader", "selector", "what"}
     assert spec["reader"] == ENTRIES[name][0]
     (m,) = [m for m in BENCH["per_layer"] if m["name"] == name]
-    assert m == {"name": name, "unit": spec["unit"],
-                 "better": ENTRIES[name][1], "source": "program_counter",
-                 "layer": "scan", "moves": "query_p90_ms",
-                 "workloads": [CELL]}
+    assert dict(m, workloads=None) == {
+        "name": name, "unit": spec["unit"], "better": ENTRIES[name][1],
+        "source": "program_counter", "layer": "scan",
+        "moves": "query_p90_ms", "workloads": None}
     assert spec["layer"] == "scan"
-    # new entries stand at the end of their list
-    assert [x["name"] for x in BENCH["per_layer"][-3:]] == [
-        "resident_mb", "resident_hits", "resident_bypassed"]
+    # kept, once, in the order the three came in and each listing the
+    # cell it came with — wherever in the list they stand: the rules'
+    assert name in R.KEPT and R.names_kept(BENCH) == []
+    assert R.IN_ORDER == ("resident_mb", "resident_hits",
+                          "resident_bypassed")
+    assert R.IN_ORDER_LIST == CELL and R.kept_in_order(BENCH) == []
+    assert CELL in m["workloads"]
+    assert {R.family(BENCH, c) for c in m["workloads"]} == {"query_p90_ms"}
 
 
 def test_the_resident_reader_on_a_made_up_context():
@@ -132,12 +137,15 @@ def test_the_configuration_is_tpch_sf1s_in_all_but_scale_and_residency():
     assert {k: cfg["guarantees"][k] for k in sf1["guarantees"]} == \
         sf1["guarantees"]
     assert "resident_bypassed 0" in cfg["guarantees"]["residency"]
-    # the cell is the SF1 scan cell's pair: the same traffic file, and
-    # listed wherever that cell is and nowhere else
+    # the cell is the SF1 scan cell's pair in scale: the same traffic
+    # file, listed at least wherever that cell is, and beyond it by
+    # entries of the scan layer alone, PR 41's three among them (the
+    # rule ``scaled_pairs``)
     cells = {w["name"]: w for w in BENCH["workloads"]}
     assert cells[CELL]["traffic"] == cells[PAIR]["traffic"]
     assert (cells[CELL]["config"], cells[CELL]["chips"]) == ("tpch_sf10", 1)
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
-        if "workloads" in m and m["name"] not in ENTRIES:
-            assert (CELL in m["workloads"]) == (PAIR in m["workloads"]), m
+    assert R.PAIRS[CELL] == PAIR and R.scaled_pairs(BENCH) == []
+    mine, theirs = R.listed_by(BENCH, CELL), R.listed_by(BENCH, PAIR)
+    assert set(theirs) <= set(mine) and set(ENTRIES) <= set(mine) - set(theirs)
+    assert {mine[n]["layer"] for n in set(mine) - set(theirs)} == {"scan"}
     assert R.broken(BENCH) == {}
