@@ -69,7 +69,13 @@ from presto_tpu.exec.operators import (
 )
 from presto_tpu.exec.ladder import OomLadderMixin
 from presto_tpu.exec.pipeline import BatchSource, Pipeline
-from presto_tpu.expr import BIGINT, evaluate, bind_scalars, param_scope
+from presto_tpu.expr import (
+    BIGINT,
+    InputRef,
+    bind_scalars,
+    evaluate,
+    param_scope,
+)
 from presto_tpu.ops.groupby import gather_padded, sorted_group_reduce
 from presto_tpu.ops.hashing import partition_ids
 from presto_tpu.ops.pallas_mode import count_program
@@ -140,23 +146,27 @@ import functools
 
 
 @functools.lru_cache(maxsize=64)
-def _compact_step(mesh, out_cap: int):
+def _compact_step(mesh, out_cap: int, name: str = "dist_compact_step"):
     """Compiled per-device compaction, cached per (mesh, capacity) so
     repeated guarded replications reuse the XLA program: the live rows
     first, every column's data and ``valid`` moved by ONE gather of
-    packed rows (``ops/partition.take_rows``) — the exchange's mover."""
+    packed rows (``ops/partition.take_rows``) — the exchange's mover.
+    ``name`` is the program's on the device's lines (``jit_<name>``):
+    the compaction before a hash exchange keeps the default, one that
+    precedes no exchange (the TopN's) names itself."""
     from presto_tpu.cache.exec_cache import trace_probe
     from presto_tpu.ops.compact import compact_indices
     from presto_tpu.ops.partition import take_rows
 
     ax = worker_axes(mesh)
-    @partial(shard_map, mesh=mesh, in_specs=(P(ax),), out_specs=P(ax),
-             check_vma=False)
+
     def dist_compact_step(local):
         trace_probe()
         return take_rows(local, compact_indices(local.live, out_cap)[0])
 
-    return jax.jit(dist_compact_step)
+    dist_compact_step.__name__ = name
+    return jax.jit(shard_map(dist_compact_step, mesh=mesh, in_specs=(P(ax),),
+                             out_specs=P(ax), check_vma=False))
 
 
 @functools.lru_cache(maxsize=8)
@@ -470,22 +480,31 @@ class DistributedExecutor(OomLadderMixin):
         if counts is None:
             counts = self._device_live_counts(d)
         rows = int(counts.sum())
-        b = d.batch
         if not d.sharded:
             return d, rows
+        out = self._compacted(d.batch, counts, "step:exchange_compact",
+                              "dist_compact_step", site)
+        if out is None:
+            REGISTRY.counter("exchange.compact_skipped").add()
+            return d, rows
+        REGISTRY.counter("exchange.compacted").add()
+        REGISTRY.counter("exchange.compact_slots_in").add(d.batch.capacity)
+        REGISTRY.counter("exchange.compact_slots_out").add(out.capacity)
+        return DistBatch(out, sharded=True), rows
+
+    def _compacted(self, b: Batch, counts: np.ndarray, span: str, name: str,
+                   site: str) -> Batch | None:
+        """The sharded ``b`` compacted per device to ``exchange_
+        capacity`` of its fullest device's live rows (``counts``), under
+        the span ``span`` by the program ``name`` — or None where that
+        does not at least halve its slots."""
         cap2 = exchange_capacity(int(counts.max()), self.nworkers)
         slots_out = self.nworkers * cap2
         if 2 * slots_out > b.capacity:
-            REGISTRY.counter("exchange.compact_skipped").add()
-            return d, rows
-        with trace_span("step:exchange_compact", "step",
-                        {"site": site, "slots_in": b.capacity,
-                         "slots_out": slots_out}):
-            out = _compact_step(self.mesh, cap2)(b)
-        REGISTRY.counter("exchange.compacted").add()
-        REGISTRY.counter("exchange.compact_slots_in").add(b.capacity)
-        REGISTRY.counter("exchange.compact_slots_out").add(slots_out)
-        return DistBatch(out, sharded=True), rows
+            return None
+        with trace_span(span, "step", {"site": site, "slots_in": b.capacity,
+                                       "slots_out": slots_out}):
+            return _compact_step(self.mesh, cap2, name)(b)
 
     # ---- exchange-skew telemetry -----------------------------------------
     def _note_exchange_skew(self, site: str, node, dest, row_bytes: int,
@@ -1221,7 +1240,9 @@ class DistributedExecutor(OomLadderMixin):
             or not left.sharded
         ):
             self._count_distribution("broadcast")
-            return self._broadcast_join(node, left, right, lkey, rkey, verify)
+            # the build's live rows are read already: no second count
+            return self._broadcast_join(node, left, right, lkey, rkey, verify,
+                                        rows_hint=build_rows)
         self._count_distribution("repartition")
         # adaptive skew salting (plan/adaptive.py): recurring-history
         # hot destination -> spread probe rows / replicate build rows
@@ -1276,9 +1297,26 @@ class DistributedExecutor(OomLadderMixin):
         # when chosen because a side is unsharded (not because the build
         # is small), an oversized build must fail fast, not silently
         # multiply HBM by the mesh size
+        from presto_tpu.exec.local_planner import (
+            build_key_interval,
+            dense_domain,
+            key_upper_bound,
+        )
+
         rb = self._replicate(right, guard="BroadcastJoinBuild",
                              rows_hint=rows_hint).batch
-        build = JoinBuildOperator(rkey, params=self.params)
+        # the local executor's stats-driven probe choice, on the
+        # replica every device holds: a declared key domain (a star's
+        # surrogate keys) is probed by ONE gather of a direct-address
+        # table, not by the sorted build's search over every probe slot
+        iv = (build_key_interval(node.right, node.right_keys, self.catalog)
+              if node.unique else None)
+        rows = None if iv is None else (
+            rows_hint if rows_hint is not None else live_count(rb))
+        build = JoinBuildOperator(
+            rkey, dense_domain=dense_domain(iv, rows),
+            key_max=key_upper_bound(iv) if node.unique else None,
+            params=self.params)
         build.process(rb)
         build.finish()
         outs = [BuildOutput(n, n) for n in node.output_right]
@@ -1362,8 +1400,6 @@ class DistributedExecutor(OomLadderMixin):
         ~1x delivered-row balance (EXPLAIN: ``repartition=salted(S)``).
         FULL OUTER is excluded upstream: its unmatched-build tail would
         emit one NULL-extended row per REPLICA."""
-        from presto_tpu.expr import InputRef
-
         # runtime backstop mirroring LookupJoinOperator._check_probe_dict:
         # dictionary codes from two different dictionaries must never be
         # hashed/partitioned/joined as if comparable (the planner's
@@ -2050,21 +2086,63 @@ class DistributedExecutor(OomLadderMixin):
         data movement). Unsharded children are resharded first; the
         concat + dictionary alignment is ``_concat_sharded_many``."""
         names = node.field_names()
-        parts = []
-        for c in node.inputs:
-            d = self._exec(c, scalars)
-            b = d.batch.select(names)
-            if not d.sharded:
-                b = self._shard(_pad_rows(b, -(-b.capacity // self.nworkers)
-                                          * self.nworkers))
-            parts.append(b)
-        return self._concat_sharded_many(parts, names=list(names))
+        # counted as the local executor's: a nested union is not a
+        # branch, its own leaves are counted when it executes
+        REGISTRY.counter("exec.union.inputs").add(
+            sum(not isinstance(c, N.Union) for c in node.inputs))
+        parts = [self._exec(c, scalars) for c in node.inputs]
+        return self._concat_parts(parts, list(names))
+
+    def _concat_parts(self, parts: list[DistBatch], names: list) -> DistBatch:
+        """The bag union of ``parts`` as one sharded batch: an unsharded
+        part is resharded first (its rows land on whichever devices its
+        padded row axis puts them), then ``_concat_sharded_many``."""
+        Pn = self.nworkers
+        return self._concat_sharded_many(
+            [d.batch.select(names) if d.sharded else self._shard(_pad_rows(
+                d.batch.select(names), -(-d.batch.capacity // Pn) * Pn))
+             for d in parts], names=names)
 
     def _exec_groupingsets(self, node: N.GroupingSets, scalars) -> DistBatch:
-        """Grouping sets on the mesh: one grouped branch a set, each
-        over the child again (``GroupingSets.as_union``) — the local
-        executor's one-pass fold has no distributed twin (ROADMAP C1)."""
-        return self._exec(node.as_union(), scalars)
+        """ROLLUP / CUBE / GROUPING SETS over ONE evaluation of the
+        child, the local executor's level loop (``fold_grouping_sets``)
+        over the mesh's aggregations: the finest level through every
+        strategy ``_exec_aggregate`` has, each fold ``_fold_level``. The
+        sets' rows are concatenated per device (no collective)."""
+        from presto_tpu.exec.local_planner import fold_grouping_sets
+
+        def emit(d: DistBatch, exprs) -> DistBatch:
+            op = FilterProjectOperator(None, dict(exprs), params=self.params)
+            return DistBatch(op.process(d.batch)[0], d.sharded)
+
+        emitted = fold_grouping_sets(
+            node, self._exec_aggregate(node.finest, scalars),
+            partial(self._fold_level, node), self._device_live_counts, emit)
+        return self._concat_parts([d for d, _ in emitted],
+                                  [f.name for f in node.fields])
+
+    def _fold_level(self, node, keys, aggs, d: DistBatch, counts: np.ndarray,
+                    phase: str) -> DistBatch:
+        """A level's groups aggregated by ``keys`` (``fold_grouping_
+        sets``' ``fold``; ``counts``: the level's live groups a device,
+        already read). A sharded level is compacted to them and goes
+        through PARTIAL -> all_to_all -> FINAL (``_dist_grouped_agg``),
+        its aggregates merged by plain aggregates over its own columns
+        where ``phase`` is ``final``; an unsharded one, and the empty
+        set's one row, through the local executor's ``fold_level``."""
+        if d.sharded and keys:
+            d, _ = self._compact_for_exchange(d, "aggregate", counts=counts)
+            if phase == "final":
+                aggs = [AggSpec(a.merge_kind, InputRef(a.dtype, a.name),
+                                a.name, a.dtype) for a in aggs]
+            REGISTRY.counter("agg.strategy.partial").add()
+            return self._dist_grouped_agg(d.batch, keys, list(aggs), (),
+                                          node=node)
+        from presto_tpu.exec.local_planner import fold_level
+
+        out = fold_level(keys, aggs, [d.batch], int(counts.sum()), phase,
+                         self.params, self.direct_group_limit)
+        return DistBatch(out[0], sharded=False)
 
     # ---- window functions ------------------------------------------------
     def _exec_window(self, node: N.Window, scalars) -> DistBatch:
@@ -2082,20 +2160,30 @@ class DistributedExecutor(OomLadderMixin):
         op = window_operator_from_node(node, scalars, params=self.params)
         if d.sharded and self.nworkers > 1 and node.partition_by:
             part = [bind_scalars(e, scalars) for e in node.partition_by]
-            return self._partitioned_window(d, part, op)
+            return self._partitioned_window(d, part, op, node=node)
         d = self._replicate(d, guard="Window")
         out = Pipeline(BatchSource([d.batch]), [op]).run()
         return DistBatch(out[0], sharded=False)
 
-    def _partitioned_window(self, d: DistBatch, part_exprs, op) -> DistBatch:
+    def _partitioned_window(self, d: DistBatch, part_exprs, op,
+                            node=None) -> DistBatch:
+        """The window behind its FIXED_HASH exchange, one compiled step.
+        A window's partitions are few and uneven (a ROLLUP's levels, a
+        category), so a destination can own more than the twice a
+        device's share the step starts with: the retry doubles the
+        receive capacity and records the hot destination."""
         fault_point("exchange.window")
         Pn = self.nworkers
+        # every device sorts the slots it receives, live or not: the
+        # capacities follow what is live, as before every hash exchange
+        d, _ = self._compact_for_exchange(d, "window")
         b = d.batch
         cap_dev = max(b.capacity // Pn, 1)
         quota = batch_capacity(-(-cap_dev // Pn), minimum=64)
-        recv_cap = batch_capacity(2 * cap_dev, minimum=64)
         from presto_tpu.cache.exec_cache import EXEC_CACHE
 
+        recv_cap = batch_capacity(2 * cap_dev, minimum=64)
+        row_b = exchange_row_bytes(b)
         for _ in range(MAX_RETRIES):
             rc = recv_cap
             step = EXEC_CACHE.get_or_build(
@@ -2109,13 +2197,22 @@ class DistributedExecutor(OomLadderMixin):
             with trace_span("step:dist_window", "step",
                             {"quota": quota, "recv_cap": rc}), \
                     exchange_dispatch("window", Pn) as ex:
-                out, overflow, rounds = step(b, self.params)
+                out, overflow, rounds, dest = step(b, self.params)
                 with trace_sync("exchange_flags"):
                     ok = not bool(overflow)
                     r = ex["rounds"] = int(np.asarray(rounds))
-                ex["bytes"] = a2a_wire_bytes(
-                    exchange_row_bytes(b), Pn, quota, r)
+                ex["bytes"] = a2a_wire_bytes(row_b, Pn, quota, r)
+                if not ok:
+                    # the step's one flag is the exchange's: a window
+                    # has no group capacity of its own to overflow
+                    ex["hot_partition"] = self._hot_partition(dest)
             if ok:
+                self._note_exchange_skew("window", node, dest, row_b)
+                # as WindowOperator.finish counts its one step: the
+                # slots every device's sort covers, live or not
+                REGISTRY.counter("exec.window.dispatches").add()
+                REGISTRY.counter("exec.window.inputs").add()
+                REGISTRY.counter("exec.window.slots").add(Pn * rc)
                 return DistBatch(out, sharded=True)
             recv_cap *= 2
         raise CapacityOverflow("PartitionedWindow", recv_cap)
@@ -2148,18 +2245,18 @@ class DistributedExecutor(OomLadderMixin):
 
         @partial(
             shard_map, mesh=self.mesh,
-            in_specs=(P(axes), P()), out_specs=(P(axes), P(), P()),
+            in_specs=(P(axes), P()), out_specs=(P(axes), P(), P(), P()),
             check_vma=False,
         )
         def dist_window_step(local: Batch, params=()):
             trace_probe()
             with param_scope(params):
                 pids = partition_ids(hash_cols(local), Pn)
-                exch, ovf, rounds = exchange_multiround(
+                exch, ovf, rounds, dest = exchange_multiround(
                     local, pids, Pn, quota, recv_cap, axes=axes,
-                    with_rounds=True)
+                    with_rounds=True, with_stats=True)
                 out = window_body(exch, params)
-                return out, any_flag(ovf, axes), rounds
+                return out, any_flag(ovf, axes), rounds, dest
 
         return jax.jit(dist_window_step)
 
@@ -2195,10 +2292,24 @@ class DistributedExecutor(OomLadderMixin):
         """Local-first TopN: each device keeps its own top n, only the
         P*n survivors are gathered for the final pass (reference:
         partial TopN below the exchange [SURVEY §2.1 TopNOperator])."""
+        from presto_tpu.exec.local_planner import SORT_COMPACT_SLOTS
+
         d = self._exec(node.child, scalars)
         keys = [SortKey(bind_scalars(k.expr, scalars), k.descending, k.nulls_first)
                 for k in node.keys]
         if d.sharded and self.nworkers > 1:
+            if d.batch.capacity >= SORT_COMPACT_SLOTS:
+                # the local TopN's rule (``_compact_large``): a sort
+                # operand of 2^20 slots or more follows what is live
+                # (q67 keeps ~1 k ranked rows of 2 M slots). No
+                # exchange follows: a span, a program and a counter of
+                # its own, none of ``exchange.compact*``
+                out = self._compacted(
+                    d.batch, self._device_live_counts(d),
+                    "step:topn_compact", "dist_topn_compact_step", "topn")
+                if out is not None:
+                    REGISTRY.counter("exec.topn.compacted").add()
+                    d = DistBatch(out, sharded=True)
             d = self._local_topn(d, keys, node.count)
         # normally P*n survivors; a huge n degenerates to replicating
         # the table, which the gather guard must still catch

@@ -204,6 +204,153 @@ def count_live_rows(batches) -> list[int]:
         return [live_count(b) for b in batches]
 
 
+def build_key_interval(node_right, right_keys, catalog):
+    """Stats (min, max) interval of a single build key, or None —
+    computed ONCE per join; the dense-domain and packed-build decisions
+    (``dense_domain`` / ``key_upper_bound``, which the mesh's broadcast
+    join takes too) both derive from it."""
+    if len(right_keys) != 1:
+        return None
+    from presto_tpu.plan.bounds import expr_interval, node_intervals
+
+    return expr_interval(right_keys[0], node_intervals(node_right, catalog))
+
+
+def key_upper_bound(iv):
+    """Packed-build bound: a non-negative stats max (None otherwise)."""
+    if iv is None or iv[0] < 0:
+        return None
+    return int(iv[1])
+
+
+def dense_domain(iv, rows):
+    """(key_min, domain) when the stats interval is tight enough
+    for a dense direct-address table — the planner's stats-driven
+    probe-kernel choice (one gather vs a probe-side sort). None
+    falls back to the sorted build."""
+    if iv is None:
+        return None
+    domain = iv[1] - iv[0] + 1
+    # < 2^31: the probe gathers with int32 indices (ops/join.py —
+    # a wider domain would wrap the index and silently mis-match)
+    if 0 < domain <= min(max(1 << 20, 16 * rows), (1 << 31) - 1):
+        return (iv[0], int(domain))
+    return None
+
+
+def run_hash_agg(child: BatchStream, keys, aggs, pax, strategy,
+                 sort_strategy, phase: str = "single",
+                 params: Sequence[Any] = ()) -> BatchStream:
+    """One keyed aggregation of ``child`` under the re-plan loop its
+    state's flags ask for; ``sort_strategy()`` is the strategy that
+    groups NULL keys."""
+    from presto_tpu.ops.groupby import ValueBitsOverflow
+
+    for attempt in range(MAX_RETRIES):
+        op = HashAggregationOperator(keys, aggs, strategy, phase=phase,
+                                     passengers=pax, params=params)
+        try:
+            # draining the (replayable) child stream folds one morsel
+            # at a time into device-resident state — bounded memory
+            return BatchStream.of(Pipeline(child, [op]).run())
+        except ValueBitsOverflow:
+            aggs = [dataclasses.replace(a, value_bits=63) for a in aggs]
+        except NullGroupKeys:
+            # the packed direct domain has no NULL slot; re-plan on
+            # the sort strategy, which groups NULL as its own value
+            strategy = sort_strategy()
+        except CapacityOverflow as e:
+            # only THIS aggregation's group overflow is retryable
+            # here — an overflow raised by the lazy child stream
+            # (e.g. a join under it) must propagate to its owner,
+            # not double our group capacity 6 times
+            if e.op != "HashAggregation":
+                raise
+            if not isinstance(strategy, SortStrategy):
+                raise
+            strategy = SortStrategy(strategy.max_groups * 2)
+    raise CapacityOverflow("Aggregate", strategy.max_groups)
+
+
+def fold_level(keys, aggs, source: list[Batch], rows: int, phase: str,
+               params: Sequence[Any] = (),
+               direct_limit: int = DIRECT_LIMIT) -> list[Batch]:
+    """The groups of ``source`` — a level that holds ``keys``, on one
+    device or replicated — aggregated by ``keys``: ``final`` merges the
+    level's aggregates, ``single`` evaluates ``aggs`` over its columns.
+    ``rows``, the source's live groups, bounds what can be live here."""
+    if not keys:
+        from presto_tpu.exec.operators import GlobalAggregationOperator
+
+        op = GlobalAggregationOperator(aggs, phase=phase, params=params)
+        return Pipeline(source, [op]).run()
+
+    def dict_len(name: str):
+        d = source[0][name].dictionary if source else None
+        return len(d) if d is not None else None
+
+    def strategy(limit: int):
+        return pick_group_strategy(keys, (), dict_len, rows,
+                                   direct_limit=limit)
+
+    return run_hash_agg(
+        BatchStream.of(source), keys, list(aggs), (), strategy(direct_limit),
+        lambda: strategy(0), phase=phase, params=params).materialize()
+
+
+def fold_grouping_sets(node: N.GroupingSets, finest, fold, live_rows, emit):
+    """The level loop of a ``GroupingSets`` node, for both executors:
+    ``finest`` — the child's rows aggregated by all the keys, as the
+    executor holds a level — answers the child once, and each set is
+    folded from the smallest level already answered that holds its keys
+    (``node.parents()``: a ROLLUP's levels chain) with the level's
+    aggregates MERGED (phase ``final``: sum of sums and of counts, min
+    of mins, max of maxes). Under count(distinct) the distinct column
+    is a key of every level, and a set's rows are its level's groups
+    aggregated once more without it (``node.finals``, phase ``single``).
+    A set's rows leave as ``node.set_exprs`` says: its absent keys
+    NULL, its ordinal under ``node.gid``.
+
+    The executor hands in how it aggregates a level:
+    ``fold(keys, aggs, level, rows, phase) -> level``, ``live_rows
+    (level)`` — what ``fold`` takes as ``rows``, read at most once a
+    level — and ``emit(level, exprs)``. Returns, a set, what ``emit``
+    gave and its level's ``rows`` where they were read."""
+    from presto_tpu.runtime.metrics import REGISTRY
+
+    REGISTRY.counter("exec.grouping_sets.sets").add(len(node.sets))
+    # what the union expansion counted; named so that it reads 0
+    REGISTRY.counter("exec.union.inputs")
+    # level -1 is the finest; a set that is not all the keys is a
+    # level of its own, under its ordinal
+    levels = {-1: finest}
+    rows: dict = {}
+
+    def live(lv: int):
+        if lv not in rows:
+            rows[lv] = live_rows(levels[lv])
+        return rows[lv]
+
+    level_of: list[int] = []
+    for i, (s, parent) in enumerate(zip(node.sets, node.parents())):
+        if len(s) == len(node.keys):
+            level_of.append(-1)
+            continue
+        src = -1 if parent < 0 else level_of[parent]
+        levels[i] = fold(node.key_refs(s), node.aggs, levels[src],
+                         live(src), "final")
+        level_of.append(i)
+        REGISTRY.counter("exec.grouping_sets.folds").add()
+    emitted = []
+    for i, (s, lv) in enumerate(zip(node.sets, level_of)):
+        level, known = levels[lv], rows.get(lv)
+        if node.finals:
+            level, known = fold(node.key_refs(s[:-1]), node.finals, level,
+                                live(lv), "single"), None
+        emitted.append((emit(level, node.set_exprs(i)), known))
+    return emitted
+
+
 class LocalExecutor(OomLadderMixin):
     #: the cross-query batched dispatcher (server/batcher.py) can stack
     #: this executor's param bindings into one vmapped dispatch — the
@@ -638,94 +785,32 @@ class LocalExecutor(OomLadderMixin):
         else:
             REGISTRY.counter("agg.strategy.partial").add()
         fault_point("step.agg")
-        return self._run_hash_agg(
+        return run_hash_agg(
             child, keys, aggs, pax, strategy,
             lambda: self._pick_group_strategy(keys, pax, node, child,
-                                              force_sort=True))
-
-    def _run_hash_agg(self, child: BatchStream, keys, aggs, pax, strategy,
-                      sort_strategy, phase: str = "single") -> BatchStream:
-        """One keyed aggregation of ``child`` under the re-plan loop its
-        state's flags ask for; ``sort_strategy()`` is the strategy that
-        groups NULL keys."""
-        from presto_tpu.ops.groupby import ValueBitsOverflow
-
-        for attempt in range(MAX_RETRIES):
-            op = HashAggregationOperator(keys, aggs, strategy, phase=phase,
-                                         passengers=pax, params=self.params)
-            try:
-                # draining the (replayable) child stream folds one morsel
-                # at a time into device-resident state — bounded memory
-                return BatchStream.of(Pipeline(child, [op]).run())
-            except ValueBitsOverflow:
-                aggs = [dataclasses.replace(a, value_bits=63) for a in aggs]
-            except NullGroupKeys:
-                # the packed direct domain has no NULL slot; re-plan on
-                # the sort strategy, which groups NULL as its own value
-                strategy = sort_strategy()
-            except CapacityOverflow as e:
-                # only THIS aggregation's group overflow is retryable
-                # here — an overflow raised by the lazy child stream
-                # (e.g. a join under it) must propagate to its owner,
-                # not double our group capacity 6 times
-                if e.op != "HashAggregation":
-                    raise
-                if not isinstance(strategy, SortStrategy):
-                    raise
-                strategy = SortStrategy(strategy.max_groups * 2)
-        raise CapacityOverflow("Aggregate", strategy.max_groups)
+                                              force_sort=True),
+            params=self.params)
 
     # ---- grouping sets ----------------------------------------------------
     def _exec_groupingsets(self, node: N.GroupingSets, scalars):
         """ROLLUP / CUBE / GROUPING SETS over ONE evaluation of the
-        child: the finest level — a plain aggregation by all the keys,
-        through every strategy ``_exec_aggregate`` has — answers the
-        child's rows, and each set is folded from the smallest level
-        already answered that holds its keys (``node.parents()``: a
-        ROLLUP's levels chain) by a final-phase aggregation of that
-        level's groups, sized by the live count just read. Every
-        aggregate kind an operator takes merges, so no set needs the
-        child's rows again; under count(distinct) the distinct column
-        is a key of every level and a set's rows are its level's groups
-        aggregated once more without it (``node.finals``). A set's rows
-        leave with its absent keys NULL and its ordinal under
-        ``node.gid``."""
-        from presto_tpu.runtime.metrics import REGISTRY
+        child (``fold_grouping_sets``): the finest level is a plain
+        aggregation by all the keys, through every strategy
+        ``_exec_aggregate`` has, and each fold a final-phase
+        aggregation of a level's groups, sized by the live count just
+        read (``fold_level``)."""
 
-        REGISTRY.counter("exec.grouping_sets.sets").add(len(node.sets))
-        # what the union expansion counted; named so that it reads 0
-        REGISTRY.counter("exec.union.inputs")
-        # level -1 is the finest; a set that is not all the keys is a
-        # level of its own, under its ordinal
-        levels = {-1: self._exec_aggregate(node.finest, scalars)
-                  .materialize()}
-        rows: dict[int, int] = {}
+        def fold(keys, aggs, source, rows, phase):
+            return fold_level(keys, aggs, source, rows, phase, self.params,
+                              self.direct_group_limit)
 
-        def live(lv: int) -> int:
-            if lv not in rows:
-                rows[lv] = sum(count_live_rows(levels[lv]))
-            return rows[lv]
+        def emit(batches, exprs):
+            op = FilterProjectOperator(None, dict(exprs), params=self.params)
+            return [op.process(b)[0] for b in batches]
 
-        level_of: list[int] = []
-        for i, (s, parent) in enumerate(zip(node.sets, node.parents())):
-            if len(s) == len(node.keys):
-                level_of.append(-1)
-                continue
-            src = -1 if parent < 0 else level_of[parent]
-            levels[i] = self._fold_level(
-                node.key_refs(s), node.aggs, levels[src], live(src), "final")
-            level_of.append(i)
-            REGISTRY.counter("exec.grouping_sets.folds").add()
-        emitted: list[tuple[list[Batch], int | None]] = []
-        for i, (s, lv) in enumerate(zip(node.sets, level_of)):
-            batches, known = levels[lv], rows.get(lv)
-            if node.finals:
-                batches, known = self._fold_level(
-                    node.key_refs(s[:-1]), node.finals, batches, live(lv),
-                    "single"), None
-            op = FilterProjectOperator(None, dict(node.set_exprs(i)),
-                                       params=self.params)
-            emitted.append(([op.process(b)[0] for b in batches], known))
+        emitted = fold_grouping_sets(
+            node, self._exec_aggregate(node.finest, scalars).materialize(),
+            fold, lambda batches: sum(count_live_rows(batches)), emit)
         # a large output leaves as ONE batch of its live rows' bucket,
         # whatever that saves (the counts are the folds' own where a
         # fold read one): a window or a TopN above sorts every slot it
@@ -736,32 +821,6 @@ class LocalExecutor(OomLadderMixin):
             rows=lambda: sum(
                 sum(count_live_rows(batches)) if known is None else known
                 for batches, known in emitted))
-
-    def _fold_level(self, keys, aggs, source: list[Batch], rows: int,
-                    phase: str) -> list[Batch]:
-        """The groups of ``source`` — a level that holds ``keys`` —
-        aggregated by ``keys``: ``final`` merges the level's aggregates,
-        ``single`` evaluates ``aggs`` over its columns. ``rows``, the
-        source's live groups, bounds what can be live here."""
-        if not keys:
-            from presto_tpu.exec.operators import GlobalAggregationOperator
-
-            op = GlobalAggregationOperator(aggs, phase=phase,
-                                           params=self.params)
-            return Pipeline(source, [op]).run()
-
-        def dict_len(name: str):
-            d = source[0][name].dictionary if source else None
-            return len(d) if d is not None else None
-
-        def strategy(direct_limit: int):
-            return pick_group_strategy(keys, (), dict_len, rows,
-                                       direct_limit=direct_limit)
-
-        return self._run_hash_agg(
-            BatchStream.of(source), keys, list(aggs), (),
-            strategy(self.direct_group_limit), lambda: strategy(0),
-            phase=phase).materialize()
 
     def _use_agg_bypass(self, node: N.Aggregate) -> bool:
         """The adaptive partial-aggregation bypass decision for one
@@ -957,24 +1016,6 @@ class LocalExecutor(OomLadderMixin):
             minmax_memo=self._minmax_memo,
         )
 
-    def _build_key_interval(self, node_right, right_keys):
-        """Stats (min, max) interval of a single build key, or None —
-        computed ONCE per join; the dense-domain and packed-build
-        decisions both derive from it."""
-        if len(right_keys) != 1:
-            return None
-        from presto_tpu.plan.bounds import expr_interval, node_intervals
-
-        return expr_interval(right_keys[0],
-                             node_intervals(node_right, self.catalog))
-
-    @staticmethod
-    def _key_upper_bound(iv):
-        """Packed-build bound: a non-negative stats max (None otherwise)."""
-        if iv is None or iv[0] < 0:
-            return None
-        return int(iv[1])
-
     @staticmethod
     def _build_rows(iv, right_batches):
         """The build side's live rows, read where a declared key
@@ -983,21 +1024,6 @@ class LocalExecutor(OomLadderMixin):
         if iv is None:
             return None
         return sum(count_live_rows(right_batches))
-
-    @staticmethod
-    def _dense_domain(iv, rows):
-        """(key_min, domain) when the stats interval is tight enough
-        for a dense direct-address table — the planner's stats-driven
-        probe-kernel choice (one gather vs a probe-side sort). None
-        falls back to the sorted build."""
-        if iv is None:
-            return None
-        domain = iv[1] - iv[0] + 1
-        # < 2^31: the probe gathers with int32 indices (ops/join.py —
-        # a wider domain would wrap the index and silently mis-match)
-        if 0 < domain <= min(max(1 << 20, 16 * rows), (1 << 31) - 1):
-            return (iv[0], int(domain))
-        return None
 
     # ---- sideways information passing ------------------------------------
     def _register_join_filter(self, node):
@@ -1298,14 +1324,15 @@ class LocalExecutor(OomLadderMixin):
                     "(verification cannot re-synthesize the null-extended "
                     "row)"
                 )
-            iv = (self._build_key_interval(node.right, node.right_keys)
+            iv = (build_key_interval(node.right, node.right_keys,
+                                     self.catalog)
                   if node.unique else None)
             rows = self._build_rows(iv, right)
             # dense/packed only help the UNIQUE probe; other probe kinds
             # would pay the advisory-stats refusal for no benefit
             build = JoinBuildOperator(
-                rkey, dense_domain=self._dense_domain(iv, rows),
-                key_max=self._key_upper_bound(iv) if node.unique else None,
+                rkey, dense_domain=dense_domain(iv, rows),
+                key_max=key_upper_bound(iv) if node.unique else None,
                 filter_bits=self._filter_bits(node.right) if fslot else 0,
                 params=self.params)
         Pipeline(BatchSource(right), [build]).run()
@@ -1632,10 +1659,11 @@ class LocalExecutor(OomLadderMixin):
             # semi/anti existence probes prefer the dense table when
             # stats allow; the packed build would be dead weight
             # (probe_exists has no packed path)
-            iv = self._build_key_interval(node.right, node.right_keys)
+            iv = build_key_interval(node.right, node.right_keys,
+                                    self.catalog)
             rows = self._build_rows(iv, right)
             build = JoinBuildOperator(
-                rkey, dense_domain=self._dense_domain(iv, rows),
+                rkey, dense_domain=dense_domain(iv, rows),
                 filter_bits=self._filter_bits(node.right) if fslot else 0,
                 params=self.params)
         Pipeline(BatchSource(right), [build]).run()

@@ -199,19 +199,6 @@ class GroupingSets(PlanNode):
             exprs += ((self.gid, Literal(INTEGER, i)),)
         return exprs
 
-    def as_union(self) -> "Union":
-        """The same rows as one grouped branch a set, each over the
-        child again: the mesh's plan (``exec/distributed.py`` has no
-        one-pass operator; no cell runs grouping sets there)."""
-        branches = []
-        for i, s in enumerate(self.sets):
-            agg = Aggregate(
-                self.child, tuple(self.keys[k] for k in s), self.aggs)
-            if self.finals:
-                agg = Aggregate(agg, tuple(self.key_refs(s[:-1])), self.finals)
-            branches.append(Project(agg, self.set_exprs(i)))
-        return Union(tuple(branches))
-
 
 @dataclass(frozen=True)
 class Window(PlanNode):

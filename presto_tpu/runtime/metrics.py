@@ -502,14 +502,16 @@ METRIC_HELP: dict[str, str] = {
         "taken in (nothing is evicted: what is held)"),
     "exec.window.dispatches": (
         "window steps dispatched (WindowOperator.finish: one sort by "
-        "partition and order keys, then segmented scans)"),
+        "partition and order keys, then segmented scans; on the mesh "
+        "the step behind the exchange on the partition keys, counted "
+        "once it fitted)"),
     "exec.window.inputs": (
         "buffered batches the window steps concatenated into their "
-        "one operand"),
+        "one operand (the mesh's step takes one sharded batch)"),
     "exec.window.slots": (
         "row slots the window steps sorted: the summed capacities of "
         "the batches concatenated, live or not (static shapes, no "
-        "device read)"),
+        "device read); on the mesh every device's receive capacity"),
     "exec.sort.steps": (
         "final-sort steps dispatched (OrderByOperator / TopNOperator."
         "finish: the held batches' concatenation, the key expressions, "
@@ -524,7 +526,9 @@ METRIC_HELP: dict[str, str] = {
         "TopN inputs of 2^20 slots or more compacted to their live "
         "rows' capacity bucket before the sort step, which sorts every "
         "slot it is handed (where that at least halves the slots; the "
-        "count is one sync:live_count read)"),
+        "count is one sync:live_count read; on the mesh a device's "
+        "shard, under step:topn_compact by jit_dist_topn_compact_step: "
+        "no exchange follows, none of exchange.compact* moves)"),
     "exec.probe.slots": (
         "row slots the unique, semi and anti join probes gathered over: "
         "the capacity of every batch handed to a probe step, live or "

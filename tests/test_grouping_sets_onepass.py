@@ -1,10 +1,11 @@
 """ROLLUP / CUBE / GROUPING SETS as ONE plan node over ONE evaluation of
-their input (``plan.nodes.GroupingSets``; ``LocalExecutor.
-_exec_groupingsets`` folds each set from the level below it, the mesh
-lowers the node to one grouped branch a set): every statement against
-pandas on both executors, and the counters that say the input was
-evaluated once (``exec.grouping_sets.*``, ``exec.union.inputs`` 0,
-``exec.scan.splits``)."""
+their input (``plan.nodes.GroupingSets``; both executors drive
+``local_planner.fold_grouping_sets``, which folds each set from the
+level below it — on the mesh through the shuffled aggregation since
+PR 49): every statement against pandas on both executors, and the
+counters that say the input was evaluated once
+(``exec.grouping_sets.*``, ``exec.union.inputs`` 0,
+``exec.scan.splits``; the mesh's are ``tests/test_tpcds_mesh4.py``'s)."""
 
 import numpy as np
 import pandas as pd

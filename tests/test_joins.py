@@ -243,12 +243,12 @@ def test_sql_join_uses_dense_when_stats_bound_the_key():
     # same query with stats disabled -> sorted path; answers must agree
     import presto_tpu.exec.local_planner as LP
 
-    orig_dd = LP.LocalExecutor.__dict__["_dense_domain"]  # keep staticmethod
-    LP.LocalExecutor._dense_domain = staticmethod(lambda *a: None)
+    orig_dd = LP.dense_domain
+    LP.dense_domain = lambda *a: None
     try:
         want = Session({"tpch": TpchConnector(sf=0.01)}).sql(q)
     finally:
-        LP.LocalExecutor._dense_domain = orig_dd
+        LP.dense_domain = orig_dd
     pd.testing.assert_frame_equal(got, want)
 
 

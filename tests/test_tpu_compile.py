@@ -406,17 +406,22 @@ def test_a_grouping_set_fold_compiles_in_seconds(one_chip, shape):
     init = op._sort_init if out_caps else op._direct_init
     state = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
                                    jax.eval_shape(init))
-    t0 = time.perf_counter()
+    # PR 42's limit of 120 s of wall clock, restated in the compiler's
+    # own CPU seconds at the ratio read alone (145 CPU s for 71 s of
+    # wall, 2.04: the same 1.7x of room): under the driver's six
+    # workers on a shared machine the wall read 132-147 s for 59-77
+    # alone (PERF.md §7, PR 48 (g))
+    t0 = time.process_time()
     text = op._update.lower(state, level(cap), ()).compile().as_text()
-    took = time.perf_counter() - t0
+    took = time.process_time() - t0
     assert "tpu_custom_call" not in text
     if out_caps:
-        t0 = time.perf_counter()
+        t0 = time.process_time()
         jax.jit(lambda bs: compact_batch(concat_batches(list(bs)), 1 << 20)
                 ).lower(tuple(level(c) for c in out_caps)).compile()
-        took += time.perf_counter() - t0
-    assert took < 120, f"{shape}: {took:.1f} s"
-    print(f"{shape}: compiled in {took:.1f} s")
+        took += time.process_time() - t0
+    assert took < 245, f"{shape}: {took:.1f} CPU s"
+    print(f"{shape}: compiled in {took:.1f} CPU s")
 
 
 # the local leaf route's unit of dispatch (exec/leaf_route.py): ONE
