@@ -48,6 +48,7 @@ from presto_tpu.ops.join import (
     probe_expand,
     probe_unique,
     probe_unique_dense,
+    sorted_positions,
 )
 from presto_tpu.ops.hashing import bloom_build
 from presto_tpu.ops.pallas_mode import count_program
@@ -680,7 +681,7 @@ def verified_unique_probe(side, key, verify, payload: Batch, batch: Batch):
 
     Distinct build values can collide on one hashed key, making the
     hashed key non-unique even though the original build keys are
-    unique — searchsorted alone would return one arbitrary colliding
+    unique — the position search alone would return one arbitrary colliding
     candidate and the bytes check would then wrongly reject the true
     match, silently dropping join rows. So scan the whole collision
     run (VERIFY_CANDIDATES wide; builds refuse longer runs via
@@ -689,7 +690,7 @@ def verified_unique_probe(side, key, verify, payload: Batch, batch: Batch):
     v = evaluate(key, batch)
     plive = batch.live & v.valid
     pk = jnp.where(plive, v.data.astype(jnp.int64), _I64_SENTINEL)
-    lo = jnp.searchsorted(side.sorted_keys, pk, side="left", method="sort")
+    lo = sorted_positions(side.sorted_keys, pk)
     cap = side.row_idx.shape[0]
     best = jnp.full(pk.shape, cap, side.row_idx.dtype)
     matched = jnp.zeros(pk.shape, jnp.bool_)

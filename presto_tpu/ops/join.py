@@ -6,10 +6,16 @@ reference tree unavailable].
 
 TPU-first (SURVEY §7.1): the "hash table" is a *sorted key array* —
 build compacts live rows and sorts them by key; probe is
-``searchsorted(method="sort")``, i.e. sort-merge: the probe keys are
-sorted and merged against the build keys (binary-search probing is
-~17x slower on TPU — its log2(B) dependent gathers serialize, while
-sorts ride the native sort unit; round 3, on another runtime).
+``sorted_positions``, i.e. sort-merge: the probe keys are sorted
+together with the build keys, each counts the build keys ahead of it,
+and a second sort brings the counts back to probe order (binary-search
+probing is ~17x slower on TPU — its log2(B) dependent gathers
+serialize, while sorts ride the native sort unit; round 3, on another
+runtime). It is what ``jnp.searchsorted(method="sort")`` computes,
+without that call's second ``argsort`` and its two permutation scatters
+(``zeros.at[argsort(x)].set(iota)``): 218 ms a search at 2^23 probe /
+2^21 build slots on this chip with the library call, 80 this way
+(PERF.md §6, PR 50).
 Duplicate build keys are handled by (lo, hi) range probes plus a
 prefix-sum expansion with a static output capacity and an overflow
 flag. FK->PK joins (unique build keys: most TPC-H joins) take the
@@ -26,8 +32,10 @@ from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from presto_tpu.ops.groupby import gather_padded
+from presto_tpu.runtime.metrics import REGISTRY
 
 
 class BuildSide(NamedTuple):
@@ -101,6 +109,66 @@ def build_lookup(keys, live, build_capacity: int,
                      sentinel_hit)
 
 
+_BLOCK = 256  # a block's count is exact in bfloat16, a superblock's in float32
+
+
+def _count_before(flags):
+    """How many of ``flags`` are set before each place (an exclusive
+    running count) with no scan over the rows: two levels of strictly
+    triangular matmuls — 256 places a block, 256 blocks a superblock;
+    0/1 and block counts in bfloat16, sums in float32, all exact — and
+    a ``cumsum`` over the superblocks' totals alone (one in 65,536)."""
+    n = flags.shape[0]
+    before = jnp.arange(_BLOCK)[:, None] < jnp.arange(_BLOCK)[None, :]
+    tri = before.astype(jnp.bfloat16)
+    f = jnp.pad(flags, (0, -n % (_BLOCK * _BLOCK))).reshape(
+        -1, _BLOCK, _BLOCK).astype(jnp.bfloat16)
+    in_block = jnp.einsum("sbk,kj->sbj", f, tri,
+                          preferred_element_type=jnp.float32)
+    blocks = jnp.sum(f, axis=2, dtype=jnp.float32)
+    in_super = jnp.einsum("sb,bj->sj", blocks.astype(jnp.bfloat16), tri,
+                          preferred_element_type=jnp.float32)
+    supers = jnp.sum(blocks, axis=1).astype(jnp.int32)
+    out = ((in_block + in_super[:, :, None]).astype(jnp.int32)
+           + (jnp.cumsum(supers) - supers)[:, None, None])
+    return out.reshape(-1)[:n]
+
+
+def sorted_positions(sorted_arr, query, side: str = "left"):
+    """``jnp.searchsorted(sorted_arr, query, side=side, method="sort")``
+    bit for bit (int32), with no scatter in the lowered program and one
+    ``argsort`` where that call has two: queries and array are sorted
+    together (stable — ties: queries first for ``left``, the array
+    first for ``right``), a query's position is the count of array
+    elements ahead of it in that order (``_count_before``), and ONE
+    single-operand unstable sort of the distinct words ``query index <<
+    bits | position`` (array elements behind every query; 32 bits where
+    they hold both, else 64) brings the positions back to query order.
+    ``join.search.sort_rank`` counts the lowerings (trace time: a warm
+    window reads 0)."""
+    REGISTRY.counter("join.search.sort_rank").add()
+    n, m = query.shape[0], sorted_arr.shape[0]
+    if n == 0 or m == 0:
+        return jnp.zeros(n, jnp.int32)
+    if side == "left":
+        order = jnp.argsort(jnp.concatenate([query, sorted_arr]),
+                            stable=True)
+        from_arr = order >= n
+        slot = order
+    else:
+        order = jnp.argsort(jnp.concatenate([sorted_arr, query]),
+                            stable=True)
+        from_arr = order < m
+        slot = jnp.where(from_arr, order + n, order - m)
+    low = m.bit_length()
+    kt = (jnp.uint32 if (n + m - 1).bit_length() + low <= 32
+          else jnp.uint64)
+    words = lax.sort(
+        (slot.astype(kt) << low) | _count_before(from_arr).astype(kt),
+        is_stable=False)
+    return (words[:n] & ((1 << low) - 1)).astype(jnp.int32)
+
+
 class UniqueProbe(NamedTuple):
     build_row: jnp.ndarray  # [probe_cap] build-side original row idx (cap = miss)
     matched: jnp.ndarray  # [probe_cap] bool
@@ -119,8 +187,7 @@ def probe_unique(build: BuildSide, probe_keys, probe_live,
     pk = probe_keys.astype(jnp.int64)
     if pack_bits is not None and build.packed is not None:
         target = pk << np.int64(pack_bits)
-        pos = jnp.searchsorted(build.packed, target, side="left",
-                               method="sort")
+        pos = sorted_positions(build.packed, target)
         hit = gather_padded(build.packed, pos, _I64_MAX)
         in_range = (pk >= 0) & (pk < (np.int64(1) << np.int64(62 - pack_bits)))
         matched = ((hit >> np.int64(pack_bits)) == pk) & probe_live & (
@@ -129,7 +196,7 @@ def probe_unique(build: BuildSide, probe_keys, probe_live,
         build_row = jnp.where(matched, (hit & mask).astype(jnp.int32),
                               build.row_idx.shape[0])
         return UniqueProbe(build_row, matched)
-    pos = jnp.searchsorted(build.sorted_keys, pk, method="sort")
+    pos = sorted_positions(build.sorted_keys, pk)
     hit_key = gather_padded(build.sorted_keys, pos, _I64_MAX)
     matched = (hit_key == pk) & probe_live & (pk != _I64_MAX)
     build_row = jnp.where(matched, gather_padded(build.row_idx, pos, 0), build.row_idx.shape[0])
@@ -164,8 +231,8 @@ def probe_expand(
     """
     probe_cap = probe_keys.shape[0]
     pk = jnp.where(probe_live, probe_keys.astype(jnp.int64), _I64_MAX)
-    lo = jnp.searchsorted(build.sorted_keys, pk, side="left", method="sort")
-    hi = jnp.searchsorted(build.sorted_keys, pk, side="right", method="sort")
+    lo = sorted_positions(build.sorted_keys, pk)
+    hi = sorted_positions(build.sorted_keys, pk, side="right")
     matches = jnp.where(probe_live & (pk != _I64_MAX), hi - lo, 0)
     el = probe_live if emit_live is None else emit_live
     counts = jnp.where(el & (matches == 0), 1, matches) if left else matches
@@ -174,7 +241,7 @@ def probe_expand(
 
     j = jnp.arange(out_capacity)
     # probe row owning output slot j: last i with offsets[i] <= j
-    probe_row = jnp.searchsorted(offsets, j, side="right", method="sort") - 1
+    probe_row = sorted_positions(offsets, j, side="right") - 1
     probe_row = jnp.clip(probe_row, 0, probe_cap - 1)
     rank = j - offsets[probe_row]
     valid = (j < total) & (rank >= 0) & (rank < counts[probe_row])
@@ -257,7 +324,7 @@ def probe_exists(build: BuildSide, probe_keys, probe_live):
     """Semi-join membership: True where the probe key exists in build.
     (reference: SetBuilderOperator / HashSemiJoinOperator)."""
     pk = probe_keys.astype(jnp.int64)
-    pos = jnp.searchsorted(build.sorted_keys, pk, method="sort")
+    pos = sorted_positions(build.sorted_keys, pk)
     hit_key = gather_padded(build.sorted_keys, pos, _I64_MAX)
     return (hit_key == pk) & probe_live & (pk != _I64_MAX)
 

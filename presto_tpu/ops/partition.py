@@ -12,7 +12,14 @@ gather before its round loop, contiguous slices inside it. On this chip
 a scatter costs 36–124 ns a row and a column gathered by a permutation
 16–20 ns an index; a gather of whole packed rows costs ONE index a row
 whatever its width (3 ns a row at 2^18 rows, 19 at 2^23) and a sort
-under 1 ns a key (PERF.md §6–§7, PR 26 / 30 / 35 / 45).
+under 1 ns a key (PERF.md §6–§7, PR 26 / 30 / 35 / 45). A scatter
+THROUGH a permutation (distinct targets, as the two inside
+``jnp.searchsorted(method="sort")`` were) is the cheap kind, ~7 ns an
+element at 2^23 — still dearer than the 64-bit single-operand sort
+that inverts the permutation (~2 ns a word), and a stable ``argsort``
+of an int64 key ~4–5 ns a key: the join probe's position search
+(``ops/join.sorted_positions``) took 218 ms at 2^23 probe / 2^21 build
+slots with the library call and takes 80 (PERF.md §6, PR 50).
 """
 
 from __future__ import annotations

@@ -653,6 +653,11 @@ METRIC_HELP: dict[str, str] = {
         "probe rows pruned by join-pushdown filters"),
     "join.filter_selectivity": (
         "observed selectivity of join-pushdown filters"),
+    "join.search.sort_rank": (
+        "position searches of a sorted join build lowered by "
+        "ops.join.sorted_positions (one argsort, a blocked count, one "
+        "packed sort, no scatter; one per search at trace time, so a "
+        "warm window reads 0)"),
     # ---- memory pool
     "memory.queue_timeouts": (
         "pool admissions that timed out waiting for capacity"),

@@ -439,3 +439,132 @@ def test_sql_join_packed_path_fires_and_matches():
             .groupby("n_name", as_index=False).size()
             .rename(columns={"size": "n"}).sort_values("n_name"))
     assert got["n"].tolist() == want["n"].tolist()
+
+
+_I64_MAX = np.iinfo(np.int64).max
+
+
+def _search_case(name, rng):
+    """(sorted array, queries) of one shape the probes hand the
+    position search; dead slots carry the int64 sentinel in both."""
+    def dead_tail(a, share):
+        a = np.sort(a.astype(np.int64))
+        a[len(a) - int(len(a) * share):] = _I64_MAX
+        return a
+
+    if name == "duplicates_in_the_array":
+        return np.sort(rng.integers(0, 5, 300)), np.arange(-1, 7)
+    if name == "duplicates_in_the_queries":
+        return np.arange(0, 90, 3), rng.integers(0, 90, 400)
+    if name == "duplicates_in_both":
+        return np.sort(rng.integers(0, 9, 200)), rng.integers(0, 9, 500)
+    if name == "below_above_between":
+        return (np.array([10, 20, 30]),
+                np.array([-5, 9, 10, 11, 15, 29, 30, 31, 10 ** 15,
+                          np.iinfo(np.int64).min]))
+    if name == "sentinel_in_both":
+        q = rng.integers(-50, 50, 257).astype(np.int64)
+        q[::3] = _I64_MAX
+        return dead_tail(rng.integers(-40, 40, 128), 0.25), q
+    if name == "no_live_probe":
+        return (dead_tail(rng.integers(0, 1000, 64), 0.5),
+                np.full(96, _I64_MAX))
+    if name == "no_live_build":
+        return np.full(32, _I64_MAX), rng.integers(0, 10, 50)
+    if name == "one_element_each":
+        return np.array([7]), np.array([7])
+    if name == "full_range_keys":
+        return (dead_tail(rng.integers(-2 ** 62, 2 ** 62, 3000), 0.1),
+                rng.integers(-2 ** 62, 2 ** 62, 5000))
+    if name == "several_superblocks":
+        return (dead_tail(rng.integers(0, 10 ** 6, 70_000), 0.2),
+                rng.integers(-5, 10 ** 6 + 5, 140_000))
+    # either side of the packed words' switch from 32 to 64 bits: an
+    # array of 2^15 slots takes 16 bits of a word for the position, and
+    # queries and array together 16 bits or 17 for the index
+    total = {"words_32_bit": 1 << 16, "words_64_bit": (1 << 16) + 1}[name]
+    return (dead_tail(rng.integers(0, 40_000, 1 << 15), 0.2),
+            rng.integers(-5, 40_005, total - (1 << 15)))
+
+
+@pytest.mark.parametrize("side", ["left", "right"])
+@pytest.mark.parametrize("case", [
+    "duplicates_in_the_array", "duplicates_in_the_queries",
+    "duplicates_in_both", "below_above_between", "sentinel_in_both",
+    "no_live_probe", "no_live_build", "one_element_each",
+    "full_range_keys", "several_superblocks", "words_32_bit",
+    "words_64_bit"])
+def test_sorted_positions_equals_searchsorted(rng, case, side):
+    """``sorted_positions`` is ``numpy.searchsorted`` and, bit for bit,
+    the library call it replaced."""
+    from presto_tpu.ops.join import sorted_positions
+
+    arr, q = (np.asarray(a, np.int64) for a in _search_case(case, rng))
+    got = sorted_positions(jnp.asarray(arr), jnp.asarray(q), side=side)
+    assert got.dtype == jnp.int32 and got.shape == q.shape
+    np.testing.assert_array_equal(got, np.searchsorted(arr, q, side=side))
+    was = jnp.searchsorted(jnp.asarray(arr), jnp.asarray(q), side=side,
+                           method="sort")
+    assert was.dtype == got.dtype
+    np.testing.assert_array_equal(got, was)
+
+
+@pytest.mark.parametrize("n", [1, 4096, 4097, 65536, 65537, 200_000])
+def test_count_before_is_an_exclusive_running_count(rng, n):
+    """One place, part of a superblock, one superblock and a place
+    over, several; all set / none set."""
+    from presto_tpu.ops.join import _count_before
+
+    for flags in (rng.random(n) < 0.3, np.ones(n, bool), np.zeros(n, bool)):
+        got = _count_before(jnp.asarray(flags))
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(
+            got, np.cumsum(flags) - flags.astype(np.int64))
+
+
+@pytest.mark.parametrize("probe", [
+    "probe_unique", "probe_unique_packed", "probe_expand",
+    "probe_expand_left", "probe_exists", "verified_unique_probe"])
+def test_lowered_probe_has_no_scatter(probe):
+    """The mechanism (after ``test_exchange_rows``' exchange): no probe
+    of a sorted build lowers to a scatter — the position search ranks by
+    sorts — and each counts its searches at trace time. (FULL OUTER's
+    ``flags.at[...].set`` lives in the callers, not in these.)"""
+    import jax
+
+    from presto_tpu.exec.joins import verified_unique_probe
+    from presto_tpu.ops import join as J
+    from presto_tpu.runtime.metrics import REGISTRY
+
+    cap, bcap, bits = 512, 128, 8
+
+    def f(bk, blive, pk, plive):
+        if probe == "probe_unique_packed":
+            side = J.build_lookup(bk, blive, bcap, pack_bits=bits)
+            return J.probe_unique(side, pk, plive, pack_bits=bits)
+        side = J.build_lookup(bk, blive, bcap)
+        if probe == "probe_unique":
+            return J.probe_unique(side, pk, plive)
+        if probe.startswith("probe_expand"):
+            return J.probe_expand(side, pk, plive, 1024,
+                                  left=probe.endswith("left"))
+        if probe == "probe_exists":
+            return J.probe_exists(side, pk, plive)
+        payload = Batch.from_numpy(
+            {"bk": np.zeros(bcap, np.int64)}, {"bk": BIGINT}, capacity=bcap)
+        batch = Batch.from_numpy(
+            {"pk": np.zeros(cap, np.int64)}, {"pk": BIGINT}, capacity=cap)
+        batch = batch.with_live(plive)
+        return verified_unique_probe(
+            side, col("pk", BIGINT), [(col("pk", BIGINT), col("bk", BIGINT))],
+            payload, batch)
+
+    searches = {"probe_expand": 3, "probe_expand_left": 3}.get(probe, 1)
+    before = REGISTRY.counter("join.search.sort_rank").total
+    text = jax.jit(f).lower(
+        jnp.zeros(bcap, jnp.int64), jnp.ones(bcap, jnp.bool_),
+        jnp.zeros(cap, jnp.int64), jnp.ones(cap, jnp.bool_)).as_text()
+    assert REGISTRY.counter("join.search.sort_rank").total \
+        == before + searches
+    assert "scatter" not in text
+    assert text.count("stablehlo.sort") >= 2 * searches
